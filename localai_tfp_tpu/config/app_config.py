@@ -61,7 +61,6 @@ class ApplicationConfig:
     node_name: str = ""
     # TPU-native:
     mesh_shape: dict[str, int] = field(default_factory=dict)
-    compilation_cache_dir: str = ""
 
     @classmethod
     def from_env(cls) -> "ApplicationConfig":
@@ -94,9 +93,6 @@ class ApplicationConfig:
         cfg.opaque_errors = _env("OPAQUE_ERRORS", cfg.opaque_errors, bool)
         cfg.machine_tag = _env("MACHINE_TAG", cfg.machine_tag)
         cfg.upload_limit_mb = int(_env("UPLOAD_LIMIT", cfg.upload_limit_mb))
-        cfg.compilation_cache_dir = _env(
-            "COMPILATION_CACHE_DIR", cfg.compilation_cache_dir
-        )
         galleries = _env("GALLERIES", None)
         if galleries:
             import json

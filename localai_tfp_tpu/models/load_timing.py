@@ -1,9 +1,9 @@
 """Phase-timing breakdown for model cold starts.
 
-BENCH_r05 reported ``checkpoint_load_s = 256.9`` in artifact mode where
-the load path's own annotation expects ~90 s — 167 seconds with no
-owner. This module is the instrument that makes such a gap impossible
-to hide: every load accumulates wall time into named phases
+An early bench round reported a checkpoint load far longer than the
+load path's own annotation expected, with no way to say where the
+difference went. This module is the instrument that makes such a gap
+impossible to hide: every load accumulates wall time into named phases
 
     read_s      host IO: checkpoint/artifact bytes off disk
     dequant_s   host compute: gguf dequantize, host-staged quantize

@@ -6,7 +6,7 @@
 // :266, StoresFindNormalized :373 normalized fast path, top-K heap :349).
 // Values stay on the Python side keyed by row id; this library owns the
 // numeric hot path: key storage, dedup, deletion compaction, and the
-// similarity scan (vectorized by the compiler at -O3 -march=native).
+// similarity scan (vectorized by the compiler at -O3).
 //
 // Build: make -C localai_tfp_tpu/native   (produces build/libvecstore.so)
 

@@ -203,8 +203,6 @@ def sharded_append_attend(
 
     Returns (out [S, H*Dh] sharded ("data", "model"), ck, cv, ks, vs).
     """
-    from jax.experimental.shard_map import shard_map
-
     from ..parallel.sharding import (
         BATCH_SPEC, DENSE_Q_SPEC, DENSE_ROW_SPEC, DENSE_SCALE_SPEC,
         KV_CACHE_SPEC, REPLICATED,
@@ -258,10 +256,10 @@ def sharded_append_attend(
             return out, ck, cv, ksc, vsc
         return out, ck, cv
 
-    # check_rep=False: the model-replicated scale buffers are updated with
+    # check_vma=False: the model-replicated scale buffers are updated with
     # identical values on every model shard (global-amax quantization), a
     # replication invariant shard_map cannot verify itself
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(*operands)

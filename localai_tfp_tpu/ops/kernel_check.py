@@ -1,18 +1,23 @@
 """Compiled-kernel parity checks, run on the REAL device.
 
 The pytest suite pins itself to CPU, where every Pallas kernel runs in
-interpret mode — a mosaic miscompile or tiling regression would ship
-silently (VERDICT r3 weak #4 / next #5). bench.py calls
-``run_kernel_checks()`` on the TPU each round and embeds the result in
-the bench JSON, so compiled-kernel correctness is a driver-captured
-artifact, not an assumption.
+interpret mode — a Mosaic compile error or tiling regression would ship
+silently. ``python -m localai_tfp_tpu.ops.kernel_check`` runs every
+check on whatever device JAX finds, prints one JSON object and exits
+non-zero unless all of them passed; chip_smoke.py runs it as its kernel
+phase, and it is the quick chip check after a kernel edit
+(``chiprun -- python -m localai_tfp_tpu.ops.kernel_check``).
 
-Each check compares the mosaic-compiled kernel against a straightforward
-XLA reference on identical random inputs and reports the max abs error.
+Each check compares the compiled kernel against a straightforward XLA
+reference on identical random inputs and reports the max abs error. The
+serving legs run at the head geometry, page size and row shapes the
+engine dispatches for one model (``Geometry``; the default is the
+chip_smoke model at its serving settings).
 """
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any
 
 import jax
@@ -201,81 +206,6 @@ def check_paged_gather(quantized: bool = False, seed: int = 0) -> float:
         want = _ref_decode_attention(
             q, dense_k, dense_v, 1, jnp.asarray(lengths), n_kv, scale)
     return float(jnp.max(jnp.abs(got - want)))
-
-
-_RAGGED_MIXES = ("decode", "prefill", "mixed", "verify")
-
-
-def check_ragged_attention(quantized: bool = False, seed: int = 0,
-                           mix: str = "mixed") -> float:
-    """Ragged-paged-attention parity: one kernel invocation over a
-    shuffled-page-table arena serving a ROW MIX — decode rows
-    (q_len 1), prefill chunk rows (q_len = chunk), spec-decode verify
-    rows (q_len = k+1) — against the dense XLA oracle. ``mix`` selects
-    the composition: decode-only, prefill-only, mixed, or
-    verify-heavy; fp and int8 legs share the tolerance budget of the
-    decode kernel (same accumulation discipline)."""
-    from ..models.transformer import _quantize_rows
-    from .ragged_paged_attention import (
-        ragged_attention_reference, ragged_paged_attention,
-    )
-
-    rng = np.random.default_rng(seed)
-    L, n_kv, dh, H, page = 2, 8, 128, 32, 128
-    F = n_kv * dh
-    B, max_pages = 6, 4
-    kd = 4
-    if mix == "decode":
-        q_lens = np.ones(B, np.int32)
-    elif mix == "prefill":
-        q_lens = rng.integers(2, 33, B).astype(np.int32)
-    elif mix == "verify":
-        q_lens = np.full(B, kd, np.int32)
-    else:  # mixed: decode rows + chunks + one verify row together
-        q_lens = np.asarray([1, 1, 7, 32, kd, 16], np.int32)[:B]
-    T = int(q_lens.max())
-    cap = max_pages * page
-    pos0 = np.asarray(
-        [int(rng.integers(0, cap - int(n))) for n in q_lens], np.int32)
-    n_pages = B * max_pages + 1
-    pt = rng.permutation(np.arange(1, n_pages)).reshape(
-        B, max_pages).astype(np.int32)
-    arena_k = rng.standard_normal((L, n_pages, page, F)) * 0.5
-    arena_v = rng.standard_normal((L, n_pages, page, F)) * 0.5
-    q = jnp.asarray(rng.standard_normal((B, T, H, dh)) * 0.3,
-                    jnp.float32)
-    layer = jnp.asarray(1, jnp.int32)
-    scale = 1.0 / np.sqrt(dh)
-    pt_j = jnp.asarray(pt)
-    pos_j = jnp.asarray(pos0)
-    len_j = jnp.asarray(q_lens)
-    if quantized:
-        kq, ks = _quantize_rows(jnp.asarray(arena_k, jnp.float32))
-        vq, vs = _quantize_rows(jnp.asarray(arena_v, jnp.float32))
-        got = ragged_paged_attention(
-            q.astype(jnp.bfloat16), kq, vq, layer, pt_j, pos_j, len_j,
-            n_kv, scale=scale, page=page, cache_k_scale=ks,
-            cache_v_scale=vs)
-        want = ragged_attention_reference(
-            q, kq, vq, 1, pt_j, pos_j, len_j, n_kv, scale=scale,
-            page=page, cache_k_scale=ks, cache_v_scale=vs)
-    else:
-        ak = jnp.asarray(arena_k, jnp.bfloat16)
-        av = jnp.asarray(arena_v, jnp.bfloat16)
-        got = ragged_paged_attention(
-            q.astype(jnp.bfloat16), ak, av, layer, pt_j, pos_j, len_j,
-            n_kv, scale=scale, page=page)
-        want = ragged_attention_reference(
-            q, ak, av, 1, pt_j, pos_j, len_j, n_kv, scale=scale,
-            page=page)
-    # pad queries beyond each row's ragged length are garbage by
-    # contract — compare the valid rows only
-    err = 0.0
-    for b in range(B):
-        n = int(q_lens[b])
-        err = max(err, float(jnp.max(jnp.abs(
-            got[b, :n] - want[b, :n]))))
-    return err
 
 
 def _tp_mesh(n_kv_heads: int):
@@ -482,66 +412,282 @@ def check_int8_matmul(seed: int = 0) -> float:
     return float(jnp.max(jnp.abs(got - want)))
 
 
-def run_kernel_checks() -> dict[str, Any]:
-    """All compiled-kernel parity numbers + a pass/fail verdict.
+@dataclasses.dataclass(frozen=True)
+class Geometry:
+    """The shapes the engine hands the ragged kernel for one model."""
 
-    Tolerances: attention outputs are O(1) post-softmax — bf16 inputs
-    put parity at ~1e-2; the int8 matmul accumulates in f32 over K=1024
-    with ~0.1-magnitude entries (sum magnitude ~30) — bf16 x-quantization
-    noise bounds parity at ~0.25 abs on that scale."""
-    out: dict[str, Any] = {}
-    try:
-        out["decode_attention_max_err"] = round(
-            check_decode_attention(False), 5)
-        out["decode_attention_int8_max_err"] = round(
-            check_decode_attention(True), 5)
-        out["paged_gather_max_err"] = round(check_paged_gather(False), 5)
-        out["paged_gather_int8_max_err"] = round(
-            check_paged_gather(True), 5)
-        # ragged unification: every row-kind composition through the
-        # ONE kernel (decode rows, prefill chunks, verify rows,
-        # shuffled page tables) vs the dense oracle
-        out["ragged_attention_max_err"] = round(max(
-            check_ragged_attention(False, mix=m)
-            for m in _RAGGED_MIXES), 5)
-        out["ragged_attention_int8_max_err"] = round(max(
-            check_ragged_attention(True, mix=m)
-            for m in _RAGGED_MIXES), 5)
-        # pod-scale legs: the shard_map'd append+attend wrapper and the
-        # GSPMD gather fallback over a "model"-sharded arena vs the same
-        # dense single-device oracles (skipped on 1-device hosts)
-        mm = check_meshed_ragged_attention(False, mix="mixed")
-        if mm is not None:
-            out["meshed_ragged_max_err"] = round(max(
-                mm, check_meshed_ragged_attention(False, mix="decode")),
-                5)
-            out["meshed_ragged_int8_max_err"] = round(max(
-                check_meshed_ragged_attention(True, mix=m)
-                for m in ("mixed", "decode")), 5)
-            out["meshed_paged_gather_max_err"] = round(
-                check_meshed_paged_gather(False), 5)
-            out["meshed_paged_gather_int8_max_err"] = round(
-                check_meshed_paged_gather(True), 5)
-        out["int8_matmul_max_err"] = round(check_int8_matmul(), 5)
-        out["ok"] = (
-            out["decode_attention_max_err"] < 2e-2
-            and out["decode_attention_int8_max_err"] < 5e-2
-            # paged kernel reads the same values through the table, so
-            # its tolerance matches the dense kernel's
-            and out["paged_gather_max_err"] < 2e-2
-            and out["paged_gather_int8_max_err"] < 5e-2
-            and out["ragged_attention_max_err"] < 2e-2
-            and out["ragged_attention_int8_max_err"] < 5e-2
-            # sharded legs read the same values through the same tables,
-            # so their tolerances match the dense legs'; the GSPMD
-            # gather is pure indexing — anything nonzero is a bug
-            and out.get("meshed_ragged_max_err", 0.0) < 2e-2
-            and out.get("meshed_ragged_int8_max_err", 0.0) < 5e-2
-            and out.get("meshed_paged_gather_max_err", 0.0) == 0.0
-            and out.get("meshed_paged_gather_int8_max_err", 0.0) == 0.0
-            and out["int8_matmul_max_err"] < 0.25
-        )
-    except Exception as e:  # a crash IS the finding — record it
-        out["error"] = f"{type(e).__name__}: {e}"
-        out["ok"] = False
+    n_heads: int = 32
+    n_kv_heads: int = 8
+    d_head: int = 128
+    page: int = 256  # the engine's page size at this max_seq
+    max_seq: int = 4096
+    n_slots: int = 16  # decode and mixed dispatches are [n_slots, T]
+    chunk: int = 2048  # largest prefill bucket: rides the kernel as a
+    # [1, chunk] "prefill" row and [B, chunk] prefill_final rows
+    mixed: int = 512  # largest bucket with n_slots * bucket inside the
+    # mixed/identity token budget
+
+
+# Mistral-7B-Instruct-v0.3 heads at context_size 4096 / 16 slots / the
+# default bucket ladder — what chip_smoke.py serves
+SERVING = Geometry()
+# same code paths at interpreter-friendly sizes (CPU debugging, tests)
+SMALL = Geometry(n_heads=4, n_kv_heads=2, d_head=128, page=16,
+                 max_seq=128, n_slots=3, chunk=64, mixed=32)
+
+
+def check_serving_rows(geom: Geometry, kind: str, cache: str,
+                       seed: int = 0) -> float:
+    """Max abs error of ONE ragged kernel invocation at the engine's
+    shapes against the dense oracle, for one dispatch kind:
+
+    - ``decode``: ``[n_slots, 1]`` seeded rows at ragged context lengths
+      (decode1 / decodek) — the T == 1 tiling case;
+    - ``chunk``: a ``[1, chunk]`` full-width prompt chunk deep in the
+      context (the "prefill" kind; the longest query row the engine
+      routes through the kernel);
+    - ``mixed``: ``[n_slots, mixed]`` with decode rows (q_len 1), short
+      and full chunks and a verify-sized row together, each at its own
+      context offset (mixed / identity prefill_final).
+
+    ``cache`` is the arena dtype: "bf16" and "int8" under a bf16 model
+    (queries and seed rows bf16), "f32" for an f32 model end to end.
+    The arena sits behind a shuffled page table with the trash page in
+    every unallocated entry."""
+    from ..models.transformer import _quantize_rows
+    from .ragged_paged_attention import (
+        ragged_attention_reference, ragged_paged_attention,
+    )
+
+    rng = np.random.default_rng(seed)
+    H, n_kv, dh, page = (geom.n_heads, geom.n_kv_heads, geom.d_head,
+                         geom.page)
+    F = n_kv * dh
+    max_pages = geom.max_seq // page
+    if kind == "decode":
+        B, T = geom.n_slots, 1
+        q_lens = np.ones(B, np.int32)
+        pos0 = rng.integers(0, geom.max_seq - 1, B).astype(np.int32)
+        pos0[0] = 0  # a first decode step: the seed row alone
+        pos0[-1] = geom.max_seq - 1  # the last position of the context
+    elif kind == "chunk":
+        B, T = 1, geom.chunk
+        q_lens = np.full(B, T, np.int32)
+        pos0 = np.asarray([geom.max_seq - T - 1], np.int32)
+    elif kind == "mixed":
+        B, T = geom.n_slots, geom.mixed
+        lens = [1, T, 1, max(T // 3, 1), 4, T - 1]
+        q_lens = np.asarray([lens[i % len(lens)] for i in range(B)],
+                            np.int32)
+        pos0 = np.asarray(
+            [int(rng.integers(0, geom.max_seq - int(n))) for n in q_lens],
+            np.int32)
+    else:
+        raise ValueError(f"unknown row kind {kind!r}")
+    L = 2
+    n_pages = B * max_pages + 1
+    pt = rng.permutation(np.arange(1, n_pages)).reshape(
+        B, max_pages).astype(np.int32)
+    for b in range(B):  # pages beyond the row's context are unallocated
+        used = -(-(int(pos0[b]) + int(q_lens[b])) // page)
+        pt[b, used:] = 0
+    arena_k = rng.standard_normal((L, n_pages, page, F), np.float32) * 0.5
+    arena_v = rng.standard_normal((L, n_pages, page, F), np.float32) * 0.5
+    q = jnp.asarray(
+        rng.standard_normal((B, T, H, dh), np.float32) * 0.3)
+    layer = jnp.asarray(1, jnp.int32)
+    scale = 1.0 / np.sqrt(dh)
+    pt_j, pos_j, len_j = (jnp.asarray(pt), jnp.asarray(pos0),
+                          jnp.asarray(q_lens))
+    act = jnp.float32 if cache == "f32" else jnp.bfloat16
+    if cache == "int8":
+        ak, ks = _quantize_rows(jnp.asarray(arena_k))
+        av, vs = _quantize_rows(jnp.asarray(arena_v))
+    else:
+        ak, av = jnp.asarray(arena_k, act), jnp.asarray(arena_v, act)
+        ks = vs = None
+    seed_kv = None
+    if kind == "decode":
+        # the current token's exact rows ride in VMEM; the HBM copy at
+        # pos0 is what the caller scatter-appended (masked in-kernel)
+        seed_kv = (
+            jnp.asarray(rng.standard_normal((B, F), np.float32) * 0.5,
+                        act),
+            jnp.asarray(rng.standard_normal((B, F), np.float32) * 0.5,
+                        act))
+    got = ragged_paged_attention(
+        q.astype(act), ak, av, layer, pt_j, pos_j, len_j, n_kv,
+        scale=scale, page=page, cache_k_scale=ks, cache_v_scale=vs,
+        seed_kv=seed_kv)
+    if not bool(jnp.all(jnp.isfinite(got))):
+        return float("inf")  # pad queries are garbage, never non-finite
+
+    @jax.jit
+    def oracle(q1, ak, av, ks, vs, pt1, pos1, len1, seed1):
+        return ragged_attention_reference(
+            q1, ak, av, 1, pt1, pos1, len1, n_kv, scale=scale,
+            page=page, cache_k_scale=ks, cache_v_scale=vs, seed_kv=seed1)
+
+    err = 0.0
+    for b in range(B):  # one row at a time: the oracle materializes
+        # [H, T, max_seq] f32 scores
+        sl = slice(b, b + 1)
+        want = oracle(q[sl], ak, av, ks, vs, pt_j[sl], pos_j[sl],
+                      len_j[sl],
+                      None if seed_kv is None
+                      else (seed_kv[0][sl], seed_kv[1][sl]))
+        n = int(q_lens[b])
+        err = max(err, float(jnp.max(jnp.abs(
+            got[b, :n] - want[0, :n]))))
+    return err
+
+
+def check_forward_parity(geom: Geometry, seed: int = 0) -> float:
+    """The kernel INSIDE the model: ``transformer.forward`` through the
+    ragged route (table scatter-append + kernel, as every paged engine
+    dispatch calls it) against the XLA gather/scatter route on the same
+    two-layer model at this head geometry — a prompt chunk, then one
+    decode step that attends what the chunk wrote. Returns the max
+    logit difference over both steps relative to the logit scale."""
+    from ..models.llm_spec import LLMSpec
+    from ..models.transformer import (
+        KVCache, forward, gather_kv_pages, init_params,
+    )
+
+    spec = LLMSpec(
+        vocab_size=512, d_model=256, n_layers=2, n_heads=geom.n_heads,
+        n_kv_heads=geom.n_kv_heads, d_head=geom.d_head, d_ff=512,
+        max_position=geom.max_seq)
+    params = init_params(jax.random.PRNGKey(seed), spec)
+    page = geom.page
+    B, T = 2, 16
+    max_pages = geom.max_seq // page
+    rng = np.random.default_rng(seed)
+    pt = jnp.asarray(rng.permutation(np.arange(1, B * max_pages + 1))
+                     .reshape(B, max_pages).astype(np.int32))
+    tokens = jnp.asarray(rng.integers(0, spec.vocab_size, (B, T)),
+                         jnp.int32)
+    q_lens = jnp.asarray([T, T - 5], jnp.int32)
+    worst = 0.0
+
+    def ragged(cache, toks, pos0, lens):
+        # the arena itself: table scatter-append + kernel
+        return forward(spec, params, toks, pos0, cache, None,
+                       page_table=pt, kv_page=page, q_lens=lens,
+                       write_table=pt)
+
+    def gathered(win, toks, pos0, lens):
+        # a dense window view, carried from step to step
+        return forward(spec, params, toks, pos0, win, None)
+
+    arena = KVCache.create(spec, B * max_pages + 1, page, jnp.bfloat16)
+    ones = jnp.ones((B,), jnp.int32)
+    outs = []
+    for step, cache in ((ragged, arena),
+                        (gathered, gather_kv_pages(arena, pt, page))):
+        l1, cache = jax.jit(step)(cache, tokens,
+                                  jnp.zeros((B,), jnp.int32), q_lens)
+        l2, _ = jax.jit(step)(cache, tokens[:, :1], q_lens, ones)
+        outs.append((l1, l2))
+    (r1, r2), (g1, g2) = outs
+    for b in range(B):
+        n = int(q_lens[b])
+        for r, g in ((r1[b, :n], g1[b, :n]), (r2[b], g2[b])):
+            worst = max(worst, float(
+                jnp.max(jnp.abs(r - g)) / (jnp.max(jnp.abs(g)) + 1e-6)))
+    return worst
+
+
+# (max abs error) budgets: attention outputs are O(1) post-softmax and
+# bf16 inputs put parity at ~1e-2; int8 pages add their rounding
+_TOL_FP, _TOL_INT8 = 2e-2, 5e-2
+_TOL_FORWARD = 5e-2  # relative to the logit scale, bf16 end to end
+
+
+def run_kernel_checks(geom: Geometry = SERVING) -> dict[str, Any]:
+    """Every compiled-kernel parity number plus the device they ran on
+    and a pass/fail verdict. A kernel that does not compile raises: a
+    crash here is the finding, and the caller (module entry, bench,
+    chip_smoke) must not mistake it for a result."""
+    from . import int8_matmul  # noqa: F401  off by default (ROADMAP D7)
+    # — not checked here, but it has to keep importing
+
+    dev = jax.devices()[0]
+    out: dict[str, Any] = {
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "device_count": len(jax.devices()),
+        "geometry": dataclasses.asdict(geom),
+    }
+    budget: dict[str, float] = {}
+
+    def leg(name: str, err: float, tol: float) -> None:
+        out[name] = round(err, 5)
+        budget[name] = tol
+
+    # the engine's own row shapes through the ONE kernel
+    for kind in ("decode", "chunk", "mixed"):
+        leg(f"serving_{kind}_max_err",
+            check_serving_rows(geom, kind, "bf16"), _TOL_FP)
+        leg(f"serving_{kind}_int8_max_err",
+            check_serving_rows(geom, kind, "int8"), _TOL_INT8)
+    # an f32 model (dtype: float32): f32 queries, seed rows and pages
+    for kind in ("decode", "mixed"):
+        leg(f"serving_{kind}_f32_max_err",
+            check_serving_rows(geom, kind, "f32"), _TOL_FP)
+    leg("forward_parity_rel_err", check_forward_parity(geom),
+        _TOL_FORWARD)
+    # the wrappers the non-default cache layouts use: dense cache viewed
+    # as pages (LOCALAI_PAGED_KV=off) and the page-table indirection
+    leg("decode_attention_max_err", check_decode_attention(False),
+        _TOL_FP)
+    leg("decode_attention_int8_max_err", check_decode_attention(True),
+        _TOL_INT8)
+    leg("paged_gather_max_err", check_paged_gather(False), _TOL_FP)
+    leg("paged_gather_int8_max_err", check_paged_gather(True), _TOL_INT8)
+    # pod-scale legs: the shard_map'd append+attend wrapper and the
+    # GSPMD gather fallback over a "model"-sharded arena vs the same
+    # dense single-device oracles (skipped on 1-device hosts). The
+    # gather is pure indexing — anything nonzero is a bug
+    if check_meshed_ragged_attention(False, mix="decode") is not None:
+        leg("meshed_ragged_max_err", max(
+            check_meshed_ragged_attention(False, mix=m)
+            for m in ("mixed", "decode")), _TOL_FP)
+        leg("meshed_ragged_int8_max_err", max(
+            check_meshed_ragged_attention(True, mix=m)
+            for m in ("mixed", "decode")), _TOL_INT8)
+        leg("meshed_paged_gather_max_err",
+            check_meshed_paged_gather(False), 0.0)
+        leg("meshed_paged_gather_int8_max_err",
+            check_meshed_paged_gather(True), 0.0)
+    out["failed"] = sorted(  # (NaN fails: it is not <= anything)
+        name for name, tol in budget.items() if not out[name] <= tol)
+    out["ok"] = not out["failed"]
     return out
+
+
+def main(argv: "list[str] | None" = None) -> int:
+    import argparse
+    import json
+
+    ap = argparse.ArgumentParser(
+        "python -m localai_tfp_tpu.ops.kernel_check",
+        description="compiled-kernel parity on the device JAX finds")
+    ap.add_argument("--small", action="store_true",
+                    help="interpreter-friendly sizes (CPU debugging)")
+    for f in dataclasses.fields(Geometry):
+        ap.add_argument("--" + f.name.replace("_", "-"), type=int,
+                        default=None)
+    args = ap.parse_args(argv)
+    geom = SMALL if args.small else SERVING
+    geom = dataclasses.replace(geom, **{
+        f.name: getattr(args, f.name)
+        for f in dataclasses.fields(Geometry)
+        if getattr(args, f.name) is not None})
+    res = run_kernel_checks(geom)
+    print(json.dumps(res))
+    return 0 if res["ok"] else 1
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

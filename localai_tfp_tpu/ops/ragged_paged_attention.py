@@ -24,14 +24,19 @@ Shapes:
   pos0[b] + t and attends positions [max(0, pos+1-window), pos].
 
 Design notes (see /opt/skills/guides/pallas_guide.md):
-- ONE grid step per row; an inner double-buffered manual-DMA loop walks
-  only that row's valid pages (a grid=(B, n_pages) formulation pays a
-  fixed ~5us cost per page of max_seq, valid or not — the measured
-  decode dominator on v5e, ops/decode_attention.py history).
-- logits are per-kv-head MXU contractions ``q_h [G, Dh] @ k_page_h.T``
-  with G = group * T query rows laid out [Hkv*G, Dh] — the multi-query
-  generalization of the decode kernel's one-matmul trick (whose
-  block-diagonal wq would cost F x T*H VMEM at prefill chunk sizes).
+- grid = (row, query block): an inner double-buffered manual-DMA loop
+  walks only the pages that block's queries can see (a grid over
+  max_seq pages pays a fixed per-page cost, valid or not). Query
+  blocking (``_q_tiling``) bounds the per-step VMEM footprint — a
+  2048-token chunk never holds an [n_heads * 2048, page] logits slab —
+  and blocks wholly beyond a row's q_len read nothing.
+- queries ride as ``[B, Hkv, T*group, Dh]``: the kernel picks a kv head
+  on a LEADING dim and contracts ``q_h [TQ*group, Dh] @ k_page_h.T`` on
+  the MXU; the per-head k/v bands are 128-lane-aligned column slices of
+  the head-flat page. No sublane slicing or stacking anywhere, so the
+  T == 1 / group 4 decode case lays out as whole tiles.
+- flash state (m, l, acc) lives in VMEM scratch, one slab per kv head,
+  m/l lane-replicated so every load/store is a full vreg.
 - int8 k/v pages dequantize by PER-ROW scales that commute through the
   row-wise contractions: the k scale multiplies logits on the kv axis
   and the v scale folds into pexp before the pv matmul — the MXU never
@@ -52,6 +57,7 @@ below is the dense-math oracle kernel_check compares against).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -64,111 +70,121 @@ from .decode_attention import _interpret
 
 NEG_INF = -1e30
 
+# query rows (n_heads * TQ) of f32 softmax state one grid step may hold.
+# At 32 heads this is TQ 64: per step ~1 MiB each of m/l/acc scratch,
+# a [256, page] f32 logits slab per kv head, and the double-buffered
+# q/out blocks — about 10 MiB, against which the limit below leaves the
+# compiler room for its own temporaries.
+_ROWS_PER_STEP = 2048
+_STAT_LANES = 128  # m/l scratch rows are lane-replicated (full vregs)
+_VMEM_LIMIT_BYTES = 48 * 1024 * 1024
+
+
+def _q_tiling(T: int, n_heads: int, group: int) -> tuple[int, int]:
+    """(TQ, Tp): query tokens per grid step and the padded query length.
+
+    Two constraints, both about what Mosaic will lay out:
+    - each kv head's query slab is ``[TQ * group, Dh]``; its row count
+      must fill whole sublane tiles (16 rows covers bf16 and f32), so TQ
+      is a multiple of ``16 / gcd(16, group)`` — a T == 1 decode row at
+      group 4 rides as 4 query slots of which 1 is valid, never as a
+      4-row sub-tile slice;
+    - one grid step holds ``n_heads * TQ`` query rows of f32 softmax
+      state in VMEM; _ROWS_PER_STEP bounds that, so a 2048-token prefill
+      chunk walks its pages in query blocks instead of materializing a
+      [n_heads * 2048, page] logits slab."""
+    tq_min = 16 // math.gcd(16, group)
+    tq_cap = max(tq_min, _ROWS_PER_STEP // n_heads // tq_min * tq_min)
+    if T <= tq_cap:
+        tq = -(-T // tq_min) * tq_min
+        return tq, tq
+    return tq_cap, -(-T // tq_cap) * tq_cap
+
 
 def _ragged_kernel(*refs, scale: float, sliding_window: Optional[int],
-                   page: int, T: int, n_kv_heads: int, d_head: int,
-                   quantized: bool, seeded: bool):
+                   page: int, tq: int, group: int, n_kv_heads: int,
+                   d_head: int, quantized: bool, seeded: bool):
     qlen_ref, pos_ref, layer_ref, pt_ref, q_ref, *rest = refs
     if seeded:
         newk_ref, newv_ref, *rest = rest
     ck_in, cv_in, *rest = rest
     if quantized:
-        ks_ref, vs_ref, out_ref, kbuf, vbuf, rsem = rest
-    else:
-        out_ref, kbuf, vbuf, rsem = rest
-        ks_ref = vs_ref = None
+        ks_ref, vs_ref, *rest = rest
+    out_ref, kbuf, vbuf, rsem, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
+    t0 = pl.program_id(1) * tq  # first query token of this block
     layer = layer_ref[0]
     qlen = qlen_ref[b]
     p0 = pos_ref[b]
-    ctx = p0 + qlen  # valid context INCLUDING this dispatch's tokens
-    # rows read from HBM: seeded mode keeps the current token in VMEM
-    # and masks its HBM copy (the decode kernel's contract)
-    n_hbm = ctx - 1 if seeded else ctx
-    n_pages = lax.div(n_hbm + page - 1, page)
+    R = tq * group  # query rows per kv head: row = t_local*group + g
+    # absolute position of each query row of the block
+    row_i = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+    t_i = t0 + row_i // group
+    qpos = p0 + t_i  # [R, 1]
+    q_valid = t_i < qlen  # pad queries beyond the row's ragged length
+    hi = qpos - (1 if seeded else 0)  # last HBM row each query attends
+    # context this block reads: through its LAST valid query (causal),
+    # nothing at all when the block lies wholly beyond q_len. Seeded
+    # mode keeps the current token in VMEM and masks its HBM copy (the
+    # decode kernel's contract)
+    t_end = jnp.minimum(qlen, t0 + tq)
+    n_hbm = p0 + t_end - (1 if seeded else 0)
+    n_pages = jnp.where(t_end > t0, lax.div(n_hbm + page - 1, page), 0)
     if sliding_window is not None:
-        # pages wholly below the EARLIEST query's window are never read;
-        # the per-query mask below handles the ragged boundary exactly
-        first_page = lax.div(jnp.maximum(p0 + 1 - sliding_window, 0),
-                             page)
+        # pages wholly below the block's EARLIEST query window are never
+        # read; the per-query mask below handles the ragged boundary
+        first_page = lax.div(
+            jnp.maximum(p0 + t0 + 1 - sliding_window, 0), page)
     else:
         first_page = 0
 
-    q2 = q_ref[0]  # [Hkv*G, Dh], G = group*T, row = (h*group+g)*T + t
-    HG = q2.shape[0]
-    G = HG // n_kv_heads
-    # absolute position of each query row (t = row % T)
-    row_i = jax.lax.broadcasted_iota(jnp.int32, (HG, 1), 0)
-    t_i = lax.rem(row_i, T)
-    qpos = p0 + t_i  # [HG, 1]
-    q_valid = t_i < qlen  # pad queries beyond the row's ragged length
-    hi = qpos - (1 if seeded else 0)  # last HBM row each query attends
+    def band(h):
+        return slice(h * d_head, (h + 1) * d_head)
+
+    def widen(x):
+        """int8 page rows -> the query dtype. Mosaic converts int8 only
+        through f32; the values (|x| <= 127) are exact in bf16."""
+        if quantized:
+            return x.astype(jnp.float32).astype(q_ref.dtype)
+        return x
+
+    def lanes(x):
+        """[R, 1] softmax statistic -> its lane-replicated scratch row."""
+        return jnp.broadcast_to(x, (R, _STAT_LANES))
+
+    def stat(ref, h):
+        """Scratch row -> [R, 1] (every lane holds the same value)."""
+        return jnp.max(ref[h], axis=1, keepdims=True)
+
+    # flash accumulator state lives in VMEM scratch, one slab per kv head
+    for h in range(n_kv_heads):
+        if seeded:
+            # the current token's contribution seeds the accumulator
+            # from VMEM (always valid, needs no HBM read): a VPU row dot,
+            # not a [R, Dh] x [Dh, 1] matmul
+            # (the band is sliced on the REF: slicing the loaded [1, F]
+            # row leaves an f32 value at lane offset 128, which Mosaic
+            # then refuses to broadcast — "Invalid input layout")
+            qh = q_ref[0, h].astype(jnp.float32)
+            kc = newk_ref[0, :, band(h)].astype(jnp.float32)  # [1, Dh]
+            vc = newv_ref[0, :, band(h)].astype(jnp.float32)
+            m_ref[h] = lanes(
+                jnp.sum(qh * kc, axis=1, keepdims=True) * scale)
+            l_ref[h] = jnp.ones((R, _STAT_LANES), jnp.float32)
+            acc_ref[h] = jnp.broadcast_to(vc, (R, d_head))
+        else:
+            m_ref[h] = jnp.full((R, _STAT_LANES), NEG_INF, jnp.float32)
+            l_ref[h] = jnp.zeros((R, _STAT_LANES), jnp.float32)
+            acc_ref[h] = jnp.zeros((R, d_head), jnp.float32)
 
     def get_dma(slot, p):
         phys = pt_ref[b, p]
         return (
-            pltpu.make_async_copy(ck_in.at[layer, phys, :, :],
+            pltpu.make_async_copy(ck_in.at[layer, phys],
                                   kbuf.at[slot], rsem.at[slot, 0]),
-            pltpu.make_async_copy(cv_in.at[layer, phys, :, :],
+            pltpu.make_async_copy(cv_in.at[layer, phys],
                                   vbuf.at[slot], rsem.at[slot, 1]),
         )
-
-    def scale_row(sref, p):
-        """Page p's per-row scales as a (1, page) row: the MXU
-        contraction against a one-hot both selects the page and keeps
-        lanes as lanes, so no vector relayout is emitted (same trick as
-        the decode kernel, transposed)."""
-        mat = sref[0]  # [max_pages, page] f32
-        onehot = (jax.lax.broadcasted_iota(
-            jnp.int32, (mat.shape[0], 1), 0) == p).astype(jnp.float32)
-        return jax.lax.dot_general(
-            onehot, mat, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [1, page]
-
-    def head_logits(k):
-        """Per-kv-head q @ k_band.T, stacked to [HG, page]."""
-        cols = []
-        for h in range(n_kv_heads):
-            qh = q2[h * G:(h + 1) * G, :]  # [G, Dh]
-            kh = k[:, h * d_head:(h + 1) * d_head]  # [page, Dh]
-            cols.append(jax.lax.dot_general(
-                qh, kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ))  # [G, page]
-        return jnp.concatenate(cols, axis=0)
-
-    def head_pv(pexp_v, v):
-        """Per-kv-head pexp @ v_band, stacked to [HG, Dh]."""
-        outs = []
-        for h in range(n_kv_heads):
-            ph = pexp_v[h * G:(h + 1) * G, :]  # [G, page]
-            vh = v[:, h * d_head:(h + 1) * d_head]  # [page, Dh]
-            outs.append(jax.lax.dot_general(
-                ph, vh, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ))
-        return jnp.concatenate(outs, axis=0)
-
-    if seeded:
-        # current token's contribution seeds the flash accumulator from
-        # VMEM (it is always valid and needs no HBM read)
-        new_k = newk_ref[0]  # [1, F]
-        new_v = newv_ref[0]
-        logit_c = head_logits(new_k.astype(q2.dtype)).reshape(
-            HG, 1) * scale
-        m0 = logit_c
-        l0 = jnp.ones_like(logit_c)
-        accs = []
-        for h in range(n_kv_heads):
-            band = new_v[:, h * d_head:(h + 1) * d_head].astype(
-                jnp.float32)
-            accs.append(jnp.tile(band, (G, 1)))
-        acc0 = jnp.concatenate(accs, axis=0)  # [HG, Dh]
-    else:
-        m0 = jnp.full((HG, 1), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((HG, 1), jnp.float32)
-        acc0 = jnp.zeros((HG, d_head), jnp.float32)
 
     @pl.when(first_page < n_pages)
     def _():
@@ -177,47 +193,60 @@ def _ragged_kernel(*refs, scale: float, sliding_window: Optional[int],
         v0.start()
 
     def body(p, carry):
-        acc, m, l = carry
         slot = lax.rem(p - first_page, 2)
-        nxt = lax.rem(p - first_page + 1, 2)
 
         @pl.when(p + 1 < n_pages)
         def _():
-            kn, vn = get_dma(nxt, p + 1)
+            kn, vn = get_dma(1 - slot, p + 1)
             kn.start()
             vn.start()
 
         kp, vp = get_dma(slot, p)
         kp.wait()
         vp.wait()
-        k = kbuf[slot]
-        if quantized:
-            k = k.astype(q2.dtype)
-        logits = head_logits(k) * scale  # [HG, page]
-        if quantized:
-            logits = logits * scale_row(ks_ref, p)
         kvrow = p * page + jax.lax.broadcasted_iota(
-            jnp.int32, logits.shape, 1)
+            jnp.int32, (R, page), 1)
         valid = (kvrow <= hi) & q_valid
         if sliding_window is not None:
             valid &= kvrow > qpos - sliding_window
-        logits = jnp.where(valid, logits, NEG_INF)
-        m_page = jnp.max(logits, axis=1, keepdims=True)  # [HG, 1]
-        m_new = jnp.maximum(m, m_page)
-        alpha = jnp.exp(m - m_new)
-        pexp = jnp.exp(logits - m_new)
-        pexp = jnp.where(valid, pexp, 0.0)
-        l = l * alpha + jnp.sum(pexp, 1, keepdims=True)
         if quantized:
-            pexp_v = pexp * scale_row(vs_ref, p)
-            vpage = vbuf[slot].astype(jnp.float32)
-        else:
-            pexp_v, vpage = pexp, vbuf[slot]
-        acc = acc * alpha + head_pv(pexp_v, vpage)
-        return acc, m_new, l
+            # per-row page scales, [1, page]: the k scale multiplies
+            # logits on the kv axis, the v scale folds into pexp
+            ks_row = ks_ref[0, pl.ds(p, 1), :]
+            vs_row = vs_ref[0, pl.ds(p, 1), :]
+        for h in range(n_kv_heads):
+            qh = q_ref[0, h]  # [R, Dh]
+            kh = widen(kbuf[slot, :, band(h)])  # [page, Dh]
+            logits = jax.lax.dot_general(
+                qh, kh, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [R, page]
+            if quantized:
+                logits = logits * ks_row
+            logits = jnp.where(valid, logits, NEG_INF)
+            m_prev = stat(m_ref, h)
+            m_new = jnp.maximum(
+                m_prev, jnp.max(logits, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            pexp = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
+            l_new = stat(l_ref, h) * alpha + jnp.sum(
+                pexp, axis=1, keepdims=True)
+            if quantized:
+                pexp = pexp * vs_row
+            vh = widen(vbuf[slot, :, band(h)])  # [page, Dh]
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                pexp.astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_ref[h] = lanes(m_new)
+            l_ref[h] = lanes(l_new)
+        return carry
 
-    acc, m, l = lax.fori_loop(first_page, n_pages, body, (acc0, m0, l0))
-    out_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(out_ref.dtype)
+    lax.fori_loop(first_page, n_pages, body, 0)
+    for h in range(n_kv_heads):
+        out_ref[0, h] = (
+            acc_ref[h] / jnp.maximum(stat(l_ref, h), 1e-30)
+        ).astype(out_ref.dtype)
 
 
 def ragged_paged_attention(
@@ -250,68 +279,79 @@ def ragged_paged_attention(
     assert PG == page, (PG, page)
     _, max_pages = page_table.shape
     group = H // n_kv_heads
-    G = group * T
-    HG = n_kv_heads * G
     quantized = cache_k_scale is not None
     seeded = seed_kv is not None
     if seeded:
         assert T == 1, "seed_kv is the decode (T == 1) contract"
-    # [B, T, H, Dh] -> [B, Hkv*G, Dh] with row (h*group+g)*T + t, so the
-    # kernel recovers t as row % T
-    q2 = q.reshape(B, T, n_kv_heads, group, Dh).transpose(
-        0, 2, 3, 1, 4).reshape(B, HG, Dh)
+    tq, Tp = _q_tiling(T, H, group)
+    R = tq * group
+    # [B, T, H, Dh] -> [B, Hkv, Tp*group, Dh], row t*group + g: a query
+    # block is a contiguous row range of every kv head's slab, and the
+    # kernel indexes heads on a LEADING dim (no sublane slicing)
+    q3 = jnp.pad(q, ((0, 0), (0, Tp - T), (0, 0), (0, 0))).reshape(
+        B, Tp, n_kv_heads, group, Dh).transpose(0, 2, 1, 3, 4).reshape(
+        B, n_kv_heads, Tp * group, Dh)
     nsp = 4  # q_lens, pos0, layer, page_table
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
 
-    def _bspec(shape):
+    def _row_spec(shape):
+        # one block per batch row, whole along every other dim
         return pl.BlockSpec(
-            shape, lambda b, qls, p0s, lay, pt: (b,) + (0,) * (
-                len(shape) - 1))
+            shape, lambda b, qi, *_: (b,) + (0,) * (len(shape) - 1))
 
-    operands = [q_lens, pos0, layer[None], page_table, q2]
-    in_specs = [_bspec((1, HG, Dh))]
+    q_spec = pl.BlockSpec((1, n_kv_heads, R, Dh),
+                          lambda b, qi, *_: (b, 0, qi, 0))
+    operands = [q_lens, pos0, layer[None], page_table, q3]
+    in_specs = [q_spec]
     if seeded:
         new_k, new_v = seed_kv
         operands += [new_k[:, None, :], new_v[:, None, :]]
-        in_specs += [_bspec((1, 1, F)), _bspec((1, 1, F))]
+        in_specs += [_row_spec((1, 1, F)), _row_spec((1, 1, F))]
     operands += [cache_k, cache_v]
     in_specs += [any_spec, any_spec]
     if quantized:
         # per-row scale pages gathered through the table ([B, max_pages,
-        # page] — logical page p of row b lands at row p, matching the
-        # kernel's one-hot page selection)
+        # page] — logical page p of row b lands at row p, where the
+        # kernel's page walk indexes it)
         ks_g = lax.dynamic_index_in_dim(
             cache_k_scale, layer, 0, keepdims=False)[page_table]
         vs_g = lax.dynamic_index_in_dim(
             cache_v_scale, layer, 0, keepdims=False)[page_table]
         operands += [ks_g, vs_g]
-        in_specs += [_bspec((1, max_pages, page)),
-                     _bspec((1, max_pages, page))]
+        in_specs += [_row_spec((1, max_pages, page)),
+                     _row_spec((1, max_pages, page))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=nsp,
-        grid=(B,),
+        grid=(B, Tp // tq),
         in_specs=in_specs,
-        out_specs=_bspec((1, HG, Dh)),
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((2, page, F), cache_k.dtype),
             pltpu.VMEM((2, page, F), cache_v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((n_kv_heads, R, _STAT_LANES), jnp.float32),  # m
+            pltpu.VMEM((n_kv_heads, R, _STAT_LANES), jnp.float32),  # l
+            pltpu.VMEM((n_kv_heads, R, Dh), jnp.float32),  # acc
         ],
     )
     kernel = functools.partial(
         _ragged_kernel, scale=scale, sliding_window=sliding_window,
-        page=page, T=T, n_kv_heads=n_kv_heads, d_head=Dh,
+        page=page, tq=tq, group=group, n_kv_heads=n_kv_heads, d_head=Dh,
         quantized=quantized, seeded=seeded,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, HG, Dh), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(
+            (B, n_kv_heads, Tp * group, Dh), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=_interpret(),
+        name="ragged_paged_attention",
     )(*operands)
-    # [B, Hkv*G, Dh] -> [B, T, H*Dh]
-    return out.reshape(B, n_kv_heads, group, T, Dh).transpose(
-        0, 3, 1, 2, 4).reshape(B, T, H * Dh)
+    # [B, Hkv, Tp*group, Dh] -> [B, T, H*Dh]
+    return out.reshape(B, n_kv_heads, Tp, group, Dh).transpose(
+        0, 2, 1, 3, 4).reshape(B, Tp, H * Dh)[:, :T]
 
 
 def mesh_ragged_eligible(mesh, n_kv_heads: int, n_heads: int,
@@ -376,8 +416,6 @@ def sharded_ragged_append_attend(
 
     Returns (out [B, T, H*Dh] sharded over "model", ck, cv[, ks, vs]).
     """
-    from jax.experimental.shard_map import shard_map
-
     from ..parallel.sharding import (
         PAGED_KV_SPEC, RAGGED_Q_SPEC, RAGGED_ROW_SPEC, REPLICATED,
     )
@@ -437,12 +475,12 @@ def sharded_ragged_append_attend(
             return out, ck, cv, ksp, vsp
         return out, ck, cv
 
-    # check_rep=False: the model-replicated scale planes are updated with
+    # check_vma=False: the model-replicated scale planes are updated with
     # identical values on every model shard (global-amax quantization), a
     # replication invariant shard_map cannot verify itself
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(*operands)
 
 

@@ -103,7 +103,7 @@ def tokens_match(a: str, b: str) -> bool:
 
 @dataclass
 class Node:
-    """ref: p2p.NodeData {Name, ID, TunnelAddress, LastSeen} + the
+    """ref: p2p.NodeData (name, id, address, last seen) + the
     circuit-breaker record the registry drives."""
 
     id: str

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 NEG_INF = -1e30
 
@@ -93,13 +92,13 @@ def ring_attention(
     sharding. T must divide evenly across the axis."""
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     spec = P(None, axis_name, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ring_body, axis_name=axis_name, causal=causal,
                 scale=scale),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k, v)
 

@@ -57,16 +57,13 @@ class Application:
         )
 
     def startup(self) -> None:
-        if self.config.compilation_cache_dir:
-            # persistent XLA compile cache: cold-start compiles of the
-            # serving executables are paid once per config, not per boot
-            # (SURVEY.md §7 hard part #2 — TTFT must hide cold compiles)
-            import jax
+        # persistent XLA compile cache: cold-start compiles of the
+        # serving executables are paid once per config, not per boot
+        # (SURVEY.md §7 hard part #2 — TTFT must hide cold compiles).
+        # JAX_COMPILATION_CACHE_DIR places it; see utils/compile_cache
+        from ..utils import compile_cache
 
-            jax.config.update("jax_compilation_cache_dir",
-                              self.config.compilation_cache_dir)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0)
+        log.info("compile cache: %s", compile_cache.configure())
         register_default_backends()
         n = self.config_loader.load_configs_from_path()
         log.info("loaded %d model configs from %s", n,

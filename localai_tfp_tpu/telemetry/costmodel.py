@@ -22,8 +22,9 @@ known, and each harvest also feeds an EWMA MFU estimate:
 ``roofline()`` classifies each kind compute- vs bandwidth-bound by
 comparing its arithmetic intensity (flops / bytes accessed) against the
 machine balance point ``peak_flops / peak_bw``; peaks come from a
-built-in per-platform table overridable via ``LOCALAI_PEAK_FLOPS`` /
-``LOCALAI_PEAK_HBM_GBS``.
+built-in table keyed by ``device_kind`` (each row with its source),
+overridable via ``LOCALAI_PEAK_FLOPS`` / ``LOCALAI_PEAK_HBM_GBS``. A
+device that is not in the table is an error, not a default.
 
 ``predict_ms()`` turns the same table into a per-dispatch DEVICE-TIME
 predictor, which is what cost-model-driven scheduling
@@ -64,15 +65,19 @@ __all__ = ["CostModel", "dispatch_key", "peak_rates",
 # account at harvest (span known), everything else at dispatch
 FLIGHT_KINDS = frozenset({"prefill_final", "mixed", "decodek"})
 
-# (peak FLOP/s, peak HBM bytes/s) per device, by jax platform. The TPU
-# row is a v5e-class part (matches the paper's serving baselines); the
-# CPU row is a laptop-class core (ridge = 50e9/50e9 = 1 flop/byte),
-# which puts the tiny f32 test models on both sides of the ridge: XLA
-# measures their decode at ~0.2 flops/byte (weights re-read per token)
-# and their batched prefill at ~2.3 (weights amortized per bucket).
+# (peak FLOP/s, peak HBM bytes/s) per device, by jax ``device_kind``.
+# These peaks feed predict_ms(), which sizes dispatches by default, so
+# a device this table does not know is an error (peak_rates), never
+# another device's row.
 _PEAK_TABLE: dict[str, tuple[float, float]] = {
-    "tpu": (197e12, 819e9),
-    "gpu": (60e12, 1000e9),
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
+    # HBM per chip
+    "TPU v5 lite": (197e12, 819e9),
+    # not a measurement: a laptop-class core (ridge = 50e9/50e9 = 1
+    # flop/byte) for the CPU test suite. It puts the tiny f32 test
+    # models on both sides of the ridge: XLA measures their decode at
+    # ~0.2 flops/byte (weights re-read per token) and their batched
+    # prefill at ~2.3 (weights amortized per bucket).
     "cpu": (50e9, 50e9),
 }
 
@@ -91,14 +96,23 @@ _CALIB_MIN_SAMPLES = 3
 _CALIB_CLIP = 4.0
 
 
-def peak_rates(platform: str) -> tuple[float, float]:
+def peak_rates(device_kind: str) -> tuple[float, float]:
     """(peak FLOP/s, peak bytes/s) per device — knob overrides first,
-    then the platform table, then the CPU row."""
+    then the ``device_kind`` table. Raises for a device the table does
+    not know (unless both knobs stand in for it)."""
     flops = knobs.float_("LOCALAI_PEAK_FLOPS")
     bw = knobs.float_("LOCALAI_PEAK_HBM_GBS") * 1e9
-    table = _PEAK_TABLE.get(platform.lower(), _PEAK_TABLE["cpu"])
-    return (flops if flops > 0 else table[0],
-            bw if bw > 0 else table[1])
+    if flops > 0 and bw > 0:
+        return flops, bw
+    row = _PEAK_TABLE.get(device_kind)
+    if row is None:
+        raise ValueError(
+            f"no peak FLOP/s / HBM bandwidth known for device_kind "
+            f"{device_kind!r} (known: {sorted(_PEAK_TABLE)}): add a row "
+            "with its source to telemetry/costmodel._PEAK_TABLE, or set "
+            "both LOCALAI_PEAK_FLOPS and LOCALAI_PEAK_HBM_GBS")
+    return (flops if flops > 0 else row[0],
+            bw if bw > 0 else row[1])
 
 
 def dispatch_key(kind: str, payload: dict) -> tuple:
@@ -128,20 +142,11 @@ def dispatch_key(kind: str, payload: dict) -> tuple:
     return (kind,)
 
 
-def _extract_costs(analysis: Any) -> tuple[float, float]:
-    """(flops, bytes accessed) from a cost_analysis() result, which is
-    a dict or a per-device list of dicts depending on jax version."""
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else {}
-    if not isinstance(analysis, dict):
-        return 0.0, 0.0
-    flops = float(analysis.get("flops", 0.0) or 0.0)
-    by = analysis.get("bytes accessed")
-    if by is None:
-        # some versions only expose per-operand rows
-        by = sum(float(v) for k, v in analysis.items()
-                 if isinstance(k, str) and k.startswith("bytes accessed"))
-    return flops, float(by or 0.0)
+def _extract_costs(analysis: dict) -> tuple[float, float]:
+    """(flops, bytes accessed) from a ``compiled.cost_analysis()`` dict
+    (what the installed jax returns on CPU and TPU alike)."""
+    return (float(analysis.get("flops", 0.0)),
+            float(analysis.get("bytes accessed", 0.0)))
 
 
 def analytic_flops_per_token(params: Any) -> float:
@@ -166,10 +171,11 @@ class CostModel:
     single lock covers the shared tables).
     """
 
-    def __init__(self, model: str, platform: str,
+    def __init__(self, model: str, device_kind: str,
                  n_devices: int = 1) -> None:
         self.model = model
-        self.platform = platform
+        self.device_kind = device_kind
+        peak_rates(device_kind)  # an unknown device fails construction
         self.n_devices = max(1, int(n_devices))
         self.capturing = False
         self._lock = threading.Lock()
@@ -287,7 +293,7 @@ class CostModel:
         flops = self._account(kind, key)
         if flops <= 0.0 or span_s <= 0.0:
             return
-        peak_flops, _ = peak_rates(self.platform)
+        peak_flops, _ = peak_rates(self.device_kind)
         sample = min(1.0, flops / (span_s * peak_flops * self.n_devices))
         span_ms = span_s * 1e3
         with self._lock:
@@ -348,7 +354,7 @@ class CostModel:
         if row is None:
             return None
         flops, by = row
-        peak_flops, peak_bw = peak_rates(self.platform)
+        peak_flops, peak_bw = peak_rates(self.device_kind)
         t_s = max(flops / (peak_flops * self.n_devices),
                   by / (peak_bw * self.n_devices))
         return t_s * 1e3 if t_s > 0.0 else None
@@ -430,7 +436,7 @@ class CostModel:
         against the machine balance point. Kinds with dispatch traffic
         use accounted totals; kinds only ever captured fall back to
         their captured rows so the classification exists pre-traffic."""
-        peak_flops, peak_bw = peak_rates(self.platform)
+        peak_flops, peak_bw = peak_rates(self.device_kind)
         ridge = peak_flops / max(peak_bw, 1.0)
         with self._lock:
             per_kind: dict[str, list[float]] = {
@@ -457,7 +463,7 @@ class CostModel:
 
     def stats(self) -> dict:
         """Host-held summary for /backend/monitor and bench."""
-        peak_flops, peak_bw = peak_rates(self.platform)
+        peak_flops, peak_bw = peak_rates(self.device_kind)
         with self._lock:
             mfu = self._mfu
             samples = self._mfu_samples
@@ -468,7 +474,7 @@ class CostModel:
             variants_calibrated = sum(
                 1 for c in self._calib_var.values() if c[1] >= 2)
         return {
-            "platform": self.platform,
+            "device_kind": self.device_kind,
             "n_devices": self.n_devices,
             "peak_flops_per_device": peak_flops,
             "peak_hbm_bytes_s_per_device": peak_bw,

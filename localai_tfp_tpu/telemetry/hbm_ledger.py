@@ -123,12 +123,16 @@ class HBMLedger:
         """
         attr = self.attributed()
         in_use: Optional[int] = None
+        peak: Optional[int] = None  # allocator high-water mark, where
+        # the backend reports one
         provider = (memory_stats if memory_stats is not None
                     else _device_memory_stats)
         try:
             st = provider()
             if st is not None:
                 in_use = int(st.get("bytes_in_use", 0))
+                if "peak_bytes_in_use" in st:
+                    peak = int(st["peak_bytes_in_use"])
         except Exception:  # pragma: no cover - backend-specific
             log.debug("memory_stats provider failed", exc_info=True)
             in_use = None
@@ -141,7 +145,8 @@ class HBMLedger:
             host = set(self._host)
         total = sum(b for n, b in attr.items() if n not in host)
         snap: dict[str, Any] = {"components": attr, "attributed": total,
-                                "bytes_in_use": in_use}
+                                "bytes_in_use": in_use,
+                                "peak_bytes_in_use": peak}
         if in_use is not None:
             drift = in_use - total
             tm.ENGINE_HBM_BYTES.labels(

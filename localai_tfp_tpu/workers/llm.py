@@ -859,6 +859,16 @@ class JaxLLMBackend(Backend):
         resident = sum(len(s.cache_tokens) for s in eng.slots)
         reused, filled = m.prefix_reused_tokens, m.prefill_tokens
         return {
+            # the device and the attention route this engine chose at
+            # construction, as JAX reported them then
+            "platform": eng.platform,
+            "device_kind": eng.device_kind,
+            "paged": bool(eng._paged),
+            "attention_path": eng.attention_path,
+            # "" on the kernel route, else the condition that ruled the
+            # Pallas kernel out
+            "kernel_ineligible": eng.kernel_ineligible,
+            "warmup_variants": eng.warmup_variants,
             "n_slots": eng.n_slots,
             "slots_busy": busy,
             "queue_depth": queue_depth,

@@ -39,6 +39,36 @@ from .remote import RemoteOpenAIBackend
 DEFAULT_LOAD_TIMEOUT_S = 600.0
 
 
+def _chip_held_by_parent(backend: str) -> str:
+    """Why a child running ``backend`` cannot start on this host, or "".
+
+    A TPU chip belongs to one process at a time, and this server is a
+    JAX process: it holds every local chip from its first device call
+    (the memory gauge loop makes one seconds after startup, any loaded
+    jax-* model long before). A child that needs the chip would then
+    fail or hang at backend init for the whole ``load_timeout_s`` — so a
+    jax-* backend under ``isolation: subprocess`` is refused up front
+    when this process's devices are TPUs. CPU hosts (tests, development)
+    and backends that need no chip are unaffected."""
+    from ..engine.loader import resolve_backend
+
+    if not resolve_backend(backend).startswith("jax-"):
+        return ""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return ""
+    return (
+        f"isolation: subprocess cannot serve backend '{backend}' on this "
+        f"host: this server process holds its TPU ({len(jax.devices())} x "
+        f"{dev.device_kind}), a chip belongs to one process at a time, "
+        "and the child would fail or hang at backend init. Drop "
+        "`isolation` from the model YAML (the model then loads "
+        "in-process), or serve it from its own server process on a "
+        "host whose chips nothing else holds.")
+
+
 def _free_port() -> int:
     s = socket.socket()
     s.bind(("127.0.0.1", 0))
@@ -63,6 +93,11 @@ class SubprocessBackend(RemoteOpenAIBackend):
         name = raw.get("name") or opts.model
         timeout = float(opts.extra.get("load_timeout_s",
                                        DEFAULT_LOAD_TIMEOUT_S))
+        custom_argv = opts.extra.get("_argv")  # test hook
+        if custom_argv is None:
+            held = _chip_held_by_parent(raw.get("backend", ""))
+            if held:
+                return Result(False, held)
 
         # child models dir: ONLY this model's yaml (minus the isolation
         # key — recursion guard), plus links to the parent's model files
@@ -89,14 +124,12 @@ class SubprocessBackend(RemoteOpenAIBackend):
         # than a load failure
         env = dict(os.environ)
         # the child must import this package; PREPEND its root to any
-        # existing PYTHONPATH (never clobber: TPU plugin site dirs ride
-        # there in some deployments)
+        # existing PYTHONPATH (never clobber what the deployment set)
         pkg_root = os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))))
         env["PYTHONPATH"] = os.pathsep.join(
             p for p in [pkg_root, env.get("PYTHONPATH", "")] if p)
         log_path = os.path.join(self._child_dir, "child.log")
-        custom_argv = opts.extra.get("_argv")  # test hook
         for attempt in range(2):
             port = _free_port()
             argv = custom_argv or [

@@ -1,6 +1,9 @@
 """Config system tests (ref test model: core/config/backend_config_test.go)."""
 
+import os
 import textwrap
+
+import pytest
 
 from localai_tfp_tpu.config import ConfigLoader, ModelConfig, Usecase
 
@@ -119,26 +122,97 @@ def test_app_config_from_env(monkeypatch):
     assert cfg.api_keys == ["k1", "k2"]
 
 
-def test_compilation_cache_wiring(tmp_path, monkeypatch):
-    """compilation_cache_dir turns on jax's persistent compile cache."""
+@pytest.fixture
+def restore_cache_config():
+    """Tests below move jax's cache directory; put it back."""
+    import jax
+
+    old_dir = jax.config.jax_compilation_cache_dir
+    old_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    yield
+    jax.config.update("jax_compilation_cache_dir", old_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      old_min)
+
+
+def test_compile_cache_default_is_fixed_in_checkout(
+        tmp_path, monkeypatch, restore_cache_config):
+    """No JAX_COMPILATION_CACHE_DIR: the server's startup points the
+    persistent cache at <checkout>/.jax_cache — the same place on every
+    start (a moving directory never hits)."""
     import jax
 
     from localai_tfp_tpu.config.app_config import ApplicationConfig
     from localai_tfp_tpu.server.state import Application
+    from localai_tfp_tpu.utils import compile_cache
 
-    cache_dir = str(tmp_path / "xla-cache")
-    cfg = ApplicationConfig(
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.resolve() == (
+        os.path.join(repo, ".jax_cache"), False)
+    app = Application(ApplicationConfig(
         models_path=str(tmp_path / "models"),
         generated_content_dir=str(tmp_path / "gen"),
         upload_dir=str(tmp_path / "up"),
         config_dir=str(tmp_path / "conf"),
-        compilation_cache_dir=cache_dir,
-    )
-    app = Application(cfg)
-    old = jax.config.jax_compilation_cache_dir
+        state_dir=str(tmp_path / "run"),
+    ))
     try:
         app.startup()
-        assert jax.config.jax_compilation_cache_dir == cache_dir
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            repo, ".jax_cache")
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
     finally:
         app.shutdown()
-        jax.config.update("jax_compilation_cache_dir", old)
+
+
+def test_compile_cache_env_wins(tmp_path, monkeypatch,
+                                restore_cache_config):
+    """JAX_COMPILATION_CACHE_DIR set: jax's own reading of it stands and
+    the helper sets no other directory."""
+    import jax
+
+    from localai_tfp_tpu.utils import compile_cache
+
+    placed = str(tmp_path / "placed")
+    monkeypatch.setenv(compile_cache.ENV_VAR, placed)
+    # jax read the variable when it was imported; stand in for that
+    jax.config.update("jax_compilation_cache_dir", placed)
+    assert compile_cache.resolve() == (placed, True)
+    assert compile_cache.configure() == placed
+    assert jax.config.jax_compilation_cache_dir == placed
+
+
+def test_no_other_compile_cache_setter():
+    """One rule, one place: nothing outside utils/compile_cache.py (and
+    the tests that save/restore it) sets jax_compilation_cache_dir, and
+    the old names are gone."""
+    import re
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    setter = re.compile(
+        r"""update\(\s*["']jax_compilation_cache_dir["']""")
+    # (spelled in pieces so a grep of the tree for the old names stays
+    # empty)
+    gone = re.compile("localai_" + "xla|LOCALAI_" + "COMPILATION_CACHE_DIR"
+                      + r"|compilation_cache_dir\s*=")
+    offenders = []
+    for base, dirs, files in os.walk(repo):
+        dirs[:] = [d for d in dirs
+                   if not d.startswith(".") and d != "chiprun_out"]
+        for fn in files:
+            if not fn.endswith(".py"):
+                continue
+            path = os.path.join(base, fn)
+            rel = os.path.relpath(path, repo)
+            with open(path) as f:
+                text = f.read()
+            if rel.startswith("tests" + os.sep):
+                continue  # save/restore and this scan's own patterns
+            if rel != os.path.join("localai_tfp_tpu", "utils",
+                                   "compile_cache.py") \
+                    and setter.search(text):
+                offenders.append(rel + ": sets the cache dir")
+            if gone.search(text):
+                offenders.append(rel + ": old cache name")
+    assert not offenders, offenders

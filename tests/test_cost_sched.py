@@ -332,7 +332,9 @@ def test_warmup_reuse_restores_cost_rows(model, tmp_path, monkeypatch):
                          autostart=False)
 
     prev_cache = jax.config.jax_compilation_cache_dir
+    prev_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_enable_compilation_cache", True)
     try:
         eng1 = build()
         try:
@@ -367,3 +369,4 @@ def test_warmup_reuse_restores_cost_rows(model, tmp_path, monkeypatch):
         assert os.path.exists(marker + ".cost.json")
     finally:
         jax.config.update("jax_compilation_cache_dir", prev_cache)
+        jax.config.update("jax_enable_compilation_cache", prev_on)

@@ -70,15 +70,25 @@ def test_dispatch_key_tracks_jit_cache_signature():
         "prefill_final", {"toks": toks, "window": 128})
 
 
-def test_peak_rates_platform_table_and_overrides(monkeypatch):
+def test_peak_rates_device_kind_table_and_overrides(monkeypatch):
     monkeypatch.delenv("LOCALAI_PEAK_FLOPS", raising=False)
     monkeypatch.delenv("LOCALAI_PEAK_HBM_GBS", raising=False)
     assert costmodel.peak_rates("cpu") == (50e9, 50e9)
-    assert costmodel.peak_rates("tpu") == (197e12, 819e9)
-    assert costmodel.peak_rates("weird") == costmodel.peak_rates("cpu")
+    assert costmodel.peak_rates("TPU v5 lite") == (197e12, 819e9)
+    # a device the table does not know is an error — never the v5e row
+    # (these peaks size dispatches) and never the CPU row
+    for kind in ("TPU v9 mega", "tpu", "NVIDIA H100"):
+        with pytest.raises(ValueError, match="device_kind"):
+            costmodel.peak_rates(kind)
+        with pytest.raises(ValueError, match="device_kind"):
+            costmodel.CostModel("m", kind)
+    # one knob alone cannot stand in for an unknown device
     monkeypatch.setenv("LOCALAI_PEAK_FLOPS", "1e12")
+    with pytest.raises(ValueError, match="device_kind"):
+        costmodel.peak_rates("TPU v9 mega")
     monkeypatch.setenv("LOCALAI_PEAK_HBM_GBS", "100")
     assert costmodel.peak_rates("cpu") == (1e12, 100e9)
+    assert costmodel.peak_rates("TPU v9 mega") == (1e12, 100e9)
 
 
 # --------------------------------------------- capture and accounting

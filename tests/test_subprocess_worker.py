@@ -128,3 +128,32 @@ def test_isolated_model_serves_end_to_end(tmp_path):
         time.sleep(0.1)
     else:
         raise AssertionError("child process still alive after shutdown")
+
+
+def test_jax_backend_refused_when_parent_holds_the_chip(tmp_path,
+                                                        monkeypatch):
+    """On a TPU host this server process owns the chip, so a child
+    running a jax-* backend could never initialize: the load must fail
+    at once with a message that says why — not hang for load_timeout_s.
+    Backends that need no chip, and CPU hosts, pass the fence."""
+    import jax
+
+    from localai_tfp_tpu.workers import subprocess_worker as sw
+
+    class FakeTPU:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    assert sw._chip_held_by_parent("jax-llm") == ""  # CPU host: fine
+    monkeypatch.setattr(jax, "devices", lambda *a: [FakeTPU()])
+    assert sw._chip_held_by_parent("remote-openai") == ""
+    assert "one process at a time" in sw._chip_held_by_parent("llama")
+
+    register_default_backends()
+    loader = ModelLoader(models_path=str(tmp_path))
+    cfg = _cfg()
+    cfg.extra["load_timeout_s"] = 600.0
+    t0 = time.monotonic()
+    with pytest.raises(RuntimeError, match="holds its TPU"):
+        loader.load(cfg)
+    assert time.monotonic() - t0 < 5

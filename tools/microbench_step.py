@@ -49,11 +49,10 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
-    jax.config.update("jax_compilation_cache_dir",
-                      "/root/.cache/localai_xla")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
-
     import bench
+    from localai_tfp_tpu.utils import compile_cache
+
+    compile_cache.configure()
 
     from localai_tfp_tpu.engine.engine import (LLMEngine, _sample_masked)
     from localai_tfp_tpu.engine.tokenizer import ByteTokenizer

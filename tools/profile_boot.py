@@ -42,9 +42,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 
-def _leg(cache_dir: str, n_devices: int) -> dict:
+def _leg(n_devices: int) -> dict:
     """One boot, in THIS process: force the host device count, enable
-    the persistent cache, construct the meshed paged engine, warm up."""
+    the persistent cache (where JAX_COMPILATION_CACHE_DIR — set by the
+    parent — says), construct the meshed paged engine, warm up."""
     from __graft_entry__ import _force_host_devices
 
     os.environ["JAX_PLATFORMS"] = "cpu"
@@ -54,8 +55,9 @@ def _leg(cache_dir: str, n_devices: int) -> dict:
     import jax
     import jax.numpy as jnp
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    from localai_tfp_tpu.utils import compile_cache
+
+    compile_cache.configure()
 
     from localai_tfp_tpu.engine.engine import LLMEngine
     from localai_tfp_tpu.engine.tokenizer import ByteTokenizer
@@ -115,7 +117,7 @@ def main() -> None:
     args = ap.parse_args()
 
     if args.leg is not None:
-        out = _leg(args.cache_dir, args.devices)
+        out = _leg(args.devices)
         out["mode"] = args.leg
         print("BOOT_LEG " + json.dumps(out))
         return
@@ -129,8 +131,11 @@ def main() -> None:
     def run(leg: str) -> dict:
         proc = subprocess.run(
             [sys.executable, os.path.abspath(__file__),
-             "--leg", leg, "--cache-dir", cache_dir,
-             "--devices", str(args.devices)],
+             "--leg", leg, "--devices", str(args.devices)],
+            # the cold leg needs a cache that is known empty, so this
+            # tool places it for its children the way any launcher
+            # does: through the environment
+            env={**os.environ, "JAX_COMPILATION_CACHE_DIR": cache_dir},
             capture_output=True, text=True, timeout=1800)
         for line in proc.stdout.splitlines():
             if line.startswith("BOOT_LEG "):

@@ -524,6 +524,9 @@ def main() -> None:
         "gallery": gallery_leg(),
         "federation": asyncio.run(federation_leg(args.probe_s)),
         "tracing": asyncio.run(tracing_leg()),
+        # member servers are children forced onto the CPU
+        # (_spawn_member: a chip has one owner)
+        "members_platform": "cpu",
     }
     print(json.dumps(report, indent=2))
 

@@ -193,9 +193,9 @@ def main() -> None:
 
     import jax
 
-    jax.config.update("jax_compilation_cache_dir",
-                      "/root/.cache/localai_xla")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
+    from localai_tfp_tpu.utils import compile_cache
+
+    compile_cache.configure()
 
     import shutil
     import tempfile

@@ -858,6 +858,9 @@ def main() -> None:
         report = asyncio.run(fleet_leg(
             n_members=args.members, probe_s=args.probe_s,
             n_requests=args.requests))
+    # member servers are children forced onto the CPU (_spawn_member:
+    # a chip has one owner); nothing below is a device number
+    report["members_platform"] = "cpu"
     print(json.dumps(report) if args.json
           else json.dumps(report, indent=2))
 

@@ -563,9 +563,9 @@ if __name__ == "__main__":
     ap.add_argument("--burst-size", type=int, default=16,
                     help="--mixed: requests per burst")
     args = ap.parse_args()
-    jax.config.update("jax_compilation_cache_dir",
-                      "/root/.cache/localai_xla")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
+    from localai_tfp_tpu.utils import compile_cache
+
+    compile_cache.configure()
     if args.shared_prefix:
         shared_prefix_scenario(args.small, args.requests,
                                args.prefix_tokens)

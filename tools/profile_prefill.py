@@ -16,10 +16,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-jax.config.update("jax_compilation_cache_dir", "/root/.cache/localai_xla")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
-
 from bench import _fast_int8_params  # noqa: E402
+from localai_tfp_tpu.utils import compile_cache  # noqa: E402
+
+compile_cache.configure()
 
 from localai_tfp_tpu.engine.engine import LLMEngine  # noqa: E402
 from localai_tfp_tpu.engine.tokenizer import ByteTokenizer  # noqa: E402

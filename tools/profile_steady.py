@@ -35,8 +35,9 @@ def main() -> None:
     ap.add_argument("--gap", type=float, default=0.5)
     args = ap.parse_args()
 
-    jax.config.update("jax_compilation_cache_dir", "/root/.cache/localai_xla")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
+    from localai_tfp_tpu.utils import compile_cache
+
+    compile_cache.configure()
 
     from bench import _fast_int8_params  # type: ignore
     from tools.profile_ttft import WideByteTok
@@ -131,8 +132,8 @@ def main() -> None:
         while True:
             try:
                 # generous: a first-of-shape arrival may sit behind a
-                # cold jit (minutes through the remote AOT helper);
-                # later arrivals of the same shape measure serving
+                # cold jit; later arrivals of the same shape measure
+                # serving
                 ev = q.get(timeout=900)
             except Exception:
                 states = {}

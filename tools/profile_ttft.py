@@ -79,12 +79,12 @@ def main():
     ap.add_argument("--no-http", action="store_true")
     args = ap.parse_args()
 
-    jax.config.update("jax_compilation_cache_dir", "/root/.cache/localai_xla")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
-
     import sys
 
     sys.path.insert(0, "/root/repo")
+    from localai_tfp_tpu.utils import compile_cache
+
+    compile_cache.configure()
 
     eng, tok, n_req, n_tok = build_engine(args.small)
 
