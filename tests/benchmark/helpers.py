@@ -1,0 +1,95 @@
+"""Shared pieces of the benchmark's tests: a tiny configuration, a tiny
+mix, and a temp copy of the benchmark as a checkout of its own. Nothing
+here imports JAX."""
+
+from __future__ import annotations
+
+import json
+import os
+import shutil
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+PARITY_PROMPTS = [
+    "A paged cache hands out attention memory page by page.",
+    "Continuous batching admits a request as soon as a slot is free.",
+]
+
+TINY = {
+    "architectures": ["MistralForCausalLM"], "model_type": "mistral",
+    "hidden_size": 64, "intermediate_size": 128,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "num_hidden_layers": 2, "vocab_size": 512, "rope_theta": 1000000.0,
+    "rms_norm_eps": 1e-05, "sliding_window": None,
+    "max_position_embeddings": 4096, "hidden_act": "silu",
+    "tie_word_embeddings": False, "torch_dtype": "bfloat16",
+    "bos_token_id": 510, "eos_token_id": 511,
+    "source": "tests/benchmark", "chips": 1, "mesh": None, "reduced": [],
+    "serving": {"backend": "jax-llm", "context_size": 4096,
+                "max_batch_slots": 4, "embeddings": True},
+    "assumed": {"served_bytes_per_param": {"dense": 2, "experts": 2},
+                "kv_bytes_per_value": 2, "kv_page_tokens": 256,
+                "kv_pool_pages": 64},
+    "deployment": "a toy for the CPU rehearsal", "weights_seed": 0,
+    "parity_prompts": PARITY_PROMPTS, "parity_tol": 0.05,
+    "parity_tol_reason": "bf16 activations against float32",
+}
+TINY_MOE = dict(TINY, model_type="mixtral",
+                architectures=["MixtralForCausalLM"],
+                num_local_experts=4, num_experts_per_tok=2)
+
+_REQ = {"temperature": 0, "ignore_eos": True}
+TINY_OPEN = {
+    "loop": "open", "rate_rps": 3,
+    "prompt_tokens": {"dist": "lognormal", "median": 24, "sigma": 0.6,
+                      "min": 8, "max": 100},
+    "output_tokens": {"dist": "uniform", "min": 4, "max": 12},
+    "preroll_s": 1, "drain_s": 60,
+    "endpoint": "/v1/chat/completions", "request": _REQ,
+}
+TINY_CLOSED = {
+    "loop": "closed", "clients": 3,
+    "prompt_tokens": {"dist": "uniform", "min": 8, "max": 40},
+    "output_tokens": {"dist": "fixed", "value": 8},
+    "preroll_s": 8, "warm_episode_s": 2, "ramp_s": 0.5, "drain_s": 60,
+    "endpoint": "/v1/chat/completions", "request": _REQ,
+}
+
+
+def copy_benchmark(dst: str) -> str:
+    """A temp checkout: BENCHMARK.json + benchmark/ copied (no cache),
+    the program linked. -> its root."""
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), dst)
+    shutil.copytree(
+        os.path.join(ROOT, "benchmark"), os.path.join(dst, "benchmark"),
+        ignore=shutil.ignore_patterns(".cache", "__pycache__"))
+    os.symlink(os.path.join(ROOT, "localai_tfp_tpu"),
+               os.path.join(dst, "localai_tfp_tpu"))
+    return dst
+
+
+def add_cell(root: str, *, config_name: str, config: dict, mix_name: str,
+             mix: dict, cell_name: str, join: list) -> None:
+    """What a later PR does to add a cell: new files, appended entries
+    (``join``: the metrics with a ``workloads`` list the cell joins)."""
+    bdir = os.path.join(root, "benchmark")
+    with open(os.path.join(bdir, "configs", config_name + ".json"), "w") as f:
+        json.dump(config, f)
+    with open(os.path.join(bdir, "traffic", mix_name + ".json"), "w") as f:
+        json.dump(mix, f)
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        man = json.load(f)
+    man["configs"].append({
+        "name": config_name, "source": "tests/benchmark",
+        "file": f"benchmark/configs/{config_name}.json", "reduced": [],
+        "why": "toy"})
+    man["workloads"].append({
+        "name": cell_name, "config": config_name, "traffic": mix_name,
+        "chips": 1, "why": "toy"})
+    for m in man["end_to_end"] + man["per_layer"]:
+        if "workloads" in m and m["name"] in join:
+            m["workloads"].append(cell_name)
+    with open(path, "w") as f:
+        json.dump(man, f)

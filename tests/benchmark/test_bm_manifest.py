@@ -1,0 +1,156 @@
+"""BENCHMARK.json against the contract, and the claim that a cell, a
+configuration, a mix and a metric are added by data alone."""
+
+import json
+import os
+import re
+
+import pytest
+
+from benchmark.lib import layer_metrics, traffic
+from benchmark.lib import manifest as M
+
+from . import helpers as H
+
+MAN = M.load(H.ROOT)
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+ALL_METRICS = MAN["end_to_end"] + MAN["per_layer"]
+
+
+def test_manifest_is_sound():
+    assert M.problems(MAN, H.ROOT) == []
+
+
+def test_manifest_keys_and_size():
+    assert set(MAN) == {"command", "paths", "run_seconds", "configs",
+                        "workloads", "end_to_end", "per_layer"}
+    assert os.path.getsize(os.path.join(H.ROOT, "BENCHMARK.json")) < 65536
+    assert MAN["command"] == ["python3", "benchmark/run.py"]
+    assert 1 <= MAN["run_seconds"] <= 51
+    # a full check of 24 cells fits the driver's allowance
+    assert (2 + 14 * 24) * (MAN["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
+
+
+@pytest.mark.parametrize("m", ALL_METRICS, ids=lambda m: m["name"])
+def test_metric_entry(m):
+    assert NAME.match(m["name"]) and UNIT.match(m["unit"])
+    assert m["better"] in ("lower", "higher")
+    assert m["source"] in M.SOURCES
+    if m["name"].endswith("_roofline"):
+        assert m["unit"] == "%"
+
+
+@pytest.mark.parametrize("m", MAN["per_layer"], ids=lambda m: m["name"])
+def test_layer_metric_moves_a_metric_its_cells_report(m):
+    e2e = {e["name"]: e for e in MAN["end_to_end"]}
+    target = e2e[m["moves"]]
+    cells = m.get("workloads", [w["name"] for w in MAN["workloads"]])
+    for c in cells:
+        assert "workloads" not in target or c in target["workloads"]
+    assert layer_metrics.find(
+        os.path.join(H.ROOT, "benchmark", "layer_metrics"), m["name"])
+
+
+@pytest.mark.parametrize("w", MAN["workloads"], ids=lambda w: w["name"])
+def test_cell_files_exist_and_load(w):
+    assert NAME.match(w["name"]) and NAME.match(w["traffic"])
+    assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
+    cfg = M.config_path(MAN, H.ROOT, w["config"])
+    with open(cfg) as f:
+        config = json.load(f)
+    for key in ("source", "serving", "reduced", "assumed", "deployment",
+                "weights_seed", "parity_prompts", "parity_tol",
+                "parity_tol_reason"):
+        assert key in config, key
+    mix = traffic.load_mix(M.traffic_path(H.ROOT, w["traffic"]),
+                           M.cell_overrides(H.ROOT, w["name"]))
+    sched = traffic.schedule(mix, 3, MAN["run_seconds"])
+    assert sched["requests"]
+    e2e = [m["name"] for m in M.metrics_of(MAN, "end_to_end", w["name"])]
+    assert "setup_s" in e2e and len(e2e) >= 2
+    assert M.metrics_of(MAN, "per_layer", w["name"])
+
+
+@pytest.mark.parametrize("c", MAN["configs"], ids=lambda c: c["name"])
+def test_config_widths_are_the_published_ones(c):
+    """Only depth may differ from the source, and only where listed."""
+    with open(os.path.join(H.ROOT, c["file"])) as f:
+        config = json.load(f)
+    assert config["source"] == c["source"]
+    assert config["reduced"] == c["reduced"]
+    assert (config["hidden_size"], config["intermediate_size"],
+            config["num_attention_heads"], config["num_key_value_heads"],
+            config["head_dim"]) == (4096, 14336, 32, 8, 128)
+    published = config["published"]["num_hidden_layers"]
+    if "num_hidden_layers" in c["reduced"]:
+        assert config["num_hidden_layers"] < published
+    else:
+        assert config["num_hidden_layers"] == published
+
+
+def test_problems_are_reported():
+    bad = json.loads(json.dumps(MAN))
+    bad["per_layer"][0]["moves"] = "no_such_metric"
+    bad["workloads"][0]["name"] = "has space"
+    bad["end_to_end"][0]["unit"] = "tokens per second"
+    out = "\n".join(M.problems(bad, H.ROOT))
+    assert "no_such_metric" in out and "has space" in out
+    assert "bad unit" in out
+
+
+def test_add_cell_config_mix_and_metric_by_data_alone(tmp_path):
+    """A later PR's move, in a temp copy: new files and appended
+    entries, no edit to a file that is there."""
+    root = H.copy_benchmark(str(tmp_path))
+    before = {}
+    for d, _dirs, files in os.walk(os.path.join(root, "benchmark")):
+        for fn in files:
+            p = os.path.join(d, fn)
+            with open(p, "rb") as f:
+                before[p] = f.read()
+    H.add_cell(root, config_name="tiny", config=H.TINY,
+               mix_name="tiny_bursty",
+               mix=dict(H.TINY_OPEN, arrivals={"dist": "gamma", "cv": 3},
+                        burst={"every_s": 2, "count": 3,
+                               "prompt_tokens": {"dist": "fixed",
+                                                 "value": 90},
+                               "output_tokens": {"dist": "fixed",
+                                                 "value": 4}}),
+               cell_name="tiny_bursty_cell",
+               join=["tpot_p50_ms", "decode_rows_mean"])
+    mdir = os.path.join(root, "benchmark", "layer_metrics")
+    with open(os.path.join(mdir, "slots_busy_max.json"), "w") as f:
+        json.dump({"source": "metrics", "family": "engine_slots_busy_count",
+                   "reduce": "max_poll"}, f)
+    with open(os.path.join(mdir, "requests_in_log.py"), "w") as f:
+        f.write("def reduce(trace, run):\n    return len(run['log'])\n")
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        man = json.load(f)
+    for name, src in (("slots_busy_max", "program_counter"),
+                      ("requests_in_log", "host_clock")):
+        man["per_layer"].append({
+            "name": name, "unit": "rows", "better": "higher",
+            "source": src, "layer": "scheduler", "moves": "tpot_p50_ms",
+            "workloads": ["tiny_bursty_cell"]})
+    with open(path, "w") as f:
+        json.dump(man, f)
+    man = M.load(root)
+    assert M.problems(man, root) == []
+    for p, blob in before.items():  # nothing that was there changed
+        with open(p, "rb") as f:
+            assert f.read() == blob, p
+    cell = M.cell(man, "tiny_bursty_cell")
+    mix = traffic.load_mix(M.traffic_path(root, cell["traffic"]))
+    sched = traffic.schedule(mix, 5, 6.0)
+    assert any(r.get("burst") for r in sched["requests"])
+    names = [m["name"] for m in
+             M.metrics_of(man, "per_layer", "tiny_bursty_cell")]
+    assert {"slots_busy_max", "requests_in_log", "load_s"} <= set(names)
+    assert "attn_kernel_roofline" not in names
+    run = {"log": [1, 2, 3], "polls": [
+        {"engine_slots_busy_count": [({"model": "tiny"}, 2.0)]},
+        {"engine_slots_busy_count": [({"model": "tiny"}, 4.0)]}]}
+    assert layer_metrics.evaluate(mdir, "slots_busy_max", None, run) == 4.0
+    assert layer_metrics.evaluate(mdir, "requests_in_log", None, run) == 3.0
