@@ -76,7 +76,9 @@ from ..ops.sampling import (
 )
 from ..telemetry import costmodel, hbm_ledger
 from ..telemetry import metrics as tm
-from ..telemetry.flightrec import FLIGHT
+from ..telemetry.flightrec import (
+    FLIGHT, LoadWatch, PhaseClock, name_os_thread, note_jit,
+)
 from ..telemetry.tracing import TRACER, fault_scope
 from ..utils import faultinject
 from .kv_pool import TRASH_PAGE, PagePool, PagePoolExhausted
@@ -295,6 +297,11 @@ class EngineMetrics:
     prefix_reused_tokens: int = 0
     prefill_tokens: int = 0
     prefix_copies: int = 0  # kvcopy dispatches enqueued
+
+
+# dispatch kinds whose programs take the sampler state as an argument
+_SAMPLING_KINDS = frozenset(
+    ("prefill_final", "mixed", "decode1", "decodek", "spec_s"))
 
 
 def _soft_expand(tokens: jax.Array, rows: jax.Array, brow: jax.Array,
@@ -888,6 +895,20 @@ class LLMEngine:
         # compile cache (see warmup docstring); surfaced in the load
         # phase breakdown
         self.state_dir = state_dir or hbm_ledger.default_state_dir()
+        # what the scheduler was doing (telemetry/flightrec.py): phase
+        # spans whose self times are plain floats here, published as
+        # engine_sched_phase_seconds_total by _update_gauges through
+        # children bound once; program loads attributed to the dispatch
+        # that stood still for them; token counts at the dispatch sites
+        self._phases = PhaseClock()
+        self._phase_pub = dict.fromkeys(self._phases.totals, 0.0)
+        self._phase_ctr = {
+            ph: tm.ENGINE_SCHED_PHASE.labels(model=self._mlabel, phase=ph)
+            for ph in self._phases.totals}
+        self._loads = LoadWatch(self._mlabel)
+        self._in_warmup = False
+        self._tok_ctr: dict = {}  # kind -> its bound counter children
+        self._tick_t = 0.0  # last ~1 Hz gauge tick
         # warmup-captured XLA cost model: per-dispatch FLOPs/bytes
         # accounting + the MFU gauge (telemetry/costmodel.py). Host-held
         # counters only — the hot path never syncs for accounting.
@@ -903,7 +924,6 @@ class LLMEngine:
         # long-lived device allocations registered here, reconciled
         # against device.memory_stats() each gauge sweep
         self._ledger: Optional[hbm_ledger.HBMLedger] = None
-        self._ledger_t = 0.0  # last reconcile (rate-limited ~1s)
         if knobs.flag("LOCALAI_HBM_LEDGER"):
             led = hbm_ledger.HBMLedger(self._mlabel)
             if self._pager is not None:
@@ -2082,14 +2102,38 @@ class LLMEngine:
             with ch.order_lock:
                 ch.publish(kind, {"model": self.tag, "data": wire,
                                   "trace": trace})
-                out = self._dev_exec(kind, payload)
+                out = self._exec_traced(kind, payload)
             if ckey is not None:
                 cm.on_dispatch(kind, ckey)
             return out
-        out = self._dev_exec(kind, payload)
+        out = self._exec_traced(kind, payload)
         if ckey is not None:
             cm.on_dispatch(kind, ckey)
         return out
+
+    def _exec_traced(self, kind: str, payload: dict) -> Any:
+        """``_dev_exec`` under its span and its load watch: the
+        ``sched:enqueue:<kind>`` phase (payload -> device arrays ->
+        launch; a no-op off the scheduler thread) and the binding that
+        lets a program load name this dispatch's full variant key."""
+        vkey = self._variant_key(kind, payload)
+        with self._phases.span("sched:enqueue:" + kind, {"key": vkey}), \
+                self._loads.watch(kind, vkey, self._in_warmup):
+            return self._dev_exec(kind, payload)
+
+    def _variant_key(self, kind: str, payload: dict) -> tuple:
+        """``costmodel.variant_key`` plus the one selector that lives in
+        engine state, not in the payload: the sampler state as built at
+        construction is UNCOMMITTED, and every program that takes it
+        lowers again once a program's output (committed, like the cache
+        it ran with) has replaced it — so the first sampling dispatch of
+        a process loads a variant nothing reuses (seen on the chip as
+        one key loaded twice; a load's ``arg_sig`` told them apart)."""
+        key = costmodel.variant_key(kind, payload)
+        if kind in _SAMPLING_KINDS and not getattr(
+                self.sampling.rng, "committed", True):
+            key += (("sampling", "fresh"),)
+        return key
 
     def _dev_exec(self, kind: str, p: dict) -> Any:
         """Device-only work for one dispatch record. MUST be fully
@@ -2102,6 +2146,10 @@ class LLMEngine:
             return (jnp.asarray(p["pt"]), jnp.asarray(p["wb"]))
 
         def cap(fn, *args, **kw):
+            # for the load watch, by reference: the jitted function
+            # (its cache size is the fallback evidence of a load) and
+            # the call's arguments (a load's arg_sig is made from them)
+            note_jit(fn, args, kw)
             # warmup capture hook: AOT-compile this exact variant and
             # record its XLA cost row (no-op outside capture mode —
             # the serving hot path pays one attribute check)
@@ -2487,16 +2535,21 @@ class LLMEngine:
             # Capture mode rides the pass: _dev_exec records each
             # variant's XLA cost row (telemetry/costmodel.py) while the
             # pad dispatch itself stays unaccounted (it is not traffic)
+            # The pass's program loads are counted like any other,
+            # marked in_warmup: "what serving reached that warmup did
+            # not cover" is then one query over one family
             nonlocal n_variants
             n_variants += 1
             cm = self._costmodel
-            if cm is None:
-                return self._run(kind, payload)
-            cm.capturing = True
+            self._in_warmup = True
+            if cm is not None:
+                cm.capturing = True
             try:
                 return self._run(kind, payload)
             finally:
-                cm.capturing = False
+                self._in_warmup = False
+                if cm is not None:
+                    cm.capturing = False
 
         W = self.sampling.window
         pad_reset = self._reset_columns([], 1)
@@ -3002,6 +3055,9 @@ class LLMEngine:
     # ------------------------------------------------------------- scheduler
 
     def _loop(self) -> None:
+        # the profiler names a host line after the OS thread: the
+        # scheduler's sched:* spans then sit on a line of this name
+        name_os_thread("llm-engine")
         while True:
             if not self._has_work():
                 # TRUE idle transition: step() will not run again until
@@ -3091,14 +3147,25 @@ class LLMEngine:
         device-queue time, so the pipelining hides QUEUE time, and
         keeping the queue clean around latency-critical dispatches is
         what matters."""
+        span = self._phases.span
+        with span("sched:guards", root=True):
+            self._guards()
+        with span("sched:admit", root=True):
+            self._admit()
+        with span("sched:harvest", root=True):
+            harvested = self._harvest()
+        with span("sched:dispatch", root=True):
+            dispatched = self._dispatch()
+        with span("sched:gauges", root=True):
+            self._update_gauges()
+        if not (harvested or dispatched):
+            with span("sched:wait", root=True):
+                self._wait_for_event()
+
+    def _guards(self) -> None:
+        """Request lifecycle guards, ahead of admission."""
         self._apply_cancellations()
         self._apply_deadlines()
-        self._admit()
-        harvested = self._harvest()
-        dispatched = self._dispatch()
-        self._update_gauges()
-        if not (harvested or dispatched):
-            self._wait_for_event()
 
     def _refresh_prefix_summary(self, force: bool = False) -> None:
         """Recompute the gossiped prefix top-k when the refresh
@@ -3129,11 +3196,18 @@ class LLMEngine:
         busy = sum(1 for s in self.slots if s.active)
         tm.ENGINE_SLOTS_BUSY.labels(model=m).set(busy)
         tm.ENGINE_QUEUE_DEPTH.labels(model=m).set(len(self._pending))
-        # timeline counter samples: same host scalars, per-iteration
-        # cadence (one ring slot each — never per event/per request)
+        # timeline counter samples: same host scalars, recorded only
+        # when a value changed (FLIGHT.sample), so the ring keeps its
+        # step:/load:/sched: spans for minutes
         FLIGHT.sample("queue_depth", "scheduler", len(self._pending))
         FLIGHT.sample("slots_busy", "scheduler", busy)
-        FLIGHT.update_gauge()
+        # phase self times the spans added since the last pass
+        pub = self._phase_pub
+        for ph, total in self._phases.totals.items():
+            d = total - pub[ph]
+            if d > 0.0:
+                pub[ph] = total
+                self._phase_ctr[ph].inc(d)
         used = sum(s.n_past for s in self.slots if s.active)
         tm.ENGINE_KV_UTIL.labels(model=m).set(
             used / float(self.n_slots * self.max_seq))
@@ -3193,13 +3267,15 @@ class LLMEngine:
         # has no locking); host hashing only, published by atomic
         # tuple swap
         self._refresh_prefix_summary()
-        if self._ledger is not None:
-            # ledger reconcile + device/host memory gauges: host dict
-            # math and a memory_stats() host call, rate-limited to ~1/s
-            # so a ms-scale scheduler iteration never pays it
-            now = time.monotonic()
-            if now - self._ledger_t >= 1.0:
-                self._ledger_t = now
+        now = time.monotonic()
+        if now - self._tick_t >= 1.0:
+            # the ~1 Hz tick: what a ms-scale scheduler iteration must
+            # never pay — the ring-occupancy gauge, and the ledger
+            # reconcile + device/host memory gauges (host dict math and
+            # a memory_stats() host call)
+            self._tick_t = now
+            FLIGHT.update_gauge()
+            if self._ledger is not None:
                 self._ledger.reconcile()
                 from ..utils import sysinfo
 
@@ -3367,12 +3443,13 @@ class LLMEngine:
                 # already-harvested scalars
                 self._costmodel.on_harvest(
                     fl.kind, fl.meta.get("cost"), dur, predicted_ms=pred)
-            if fl.kind == "prefill_final":
-                self._complete_prefill_final(fl)
-            elif fl.kind == "mixed":
-                self._complete_mixed(fl)
-            else:
-                self._complete_decodek(fl)
+            with self._phases.span("sched:emit"):
+                if fl.kind == "prefill_final":
+                    self._complete_prefill_final(fl)
+                elif fl.kind == "mixed":
+                    self._complete_mixed(fl)
+                else:
+                    self._complete_decodek(fl)
             did = True
         return did
 
@@ -3999,7 +4076,10 @@ class LLMEngine:
                 [(slot.idx, (slot.n_past, slot.n_past + len(chunk)))],
                 window)
         self._run("prefill", payload)
-        slot.n_past += len(chunk)
+        n = len(chunk)
+        self._note_dispatch_tokens(
+            "prefill", n, bucket, n * slot.n_past + n * (n - 1) // 2)
+        slot.n_past += n
         slot.cache_tokens.extend(chunk)
         if slot.t_prefill_t0 == 0.0:
             slot.t_prefill_t0 = t0
@@ -4226,9 +4306,13 @@ class LLMEngine:
         toks_out.copy_to_host_async()
         t_disp = time.perf_counter()
         enq_ms = (t_disp - t0) * 1e3
+        real = context = 0
         for s in group:
             req = s.request
             chunk_len = len(req.prompt_ids) - s.n_past
+            real += chunk_len
+            context += (chunk_len * s.n_past
+                        + chunk_len * (chunk_len - 1) // 2)
             s.cache_tokens.extend(req.prompt_ids[s.n_past:])
             s.n_past += chunk_len
             s.state = SlotState.PENDING_FIRST
@@ -4239,6 +4323,8 @@ class LLMEngine:
         tm.ENGINE_MIXED_DISPATCH.labels(
             model=self._mlabel, composition="prefill_only").inc()
         self._note_ragged_rows("final", len(group))
+        self._note_dispatch_tokens("prefill_final", real, B * bucket,
+                                   context)
         ckey = costmodel.dispatch_key("prefill_final", payload)
         self._flights.append(_Flight(
             kind="prefill_final", arrays=[toks_out],
@@ -4431,8 +4517,12 @@ class LLMEngine:
         toks_out.copy_to_host_async()
         t_disp = time.perf_counter()
         enq_ms = (t_disp - t0) * 1e3
+        # a decode row reads its whole cache; a chunk its causal sum
+        context = sum(s.n_past for s in decoding)
         for s in prefilling:
             chunk_len = min(s.n_prompt - s.n_past, bucket)
+            context += (chunk_len * s.n_past
+                        + chunk_len * (chunk_len - 1) // 2)
             s.cache_tokens.extend(
                 s.request.prompt_ids[s.n_past: s.n_past + chunk_len])
             s.n_past += chunk_len
@@ -4448,6 +4538,8 @@ class LLMEngine:
         self._note_ragged_rows("decode", len(decoding))
         self._note_ragged_rows("final", len(finals))
         self._note_ragged_rows("prefill", len(prefilling) - len(finals))
+        self._note_dispatch_tokens("mixed", len(decoding) + chunk_tokens,
+                                   S * bucket, context)
         if decoding:
             self._note_decode_advance(t_disp)
         ckey = costmodel.dispatch_key("mixed", payload)
@@ -4530,6 +4622,31 @@ class LLMEngine:
             tm.ENGINE_DECODE_STALL.labels(model=self._mlabel).observe(
                 max(0.0, now - self._last_decode_adv))
         self._last_decode_adv = now
+
+    def _note_dispatch_tokens(self, kind: str, real: int, padded: int,
+                              context: int, steps: int = 0) -> None:
+        """Counts taken where the work is dispatched, from host scalars
+        the enqueue already holds: token positions that carry work
+        against those of the program's shape
+        (engine_dispatch_tokens_total{part}), the context tokens the
+        attention rows have to read (engine_attn_context_tokens_total)
+        and, for decode-only programs, the token-steps
+        (engine_decode_steps_total). Children are bound once a kind."""
+        ctr = self._tok_ctr.get(kind)
+        if ctr is None:
+            m = self._mlabel
+            ctr = self._tok_ctr[kind] = (
+                tm.ENGINE_DISPATCH_TOKENS.labels(
+                    model=m, kind=kind, part="real"),
+                tm.ENGINE_DISPATCH_TOKENS.labels(
+                    model=m, kind=kind, part="padded"),
+                tm.ENGINE_ATTN_CONTEXT_TOKENS.labels(model=m, kind=kind),
+                tm.ENGINE_DECODE_STEPS.labels(model=m))
+        ctr[0].inc(real)
+        ctr[1].inc(padded)
+        ctr[2].inc(context)
+        if steps:
+            ctr[3].inc(steps)
 
     def _note_ragged_rows(self, kind: str, n: int) -> None:
         """Rows advanced through the unified ragged path by kind
@@ -4918,6 +5035,12 @@ class LLMEngine:
                       if i in advancing else None)) for i in range(S)],
                 window)
         self._note_ragged_rows("decode", len(decoding))
+        # step j of the scan reads the row's cache as it stands then:
+        # n_past + the tokens of scans still in flight + j
+        self._note_dispatch_tokens(
+            "decodek", len(decoding) * k, S * k,
+            sum(k * (s.n_past + in_flight) + k * (k - 1) // 2
+                for s in decoding), steps=k)
         batches = self._run("decodek", payload)
         toks = batches[0]
         toks.copy_to_host_async()
@@ -5054,6 +5177,9 @@ class LLMEngine:
                 [(s.idx, ((s.n_past, s.n_past + 1)
                           if s.state is SlotState.DECODE else None))
                  for s in self.slots], self.max_seq)
+        self._note_dispatch_tokens(
+            "decode1", len(decoding), S,
+            sum(s.n_past for s in decoding), steps=1)
         toks = self._run("decode1", payload)
         # lint: ignore[hot-path-sync] decode1 IS the blocking path: grammar masks / logit bias need every token on host before the next dispatch
         toks_host = np.asarray(toks)

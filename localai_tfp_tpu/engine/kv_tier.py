@@ -410,10 +410,18 @@ class KVTierManager:
         tbl = jnp.asarray(np.asarray(
             copies + [TRASH_PAGE] * (_pow2(len(copies)) - len(copies)),
             np.int32))
-        handles = [_gather_pages(c.k, tbl), _gather_pages(c.v, tbl)]
+        # under the engine's load watch: one program per (page count,
+        # plane shape), each loaded on first use inside the serving loop
+        def gather(arr):
+            return eng._loads.call(
+                _gather_pages, "kv_gather",
+                ("kv_gather", int(tbl.shape[0]), tuple(arr.shape[2:]),
+                 str(arr.dtype)), arr, tbl)
+
+        handles = [gather(c.k), gather(c.v)]
         if c.quantized:
-            handles.append(_gather_pages(c.k_scale, tbl))
-            handles.append(_gather_pages(c.v_scale, tbl))
+            handles.append(gather(c.k_scale))
+            handles.append(gather(c.v_scale))
         for h in handles:
             h.copy_to_host_async()
         nbytes = sum(int(h.nbytes) for h in handles)
@@ -614,14 +622,20 @@ class KVTierManager:
         tbl = jnp.asarray(np.asarray(
             table + [TRASH_PAGE] * (b - npg), np.int32))
         dk, dv = jax.device_put(rk), jax.device_put(rv)
-        ck = _scatter_pages(c.k, tbl, dk)
-        cv = _scatter_pages(c.v, tbl, dv)
+        def scatter(arr, rows):
+            return eng._loads.call(
+                _scatter_pages, "kv_scatter",
+                ("kv_scatter", b, tuple(arr.shape[2:]), str(arr.dtype)),
+                arr, tbl, rows)
+
+        ck = scatter(c.k, dk)
+        cv = scatter(c.v, dv)
         ks, vs = c.k_scale, c.v_scale
         handles = [dk, dv]
         if c.quantized:
             dks, dvs = jax.device_put(rks), jax.device_put(rvs)
-            ks = _scatter_pages(ks, tbl, dks)
-            vs = _scatter_pages(vs, tbl, dvs)
+            ks = scatter(ks, dks)
+            vs = scatter(vs, dvs)
             handles += [dks, dvs]
         eng.cache = type(c)(k=ck, v=cv, k_scale=ks, v_scale=vs)
         eng._epoch += 1

@@ -18,6 +18,7 @@ tracked ``.cpp`` instead of running it.
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import functools
 import glob
 import hashlib
@@ -55,18 +56,32 @@ def library_path(name: str) -> str:
 def build(quiet: bool = True) -> bool:
     """Invoke make for the current source tag and drop every other
     library file from the build dir; returns True if the libraries are
-    present after."""
+    present after.
+
+    One build at a time per build dir, by an exclusive ``flock`` on the
+    directory itself (no extra file): several processes building at
+    once — pytest-xdist workers each importing tests/test_native.py on
+    a fresh tree — raced on the Makefile's ``$@.tmp`` -> ``mv``, and
+    the loser's ``make`` failed though the library was there."""
     try:
         tag = source_tag()
+        os.makedirs(BUILD_DIR, exist_ok=True)
+        lock = os.open(BUILD_DIR, os.O_RDONLY)
+    except (OSError, subprocess.CalledProcessError):
+        return False
+    try:
+        fcntl.flock(lock, fcntl.LOCK_EX)
         subprocess.run(
             ["make", "-C", _DIR, f"BUILD={BUILD_DIR}", f"TAG={tag}"],
             capture_output=quiet, check=True,
         )
+        for stale in glob.glob(os.path.join(BUILD_DIR, "lib*.so")):
+            if not stale.endswith(f"-{tag}.so"):
+                os.unlink(stale)
     except (OSError, subprocess.CalledProcessError):
         return False
-    for stale in glob.glob(os.path.join(BUILD_DIR, "lib*.so")):
-        if not stale.endswith(f"-{tag}.so"):
-            os.unlink(stale)
+    finally:
+        os.close(lock)  # releases the flock
     return True
 
 

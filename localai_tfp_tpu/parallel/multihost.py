@@ -43,7 +43,7 @@ from typing import Any, Optional, Tuple
 
 import numpy as np
 
-from ..telemetry.flightrec import FLIGHT
+from ..telemetry.flightrec import FLIGHT, PhaseClock
 from ..telemetry.tracing import TRACER
 from ..utils import faultinject
 
@@ -295,6 +295,9 @@ class Replayer:
     def __init__(self) -> None:
         self._n = 0
         self._open: set = set()  # leader trace ids with a live entry
+        # the leader's enqueue phase, on this host: the same span (ring
+        # at >= 1 ms, TraceAnnotation while a capture runs)
+        self._phases = PhaseClock("follower")
 
     def _note_trace(self, kind: str, trace: tuple) -> None:
         live = set(trace)
@@ -316,7 +319,8 @@ class Replayer:
              trace: tuple = ()) -> None:
         self._note_trace(kind, trace)
         t0 = time.perf_counter()
-        engine._dev_exec(kind, payload)
+        with self._phases.span("sched:enqueue:" + kind, root=True):
+            engine._dev_exec(kind, payload)
         # host-side enqueue span only — _dev_exec returns as soon as the
         # dispatch is queued, so no sync is implied by timing it
         FLIGHT.span("replay:" + kind, "follower", t0,

@@ -224,15 +224,26 @@ async def debug_profile(request: web.Request) -> web.Response:
     st = _state(request)
     logdir = os.path.join(st.config.state_dir, "profiles",
                           time.strftime("%Y%m%d-%H%M%S"))
+    from ..telemetry import flightrec
+
     try:
         import jax
 
         os.makedirs(logdir, exist_ok=True)
-        jax.profiler.start_trace(logdir)
+        # both ends run on the worker pool: stop_trace writes the
+        # capture, which takes seconds at serving scale, and on the
+        # event loop it held every SSE stream for as long
+        await run_blocking(jax.profiler.start_trace, logdir)
+        # the scheduler's spans are TraceAnnotations exactly while the
+        # profiler listens (flightrec.PhaseClock / LoadWatch)
+        flightrec.set_capturing(True)
+        t_start = time.perf_counter()
         try:
             await asyncio.sleep(duration)
         finally:
-            jax.profiler.stop_trace()
+            t_stop = time.perf_counter()
+            flightrec.set_capturing(False)
+            await run_blocking(jax.profiler.stop_trace)
     except Exception as e:
         raise web.HTTPInternalServerError(
             reason=f"profiler capture failed: {e!r}")
@@ -253,8 +264,13 @@ async def debug_profile(request: web.Request) -> web.Response:
                 % os.path.basename(logdir),
                 "Cache-Control": "no-store",
             })
-    return web.json_response({"path": logdir, "duration_s": duration},
-                             headers={"Cache-Control": "no-store"})
+    # perf_counter at both ends, against /debug/timeline's origin: a
+    # timeline (microseconds since timeline_t0) lays beside the capture
+    return web.json_response(
+        {"path": logdir, "duration_s": duration,
+         "perf_counter_start": t_start, "perf_counter_stop": t_stop,
+         "timeline_t0": flightrec.origin()},
+        headers={"Cache-Control": "no-store"})
 
 
 async def system(request: web.Request) -> web.Response:

@@ -142,6 +142,27 @@ def dispatch_key(kind: str, payload: dict) -> tuple:
     return (kind,)
 
 
+def variant_key(kind: str, payload: dict) -> tuple:
+    """``dispatch_key`` plus the inputs that select another executable
+    WITHOUT changing the cost row — what a program-load event has to
+    name to tell two loads of one kind apart (telemetry/flightrec.py
+    LoadWatch). ``carry``: a decodek whose token/position/active inputs
+    are the device-resident carry of the previous scan (committed
+    arrays) and not fresh host arrays lowers again; ``masks``/``soft``:
+    None or an array is a different argument tree. Kept out of
+    ``dispatch_key`` itself: the cost table is keyed by it, and the
+    warmup pass captures rows with ``carry: False`` only."""
+    p = payload
+    key = dispatch_key(kind, p)
+    if kind == "decodek":
+        key += (("carry", bool(p.get("carry"))),)
+    if "masks" in p:
+        key += (("masks", p["masks"] is not None),)
+    if "soft" in p:
+        key += (("soft", p["soft"] is not None),)
+    return key
+
+
 def _extract_costs(analysis: dict) -> tuple[float, float]:
     """(flops, bytes accessed) from a ``compiled.cost_analysis()`` dict
     (what the installed jax returns on CPU and TPU alike)."""

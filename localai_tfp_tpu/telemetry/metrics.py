@@ -408,6 +408,60 @@ TIMELINE_RING_EVENTS = REGISTRY.gauge(
     "(telemetry/flightrec.py; exported as Chrome-trace JSON via "
     "GET /debug/timeline)",
 )
+# the scheduler says what it was doing (telemetry/flightrec.py
+# PhaseClock / LoadWatch + the dispatch sites in engine.py)
+ENGINE_SCHED_PHASE = REGISTRY.counter(
+    "engine_sched_phase_seconds_total",
+    "Scheduler-thread SELF time per phase (guards = cancellations + "
+    "deadlines, admit, harvest, emit = token emission inside harvest, "
+    "dispatch = payload building, enqueue = payload -> device arrays "
+    "-> launch, gauges, wait = nothing ready and nothing to enqueue) — "
+    "the phases tile the scheduler's wall time while it has work, so "
+    "host time per phase reads over any window with no capture",
+    labels=("model", "phase"),
+)
+ENGINE_PROGRAM_LOADS = REGISTRY.counter(
+    "engine_program_loads_total",
+    "Programs loaded on first use — the first execution in this "
+    "process of a (program, input signature): traced, lowered, and "
+    "compiled (source = compile), fetched from the persistent compile "
+    "cache (cache), or only re-traced (trace) — while the calling "
+    "thread, for a dispatch the scheduler and every stream, stood "
+    "still. Warmup's loads count here too (in_warmup on "
+    "/backend/monitor tells them apart)",
+    labels=("model", "kind", "source"),
+)
+ENGINE_PROGRAM_LOAD_SECONDS = REGISTRY.histogram(
+    "engine_program_load_seconds",
+    "Wall time the dispatching thread was blocked by one dispatch "
+    "that loaded a program (trace + lower + compile or cache fetch + "
+    "the launch itself)",
+    labels=("model", "kind"),
+)
+ENGINE_DISPATCH_TOKENS = REGISTRY.counter(
+    "engine_dispatch_tokens_total",
+    "Token positions dispatched, by kind and part (real = positions "
+    "that carry work: decode rows x steps + prompt-chunk tokens; "
+    "padded = positions of the program's shape: n_slots x bucket for "
+    "mixed, group rows x bucket for prefill_final, n_slots x k x "
+    "depth for decodek) — real / padded is how full a dispatch was",
+    labels=("model", "kind", "part"),
+)
+ENGINE_ATTN_CONTEXT_TOKENS = REGISTRY.counter(
+    "engine_attn_context_tokens_total",
+    "Context tokens the attention rows of a dispatch had to read from "
+    "the KV cache, summed over rows (a decode row at context c in a "
+    "k-step scan adds c + (c+1) + ... + (c+k-1); a prompt chunk of n "
+    "tokens at position p adds its causal sum n*p + n(n-1)/2) — with "
+    "engine_decode_steps_total the mean context per decode step",
+    labels=("model", "kind"),
+)
+ENGINE_DECODE_STEPS = REGISTRY.counter(
+    "engine_decode_steps_total",
+    "Decode token-steps dispatched by decode-only programs (k x depth "
+    "per k-step scan, 1 per single-step dispatch)",
+    labels=("model",),
+)
 ENGINE_DEVICE_FLOPS = REGISTRY.counter(
     "engine_device_flops_total",
     "Device FLOPs accounted per dispatch kind from the warmup-captured "

@@ -891,6 +891,13 @@ class JaxLLMBackend(Backend):
             # host-held values only
             "costmodel": eng.cost_stats(),
             "hbm": eng.hbm_stats(),
+            # the last 32 program loads, each with its full variant key
+            # (kind, key, source, seconds, in_warmup), and the
+            # scheduler thread's self time per phase
+            "program_loads": eng._loads.stats(),
+            "sched_phase_seconds": {
+                ph: round(v, 4)
+                for ph, v in eng._phases.totals.items()},
         }
 
 

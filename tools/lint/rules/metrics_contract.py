@@ -94,6 +94,12 @@ REQUIRED_FAMILIES = {
     "engine_hbm_bytes",
     "device_hbm_used_bytes",
     "process_rss_bytes",
+    "engine_sched_phase_seconds_total",
+    "engine_program_loads_total",
+    "engine_program_load_seconds",
+    "engine_dispatch_tokens_total",
+    "engine_attn_context_tokens_total",
+    "engine_decode_steps_total",
 }
 
 _METRICS_MODULE = "localai_tfp_tpu/telemetry/metrics.py"

@@ -16,6 +16,13 @@ calls ``end_span``), or the balanced-by-construction context manager
 ``with TRACER.span(rid, "name"):``. Anything else — a discarded token,
 an end_span outside the protecting ``finally``, statements between the
 begin and the try that could raise — flags here.
+
+The scheduler's phase spans (``telemetry/flightrec.py`` ``PhaseClock``)
+and load watches (``LoadWatch``) have NO begin/end pair to misuse: the
+only way in is ``with clock.span(name):`` / ``with watch.watch(...):``,
+balanced by construction, so there is nothing for this rule to find
+there — a future ``begin_span``-shaped entry point on them would be
+checked like any other.
 """
 
 from __future__ import annotations
