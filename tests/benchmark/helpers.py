@@ -8,8 +8,12 @@ import json
 import os
 import shutil
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
+# where BENCHMARK.json and benchmark/'s data files are read from: the
+# repo, or a temp copy with one more cell in it (BM_TESTS_ROOT, set by
+# test_bm_manifest.py's N-cell proof, which runs these tests there)
+ROOT = os.environ.get("BM_TESTS_ROOT") or REPO
 
 PARITY_PROMPTS = [
     "A paged cache hands out attention memory page by page.",
@@ -40,20 +44,21 @@ TINY_MOE = dict(TINY, model_type="mixtral",
                 num_local_experts=4, num_experts_per_tok=2)
 
 _REQ = {"temperature": 0, "ignore_eos": True}
+_NOTES = "toy sizes for the CPU tests: nothing here stands for a deployment"
 TINY_OPEN = {
     "loop": "open", "rate_rps": 3,
     "prompt_tokens": {"dist": "lognormal", "median": 24, "sigma": 0.6,
                       "min": 8, "max": 100},
     "output_tokens": {"dist": "uniform", "min": 4, "max": 12},
     "preroll_s": 1, "drain_s": 60,
-    "endpoint": "/v1/chat/completions", "request": _REQ,
+    "endpoint": "/v1/chat/completions", "request": _REQ, "notes": _NOTES,
 }
 TINY_CLOSED = {
     "loop": "closed", "clients": 3,
     "prompt_tokens": {"dist": "uniform", "min": 8, "max": 40},
     "output_tokens": {"dist": "fixed", "value": 8},
     "preroll_s": 8, "warm_episode_s": 2, "ramp_s": 0.5, "drain_s": 60,
-    "endpoint": "/v1/chat/completions", "request": _REQ,
+    "endpoint": "/v1/chat/completions", "request": _REQ, "notes": _NOTES,
 }
 
 
@@ -64,18 +69,48 @@ def copy_benchmark(dst: str) -> str:
     shutil.copytree(
         os.path.join(ROOT, "benchmark"), os.path.join(dst, "benchmark"),
         ignore=shutil.ignore_patterns(".cache", "__pycache__"))
-    os.symlink(os.path.join(ROOT, "localai_tfp_tpu"),
+    os.symlink(os.path.join(REPO, "localai_tfp_tpu"),
                os.path.join(dst, "localai_tfp_tpu"))
     return dst
 
 
+def snapshot(root: str) -> dict:
+    """Every file under the copy's benchmark/, by content."""
+    out = {}
+    for d, _dirs, files in os.walk(os.path.join(root, "benchmark")):
+        for fn in files:
+            p = os.path.join(d, fn)
+            with open(p, "rb") as f:
+                out[p] = f.read()
+    return out
+
+
+def edited(before: dict) -> list:
+    """The files of a ``snapshot`` that no longer read as they did."""
+    out = []
+    for p, blob in before.items():
+        with open(p, "rb") as f:
+            if f.read() != blob:
+                out.append(p)
+    return out
+
+
 def add_cell(root: str, *, config_name: str, config: dict, mix_name: str,
-             mix: dict, cell_name: str, join: list) -> None:
+             mix: dict, cell_name: str, join: "list | None") -> None:
     """What a later PR does to add a cell: new files, appended entries
-    (``join``: the metrics with a ``workloads`` list the cell joins)."""
+    (``join``: the metrics with a ``workloads`` list the cell joins;
+    None = every one of them, as a cell that reports ``tpot_p50_ms``
+    under the same layers does)."""
     bdir = os.path.join(root, "benchmark")
     with open(os.path.join(bdir, "configs", config_name + ".json"), "w") as f:
         json.dump(config, f)
+    # the source's own numbers, beside the configuration (a toy is its
+    # own source)
+    with open(os.path.join(bdir, "configs",
+                           config_name + ".published.json"), "w") as f:
+        json.dump({"source": config["source"], "config": {
+            k: v for k, v in config.items()
+            if isinstance(v, (int, float)) and not isinstance(v, bool)}}, f)
     with open(os.path.join(bdir, "traffic", mix_name + ".json"), "w") as f:
         json.dump(mix, f)
     path = os.path.join(root, "BENCHMARK.json")
@@ -89,7 +124,7 @@ def add_cell(root: str, *, config_name: str, config: dict, mix_name: str,
         "name": cell_name, "config": config_name, "traffic": mix_name,
         "chips": 1, "why": "toy"})
     for m in man["end_to_end"] + man["per_layer"]:
-        if "workloads" in m and m["name"] in join:
+        if "workloads" in m and (join is None or m["name"] in join):
             m["workloads"].append(cell_name)
     with open(path, "w") as f:
         json.dump(man, f)

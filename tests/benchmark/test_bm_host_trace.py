@@ -272,7 +272,8 @@ def test_new_metrics_are_listed_with_the_layers_the_benchmark_names():
                  "program_loads_in_window", "program_load_stall_s",
                  "mixed_fill_share", "attn_kernel_roofline_counted"):
         assert by[name]["layer"] in layers
-        assert by[name]["workloads"] == ["mistral7b_batch_closed"]
+        # membership, never equality: later cells append their names
+        assert "mistral7b_batch_closed" in by[name]["workloads"]
         assert layer_metrics.find(MDIR, name)
 
 

@@ -4,6 +4,8 @@ configuration, a mix and a metric are added by data alone."""
 import json
 import os
 import re
+import subprocess
+import sys
 
 import pytest
 
@@ -72,21 +74,46 @@ def test_cell_files_exist_and_load(w):
     assert M.metrics_of(MAN, "per_layer", w["name"])
 
 
+# a width as BENCHMARK.json's contract names one: never cut, never listed
+WIDTH = re.compile(r"(hidden_size|intermediate_size|head_dim|_dim$|_rank$|"
+                   r"num_attention_heads|num_key_value_heads|"
+                   r"num_experts_per_tok|expansion)")
+
+
 @pytest.mark.parametrize("c", MAN["configs"], ids=lambda c: c["name"])
 def test_config_widths_are_the_published_ones(c):
-    """Only depth may differ from the source, and only where listed."""
+    """Only depth may differ from the source, and only where listed.
+    The source's own numbers are data beside the configuration
+    (``<file>.published.json``, which a new configuration brings with
+    it): every number there is the one run, unless the ``published``
+    group owns up to the source's value, and no width is ever among
+    those or in ``reduced``."""
     with open(os.path.join(H.ROOT, c["file"])) as f:
         config = json.load(f)
-    assert config["source"] == c["source"]
+    with open(os.path.join(
+            H.ROOT, c["file"][:-len(".json")] + ".published.json")) as f:
+        src = json.load(f)
+    assert src["source"] == config["source"] == c["source"]
     assert config["reduced"] == c["reduced"]
-    assert (config["hidden_size"], config["intermediate_size"],
-            config["num_attention_heads"], config["num_key_value_heads"],
-            config["head_dim"]) == (4096, 14336, 32, 8, 128)
-    published = config["published"]["num_hidden_layers"]
+    differs = config.get("published", {})
+    for key in list(c["reduced"]) + list(differs):
+        assert not WIDTH.search(key), key
+    numbers = {k: v for k, v in src["config"].items()
+               if isinstance(v, (int, float)) and not isinstance(v, bool)}
+    assert {"hidden_size", "num_hidden_layers"} <= set(numbers)
+    for key, want in numbers.items():
+        if key not in config:
+            continue  # a number the program does not read (dropout, init)
+        if config[key] != want:
+            assert differs.get(key) == want, key
+    for key in config:  # every width run is one the source states
+        if WIDTH.search(key) and key not in numbers:
+            assert key == "head_dim" and config[key] == (
+                numbers["hidden_size"] // numbers["num_attention_heads"])
     if "num_hidden_layers" in c["reduced"]:
-        assert config["num_hidden_layers"] < published
+        assert config["num_hidden_layers"] < numbers["num_hidden_layers"]
     else:
-        assert config["num_hidden_layers"] == published
+        assert config["num_hidden_layers"] == numbers["num_hidden_layers"]
 
 
 def test_problems_are_reported():
@@ -103,12 +130,7 @@ def test_add_cell_config_mix_and_metric_by_data_alone(tmp_path):
     """A later PR's move, in a temp copy: new files and appended
     entries, no edit to a file that is there."""
     root = H.copy_benchmark(str(tmp_path))
-    before = {}
-    for d, _dirs, files in os.walk(os.path.join(root, "benchmark")):
-        for fn in files:
-            p = os.path.join(d, fn)
-            with open(p, "rb") as f:
-                before[p] = f.read()
+    before = H.snapshot(root)
     H.add_cell(root, config_name="tiny", config=H.TINY,
                mix_name="tiny_bursty",
                mix=dict(H.TINY_OPEN, arrivals={"dist": "gamma", "cv": 3},
@@ -138,9 +160,7 @@ def test_add_cell_config_mix_and_metric_by_data_alone(tmp_path):
         json.dump(man, f)
     man = M.load(root)
     assert M.problems(man, root) == []
-    for p, blob in before.items():  # nothing that was there changed
-        with open(p, "rb") as f:
-            assert f.read() == blob, p
+    assert H.edited(before) == []  # nothing that was there changed
     cell = M.cell(man, "tiny_bursty_cell")
     mix = traffic.load_mix(M.traffic_path(root, cell["traffic"]))
     sched = traffic.schedule(mix, 5, 6.0)
@@ -154,3 +174,47 @@ def test_add_cell_config_mix_and_metric_by_data_alone(tmp_path):
         {"engine_slots_busy_count": [({"model": "tiny"}, 4.0)]}]}
     assert layer_metrics.evaluate(mdir, "slots_busy_max", None, run) == 4.0
     assert layer_metrics.evaluate(mdir, "requests_in_log", None, run) == 3.0
+
+
+def test_a_cell_joined_to_every_listed_metric_passes_every_check(tmp_path):
+    """The N-cell proof. A later PR's cell that reports ``tpot_p50_ms``
+    appends its name to EVERY metric that carries a ``workloads`` list
+    (a ``*roofline*`` metric that moves what the cell reports has to be
+    reported there). Done here in a temp copy of the real manifest;
+    then every test under tests/benchmark/ runs against that copy, so a
+    test that pins a cell list, a count of cells or one configuration's
+    sizes fails here, in the PR that writes it."""
+    if os.environ.get("BM_TESTS_ROOT"):
+        pytest.skip("the inner run of this very proof")
+    root = H.copy_benchmark(str(tmp_path))
+    before = H.snapshot(root)
+    H.add_cell(root, config_name="ncell_tiny", config=H.TINY,
+               mix_name="ncell_closed", mix=H.TINY_CLOSED,
+               cell_name="tiny_everywhere", join=None)
+    man = M.load(root)
+    listed = [m for m in man["end_to_end"] + man["per_layer"]
+              if "workloads" in m]
+    assert listed and all(m["workloads"][-1] == "tiny_everywhere"
+                          and len(m["workloads"]) >= 2 for m in listed)
+    assert M.problems(man, root) == []
+    assert H.edited(before) == []
+    # the new cell reports every per-layer metric and what each moves
+    assert [m["name"] for m in M.metrics_of(man, "per_layer",
+                                            "tiny_everywhere")] == \
+        [m["name"] for m in man["per_layer"]]
+    assert {"tpot_p50_ms", "setup_s"} <= {
+        m["name"] for m in M.metrics_of(man, "end_to_end",
+                                        "tiny_everywhere")}
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith("PYTEST_")}
+    env["BM_TESTS_ROOT"] = root
+    # the rehearsal builds temp cells of its own from the same copy and
+    # takes a minute: it is N cells by construction, and left out
+    p = subprocess.run(
+        [sys.executable, "-m", "pytest",
+         os.path.join(H.REPO, "tests", "benchmark"), "-m", "not slow",
+         "-k", "not rehearsal", "-p", "no:cacheprovider", "-p",
+         "no:randomly"],
+        cwd=H.REPO, env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stdout[-4000:] + p.stderr[-1000:]
+    assert " passed" in p.stdout and " failed" not in p.stdout

@@ -224,7 +224,7 @@ def recorded():
     removed; op names cut to 160 characters."""
     import gzip
 
-    path = os.path.join(H.ROOT, "tests", "benchmark", "data",
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
                         "v5e_capture_sample.json.gz")
     with gzip.open(path) as f:
         return json.load(f)

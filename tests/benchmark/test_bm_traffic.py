@@ -8,6 +8,7 @@ import time
 import pytest
 
 from benchmark.lib import checkpoint, loadgen, traffic
+from benchmark.lib.manifest import traffic_path
 
 from . import helpers as H
 
@@ -95,6 +96,39 @@ def test_lengths_follow_the_distribution():
     assert traffic.quantiles({"dist": "fixed", "value": 7}, 3) == [7, 7, 7]
     u = traffic.quantiles({"dist": "uniform", "min": 128, "max": 512}, 4)
     assert u == [176.0, 272.0, 368.0, 464.0]
+
+
+REAL_MIXES = sorted(f[:-5] for f in os.listdir(
+    os.path.join(H.ROOT, "benchmark", "traffic")) if f.endswith(".json"))
+
+
+@pytest.mark.parametrize("name", REAL_MIXES)
+def test_every_mix_file_loads_and_says_where_its_sizes_come_from(name):
+    mix = traffic.load_mix(traffic_path(H.ROOT, name))
+    assert len(mix.get("notes") or "") > 40
+    assert traffic.schedule(mix, 2**31 + 3, 51)["requests"]
+
+
+@pytest.mark.parametrize("seed", [0, 7, 1234567891, 2**31 + 5])
+@pytest.mark.parametrize("name", REAL_MIXES)
+def test_every_mix_file_is_the_same_work_under_every_seed(name, seed):
+    """What a cell's bound rests on, held for every committed mix and
+    asserting only what the mix itself states: greedy requests, sizes
+    inside its stated ranges, and in the window under another seed the
+    same multiset of sizes in another order."""
+    mix = traffic.load_mix(traffic_path(H.ROOT, name))
+    assert mix["request"].get("temperature") == 0
+    a, b = ([(r["prompt_tokens"], r["output_tokens"])
+             for r in traffic.schedule(mix, s, 51)["requests"]
+             if r["due"] is None or r["due"] >= 0] for s in (seed, seed + 1))
+    assert a != b and len(a) == len(b)
+    for k, field in enumerate(("prompt_tokens", "output_tokens")):
+        assert sorted(r[k] for r in a) == sorted(r[k] for r in b)
+        dist = mix[field]
+        if "sessions" in mix or "burst" in mix or dist["dist"] == "lognormal":
+            continue  # turns grow, bursts bring sizes of their own
+        assert dist.get("min", dist.get("value")) <= min(r[k] for r in a)
+        assert max(r[k] for r in a) <= dist.get("max", dist.get("value"))
 
 
 @pytest.mark.parametrize("bad,msg", [
