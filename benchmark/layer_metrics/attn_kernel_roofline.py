@@ -27,7 +27,8 @@ def reduce(trace, run):
     prof = run.get("profile")
     if trace is None or not prof:
         return None
-    calls = T.kernel_events(trace, T.module_events(trace, T.DECODE))
+    calls = T.kernel_events(trace, run["config"],
+                            T.module_events(trace, T.DECODE))
     if not calls:
         return None
     _, window = T.busy_and_window(trace)

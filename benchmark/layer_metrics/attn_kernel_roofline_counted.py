@@ -35,7 +35,8 @@ def reduce(trace, run):
     if before is None or after is None or CONTEXT not in after:
         return None
     steps = prom.delta(before, after, STEPS)
-    calls = T.kernel_events(trace, T.module_events(trace, T.DECODE))
+    calls = T.kernel_events(trace, run["config"],
+                            T.module_events(trace, T.DECODE))
     if steps <= 0 or not calls:
         return None
     ctx = prom.delta(before, after, CONTEXT,

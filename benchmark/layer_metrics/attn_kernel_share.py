@@ -1,12 +1,14 @@
-"""Attention kernel: device time of the ``ragged_paged_attention``
-Pallas calls over device busy time, in percent."""
+"""Attention kernel: device time of the attention calls (the ops the
+configuration's model file names: ``ragged_paged_attention``, the
+Pallas call, for the types here today) over device busy time, in
+percent."""
 from benchmark.lib import trace as T
 
 
 def reduce(trace, run):
     if trace is None:
         return None
-    kern = T.kernel_events(trace)
+    kern = T.kernel_events(trace, run["config"])
     if not kern:
         return None
     busy, _ = T.busy_and_window(trace)

@@ -2,11 +2,12 @@
 tokenizer and the model YAML a user's models dir holds.
 
 The benchmark's own copy of tools/synth_checkpoint.py (numpy only, never
-more than one shard per worker thread in host RAM), with the tensor
-names of a layer taken from a table keyed by ``model_type`` — one entry
-per type, so a later configuration of a new type adds an entry's worth
-of data, not a writer. Importing this module touches neither JAX nor
-the program.
+more than one shard per worker thread in host RAM). Which tensors a
+checkpoint holds — names, shapes, dtypes, how each is drawn, layer by
+layer — is the model type's own table, ``tensors(config)`` of
+``benchmark/models/<model_type>.py`` (lib/models.py): a configuration
+of a new type brings that file, not a writer. Importing this module
+touches neither JAX nor the program.
 """
 
 from __future__ import annotations
@@ -20,57 +21,59 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
+from . import models
+
 WRITER_VERSION = "1"
 _BF16_ONE = 0x3F80
-
-# per model_type: the per-layer tensors as (name, out_dim, in_dim) with
-# dims named by role; "{e}" repeats the tensor for every expert
-_ATTN = [
-    ("self_attn.q_proj.weight", "q", "d"),
-    ("self_attn.k_proj.weight", "kv", "d"),
-    ("self_attn.v_proj.weight", "kv", "d"),
-    ("self_attn.o_proj.weight", "d", "q"),
-]
-_NORMS = ["input_layernorm.weight", "post_attention_layernorm.weight"]
-LAYER_TENSORS = {
-    "mistral": _ATTN + [
-        ("mlp.gate_proj.weight", "f", "d"),
-        ("mlp.up_proj.weight", "f", "d"),
-        ("mlp.down_proj.weight", "d", "f"),
-    ],
-    # Mixtral: block_sparse_moe.gate is the [E, D] router; w1 = gate,
-    # w3 = up, w2 = down (models/hf_loader.py reads exactly these)
-    "mixtral": _ATTN + [
-        ("block_sparse_moe.gate.weight", "e", "d"),
-        ("block_sparse_moe.experts.{e}.w1.weight", "f", "d"),
-        ("block_sparse_moe.experts.{e}.w3.weight", "f", "d"),
-        ("block_sparse_moe.experts.{e}.w2.weight", "d", "f"),
-    ],
-}
+_EMBED_RMS = 0.02
 
 
-def _bf16_weight(rng: np.random.Generator, out_d: int, in_d: int,
+def _bf16_weight(rng: np.random.Generator, shape: tuple,
                  rms: float) -> np.ndarray:
-    """Random bf16 bit patterns (uint16) of shape [out_d, in_d]: random
-    sign and mantissa under one fixed exponent, rms near ``rms``."""
+    """Random bf16 bit patterns (uint16) of ``shape``: random sign and
+    mantissa under one fixed exponent, rms near ``rms``."""
     k = round(math.log2(rms / 1.53))  # |w| in [2^k, 2^(k+1)): rms 1.53*2^k
     exp = np.uint16((k + 127) << 7)
-    bits = rng.integers(0, 256, (out_d, in_d), dtype=np.uint8)
+    bits = rng.integers(0, 256, shape, dtype=np.uint8)
     out = bits.astype(np.uint16)
     return ((out & np.uint16(0x80)) << np.uint16(8)) | exp \
         | (out & np.uint16(0x7F))
 
 
+def _draw(rng: np.random.Generator, shape: tuple, dtype: str,
+          init: str) -> np.ndarray:
+    """One tensor as bit patterns: uint16 for BF16, uint32 for F32 (the
+    bf16 pattern in the high half, so both dtypes hold the same values).
+    Only "matrix" and "embed" draw from ``rng``."""
+    shape = tuple(shape)
+    if init == "matrix":
+        bits = _bf16_weight(rng, shape, 1.0 / math.sqrt(shape[-1]))
+    elif init == "embed":
+        bits = _bf16_weight(rng, shape, _EMBED_RMS)
+    elif init in ("ones", "zeros"):
+        bits = np.full(shape, _BF16_ONE if init == "ones" else 0, np.uint16)
+    else:
+        raise ValueError(f"unknown initialisation {init!r}")
+    if dtype == "BF16":
+        return bits
+    if dtype == "F32":
+        return bits.astype(np.uint32) << np.uint32(16)
+    raise ValueError(f"unknown dtype {dtype!r}; the writer knows BF16, F32")
+
+
+_DTYPE_OF_WIDTH = {2: "BF16", 4: "F32"}
+
+
 def _save_shard(path: str, tensors: dict) -> dict:
-    """One safetensors file of bf16 tensors given as uint16 bit patterns;
-    returns {tensor name: byte size}."""
+    """One safetensors file of tensors given as bit patterns (uint16 =
+    BF16, uint32 = F32); returns {tensor name: byte size}."""
     header: dict = {"__metadata__": {"format": "pt"}}
     offset = 0
     for name, arr in tensors.items():
-        n = arr.size * 2
-        header[name] = {"dtype": "BF16", "shape": list(arr.shape),
-                        "data_offsets": [offset, offset + n]}
-        offset += n
+        header[name] = {"dtype": _DTYPE_OF_WIDTH[arr.itemsize],
+                        "shape": list(arr.shape),
+                        "data_offsets": [offset, offset + arr.nbytes]}
+        offset += arr.nbytes
     blob = json.dumps(header, separators=(",", ":")).encode()
     blob += b" " * (-len(blob) % 8)
     tmp = path + ".tmp"
@@ -78,72 +81,39 @@ def _save_shard(path: str, tensors: dict) -> dict:
         f.write(struct.pack("<Q", len(blob)))
         f.write(blob)
         for arr in tensors.values():
-            f.write(np.ascontiguousarray(arr, dtype="<u2").data)
+            f.write(np.ascontiguousarray(
+                arr, dtype=arr.dtype.newbyteorder("<")).data)
     os.replace(tmp, path)
-    return {name: arr.size * 2 for name, arr in tensors.items()}
-
-
-def dims(config: dict) -> dict:
-    """The sizes every matrix of the model is made of, by role."""
-    d_head = config.get("head_dim") or (
-        config["hidden_size"] // config["num_attention_heads"])
-    return {
-        "d": config["hidden_size"], "f": config["intermediate_size"],
-        "q": config["num_attention_heads"] * d_head,
-        "kv": config["num_key_value_heads"] * d_head,
-        "e": config.get("num_local_experts", 0),
-        "k": config.get("num_experts_per_tok", 0),
-        "v": config["vocab_size"], "L": config["num_hidden_layers"],
-    }
+    return {name: arr.nbytes for name, arr in tensors.items()}
 
 
 def write_hf_checkpoint(dirpath: str, config: dict, *, seed: int,
                         threads: int = 4) -> int:
     """``config.json`` + sharded weights (one shard per layer, one for
-    embeddings / final norm / head). Every tensor is a pure function of
-    (seed, its shard). Returns the bytes of weights written."""
-    mt = config["model_type"]
-    if mt not in LAYER_TENSORS:
-        raise ValueError(f"no tensor-name table for model_type {mt!r}; "
-                         f"known: {sorted(LAYER_TENSORS)}")
-    dm = dims(config)
-    L = dm["L"]
+    embeddings / final norm / head), the tensors those of the model
+    type's table. Every tensor is a pure function of (seed, its shard,
+    its place in the shard). Returns the bytes of weights written."""
+    by_shard: dict = {}
+    for shard, name, shape, dtype, init in models.of(config).tensors(config):
+        by_shard.setdefault(shard, []).append((name, shape, dtype, init))
+    n = len(by_shard)
+    if sorted(by_shard) != list(range(n)):
+        raise ValueError(f"shards {sorted(by_shard)} are not 0..{n - 1}")
     os.makedirs(dirpath, exist_ok=True)
 
     def shard_name(i: int) -> str:
-        return f"model-{i + 1:05d}-of-{L + 1:05d}.safetensors"
+        return f"model-{i + 1:05d}-of-{n:05d}.safetensors"
 
-    def ones(n: int) -> np.ndarray:
-        return np.full((n,), _BF16_ONE, np.uint16)
-
-    def layer(i: int) -> dict:
+    def write(i: int) -> dict:
         rng = np.random.default_rng([seed, i])
-        lp = f"model.layers.{i}."
-        tensors = {}
-        for name, out_k, in_k in LAYER_TENSORS[mt]:
-            reps = range(dm["e"]) if "{e}" in name else [None]
-            for e in reps:
-                tensors[lp + name.replace("{e}", str(e))] = _bf16_weight(
-                    rng, dm[out_k], dm[in_k], 1.0 / math.sqrt(dm[in_k]))
-        for name in _NORMS:
-            tensors[lp + name] = ones(dm["d"])
-        return _save_shard(os.path.join(dirpath, shard_name(i)), tensors)
-
-    def globals_() -> dict:
-        rng = np.random.default_rng([seed, L])
-        return _save_shard(os.path.join(dirpath, shard_name(L)), {
-            "model.embed_tokens.weight": _bf16_weight(
-                rng, dm["v"], dm["d"], 0.02),
-            "model.norm.weight": ones(dm["d"]),
-            "lm_head.weight": _bf16_weight(
-                rng, dm["v"], dm["d"], 1.0 / math.sqrt(dm["d"])),
-        })
+        return _save_shard(os.path.join(dirpath, shard_name(i)), {
+            name: _draw(rng, shape, dtype, init)
+            for name, shape, dtype, init in by_shard[i]})
 
     weight_map: dict = {}
     total = 0
     with ThreadPoolExecutor(max_workers=max(1, threads)) as pool:
-        jobs = [(shard_name(i), pool.submit(layer, i)) for i in range(L)]
-        jobs.append((shard_name(L), pool.submit(globals_)))
+        jobs = [(shard_name(i), pool.submit(write, i)) for i in range(n)]
         for fname, job in jobs:
             for name, nbytes in job.result().items():
                 weight_map[name] = fname
@@ -199,7 +169,7 @@ def build_bpe_tokenizer(dirpath: str, vocab_size: int, *,
 _HARNESS_KEYS = {
     "source", "serving", "chips", "mesh", "reduced", "assumed",
     "deployment", "weights_seed", "parity_prompts", "parity_tol",
-    "parity_tol_reason", "published", "notes",
+    "parity_tol_reason", "published", "notes", "expect",
 }
 
 

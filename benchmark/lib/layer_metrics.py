@@ -70,6 +70,10 @@ def _json_metric(spec: dict, run: dict):
                                float(spec["q"]), spec.get("match"))
         return None if v is None else v * scale
     if kind == "ratio":
+        # a program without the counter (a parent that cannot run the
+        # configuration yet) has nothing to read: that is not a share of 0
+        if any(spec[k]["family"] not in after for k in ("num", "den")):
+            return None
         num = prom.delta(before, after, spec["num"]["family"],
                          spec["num"].get("match"))
         den = prom.delta(before, after, spec["den"]["family"],

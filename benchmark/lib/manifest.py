@@ -6,6 +6,8 @@ that name. A later PR adds a cell, a configuration, a mix or a metric by
 adding files and appending entries here; it edits nothing that exists.
 
   benchmark/configs/<config>.json          sizes as run + serving YAML
+  benchmark/models/<model_type>.py         what the harness knows of that
+                                           configuration's model type
   benchmark/traffic/<traffic>.json         parameters of the generator
   benchmark/cells/<cell>.json              optional: that cell's own
                                            overrides of mix fields (the
@@ -84,7 +86,7 @@ def end_to_end_spec(root: str, name: str) -> dict:
 def problems(manifest: dict, root: str = ROOT) -> list:
     """Everything wrong with the manifest and the files it names, as
     readable lines; empty when sound."""
-    from . import layer_metrics
+    from . import layer_metrics, models
 
     out = []
 
@@ -122,6 +124,14 @@ def problems(manifest: dict, root: str = ROOT) -> list:
         files.add(c["file"])
         if not os.path.exists(os.path.join(root, c["file"])):
             out.append(f"config {c['name']}: no file {c['file']}")
+        else:
+            with open(os.path.join(root, c["file"])) as f:
+                mt = json.load(f).get("model_type")
+            mdir = os.path.join(bench_dir(root), "models")
+            if not mt or models.find(mt, mdir) is None:
+                out.append(f"config {c['name']}: no model file "
+                           f"benchmark/models/{mt}.py for model_type {mt!r} "
+                           f"(known: {models.known(mdir)})")
         for k in c["reduced"]:
             name_ok(f"config {c['name']} reduced", k)
     e2e = {m["name"]: m for m in manifest["end_to_end"]}

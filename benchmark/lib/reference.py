@@ -1,23 +1,13 @@
 """The plain reference: a decoder-only transformer's forward pass in
-numpy float32, read straight from the bf16 safetensors shards the
-harness wrote, one layer at a time.
+numpy float32, read straight from the safetensors shards the harness
+wrote, one layer at a time.
 
-Follows the published descriptions (Mistral-7B: arXiv:2310.06825;
-Mixtral: arXiv:2401.04088; HF ``modeling_mistral`` / ``modeling_mixtral``):
-token embedding, per layer RMSNorm -> GQA attention with rotate-half
-RoPE (theta from the config) under a causal mask -> residual -> RMSNorm
--> SwiGLU MLP (Mistral) or top-k mixture of SwiGLU experts (Mixtral)
--> residual, then the final RMSNorm. No kernels, no cache, no batching
-tricks, no quantization.
-
-Departures from the published description: none in the mathematics.
-Mixtral's router is written as softmax over all experts, keep the top
-k, renormalise (the paper's form; equal to softmax over the top-k
-logits). Only the selected experts are evaluated, so an implementation
-that evaluates all of them must still weight the others by zero.
-
-``mutate`` exists for the tests only: it shows that the parity
-tolerance catches a zeroed layer, a wrong rope base or a dropped expert.
+The forward pass of a model type is ``forward_hidden`` of its model
+file (``benchmark/models/<model_type>.py``, lib/models.py), with its
+sources and its departures from them; here are the shard reader and the
+plain parts such a file is made of — no kernels, no cache, no batching
+tricks, no quantization — and ``pooled()``, which hands a configuration
+to its type's pass.
 """
 
 from __future__ import annotations
@@ -27,6 +17,8 @@ import os
 import struct
 
 import numpy as np
+
+from . import models
 
 
 class Shards:
@@ -50,11 +42,16 @@ class Shards:
         fname = self.weight_map[name]
         header, base = self._header(fname)
         meta = header[name]
-        if meta["dtype"] != "BF16":
-            raise ValueError(f"{name}: dtype {meta['dtype']}, expected BF16")
+        if meta["dtype"] not in ("BF16", "F32"):
+            raise ValueError(
+                f"{name}: dtype {meta['dtype']}, expected BF16 or F32")
         lo, hi = meta["data_offsets"]
-        raw = np.fromfile(os.path.join(self.dir, fname), dtype="<u2",
-                          count=(hi - lo) // 2, offset=base + lo)
+        path = os.path.join(self.dir, fname)
+        if meta["dtype"] == "F32":
+            return np.fromfile(path, dtype="<f4", count=(hi - lo) // 4,
+                               offset=base + lo).reshape(meta["shape"])
+        raw = np.fromfile(path, dtype="<u2", count=(hi - lo) // 2,
+                          offset=base + lo)
         # bf16 is the high half of a float32
         return (raw.astype(np.uint32) << 16).view(np.float32).reshape(
             meta["shape"])
@@ -78,13 +75,17 @@ def silu(x: np.ndarray) -> np.ndarray:
     return x / (1.0 + np.exp(-x))
 
 
-def attention(x, wq, wk, wv, wo, n_heads, n_kv, d_head, theta):
+def attention(x, wq, wk, wv, wo, n_heads, n_kv, d_head, theta, bias=None):
     """Causal grouped-query attention over one sequence; x: [T, D],
-    weights in torch [out, in] layout."""
+    weights in torch [out, in] layout; ``bias``: the (q, k, v)
+    projections' own, where a type has them."""
     T = x.shape[0]
-    q = rope((x @ wq.T).reshape(T, n_heads, d_head), theta)
-    k = rope((x @ wk.T).reshape(T, n_kv, d_head), theta)
-    v = (x @ wv.T).reshape(T, n_kv, d_head)
+    q, k, v = x @ wq.T, x @ wk.T, x @ wv.T
+    if bias is not None:
+        q, k, v = q + bias[0], k + bias[1], v + bias[2]
+    q = rope(q.reshape(T, n_heads, d_head), theta)
+    k = rope(k.reshape(T, n_kv, d_head), theta)
+    v = v.reshape(T, n_kv, d_head)
     group = n_heads // n_kv
     k = np.repeat(k, group, axis=1)
     v = np.repeat(v, group, axis=1)
@@ -102,16 +103,19 @@ def swiglu(x, w_gate, w_up, w_down):
     return (silu(x @ w_gate.T) * (x @ w_up.T)) @ w_down.T
 
 
-def moe(x, router, experts, k, drop_expert=None):
+def moe(x, router, experts, k, drop_expert=None, renormalise=True):
     """Top-k mixture; x: [T, D], router [E, D], experts(e) -> (w1, w3,
-    w2). ``drop_expert`` (tests only) zeroes one expert's output."""
+    w2): softmax over all experts, the top k kept, their weights
+    renormalised to sum to 1 unless ``renormalise`` is off.
+    ``drop_expert`` (tests only) zeroes one expert's output."""
     logits = x @ router.T  # [T, E]
     logits -= logits.max(axis=-1, keepdims=True)
     probs = np.exp(logits)
     probs /= probs.sum(axis=-1, keepdims=True)
     top = np.argsort(-probs, axis=-1, kind="stable")[:, :k]  # [T, k]
     w = np.take_along_axis(probs, top, axis=-1)
-    w /= w.sum(axis=-1, keepdims=True)
+    if renormalise:
+        w /= w.sum(axis=-1, keepdims=True)
     out = np.zeros_like(x)
     for e in range(router.shape[0]):
         rows, slot = np.nonzero(top == e)
@@ -124,56 +128,10 @@ def moe(x, router, experts, k, drop_expert=None):
 
 def forward_hidden(ckpt_dir: str, config: dict, ids_list: list,
                    mutate: "dict | None" = None) -> list:
-    """Final hidden states (after the last norm) of each id sequence:
-    -> list of [T_i, D] float32. Weights are read once per layer for
-    all sequences."""
-    mutate = mutate or {}
-    sh = Shards(ckpt_dir)
-    n_heads = config["num_attention_heads"]
-    n_kv = config["num_key_value_heads"]
-    d_head = config.get("head_dim") or config["hidden_size"] // n_heads
-    eps = float(config["rms_norm_eps"])
-    theta = float(mutate.get("rope_theta", config["rope_theta"]))
-    n_exp = config.get("num_local_experts", 0)
-    if config.get("sliding_window"):
-        raise NotImplementedError("the reference has no sliding window")
-    embed = sh.get("model.embed_tokens.weight")
-    xs = [embed[np.asarray(ids)] for ids in ids_list]
-    del embed
-    for i in range(config["num_hidden_layers"]):
-        if i == mutate.get("zero_layer"):
-            continue
-        lp = f"model.layers.{i}."
-        g = lambda n: sh.get(lp + n)  # noqa: E731
-        wq, wk, wv, wo = (g(f"self_attn.{p}_proj.weight")
-                          for p in "qkvo")
-        ln1 = g("input_layernorm.weight")
-        ln2 = g("post_attention_layernorm.weight")
-        xs = [x + attention(rms_norm(x, ln1, eps), wq, wk, wv, wo,
-                            n_heads, n_kv, d_head, theta) for x in xs]
-        del wq, wk, wv, wo
-        if n_exp:
-            router = g("block_sparse_moe.gate.weight")
-            lens = [x.shape[0] for x in xs]
-            flat = np.concatenate([rms_norm(x, ln2, eps) for x in xs])
-
-            def experts(e):
-                b = f"block_sparse_moe.experts.{e}."
-                return g(b + "w1.weight"), g(b + "w3.weight"), \
-                    g(b + "w2.weight")
-
-            y = moe(flat, router, experts, config["num_experts_per_tok"],
-                    drop_expert=mutate.get("drop_expert"))
-            parts = np.split(y, np.cumsum(lens)[:-1])
-            xs = [x + p for x, p in zip(xs, parts)]
-        else:
-            w_gate, w_up, w_down = (g(f"mlp.{p}_proj.weight")
-                                    for p in ("gate", "up", "down"))
-            xs = [x + swiglu(rms_norm(x, ln2, eps), w_gate, w_up, w_down)
-                  for x in xs]
-            del w_gate, w_up, w_down
-    norm = sh.get("model.norm.weight")
-    return [rms_norm(x, norm, eps) for x in xs]
+    """Final hidden states (after the last norm) of each id sequence by
+    the model type's own plain pass: -> list of [T_i, D] float32."""
+    return models.of(config).forward_hidden(Shards(ckpt_dir), config,
+                                            ids_list, mutate)
 
 
 def pooled(ckpt_dir: str, config: dict, ids_list: list,

@@ -161,7 +161,6 @@ def ops_inside(tr: dict, modules: list, match) -> list:
 
 DECODE = ("jit_dispatch_decodek", "jit_dispatch_decode1")
 PREFILL = ("jit_dispatch_prefill", "jit_dispatch_mixed")
-KERNEL = "ragged_paged_attention"
 
 
 def own_name(event_name: str) -> str:
@@ -170,11 +169,18 @@ def own_name(event_name: str) -> str:
     return event_name.lstrip("%").split(" ", 1)[0]
 
 
-def kernel_events(tr: dict, modules: "list | None" = None) -> list:
-    """The attention kernel's calls (first chip); inside ``modules``
-    only when given."""
+def kernel_events(tr: dict, config: dict,
+                  modules: "list | None" = None) -> list:
+    """The attention kernel's calls (first chip), the kernel being what
+    the model file of ``config`` names (``ATTENTION_KERNELS``: op-name
+    prefixes); inside ``modules`` only when given."""
+    from benchmark.lib import models  # not at the top: ``dump`` runs
+    # this file as a script, outside the package
+
+    kernels = tuple(models.of(config).ATTENTION_KERNELS)
+
     def match(name):
-        return own_name(name).startswith(KERNEL)
+        return own_name(name).startswith(kernels)
     if modules is None:
         modules = events(chip_planes(tr)[0], MODULES)
     # a call may be reported with children of its own: keep outermost
@@ -191,7 +197,7 @@ def decode_steps(tr: dict, config: dict) -> "tuple[float, float]":
     Steps = attention-kernel calls inside them / layers: one call per
     layer per step, whatever k a decodek program was built with."""
     mods = module_events(tr, DECODE)
-    calls = kernel_events(tr, mods)
+    calls = kernel_events(tr, config, mods)
     steps = len(calls) / float(config["num_hidden_layers"])
     return steps, sum(m[2] for m in mods) / 1e9
 

@@ -51,6 +51,7 @@ if ROOT not in sys.path:
 
 from benchmark.lib import checkpoint, layer_metrics, loadgen  # noqa: E402
 from benchmark.lib import manifest as M  # noqa: E402
+from benchmark.lib import models  # noqa: E402
 from benchmark.lib import peaks as P  # noqa: E402
 from benchmark.lib import prom, reference, traffic  # noqa: E402
 from benchmark.lib import reduce as R  # noqa: E402
@@ -59,6 +60,8 @@ from benchmark.lib.children import (  # noqa: E402
 )
 
 EXPECT_CHIP = {"platform": "tpu", "attention_path": "ragged_paged_kernel"}
+# what a configuration file's ``expect`` may lay over the caller's
+EXPECT_KEYS = {"attention_path", "kernel_ineligible"}
 LONG_PROMPT_TOKENS = 3000
 
 
@@ -252,7 +255,8 @@ class Cell:
                  tag: str, expect: dict = EXPECT_CHIP,
                  probe: bool = True) -> None:
         self.root, self.seed, self.trace = root, seed, trace
-        self.expect = expect
+        # a model type is a file of the checkout's own, like a metric
+        models.use(os.path.join(M.bench_dir(root), "models"))
         self.man = M.load(root)
         bad = M.problems(self.man, root)
         if bad:
@@ -262,6 +266,14 @@ class Cell:
         self.cfg_path = M.config_path(self.man, root, self.name)
         with open(self.cfg_path) as f:
             self.config = json.load(f)
+        # the engine state `correct` expects: the caller's, and over it
+        # what the configuration states for its own engine path
+        own = self.config.get("expect") or {}
+        unknown = sorted(set(own) - EXPECT_KEYS)
+        if unknown:
+            raise HarnessFailure(f"{self.cfg_path}: expect has {unknown}; "
+                                 f"it may state {sorted(EXPECT_KEYS)}")
+        self.expect = dict(expect, **own)
         self.mix = traffic.load_mix(
             M.traffic_path(root, self.cell["traffic"]),
             M.cell_overrides(root, cell_name))

@@ -4,9 +4,12 @@ here imports JAX."""
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import shutil
+
+from benchmark.lib import models as _models
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -14,6 +17,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 # repo, or a temp copy with one more cell in it (BM_TESTS_ROOT, set by
 # test_bm_manifest.py's N-cell proof, which runs these tests there)
 ROOT = os.environ.get("BM_TESTS_ROOT") or REPO
+# the model files are data of that root too: the loader looks there
+MODELS = os.path.join(ROOT, "benchmark", "models")
+_models.use(MODELS)
 
 PARITY_PROMPTS = [
     "A paged cache hands out attention memory page by page.",
@@ -42,6 +48,29 @@ TINY = {
 TINY_MOE = dict(TINY, model_type="mixtral",
                 architectures=["MixtralForCausalLM"],
                 num_local_experts=4, num_experts_per_tok=2)
+
+# model types the harness does not know, each one file under data/models/
+# that a test copies into a temp benchmark (or points the loader at)
+FIXTURE_MODELS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                              "data", "models")
+# a type the program loads and benchmark/lib has never heard of: layers
+# of two kinds (layer 0 a plain MLP), q/k/v biases, a router named
+# mlp.gate, a shared expert (data/models/qwen2_moe.py)
+TINY_QWEN_MOE = dict(
+    {k: v for k, v in TINY.items() if k != "head_dim"},
+    model_type="qwen2_moe", architectures=["Qwen2MoeForCausalLM"],
+    num_hidden_layers=3, mlp_only_layers=[0], decoder_sparse_step=1,
+    num_experts=4, num_experts_per_tok=2, moe_intermediate_size=32,
+    shared_expert_intermediate_size=128, norm_topk_prob=False)
+# a chip's share of an expert layer, as a configuration file states it:
+# 4 experts held of 32 published, ids 8-11 (data/models/held_experts.py)
+TINY_HELD = {
+    "model_type": "held_experts", "hidden_size": 64, "kv_lora_rank": 16,
+    "intermediate_size": 128, "moe_intermediate_size": 32,
+    "num_hidden_layers": 2, "first_k_dense_replace": 1, "vocab_size": 512,
+    "n_routed_experts": 4, "n_routed_experts_published": 32,
+    "expert_id_base": 8,
+}
 
 _REQ = {"temperature": 0, "ignore_eos": True}
 _NOTES = "toy sizes for the CPU tests: nothing here stands for a deployment"
@@ -72,6 +101,26 @@ def copy_benchmark(dst: str) -> str:
     os.symlink(os.path.join(REPO, "localai_tfp_tpu"),
                os.path.join(dst, "localai_tfp_tpu"))
     return dst
+
+
+def add_model_file(root: str, model_type: str,
+                   as_type: "str | None" = None) -> None:
+    """What a later PR does for a new ``model_type``: one new file (the
+    fixture's, under the name ``as_type`` where given)."""
+    dst = os.path.join(root, "benchmark", "models",
+                       (as_type or model_type) + ".py")
+    assert not os.path.exists(dst)
+    shutil.copy(os.path.join(FIXTURE_MODELS, model_type + ".py"), dst)
+
+
+@contextlib.contextmanager
+def using_models(models_dir: str):
+    """The loader pointed at another ``models/`` for a test's length."""
+    before = _models.use(models_dir)
+    try:
+        yield _models
+    finally:
+        _models.use(before)
 
 
 def snapshot(root: str) -> dict:

@@ -208,8 +208,11 @@ def test_counter_readers_on_synthetic_scrapes():
 @pytest.mark.parametrize("name", [
     "sched_host_ms_per_dispatch", "program_loads_in_window",
     "program_load_stall_s", "mixed_fill_share",
-    "attn_kernel_roofline_counted"])
+    "attn_kernel_roofline_counted", "decode_rows_mean"])
 def test_counter_readers_give_nothing_on_a_program_without_them(name):
+    """A ``delta`` whose family the scrape lacks, and a ``ratio`` whose
+    numerator's family it lacks while the denominator counts on
+    (``decode_rows_mean``: it read 0 rows), read nothing, not 0."""
     old = _scrape(engine_mixed_dispatch_total=[
         ((("composition", "mixed"),), 10.0)])
     new = _scrape(engine_mixed_dispatch_total=[

@@ -9,7 +9,7 @@ import sys
 
 import pytest
 
-from benchmark.lib import layer_metrics, traffic
+from benchmark.lib import layer_metrics, models, traffic
 from benchmark.lib import manifest as M
 
 from . import helpers as H
@@ -65,6 +65,11 @@ def test_cell_files_exist_and_load(w):
                 "weights_seed", "parity_prompts", "parity_tol",
                 "parity_tol_reason"):
         assert key in config, key
+    # its model type is a file that loads (never: one of a known list)
+    mod = models.load(config["model_type"], H.MODELS)
+    assert mod.tensors(config) and mod.kv_bytes_per_token(config) > 0
+    assert set(config.get("expect", {})) <= {"attention_path",
+                                             "kernel_ineligible"}
     mix = traffic.load_mix(M.traffic_path(H.ROOT, w["traffic"]),
                            M.cell_overrides(H.ROOT, w["name"]))
     sched = traffic.schedule(mix, 3, MAN["run_seconds"])
@@ -160,7 +165,23 @@ def test_add_cell_config_mix_and_metric_by_data_alone(tmp_path):
         json.dump(man, f)
     man = M.load(root)
     assert M.problems(man, root) == []
+    # a configuration of a model type the harness has no file for: the
+    # manifest check names the file to bring; brought, it is sound — and
+    # still nothing that was there has changed
+    new_type = dict(H.TINY_QWEN_MOE, model_type="toy_new_type")
+    H.add_cell(root, config_name="tiny_new_type", config=new_type,
+               mix_name="tiny_closed", mix=H.TINY_CLOSED,
+               cell_name="tiny_new_type_cell", join=None)
+    man = M.load(root)
+    (lacks,) = M.problems(man, root)
+    assert "benchmark/models/toy_new_type.py" in lacks and "mistral" in lacks
+    H.add_model_file(root, "qwen2_moe", as_type="toy_new_type")
+    assert M.problems(man, root) == []
     assert H.edited(before) == []  # nothing that was there changed
+    mdl = models.load("toy_new_type",
+                      os.path.join(root, "benchmark", "models"))
+    assert mdl.decode_weight_bytes(new_type, 16) > \
+        mdl.decode_weight_bytes(new_type, 1)
     cell = M.cell(man, "tiny_bursty_cell")
     mix = traffic.load_mix(M.traffic_path(root, cell["traffic"]))
     sched = traffic.schedule(mix, 5, 6.0)
@@ -183,12 +204,15 @@ def test_a_cell_joined_to_every_listed_metric_passes_every_check(tmp_path):
     reported there). Done here in a temp copy of the real manifest;
     then every test under tests/benchmark/ runs against that copy, so a
     test that pins a cell list, a count of cells or one configuration's
-    sizes fails here, in the PR that writes it."""
+    sizes fails here, in the PR that writes it. The cell's model type
+    is one more file too (a fixture the harness has no file for), so a
+    test that pins the set of model types fails here as well."""
     if os.environ.get("BM_TESTS_ROOT"):
         pytest.skip("the inner run of this very proof")
     root = H.copy_benchmark(str(tmp_path))
     before = H.snapshot(root)
-    H.add_cell(root, config_name="ncell_tiny", config=H.TINY,
+    H.add_model_file(root, "qwen2_moe")
+    H.add_cell(root, config_name="ncell_tiny", config=H.TINY_QWEN_MOE,
                mix_name="ncell_closed", mix=H.TINY_CLOSED,
                cell_name="tiny_everywhere", join=None)
     man = M.load(root)

@@ -67,6 +67,21 @@ def tiny_root(tmp_path_factory):
                cell_name="tiny_moe_closed",
                join=["tpot_p50_ms", "decode_rows_mean",
                      "kv_pages_peak_share", "first_use_loads"])
+    # a model type the harness does not know, as a later PR brings it:
+    # one new file under benchmark/models/, its configuration, its cell,
+    # joined to EVERY listed metric (the roofline readers with them)
+    H.add_model_file(root, "qwen2_moe")
+    H.add_cell(root, config_name="tiny_qwen_moe", config=H.TINY_QWEN_MOE,
+               mix_name="tiny_closed_b", mix=H.TINY_CLOSED,
+               cell_name="tiny_new_type_closed", join=None)
+    # a configuration that states its own engine path, and states it
+    # wrongly for the CPU: only that clause of `correct` may fail
+    H.add_cell(root, config_name="tiny_other_path",
+               config=dict(H.TINY, expect={
+                   "attention_path": "ragged_paged_kernel"}),
+               mix_name="tiny_open_b", mix=H.TINY_OPEN,
+               cell_name="tiny_other_path_open",
+               join=["tpot_p50_ms", "decode_rows_mean"])
     return root
 
 
@@ -74,13 +89,20 @@ CPU = {"platform": "cpu", "attention_path": "paged_xla_gather",
        "kernel_ineligible": "platform cpu: Mosaic compiles on tpu only"}
 
 
-@pytest.mark.parametrize("cell,trace,want", [
-    ("tiny_open", False, {"tpot_p50_ms", "setup_s"}),
+@pytest.mark.parametrize("cell,trace,want,fails", [
+    ("tiny_open", False, {"tpot_p50_ms", "setup_s"}, []),
     ("tiny_moe_closed", True, {"load_s", "warmup_s", "decode_rows_mean",
-                               "kv_pages_peak_share", "first_use_loads"}),
-], ids=["open_loop_end_to_end", "closed_loop_traced"])
-def test_rehearsal_on_cpu(tiny_root, cell, trace, want):
+                               "kv_pages_peak_share", "first_use_loads"}, []),
+    ("tiny_new_type_closed", True, {
+        "load_s", "warmup_s", "decode_rows_mean", "kv_pages_peak_share",
+        "first_use_loads", "mixed_fill_share", "program_load_stall_s"}, []),
+    ("tiny_other_path_open", False, {"tpot_p50_ms", "setup_s"},
+     ["attention_path"]),
+], ids=["open_loop_end_to_end", "closed_loop_traced",
+        "a_model_type_brought_as_a_file", "a_configurations_own_expect"])
+def test_rehearsal_on_cpu(tiny_root, cell, trace, want, fails):
     from benchmark import run as B
+    from benchmark.lib import models
     from benchmark.lib.children import CHILDREN
 
     t0 = time.perf_counter()
@@ -89,6 +111,7 @@ def test_rehearsal_on_cpu(tiny_root, cell, trace, want):
                          expect=CPU, probe=False)
     finally:
         CHILDREN.stop_all()
+        models.use(H.MODELS)  # run_cell pointed it at the temp copy's
     assert set(res) >= {"correct", "attempted", "failed", "metrics",
                         "device"}
     assert res["attempted"] > 0 and res["failed"] == 0
@@ -101,13 +124,15 @@ def test_rehearsal_on_cpu(tiny_root, cell, trace, want):
     assert res["device"]["count"] >= 1
     if not trace:
         assert res["metrics"]["setup_s"]["value"] > 1.0
-    # every clause of `correct` held (the route expected here is the CPU's)
-    assert res["failed_clauses"] == [] and res["correct"] is True
+    # every clause of `correct` held (the route expected here is the
+    # CPU's), but the one a configuration's own `expect` got wrong
+    assert res["failed_clauses"] == fails
+    assert res["correct"] is (not fails)
     assert set(res["compiled_in_window"]) == {"cache_entries",
                                               "first_use_loads"}
     # the closed mix asks for its traffic once through before the
     # measured episode; the open one does not
-    if cell == "tiny_moe_closed":
+    if cell.endswith("_closed"):
         assert res["warm_episode"]["requests"] > 0
         assert res["warm_episode"]["malformed"] == 0
     else:

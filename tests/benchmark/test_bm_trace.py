@@ -100,11 +100,46 @@ def test_kernel_calls_are_matched_by_the_ops_own_name(capture):
     """``%convert.122`` mentions the kernel as an operand and is not a
     call of it (the first chip run counted it: twice the steps)."""
     assert T.own_name(CONVERT) == "convert.122"
-    assert len(T.kernel_events(capture)) == 4 * L + 2 * L + 2
+    cfg = dict(H.TINY, num_hidden_layers=L)
+    assert len(T.kernel_events(capture, cfg)) == 4 * L + 2 * L + 2
     dec = T.module_events(capture, T.DECODE)
-    assert len(T.kernel_events(capture, dec)) == 6 * L
-    steps, seconds = T.decode_steps(capture, {"num_hidden_layers": L})
+    assert len(T.kernel_events(capture, cfg, dec)) == 6 * L
+    steps, seconds = T.decode_steps(capture, cfg)
     assert steps == 6 and seconds == pytest.approx(0.052)
+
+
+def test_attention_calls_are_those_the_model_file_names(capture):
+    """A type whose attention runs under another op name: the steps of
+    a decode program are counted by the prefixes its model file gives,
+    and the roofline readers are fed by that file's counts — here the
+    fixture type, its file brought into a ``models/`` of its own."""
+    renamed = json.loads(json.dumps(capture).replace(
+        "%ragged_paged_attention.", "%toy_latent_attention."))
+    known = dict(H.TINY, num_hidden_layers=L)
+    assert T.decode_steps(renamed, known)[0] == 0  # not that type's op
+    cfg = dict(H.TINY_QWEN_MOE, num_hidden_layers=L)
+    mdir = os.path.join(H.ROOT, "benchmark", "layer_metrics")
+    run = {"config": cfg, "seconds": 1.0,
+           "polls": [{"engine_kv_pages_in_use_count": [({}, 10.0)]}],
+           "peaks": {"hbm_bytes_per_s": 1e9, "bf16_flops": 1e12}}
+    with H.using_models(H.FIXTURE_MODELS) as models:
+        assert T.decode_steps(renamed, cfg) == (6, pytest.approx(0.052))
+        assert T.decode_steps(capture, cfg)[0] == 6  # either prefix
+        ev = lambda n: layer_metrics.evaluate(mdir, n, renamed, run)  # noqa
+        assert ev("attn_kernel_share") == pytest.approx(100 * 16 / 78)
+        mod = models.of(cfg)
+        # layer 0 a plain MLP, layer 1 four experts of which one row of
+        # top-2 touches two, + the shared expert and its gate, in bf16
+        d, f, fm, kv = 64, 128, 32, 32
+        want = 2 * (L * (2 * d * d + 2 * d * kv) + 3 * d * f
+                    + (3 * d * f + d) + 2 * 3 * d * fm + 512 * d) \
+            + 4 * 4 * d
+        assert mod.decode_weight_bytes(cfg, 1.0) == want
+        page_bytes = 256 * 2 * kv * 2 * L
+        assert ev("decode_hbm_roofline") == pytest.approx(
+            100 * (want + 10 * page_bytes) / 1e9 / (0.052 / 6))
+    with pytest.raises(FileNotFoundError, match="models/no_such_type.py"):
+        T.decode_steps(renamed, dict(cfg, model_type="no_such_type"))
 
 
 def test_self_time_subtracts_children():
@@ -171,7 +206,7 @@ def test_trace_layer_metrics(capture):
 
 
 def test_roofline_bytes_from_shapes():
-    from benchmark.lib import roofline
+    from benchmark.lib import models, roofline
 
     with open(os.path.join(H.ROOT, "benchmark", "configs",
                            "mistral-7b-instruct-v0.3.json")) as f:
@@ -190,9 +225,9 @@ def test_roofline_bytes_from_shapes():
     # top-2 reads 2 of 8, sixteen rows 7.9 of 8 (uniform routing); per
     # layer 8 experts x 3 x 4096 x 14336 in bf16 = 2.82 GB, + int8
     # attention, + the int8 head
-    assert roofline.experts_touched(mixtral, 1) == pytest.approx(2.0)
-    assert roofline.experts_touched(mixtral, 16) == pytest.approx(
-        8 * (1 - 0.75 ** 16))
+    touched = models.load("mixtral", H.MODELS).experts_touched
+    assert touched(mixtral, 1) == pytest.approx(2.0)
+    assert touched(mixtral, 16) == pytest.approx(8 * (1 - 0.75 ** 16))
     n = mixtral["num_hidden_layers"]
     assert roofline.decode_weight_bytes(mixtral, 1) == pytest.approx(
         n * (2.818e9 / 4 + 41.9e6) + 131e6, rel=0.01)
@@ -239,7 +274,7 @@ def test_recorded_capture_reduces_as_the_chip_run_did(recorded):
     assert len(dec) == 1 and len(T.module_events(recorded, T.PREFILL)) == 1
     # 8 steps x 32 layers: one kernel call per layer per step — and the
     # convert that names the kernel as its operand is not one
-    assert len(T.kernel_events(recorded, dec)) == 256
+    assert len(T.kernel_events(recorded, cfg, dec)) == 256
     steps, seconds = T.decode_steps(recorded, cfg)
     assert steps == 8 and seconds * 1e3 / steps == pytest.approx(14.2, abs=0.1)
     busy, window = T.busy_and_window(recorded)
