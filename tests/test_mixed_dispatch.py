@@ -115,45 +115,94 @@ def _first_token(q, timeout=120):
             return ev
 
 
+def _schedule_requests(tk):
+    """The fixed request set: two long-lived streams and a burst of
+    three (one prompt longer than the largest bucket, so it needs a
+    non-final chunk). Prompts diverge at their FIRST characters:
+    shared leading tokens would legitimately engage slot-resident
+    prefix reuse, whose donor choice is interleave-dependent — not
+    what on/off compares."""
+    return {
+        "a": GenRequest(
+            prompt_ids=tk.encode("stream alpha stays live"), max_tokens=40,
+            temperature=0.9, top_k=12, seed=7, ignore_eos=True),
+        "b": GenRequest(
+            prompt_ids=tk.encode("stream beta stays live too"),
+            max_tokens=40, temperature=0.7, top_p=0.9, seed=11,
+            ignore_eos=True),
+        "c": GenRequest(prompt_ids=tk.encode("one burst request " * 9),
+                        max_tokens=6, temperature=0.8, seed=3,
+                        ignore_eos=True),
+        "d": GenRequest(prompt_ids=tk.encode("two burst request"),
+                        max_tokens=6, ignore_eos=True),
+        # longer than the largest bucket (128): needs a non-final chunk
+        "e": GenRequest(prompt_ids=tk.encode("three burst request " * 10),
+                        max_tokens=6, temperature=0.6, seed=5,
+                        ignore_eos=True),
+    }
+
+
 def _mixed_schedule(eng, tk):
     """One fixed request schedule: two streams decode, then a burst of
-    three admissions lands mid-stream (one prompt long enough to need a
-    non-final chunk). Returns {name: (generated token ids, final
-    event)}."""
+    three admissions lands mid-stream. Returns {name: (generated token
+    ids, final event)}."""
     fin = FinishSpy(eng)
-    reqs = {}
+    reqs = _schedule_requests(tk)
     out = {}
-    ra = GenRequest(
-        prompt_ids=tk.encode("stream alpha stays live"), max_tokens=40,
-        temperature=0.9, top_k=12, seed=7, ignore_eos=True)
-    rb = GenRequest(
-        prompt_ids=tk.encode("stream beta stays live too"), max_tokens=40,
-        temperature=0.7, top_p=0.9, seed=11, ignore_eos=True)
-    qa, qb = eng.submit(ra), eng.submit(rb)
-    reqs["a"], reqs["b"] = ra, rb
+    qa, qb = eng.submit(reqs["a"]), eng.submit(reqs["b"])
     _first_token(qa)
     _first_token(qb)  # both rows are committed decoders
-    # prompts diverge at their FIRST characters: shared leading tokens
-    # would legitimately engage slot-resident prefix reuse, whose donor
-    # choice is interleave-dependent — not what on/off compares
-    burst = [
-        GenRequest(prompt_ids=tk.encode("one burst request " * 9),
-                   max_tokens=6, temperature=0.8, seed=3,
-                   ignore_eos=True),
-        GenRequest(prompt_ids=tk.encode("two burst request"),
-                   max_tokens=6, ignore_eos=True),
-        # longer than the largest bucket (128): needs a non-final chunk
-        GenRequest(prompt_ids=tk.encode("three burst request " * 10),
-                   max_tokens=6, temperature=0.6, seed=5,
-                   ignore_eos=True),
-    ]
-    qs = eng.submit_many(burst)
-    for name, r, q in zip(("c", "d", "e"), burst, qs):
-        reqs[name] = r
+    qs = eng.submit_many([reqs[n] for n in "cde"])
+    for name, q in zip("cde", qs):
         out[name] = _drain(q)
     out["a"] = _drain(qa)
     out["b"] = _drain(qb)
     return {n: (fin.generated[reqs[n].id], out[n]) for n in out}
+
+
+def _assert_same_streams(got, want):
+    for name in want:
+        assert got[name][0] == want[name][0], f"stream {name} diverged"
+        assert got[name][1].full_text == want[name][1].full_text
+        assert got[name][1].finish_reason == want[name][1].finish_reason
+
+
+@pytest.fixture(scope="module")
+def solo_streams(model):
+    """Each request of the schedule served with the engine to itself:
+    {name: (generated token ids, final event)}."""
+    eng = _engine(model)
+    try:
+        fin = FinishSpy(eng)
+        out = {}
+        for name, req in _schedule_requests(model[2]).items():
+            ev = eng.generate(req)
+            out[name] = (fin.generated[req.id], ev)
+        return out
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("mixed", [True, False],
+                         ids=["mixed", "alternating"])
+def test_interleaved_schedule_yields_each_requests_solo_tokens(
+        model, solo_streams, mixed):
+    """The reference BOTH admission paths are held to, whatever the
+    flag that chooses between them: admissions interleaved with
+    decoding are a pure scheduling matter, so every request of the
+    schedule (greedy AND seeded sampling) yields exactly the tokens it
+    yields when it has the engine to itself. On/off identity alone
+    would pass if both paths were wrong alike; PR 35 measured the two
+    on the chip and kept both, so a later change to either answers to
+    this."""
+    eng = _engine(model, mixed=mixed)
+    try:
+        spy = DispatchSpy(eng)
+        got = _mixed_schedule(eng, model[2])
+    finally:
+        eng.close()
+    assert bool(spy.mixed()) == mixed
+    _assert_same_streams(got, solo_streams)
 
 
 def test_mixed_on_off_byte_identical(model):
@@ -173,10 +222,7 @@ def test_mixed_on_off_byte_identical(model):
     finally:
         eng_on.close()
     assert spy.mixed(), "fused path never dispatched a mixed step"
-    for name in want:
-        assert got[name][0] == want[name][0], f"stream {name} diverged"
-        assert got[name][1].full_text == want[name][1].full_text
-        assert got[name][1].finish_reason == want[name][1].finish_reason
+    _assert_same_streams(got, want)
 
 
 def test_mixed_load_no_starvation_decode_priority(model):
