@@ -166,50 +166,27 @@ def _ragged_attn_extra(eng, mixed_itl_block, decode_tok_s) -> dict:
     to the decode throughput and mixed ITL p95 measured on the SAME
     engine — the acceptance series for the one-kernel unification
     (variant count collapses; decode tok/s and mixed ITL must not
-    regress vs the windowed ladder)."""
+    regress)."""
     return {
-        "enabled": bool(getattr(eng, "_ragged", False)),
+        "enabled": bool(getattr(eng, "_paged", False)),
         "warmup_variants": int(getattr(eng, "warmup_variants", 0)),
         "decode_tok_s": decode_tok_s,
         "mixed_itl_p95_ms": (mixed_itl_block or {}).get("itl_p95_ms"),
     }
 
 
-def _ragged_warmup_compare(spec, params, tok) -> dict:
-    """Warmup wall time + compiled variant count, ragged on vs off, on
-    a dedicated small engine pair (max_seq above the 256 window floor
-    so the legacy ladder is real). CPU-smoke only — at 8B scale the
-    off-ladder warmup alone costs minutes of compiles, which is the
-    point this block documents."""
+def ragged_variant_report() -> dict:
+    """Standalone variant report on a tiny model (max_seq above the 256
+    window floor): warmup wall time + compiled jit-variant count of the
+    route the engine picks. Shared by tools/profile_http.py --mixed and
+    tools/profile_kv.py so the variant count is observable without a
+    full bench run."""
     import time as _time
 
-    import jax.numpy as _jnp
-
-    from localai_tfp_tpu.engine.engine import LLMEngine
-
-    out = {}
-    for ragged in (True, False):
-        eng = LLMEngine(spec, params, tok, n_slots=2, max_seq=1024,
-                        prefill_buckets=(8,), decode_steps=2,
-                        cache_dtype=_jnp.float32, autostart=False)
-        eng._ragged = ragged and eng._paged
-        t0 = _time.perf_counter()
-        eng.warmup()
-        key = "on" if ragged else "off"
-        out[f"variants_{key}"] = eng.warmup_variants
-        out[f"warmup_s_{key}"] = round(_time.perf_counter() - t0, 2)
-        eng.close()
-    return out
-
-
-def ragged_variant_report() -> dict:
-    """Standalone variant-collapse report on a tiny model: warmup wall
-    time + compiled jit-variant count, ragged on vs off. Shared by
-    tools/profile_http.py --mixed and tools/profile_kv.py so the
-    compile-variant kill is observable without a full bench run."""
     import jax as _jax
     import jax.numpy as _jnp
 
+    from localai_tfp_tpu.engine.engine import LLMEngine
     from localai_tfp_tpu.engine.tokenizer import ByteTokenizer
     from localai_tfp_tpu.models.llm_spec import tiny_spec
     from localai_tfp_tpu.models.transformer import init_params
@@ -218,7 +195,16 @@ def ragged_variant_report() -> dict:
     spec = tiny_spec(vocab_size=tk.vocab_size, max_position=1024)
     params = init_params(_jax.random.PRNGKey(0), spec,
                          dtype=_jnp.float32)
-    return _ragged_warmup_compare(spec, params, tk)
+    eng = LLMEngine(spec, params, tk, n_slots=2, max_seq=1024,
+                    prefill_buckets=(8,), decode_steps=2,
+                    cache_dtype=_jnp.float32, autostart=False)
+    t0 = _time.perf_counter()
+    eng.warmup()
+    out = {"attention_path": eng.attention_path,
+           "variants": eng.warmup_variants,
+           "warmup_s": round(_time.perf_counter() - t0, 2)}
+    eng.close()
+    return out
 
 
 def meshed_paged_report() -> dict:

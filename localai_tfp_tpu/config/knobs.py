@@ -55,9 +55,6 @@ _knob("LOCALAI_KV_PAGE", "0", "int",
       "(0 = auto, largest <= 256).")
 _knob("LOCALAI_KV_PAGES", "0", "int",
       "Physical page-count override (0 = n_slots * pages_per_slot + 1).")
-_knob("LOCALAI_RAGGED_ATTN", "on", "flag",
-      "Ragged paged attention; off restores the legacy windowed "
-      "gather/scatter paths.")
 _knob("LOCALAI_PREFIX_CACHE", "on", "flag",
       "Cross-request prefix KV reuse (copy a resident shared prefix "
       "instead of re-prefilling).")

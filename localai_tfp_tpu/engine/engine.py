@@ -42,8 +42,8 @@ TPU-first re-design rather than translation:
   dispatches at FULL width, so the jit cache holds one variant per
   token-budget shape (no bucket x window ladder) and kernel-eligible
   engines never materialize a gathered KV window on the prefill/mixed
-  hot path. LOCALAI_RAGGED_ATTN=off restores the legacy windowed
-  paths byte-identically (see the README "Kernels" section).
+  hot path. How a program reaches the cache is ONE route chosen at
+  construction (engine/cache_route.py; README "Kernels" section).
 """
 
 from __future__ import annotations
@@ -67,10 +67,7 @@ from jax import lax
 
 from ..config import knobs
 from ..models.llm_spec import LLMSpec
-from ..models.transformer import (
-    KVCache, Params, forward, forward_hidden, gather_kv_pages,
-    scatter_kv_pages,
-)
+from ..models.transformer import KVCache, Params, forward, forward_hidden
 from ..ops.sampling import (
     SamplingState, observe_tokens, sample, seed_windows,
 )
@@ -81,6 +78,7 @@ from ..telemetry.flightrec import (
 )
 from ..telemetry.tracing import TRACER, fault_scope
 from ..utils import faultinject
+from .cache_route import choose_route, window_bucket
 from .kv_pool import TRASH_PAGE, PagePool, PagePoolExhausted
 from .prefix_index import PrefixIndex, common_prefix_len
 from .tokenizer import StreamDecoder, Tokenizer
@@ -353,73 +351,6 @@ def _sel_active(active, new, old):
     return jnp.where(a, new, old)
 
 
-def _window_cache(cache: KVCache, window: int):
-    """Slice the cache to its first ``window`` positions; returns the
-    windowed view and a restore fn writing it back into the full buffer.
-    Per-dispatch windowing keeps attention/write traffic proportional to
-    the live-context bucket, not max_seq (the XLA stand-in for ragged
-    paged attention)."""
-    L, S, SEQ, F = cache.k.shape
-    if window >= SEQ:
-        return cache, lambda c: c
-    win = KVCache(
-        k=lax.slice(cache.k, (0, 0, 0, 0), (L, S, window, F)),
-        v=lax.slice(cache.v, (0, 0, 0, 0), (L, S, window, F)),
-        k_scale=(lax.slice(cache.k_scale, (0, 0, 0), (L, S, window))
-                 if cache.quantized else None),
-        v_scale=(lax.slice(cache.v_scale, (0, 0, 0), (L, S, window))
-                 if cache.quantized else None),
-    )
-
-    def restore(c: KVCache) -> KVCache:
-        return KVCache(
-            k=lax.dynamic_update_slice(cache.k, c.k, (0, 0, 0, 0)),
-            v=lax.dynamic_update_slice(cache.v, c.v, (0, 0, 0, 0)),
-            k_scale=(lax.dynamic_update_slice(
-                cache.k_scale, c.k_scale, (0, 0, 0))
-                if cache.quantized else None),
-            v_scale=(lax.dynamic_update_slice(
-                cache.v_scale, c.v_scale, (0, 0, 0))
-                if cache.quantized else None),
-        )
-
-    return win, restore
-
-
-def _pin_win_sharding(win: KVCache, mesh, batch: bool) -> KVCache:
-    """Constrain a gathered window view [L, B, W, F] on a mesh. With
-    ``batch`` True the slot dim rides "data" and F rides "model" — the
-    DENSE cache's exact layout, which is the only window placement
-    whose jitted forward is numerically correct on a data x model mesh:
-    with the slot dim replicated (F-sharded or fully replicated alike),
-    GSPMD picks a partitioning for the fused gather -> forward ->
-    scatter program that computes O(1)-wrong hidden states and KV
-    writes (jit vs eager diverges on the written pages). With ``batch``
-    False the window is pinned back to the ARENA's layout (slot dim
-    replicated, F over "model") so the writeback scatter sees updates
-    shaped like its data-replicated operand. Scale planes are global
-    per-row amax, replicated either way."""
-    from jax.sharding import NamedSharding
-
-    from ..parallel.sharding import (
-        KV_CACHE_SPEC, PAGED_KV_SPEC, REPLICATED, _divisible_spec,
-    )
-
-    row_sp = KV_CACHE_SPEC if batch else PAGED_KV_SPEC
-    plane_sp = REPLICATED
-
-    def pin(a, sp):
-        sp = _divisible_spec(a.shape, sp, mesh)
-        return jax.lax.with_sharding_constraint(
-            a, NamedSharding(mesh, sp))
-
-    return KVCache(
-        k=pin(win.k, row_sp), v=pin(win.v, row_sp),
-        k_scale=pin(win.k_scale, plane_sp) if win.quantized else None,
-        v_scale=pin(win.v_scale, plane_sp) if win.quantized else None,
-    )
-
-
 def _sample_masked(sampling, slot_ids, logits, active, masks):
     with jax.named_scope("sample"):
         toks, new_sampling = sample(sampling, slot_ids, logits,
@@ -571,20 +502,6 @@ class LLMEngine:
                 KVCache.create(draft[0], n_slots, max_seq, cache_dtype)
                 if draft is not None else None
             )
-        # Ragged paged attention (ops/ragged_paged_attention.py): every
-        # dispatch kind — decode scans, prefill chunks, prefill finals,
-        # mixed steps, spec-decode verify — pins its page tables to
-        # FULL table width (max_seq // page entries), so the jit cache
-        # holds ONE variant per token-budget shape instead of the
-        # bucket x window ladder, and kernel-eligible engines route
-        # every row kind through the ONE ragged Pallas kernel (no
-        # materialized gather_kv_pages window on the prefill/mixed hot
-        # path). CPU/meshed/ineligible engines keep the XLA
-        # gather/scatter fallback at full width — same values, still
-        # one variant per shape. LOCALAI_RAGGED_ATTN=off restores the
-        # legacy windowed paths byte-identically.
-        self._ragged = self._paged and knobs.flag(
-            "LOCALAI_RAGGED_ATTN")
         self.warmup_variants = 0  # dispatch variants precompiled by the
         # last completed warmup() pass (engine_dispatch_compile_variants
         # gauge; 0 until warmup runs or when it was marker-skipped)
@@ -637,17 +554,12 @@ class LLMEngine:
         # that ruled it out (surfaced by engine_stats)
         self.kernel_ineligible: str = self._kernel_ineligible()
         self._use_kernel = not self.kernel_ineligible
-        if not self._paged:
-            self.attention_path = (
-                "dense_decode_kernel" if self._use_kernel else "dense_xla")
-        elif self._ragged:
-            self.attention_path = (
-                "ragged_paged_kernel" if self._use_kernel
-                else "paged_xla_gather")
-        else:
-            self.attention_path = (
-                "paged_decode_kernel+windowed_xla" if self._use_kernel
-                else "paged_windowed_xla")
+        # how every dispatch program reaches the cache, decided here and
+        # nowhere else (engine/cache_route.py)
+        self._route = route = choose_route(
+            paged=self._paged, kernel=self._use_kernel, max_seq=max_seq,
+            page=pg, mesh=mesh)
+        self.attention_path: str = route.name
         log.info(
             "attention path %s on %s (%s)%s", self.attention_path,
             self.platform, self.device_kind,
@@ -777,64 +689,22 @@ class LLMEngine:
         # (its active slots are running prompts, not streams)
         self._deadline_stage = "decode"
 
-        if self._paged:
-            _page = self._page
-
-            @partial(jax.jit, donate_argnums=(2, 5))
-            def dispatch_decode1(params, tokens, cache, pos0, slot_ids,
-                                 sampling, active, masks, phys, wb):
-                if self._use_kernel and self._ragged:
-                    # unified ragged kernel: q_len 1 per row, writes
-                    # routed through wb (parked rows append to trash
-                    # instead of their own tail pages)
-                    logits, cache = forward(
-                        spec, params, tokens, pos0, cache, None,
-                        mesh=self.mesh,
-                        page_table=phys, kv_page=_page,
-                        q_lens=jnp.ones(tokens.shape[:1], jnp.int32),
-                        write_table=wb,
-                    )
-                elif self._use_kernel:
-                    # arena + page table straight into the fused kernel
-                    # (the append routes through the table in-graph)
-                    logits, cache = forward(
-                        spec, params, tokens, pos0, cache, None, True,
-                        page_table=phys, kv_page=_page,
-                    )
-                else:
-                    win = gather_kv_pages(cache, phys, _page)
-                    if self.mesh is not None:
-                        # forward on the dense window layout, scatter on
-                        # the arena's (_pin_win_sharding: GSPMD
-                        # miscompiles any replicated-slot-dim window)
-                        win = _pin_win_sharding(win, self.mesh,
-                                                batch=True)
-                    logits, win = forward(
-                        spec, params, tokens, pos0, win, None, False,
-                    )
-                    if self.mesh is not None:
-                        win = _pin_win_sharding(win, self.mesh,
-                                                batch=False)
-                    cache = scatter_kv_pages(cache, win, wb, _page)
-                last = logits[:, -1, :]
-                toks, sampling = _sample_masked(sampling, slot_ids, last,
-                                                active, masks)
-                return toks, cache, sampling
-        else:
-            @partial(jax.jit, donate_argnums=(2, 5))
-            def dispatch_decode1(params, tokens, cache, pos0, slot_ids,
-                                 sampling, active, masks):
-                # slot_ids=None: decode batches every cache row in order,
-                # so the KV write is a per-row DUS, not a cache-sized
-                # scatter
-                logits, cache = forward(
-                    spec, params, tokens, pos0, cache, None,
-                    self._use_kernel, mesh=self.mesh,
-                )
-                last = logits[:, -1, :]
-                toks, sampling = _sample_masked(sampling, slot_ids, last,
-                                                active, masks)
-                return toks, cache, sampling
+        @partial(jax.jit, donate_argnums=(2, 5))
+        def dispatch_decode1(params, tokens, cache, pos0, slot_ids,
+                             sampling, active, masks, *tables):
+            # q_len 1 per row; the batch is every cache row in order
+            # (slot_ids feeds the sampler only), so a dense KV write is
+            # a per-row DUS, not a cache-sized scatter
+            view = route.open(cache, tables, max_seq)
+            logits, view = forward(
+                spec, params, tokens, pos0, view, **route.forward_kw(
+                    tables, jnp.ones(tokens.shape[:1], jnp.int32),
+                    decode=True))
+            cache = route.close(cache, view, tables)
+            last = logits[:, -1, :]
+            toks, sampling = _sample_masked(sampling, slot_ids, last,
+                                            active, masks)
+            return toks, cache, sampling
 
         @jax.jit
         def _sample_only(sampling, slot_ids, logits, masks):
@@ -974,16 +844,11 @@ class LLMEngine:
                 # paged meshed engines have exactly ONE kernel route:
                 # the ragged kernel over the model-sharded arena
                 # (ops.ragged_paged_attention.sharded_ragged_append_
-                # attend). The fused decode kernel's meshed wrapper
-                # addresses the DENSE [L, S, SEQ, F] layout, so with
-                # ragged off the engine takes the GSPMD gather
-                # fallback instead.
+                # attend); ineligible shapes take the GSPMD gather route
                 from ..ops.ragged_paged_attention import (
                     mesh_ragged_eligible,
                 )
 
-                if not self._ragged:
-                    return "meshed paged engine with LOCALAI_RAGGED_ATTN=off"
                 if not mesh_ragged_eligible(
                     self.mesh, self.spec.n_kv_heads, self.spec.n_heads,
                     self.spec.kv_dim,
@@ -1021,10 +886,9 @@ class LLMEngine:
             return f"kv_dim {self.spec.kv_dim} % 128 != 0"
         if self.spec.attn_logit_softcap:
             return "attn_logit_softcap"
-        # a condition forward_hidden ALSO gates on — if they disagreed
-        # the engine would skip window bucketing while forward falls
-        # back to the full-seq XLA path (int8 caches qualify: the
-        # kernel reads int8 pages + per-row scales directly)
+        # decided HERE, before the route is chosen: forward_hidden keeps
+        # its own gate on this only as a guard (int8 caches qualify:
+        # the kernel reads int8 pages + per-row scales directly)
         if _layer_windows(self.spec) is not None:
             return "per-layer sliding windows"
         return ""
@@ -1152,48 +1016,29 @@ class LLMEngine:
             return fn
         spec = self.spec
         dspec = self.draft[0]  # static; draft params passed per call
-        paged = self._paged
-        page = self._page
-        mesh = self.mesh
-        ragged_k = self._ragged and self._use_kernel
+        route, max_seq = self._route, self.max_seq
 
         @partial(jax.jit, donate_argnums=(2, 3))
         def dispatch_spec(params, dparams, cache, dcache, tokens, pos0,
-                          active, *paged_tables):
-            phys = wb = None
-            if paged and ragged_k:
-                # ragged kernel: verify rows are q_len == kd ragged rows
-                # through the SAME kernel as decode/prefill; draft steps
-                # are q_len == 1 rows. No gathered views — writes route
-                # through wb (ineligible rows' spans are trash).
-                phys, wb = paged_tables
-            elif paged:
-                # full-width gathered views for both caches; the arena
-                # writeback at the end persists only the eligible rows'
-                # verify/draft spans (wb)
-                arena, darena = cache, dcache
-                phys, wb = paged_tables
-                cache = gather_kv_pages(arena, phys, page)
-                dcache = gather_kv_pages(darena, phys, page)
-                if mesh is not None:
-                    cache = _pin_win_sharding(cache, mesh, batch=True)
-                    dcache = _pin_win_sharding(dcache, mesh, batch=True)
+                          active, *tables):
+            # both caches open once for all rounds; the close persists
+            # only the eligible rows' verify/draft spans (ineligible
+            # rows' write tables are trash)
+            arena, darena = cache, dcache
+            cache = route.open(arena, tables, max_seq)
+            dcache = route.open(darena, tables, max_seq)
             ones = jnp.ones(tokens.shape[:1], jnp.int32)
 
             def rag(n):
-                if not ragged_k:
-                    return {}
-                return {"mesh": mesh, "page_table": phys,
-                        "kv_page": page, "q_lens": ones * n,
-                        "write_table": wb}
+                # verify rows are q_len == kd rows, draft steps q_len 1
+                return route.forward_kw(tables, ones * n)
 
             def round_(carry, _):
                 tok, pos, cache, dcache = carry
 
                 def dstep(c, _):
                     t, p, dc = c
-                    lg, dc = forward(dspec, dparams, t, p, dc, None,
-                                     **rag(1))
+                    lg, dc = forward(dspec, dparams, t, p, dc, **rag(1))
                     nt = jnp.argmax(lg[:, -1], -1).astype(jnp.int32)
                     p2 = jnp.where(active, p + 1, p)
                     return (nt[:, None], p2, dc), nt
@@ -1207,7 +1052,7 @@ class LLMEngine:
                     dstep, (tok, pos, dcache), None, length=kd)
                 d_toks = dts[: kd - 1].T  # [S, kd-1]
                 xin = jnp.concatenate([tok, d_toks], axis=1)  # [S, kd]
-                lg, cache2 = forward(spec, params, xin, pos, cache, None,
+                lg, cache2 = forward(spec, params, xin, pos, cache,
                                      **rag(kd))
                 m_toks = jnp.argmax(lg, -1).astype(jnp.int32)  # [S, kd]
                 ok = (m_toks[:, : kd - 1] == d_toks).astype(jnp.int32)
@@ -1220,13 +1065,9 @@ class LLMEngine:
 
             (tok_f, pos_f, cache, dcache), (D, Mt, J) = lax.scan(
                 round_, (tokens, pos0, cache, dcache), None, length=rounds)
-            if paged and not ragged_k:
-                if mesh is not None:
-                    cache = _pin_win_sharding(cache, mesh, batch=False)
-                    dcache = _pin_win_sharding(dcache, mesh, batch=False)
-                cache = scatter_kv_pages(arena, cache, wb, page)
-                dcache = scatter_kv_pages(darena, dcache, wb, page)
-            return D, Mt, J, tok_f, pos_f, cache, dcache
+            return (D, Mt, J, tok_f, pos_f,
+                    route.close(arena, cache, tables),
+                    route.close(darena, dcache, tables))
 
         self._decode_k_fns[key] = dispatch_spec
         return dispatch_spec
@@ -1266,43 +1107,27 @@ class LLMEngine:
             )(keys, logp)
             return jnp.argmax(logp + g, axis=-1)
 
-        paged = self._paged
-        page = self._page
-        mesh = self.mesh
-        ragged_k = self._ragged and self._use_kernel
+        route, max_seq = self._route, self.max_seq
 
         @partial(jax.jit, donate_argnums=(3, 4))
         def dispatch_spec_s(params, dparams, sampling, cache, dcache,
-                            tokens, pos0, active, *paged_tables):
-            phys = wb = None
-            if paged and ragged_k:
-                phys, wb = paged_tables
-            elif paged:
-                arena, darena = cache, dcache
-                phys, wb = paged_tables
-                cache = gather_kv_pages(arena, phys, page)
-                dcache = gather_kv_pages(darena, phys, page)
-                if mesh is not None:
-                    cache = _pin_win_sharding(cache, mesh, batch=True)
-                    dcache = _pin_win_sharding(dcache, mesh, batch=True)
+                            tokens, pos0, active, *tables):
+            arena, darena = cache, dcache
+            cache = route.open(arena, tables, max_seq)
+            dcache = route.open(darena, tables, max_seq)
             all_slots = jnp.arange(S, dtype=jnp.int32)
             rep_slots = jnp.repeat(all_slots, kd)
             ones = jnp.ones(tokens.shape[:1], jnp.int32)
 
             def rag(n):
-                if not ragged_k:
-                    return {}
-                return {"mesh": mesh, "page_table": phys,
-                        "kv_page": page, "q_lens": ones * n,
-                        "write_table": wb}
+                return route.forward_kw(tables, ones * n)
 
             def round_(carry, _):
                 tok, pos, cache, dcache, rng = carry
 
                 def dstep(c, _):
                     t, p, dc, rng = c
-                    lg, dc = forward(dspec, dparams, t, p, dc, None,
-                                     **rag(1))
+                    lg, dc = forward(dspec, dparams, t, p, dc, **rag(1))
                     qp, qidx = filtered_candidates(
                         sampling, all_slots, lg[:, -1])
                     rng, k1 = split_rows(rng)
@@ -1319,7 +1144,7 @@ class LLMEngine:
                     dstep, (tok, pos, dcache, rng), None, length=kd)
                 d_toks = dts[: kd - 1].T  # [S, kd-1]
                 xin = jnp.concatenate([tok, d_toks], axis=1)  # [S, kd]
-                lg, cache2 = forward(spec, params, xin, pos, cache, None,
+                lg, cache2 = forward(spec, params, xin, pos, cache,
                                      **rag(kd))
                 pp, pidx = filtered_candidates(
                     sampling, rep_slots, lg.reshape(S * kd, -1))
@@ -1366,13 +1191,8 @@ class LLMEngine:
             (_, _, cache, dcache, rng), (D, Fin, J) = lax.scan(
                 round_, (tokens, pos0, cache, dcache, sampling.rng),
                 None, length=rounds)
-            if paged and not ragged_k:
-                if mesh is not None:
-                    cache = _pin_win_sharding(cache, mesh, batch=False)
-                    dcache = _pin_win_sharding(dcache, mesh, batch=False)
-                cache = scatter_kv_pages(arena, cache, wb, page)
-                dcache = scatter_kv_pages(darena, dcache, wb, page)
-            return D, Fin, J, rng, cache, dcache
+            return (D, Fin, J, rng, route.close(arena, cache, tables),
+                    route.close(darena, dcache, tables))
 
         self._decode_k_fns[key] = dispatch_spec_s
         return dispatch_spec_s
@@ -1389,54 +1209,26 @@ class LLMEngine:
         if fn is not None:
             return fn
         spec = self.spec
-        mesh = self.mesh
+        route = self._route
 
-        if self._paged:
-            page = self._page
-            ragged_k = self._ragged and self._use_kernel
-
-            @partial(jax.jit, donate_argnums=(2,))
-            def dispatch_prefill(params, tokens, cache, pos0, slot_ids,
-                                 phys, wb, soft=None):
-                # paged: the gathered view holds only this dispatch's
-                # rows (identity layout), so the slot mapping lives in
-                # phys/wb instead of slot_ids
-                if soft is not None:
-                    soft = _soft_expand(tokens, *soft)
-                if ragged_k:
-                    # ragged kernel: the chunk scatters through wb and
-                    # attention walks pages in-kernel — no gathered
-                    # window view (chunk dispatches are always
-                    # full-bucket wide, so q_lens is the bucket)
-                    qlens = jnp.full(tokens.shape[:1], tokens.shape[1],
-                                     jnp.int32)
-                    _, cache = forward_hidden(
-                        spec, params, tokens, pos0, cache, None,
-                        soft=soft, mesh=mesh, page_table=phys,
-                        kv_page=page, q_lens=qlens, write_table=wb)
-                    return cache
-                win = gather_kv_pages(cache, phys, page)
-                if mesh is not None:
-                    win = _pin_win_sharding(win, mesh, batch=True)
-                _, win = forward_hidden(spec, params, tokens, pos0, win,
-                                        None, soft=soft)
-                if mesh is not None:
-                    win = _pin_win_sharding(win, mesh, batch=False)
-                return scatter_kv_pages(cache, win, wb, page)
-        else:
-            @partial(jax.jit, donate_argnums=(2,))
-            def dispatch_prefill(params, tokens, cache, pos0, slot_ids,
-                         soft=None):
-                # non-final chunk: only the K/V writes matter —
-                # materializing [B, T, V] logits would waste bucket*V
-                # f32 of HBM per row
-                if soft is not None:
-                    soft = _soft_expand(tokens, *soft)
-                win, restore = _window_cache(cache, window)
-                _, win = forward_hidden(spec, params, tokens, pos0, win,
-                                        slot_ids, soft=soft, mesh=mesh,
-                                        ring_prefill=ring)
-                return restore(win)
+        @partial(jax.jit, donate_argnums=(2,))
+        def dispatch_prefill(params, tokens, cache, pos0, slot_ids,
+                             *tables, soft=None):
+            # non-final chunk: only the K/V writes matter —
+            # materializing [B, T, V] logits would waste bucket*V f32
+            # of HBM per row. On the pool the view holds only this
+            # dispatch's rows, so the slot mapping lives in the tables
+            # instead of slot_ids
+            if soft is not None:
+                soft = _soft_expand(tokens, *soft)
+            view = route.open(cache, tables, window)
+            # chunk dispatches are always full-bucket wide
+            qlens = jnp.full(tokens.shape[:1], tokens.shape[1], jnp.int32)
+            _, view = forward_hidden(
+                spec, params, tokens, pos0, view, soft=soft,
+                **route.forward_kw(tables, qlens, slot_ids=slot_ids,
+                                   ring=ring))
+            return route.close(cache, view, tables)
 
         self._decode_k_fns[key] = dispatch_prefill
         return dispatch_prefill
@@ -1466,50 +1258,29 @@ class LLMEngine:
             return fn
         spec = self.spec
         n_slots = self.n_slots
-        paged = self._paged
-        page = self._page
-        mesh = self.mesh
-        ragged_k = self._ragged and self._use_kernel
+        route = self._route
 
         @partial(jax.jit, donate_argnums=(2, 4))
         def dispatch_prefill_final(params, tokens, cache, pos0, sampling,
                                    slot_ids, n_chunk, tails, tail_lens,
-                                   masks, reset, *paged_tables,
-                                   soft=None):
+                                   masks, reset, *tables, soft=None):
             if soft is not None:
                 soft = _soft_expand(tokens, *soft)
-            if paged and ragged_k:
-                # ragged kernel: n_chunk IS the per-row ragged query
-                # length (pad rows carry 1 and write to trash via wb)
-                phys, wb = paged_tables
-                hidden, cache = forward_hidden(
-                    spec, params, tokens, pos0, cache, None, soft=soft,
-                    mesh=mesh, page_table=phys, kv_page=page,
-                    q_lens=n_chunk, write_table=wb)
-            elif paged:
-                # paged: rows map to slots via phys/wb; parked and pad
-                # rows simply never write back (their wb pages are
-                # trash), so no write_mask is needed
-                phys, wb = paged_tables
-                win = gather_kv_pages(cache, phys, page)
-                if mesh is not None:
-                    win = _pin_win_sharding(win, mesh, batch=True)
-                hidden, win = forward_hidden(
-                    spec, params, tokens, pos0, win, None, soft=soft)
-                if mesh is not None:
-                    win = _pin_win_sharding(win, mesh, batch=False)
-                cache = scatter_kv_pages(cache, win, wb, page)
-            else:
-                win, restore = _window_cache(cache, window)
-                hidden, win = forward_hidden(
-                    spec, params, tokens, pos0, win,
-                    None if identity else slot_ids, soft=soft,
-                    # identity parks non-members at pos 0 with a no-op
-                    # write, so the window can track the MEMBERS' live
-                    # context instead of max_seq
-                    write_mask=(slot_ids < n_slots) if identity else None,
-                )
-                cache = restore(win)
+            view = route.open(cache, tables, window)
+            # n_chunk IS the per-row ragged query length (pad rows carry
+            # 1). On the pool, rows map to slots through the tables, and
+            # parked and pad rows never write back (their pages are
+            # trash). The dense identity batch parks non-members at
+            # pos 0 with a no-op write (write_mask), so the window can
+            # track the MEMBERS' live context instead of max_seq
+            hidden, view = forward_hidden(
+                spec, params, tokens, pos0, view, soft=soft,
+                **route.forward_kw(
+                    tables, n_chunk,
+                    slot_ids=None if identity else slot_ids,
+                    write_mask=(slot_ids < n_slots) if identity
+                    else None))
+            cache = route.close(cache, view, tables)
             # sampler reset rides THIS dispatch (admission used to pay a
             # separate reset_batch dispatch before the prefill — one
             # dispatch off TTFT for singles and waves alike)
@@ -1573,52 +1344,28 @@ class LLMEngine:
         if fn is not None:
             return fn
         spec = self.spec
-        paged = self._paged
-        page = self._page
-        mesh = self.mesh
-        ragged_k = self._ragged and self._use_kernel
+        route = self._route
 
         @partial(jax.jit, donate_argnums=(2, 4))
         def dispatch_mixed(params, tokens, cache, pos0, sampling,
                            write_mask, n_chunk, sample_sids, reset_sids,
-                           tails, tail_lens, masks, reset, *paged_tables,
+                           tails, tail_lens, masks, reset, *tables,
                            soft=None):
             if soft is not None:
                 soft = _soft_expand(tokens, *soft)
-            if paged and ragged_k:
-                # the ragged batch in one kernel invocation: decode
-                # rows (n_chunk 1), prefill chunks, finals and parked
-                # rows (write to trash via wb) together — the unified
-                # dispatch RTP-LLM/Ragged-Paged-Attention converge on
-                phys, wb = paged_tables
-                hidden, cache = forward_hidden(
-                    spec, params, tokens, pos0, cache, None, soft=soft,
-                    mesh=mesh, page_table=phys, kv_page=page,
-                    q_lens=n_chunk, write_table=wb)
-            elif paged:
-                # paged: per-row write spans live in wb (parked rows and
-                # shared prefix pages are trash-redirected), so the
-                # write_mask no-op rewrite is unnecessary
-                phys, wb = paged_tables
-                win = gather_kv_pages(cache, phys, page)
-                if mesh is not None:
-                    # run the forward on the DENSE cache's window layout
-                    # and scatter on the arena's (_pin_win_sharding: any
-                    # replicated-slot-dim window is miscompiled by GSPMD
-                    # on a data x model mesh)
-                    win = _pin_win_sharding(win, mesh, batch=True)
-                hidden, win = forward_hidden(
-                    spec, params, tokens, pos0, win, None, soft=soft)
-                if mesh is not None:
-                    win = _pin_win_sharding(win, mesh, batch=False)
-                cache = scatter_kv_pages(cache, win, wb, page)
-            else:
-                win, restore = _window_cache(cache, window)
-                hidden, win = forward_hidden(
-                    spec, params, tokens, pos0, win, None, soft=soft,
-                    write_mask=write_mask,
-                )
-                cache = restore(win)
+            view = route.open(cache, tables, window)
+            # the ragged batch in one forward: decode rows (n_chunk 1),
+            # prefill chunks, finals and parked rows together — the
+            # unified dispatch RTP-LLM/Ragged-Paged-Attention converge
+            # on. On the pool per-row write spans live in the write
+            # table (parked rows and shared prefix pages are
+            # trash-redirected); the dense cache needs the write_mask
+            # no-op rewrite instead
+            hidden, view = forward_hidden(
+                spec, params, tokens, pos0, view, soft=soft,
+                **route.forward_kw(tables, n_chunk,
+                                   write_mask=write_mask))
+            cache = route.close(cache, view, tables)
             from ..models.transformer import _lm_head
             from ..ops.sampling import reset_slots
 
@@ -1652,13 +1399,6 @@ class LLMEngine:
         return tuple(b for b in self.prefill_buckets
                      if b * self.n_slots <= self._prefill_group_tokens)
 
-    def _window_bucket(self, need: int) -> int:
-        """Smallest power-of-two window >= need (floor 256, cap max_seq)."""
-        w = 256
-        while w < need:
-            w *= 2
-        return min(w, self.max_seq)
-
     def _itl_budget_ms(self) -> float:
         """The explicit inter-token-latency budget cost scheduling
         packs against, in ms; 0.0 when cost scheduling is off, the
@@ -1678,20 +1418,16 @@ class LLMEngine:
                       bucket: int) -> int:
         """Context window the mixed dispatch for this composition and
         bucket would select — EXACTLY the choice _enqueue_mixed makes
-        (ragged pins full width; otherwise the smallest compiled
-        window covering every advancing row), factored out so the
-        cost-packing pass can predict each candidate bucket's true
-        variant before any arrays are built."""
-        if self._ragged:
-            return self.max_seq
+        (the route's window covering every advancing row), factored
+        out so the cost-packing pass can predict each candidate
+        bucket's true variant before any arrays are built."""
         need_w = max(
             [s.n_past + 1 for s in decoding]
             + [s.n_past + min(s.n_prompt - s.n_past, bucket)
                for s in prefilling]) + 1
-        window = self._window_bucket(need_w)
-        compiled = [k[1] for k in self._decode_k_fns
-                    if k[0] == "mixed" and window <= k[1]]
-        return min(compiled) if compiled else self.max_seq
+        return self._route.window(
+            need_w, "mixed",
+            (k[1] for k in self._decode_k_fns if k[0] == "mixed"))
 
     def _cost_bucket(self, prefilling: list, decoding: list,
                      cover: int, budget_ms: float) -> int:
@@ -1727,38 +1463,19 @@ class LLMEngine:
         if fn is not None:
             return fn
         dspec = self.draft[0]
+        route, max_seq = self._route, self.max_seq
 
-        if self._paged:
-            page = self._page
-            mesh = self.mesh
-            ragged_k = self._ragged and self._use_kernel
-
-            @partial(jax.jit, donate_argnums=(2,))
-            def dispatch_draft_prefill(dparams, tokens, dcache, pos0,
-                                       slot_ids, phys, wb, qlens=None):
-                # the draft arena shares the main pool's page geometry
-                # and tables; wb carries ONLY the rows whose draft K/V
-                # must land (prefill rows — decode rows never mirror)
-                if ragged_k:
-                    _, dcache = forward(
-                        dspec, dparams, tokens, pos0, dcache, None,
-                        mesh=mesh, page_table=phys, kv_page=page,
-                        q_lens=qlens, write_table=wb)
-                    return dcache
-                win = gather_kv_pages(dcache, phys, page)
-                if mesh is not None:
-                    win = _pin_win_sharding(win, mesh, batch=True)
-                _, win = forward(dspec, dparams, tokens, pos0, win, None)
-                if mesh is not None:
-                    win = _pin_win_sharding(win, mesh, batch=False)
-                return scatter_kv_pages(dcache, win, wb, page)
-        else:
-            @partial(jax.jit, donate_argnums=(2,))
-            def dispatch_draft_prefill(dparams, tokens, dcache, pos0,
-                                       slot_ids):
-                _, dcache = forward(dspec, dparams, tokens, pos0, dcache,
-                                    slot_ids)
-                return dcache
+        @partial(jax.jit, donate_argnums=(2,))
+        def dispatch_draft_prefill(dparams, tokens, dcache, pos0,
+                                   slot_ids, *tables, q_lens=None):
+            # the draft arena shares the main pool's page geometry and
+            # tables; the write table carries ONLY the rows whose draft
+            # K/V must land (prefill rows — decode rows never mirror)
+            view = route.open(dcache, tables, max_seq)
+            _, view = forward(
+                dspec, dparams, tokens, pos0, view,
+                **route.forward_kw(tables, q_lens, slot_ids=slot_ids))
+            return route.close(dcache, view, tables)
 
         self._decode_k_fns[("draft_prefill",)] = dispatch_draft_prefill
         return dispatch_draft_prefill
@@ -1966,94 +1683,30 @@ class LLMEngine:
         if fn is not None:
             return fn
         spec = self.spec
+        route = self._route
 
-        if self._paged:
-            page = self._page
-            use_kernel = self._use_kernel
-            ragged_k = self._ragged and use_kernel
+        @partial(jax.jit, donate_argnums=(2, 5))
+        def dispatch_decodek(params, tokens, cache, pos0, slot_ids,
+                             sampling, active, *tables):
+            view = route.open(cache, tables, window)
+            kw = route.forward_kw(
+                tables, jnp.ones(tokens.shape[:1], jnp.int32), decode=True)
 
-            @partial(jax.jit, donate_argnums=(2, 5))
-            def dispatch_decodek(params, tokens, cache, pos0, slot_ids,
-                                 sampling, active, phys, wb):
-                if use_kernel:
-                    # fused kernel addresses the arena through the page
-                    # table directly — no gather, the paged decode hot
-                    # path reads only live pages. Ragged mode routes the
-                    # append through wb (parked rows write to trash
-                    # instead of their own tail pages).
-                    ones = jnp.ones(tokens.shape[:1], jnp.int32)
+            def step(carry, _):
+                tokens, pos, view, sampling = carry
+                logits, view = forward(spec, params, tokens, pos, view,
+                                       **kw)
+                toks, sampling = _sample_masked(
+                    sampling, slot_ids, logits[:, -1, :], active, None)
+                pos = jnp.where(active, pos + 1, pos)
+                return (toks[:, None], pos, view, sampling), toks
 
-                    def step(carry, _):
-                        tokens, pos, cache, sampling = carry
-                        if ragged_k:
-                            logits, cache = forward(
-                                spec, params, tokens, pos, cache, None,
-                                mesh=self.mesh, page_table=phys,
-                                kv_page=page, q_lens=ones, write_table=wb,
-                            )
-                        else:
-                            logits, cache = forward(
-                                spec, params, tokens, pos, cache, None,
-                                True, page_table=phys, kv_page=page,
-                            )
-                        toks, sampling = _sample_masked(
-                            sampling, slot_ids, logits[:, -1, :], active,
-                            None)
-                        pos = jnp.where(active, pos + 1, pos)
-                        return (toks[:, None], pos, cache, sampling), toks
-
-                    (tok_next, pos_next, cache, sampling), toks_seq = \
-                        lax.scan(step, (tokens, pos0, cache, sampling),
-                                 None, length=k)
-                    return (toks_seq.T, tok_next, pos_next, cache,
-                            sampling)
-                win = gather_kv_pages(cache, phys, page)
-                if self.mesh is not None:
-                    win = _pin_win_sharding(win, self.mesh, batch=True)
-
-                def step(carry, _):
-                    tokens, pos, win, sampling = carry
-                    logits, win = forward(
-                        spec, params, tokens, pos, win, None, False,
-                    )
-                    toks, sampling = _sample_masked(
-                        sampling, slot_ids, logits[:, -1, :], active,
-                        None)
-                    pos = jnp.where(active, pos + 1, pos)
-                    return (toks[:, None], pos, win, sampling), toks
-
-                (tok_next, pos_next, win, sampling), toks_seq = lax.scan(
-                    step, (tokens, pos0, win, sampling), None, length=k
-                )
-                if self.mesh is not None:
-                    win = _pin_win_sharding(win, self.mesh, batch=False)
-                return (toks_seq.T, tok_next, pos_next,
-                        scatter_kv_pages(cache, win, wb, page), sampling)
-        else:
-            @partial(jax.jit, donate_argnums=(2, 5))
-            def dispatch_decodek(params, tokens, cache, pos0, slot_ids,
-                                 sampling, active):
-                cache, restore = _window_cache(cache, window)
-
-                def step(carry, _):
-                    tokens, pos, cache, sampling = carry
-                    logits, cache = forward(
-                        spec, params, tokens, pos, cache, None,
-                        self._use_kernel, mesh=self.mesh,
-                    )
-                    toks, sampling = _sample_masked(
-                        sampling, slot_ids, logits[:, -1, :], active, None
-                    )
-                    pos = jnp.where(active, pos + 1, pos)
-                    return (toks[:, None], pos, cache, sampling), toks
-
-                (tok_next, pos_next, cache, sampling), toks_seq = lax.scan(
-                    step, (tokens, pos0, cache, sampling), None, length=k
-                )
-                # tok_next/pos_next are returned so the next dispatch can
-                # chain on device state without a host round trip
-                return (toks_seq.T, tok_next, pos_next, restore(cache),
-                        sampling)  # [S, k]
+            (tok_next, pos_next, view, sampling), toks_seq = lax.scan(
+                step, (tokens, pos0, view, sampling), None, length=k)
+            # tok_next/pos_next are returned so the next dispatch can
+            # chain on device state without a host round trip
+            return (toks_seq.T, tok_next, pos_next,
+                    route.close(cache, view, tables), sampling)  # [S, k]
 
         self._decode_k_fns[("decode", k, window)] = dispatch_decodek
         return dispatch_decodek
@@ -2141,8 +1794,10 @@ class LLMEngine:
         leader-side scheduler state — so follower replay stays lockstep."""
         # paged dispatches carry their page-table snapshots in the
         # payload ("pt"/"wb" int32 index arrays), so follower replay
-        # needs no allocator state
+        # needs no allocator state; a dense cache has no tables
         def tabs():
+            if not self._paged:
+                return ()
             return (jnp.asarray(p["pt"]), jnp.asarray(p["wb"]))
 
         def cap(fn, *args, **kw):
@@ -2165,27 +1820,16 @@ class LLMEngine:
             soft = self._soft_dense(p.get("soft"), *p["toks"].shape)
             fn = self._prefill_fn(
                 p.get("window", self.max_seq), p.get("ring", False))
-            if self._paged:
-                pt, wb = tabs()
-                cap(fn, self.params, toks, self.cache, pos0, sids,
-                    pt, wb, soft=soft)
-                self.cache = fn(self.params, toks, self.cache, pos0,
-                                sids, pt, wb, soft=soft)
-                if self.draft is not None:
-                    self.draft_cache = self._draft_prefill_fn()(
-                        self.draft[1], toks, self.draft_cache, pos0,
-                        sids, pt, wb,
-                        jnp.full(toks.shape[:1], toks.shape[1],
-                                 jnp.int32))
-            else:
-                cap(fn, self.params, toks, self.cache, pos0, sids,
-                    soft=soft)
-                self.cache = fn(self.params, toks, self.cache, pos0,
-                                sids, soft=soft)
-                if self.draft is not None:
-                    self.draft_cache = self._draft_prefill_fn()(
-                        self.draft[1], toks, self.draft_cache, pos0, sids
-                    )
+            tables = tabs()
+            cap(fn, self.params, toks, self.cache, pos0, sids, *tables,
+                soft=soft)
+            self.cache = fn(self.params, toks, self.cache, pos0, sids,
+                            *tables, soft=soft)
+            if self.draft is not None:
+                self.draft_cache = self._draft_prefill_fn()(
+                    self.draft[1], toks, self.draft_cache, pos0, sids,
+                    *tables, q_lens=jnp.full(
+                        toks.shape[:1], toks.shape[1], jnp.int32))
             return None
         if kind == "prefill_final":
             toks = jnp.asarray(p["toks"])
@@ -2200,24 +1844,17 @@ class LLMEngine:
                 "typical_p", "mirostat", "mirostat_tau", "mirostat_eta"))
             fn = self._prefill_final_fn(
                 p.get("window", self.max_seq), p.get("identity", False))
+            tables = tabs()
             args = [self.params, toks, self.cache, pos0, self.sampling,
                     sids, jnp.asarray(p["n_chunk"]),
                     jnp.asarray(p["tails"]), jnp.asarray(p["tail_lens"]),
-                    masks, reset]
-            if self._paged:
-                pt, wb = tabs()
-                args += [pt, wb]
+                    masks, reset, *tables]
             cap(fn, *args, soft=soft)
             toks_out, self.cache, self.sampling = fn(*args, soft=soft)
             if self.draft is not None:
-                if self._paged:
-                    self.draft_cache = self._draft_prefill_fn()(
-                        self.draft[1], toks, self.draft_cache, pos0,
-                        sids, pt, wb, jnp.asarray(p["n_chunk"]))
-                else:
-                    self.draft_cache = self._draft_prefill_fn()(
-                        self.draft[1], toks, self.draft_cache, pos0, sids
-                    )
+                self.draft_cache = self._draft_prefill_fn()(
+                    self.draft[1], toks, self.draft_cache, pos0, sids,
+                    *tables, q_lens=jnp.asarray(p["n_chunk"]))
             return toks_out
         if kind == "mixed":
             # fused mixed prefill+decode step: like prefill_final, a
@@ -2233,41 +1870,33 @@ class LLMEngine:
                 "repeat_penalty", "freq_penalty", "presence_penalty",
                 "repeat_last_n", "seeds", "has_seed",
                 "typical_p", "mirostat", "mirostat_tau", "mirostat_eta"))
+            tables = tabs()
             args = [self.params, toks, self.cache, pos0, self.sampling,
                     jnp.asarray(p["write_mask"]),
                     jnp.asarray(p["n_chunk"]),
                     jnp.asarray(p["sample_sids"]),
                     jnp.asarray(p["reset_sids"]), jnp.asarray(p["tails"]),
-                    jnp.asarray(p["tail_lens"]), masks, reset]
-            if self._paged:
-                pt, wb = tabs()
-                args += [pt, wb]
+                    jnp.asarray(p["tail_lens"]), masks, reset, *tables]
             fn = self._mixed_fn(p.get("window", self.max_seq))
             cap(fn, *args, soft=soft)
             toks_out, self.cache, self.sampling = fn(*args, soft=soft)
             if self.draft is not None:
                 # mirror ONLY the prefill rows into the draft cache
                 # (decode rows advance without draft writes, exactly as
-                # on the decodek path)
-                if self._paged:
-                    self.draft_cache = self._draft_prefill_fn()(
-                        self.draft[1], toks, self.draft_cache, pos0,
-                        jnp.asarray(p["prefill_sids"]), pt,
-                        jnp.asarray(p["wb_draft"]),
-                        jnp.asarray(p["n_chunk"]))
-                else:
-                    self.draft_cache = self._draft_prefill_fn()(
-                        self.draft[1], toks, self.draft_cache, pos0,
-                        jnp.asarray(p["prefill_sids"]),
-                    )
+                # on the decodek path): their own write table on the pool
+                dtabs = ((tables[0], jnp.asarray(p["wb_draft"]))
+                         if tables else ())
+                self.draft_cache = self._draft_prefill_fn()(
+                    self.draft[1], toks, self.draft_cache, pos0,
+                    jnp.asarray(p["prefill_sids"]), *dtabs,
+                    q_lens=jnp.asarray(p["n_chunk"]))
             return toks_out
         if kind == "decode1":
             masks = _unpack_masks(p["masks"])
             args = [self.params, jnp.asarray(p["tokens"]), self.cache,
                     jnp.asarray(p["pos0"]), self._all_slot_ids,
-                    self.sampling, jnp.asarray(p["active"]), masks]
-            if self._paged:
-                args += list(tabs())
+                    self.sampling, jnp.asarray(p["active"]), masks,
+                    *tabs()]
             cap(self._decode_fn, *args)
             toks, self.cache, self.sampling = self._decode_fn(*args)
             return toks
@@ -2281,7 +1910,7 @@ class LLMEngine:
                 tok_dev = jnp.asarray(p["tokens"])
                 pos_dev = jnp.asarray(p["pos0"])
                 act_dev = jnp.asarray(p["active"])
-            extra = list(tabs()) if self._paged else []
+            extra = tabs()
             cap(fn, self.params, tok_dev, self.cache, pos_dev,
                 self._all_slot_ids, self.sampling, act_dev, *extra)
             batches = []
@@ -2297,22 +1926,21 @@ class LLMEngine:
             return batches
         if kind == "spec":
             fn = self._spec_decode_fn(p["kd"], p["rounds"])
-            extra = list(tabs()) if self._paged else []
             D, Mt, J, _, _, self.cache, self.draft_cache = fn(
                 self.params, self.draft[1], self.cache, self.draft_cache,
                 jnp.asarray(p["tokens"]), jnp.asarray(p["pos0"]),
-                jnp.asarray(p["active"]), *extra,
+                jnp.asarray(p["active"]), *tabs(),
             )
             return D, Mt, J
         if kind == "spec_s":
             import dataclasses
 
             fn = self._spec_sampled_fn(p["kd"], p["rounds"])
-            extra = list(tabs()) if self._paged else []
             D, Fin, J, rng, self.cache, self.draft_cache = fn(
                 self.params, self.draft[1], self.sampling, self.cache,
                 self.draft_cache, jnp.asarray(p["tokens"]),
-                jnp.asarray(p["pos0"]), jnp.asarray(p["active"]), *extra,
+                jnp.asarray(p["pos0"]), jnp.asarray(p["active"]),
+                *tabs(),
             )
             self.sampling = dataclasses.replace(self.sampling, rng=rng)
             return D, Fin, J
@@ -2447,9 +2075,6 @@ class LLMEngine:
             self._mixed,  # the mixed dispatcher adds its own variants
             # the paged pool changes every variant's cache geometry
             self._paged, self._page, self.kv_pages,
-            # ragged mode collapses the window ladder to one full-width
-            # variant per shape — a different compile set entirely
-            self._ragged,
         ))
         return hashlib.sha256(blob.encode()).hexdigest()[:20]
 
@@ -2553,17 +2178,10 @@ class LLMEngine:
 
         W = self.sampling.window
         pad_reset = self._reset_columns([], 1)
-        if self._ragged:
-            # ragged paged attention: tables are full-width, so there is
-            # NO window ladder — one variant per token-budget shape
-            win_ladder = [self.max_seq]
-        else:
-            win_ladder = []
-            w = self._window_bucket(1)
-            while w < self.max_seq:
-                win_ladder.append(w)
-                w *= 2
-            win_ladder.append(self.max_seq)
+        # every window below comes from the route's ladder: full-width
+        # page tables have NO ladder (one variant per token-budget
+        # shape), a dense cache has its power-of-two rungs
+        ladder = self._route.ladder
         for bucket in self.prefill_buckets:
             id_capable = (bucket * self.n_slots
                           <= self._prefill_group_tokens)
@@ -2575,13 +2193,11 @@ class LLMEngine:
             variants: list[tuple[int, int, bool]] = []
             if id_capable:
                 # an identity final dispatch's window covers max(pos0)
-                # + bucket + 1, so ladder rungs below
-                # _window_bucket(bucket + 1) can never be dispatched —
-                # compiling them was pure dead warmup cost (at 8B,
-                # seconds per variant)
-                min_w = self._window_bucket(bucket + 1)
-                variants += [(self.n_slots, w, True) for w in win_ladder
-                             if w >= min_w]
+                # + bucket + 1, so the rungs below that can never be
+                # dispatched — compiling them was pure dead warmup cost
+                # (at 8B, seconds per variant)
+                variants += [(self.n_slots, w, True)
+                             for w in ladder("prefill_final", bucket + 1)]
             cap = self._prefill_group_cap(bucket)
             sizes = {cap}
             b = 1
@@ -2620,22 +2236,13 @@ class LLMEngine:
             # prompt stalls on a mid-request jit. Chunk dispatches are
             # always full-bucket wide, so their windows start at the
             # bucket's own window bucket (window >= n_past + bucket).
-            if self._ragged:
-                windows = {self.max_seq}
-            else:
-                w = self._window_bucket(self.prefill_buckets[-1])
-                windows = set()
-                while w < self.max_seq:
-                    windows.add(w)
-                    w *= 2
-                windows.add(self.max_seq)
             seq_ax = (self.mesh.shape.get("seq", 1)
                       if self.mesh is not None else 1)
             rings = {False}
             if (seq_ax > 1 and not self.spec.sliding_window
                     and self.prefill_buckets[-1] % seq_ax == 0):
                 rings.add(True)  # the seq-sharded first-chunk variant
-            for w in sorted(windows):
+            for w in ladder("prefill", self.prefill_buckets[-1]):
                 for ring in sorted(rings):
                     payload = {
                         "toks": np.zeros((1, self.prefill_buckets[-1]),
@@ -2664,11 +2271,10 @@ class LLMEngine:
                 # prefill row's remainder EXCEEDS the previous bucket,
                 # so its window covers at least prev_bucket + 2 —
                 # smaller ladder rungs can never be dispatched for this
-                # bucket (dead compile cost pruned; in ragged mode the
-                # ladder is already the single full-width rung)
-                min_w = self._window_bucket(prev_bucket + 2)
+                # bucket (dead compile cost pruned)
+                windows = ladder("mixed", prev_bucket + 2)
                 prev_bucket = bucket
-                for w in [w for w in win_ladder if w >= min_w]:
+                for w in windows:
                     payload = {
                         "toks": np.zeros((S, bucket), np.int32),
                         "pos0": np.zeros((S,), np.int32),
@@ -2696,7 +2302,7 @@ class LLMEngine:
                 # paged copies are always whole-page: ONE variant
                 _warm("kvcopy", {"src": 0, "dst": 0, "n": self._page})
             else:
-                for w in win_ladder:
+                for w in ladder("kvcopy"):
                     _warm("kvcopy", {"src": 0, "dst": 0, "n": w})
         S = self.n_slots
         inactive = {
@@ -2705,18 +2311,9 @@ class LLMEngine:
             "active": np.zeros((S,), bool),
         }
         ks = self._warm_ks
-        if self._use_kernel or self._ragged:
-            windows_d = {self.max_seq}  # ragged: one variant
-        else:
-            windows_d = set()
-            w = 256
-            while w < self.max_seq:
-                windows_d.add(w)
-                w *= 2
-            windows_d.add(self.max_seq)
         for k in sorted(ks):
             if k > 1:
-                for w in sorted(windows_d):
+                for w in ladder("decode"):
                     payload = {
                         "k": k, "window": w, "depth": 1, "carry": False,
                         **inactive,
@@ -3766,7 +3363,8 @@ class LLMEngine:
             # rewritten by prefill or causally invisible) and keeps the
             # jit set tiny
             self._run("kvcopy", {"src": donor, "dst": slot.idx,
-                                 "n": self._window_bucket(best)})
+                                 "n": window_bucket(
+                                     best, self.max_seq)})
             self.metrics.prefix_copies += 1
             tm.ENGINE_PREFIX_COPIES.labels(model=m).inc()
         self._prefix_index.touch(donor, now)
@@ -4054,11 +3652,7 @@ class LLMEngine:
         # [n_past+len(chunk), n_past+bucket) — harmless: they're beyond the
         # valid prefix and get overwritten when real tokens arrive (causal
         # mask keeps them invisible to attention reads at these positions).
-        # Ragged mode pins the table width to max_seq: the kernel walks
-        # only the live pages anyway, and one jit variant serves every
-        # live-context size.
-        window = (self.max_seq if self._ragged
-                  else self._window_bucket(slot.n_past + bucket))
+        window = self._route.window(slot.n_past + bucket, "prefill")
         payload = {
             "toks": toks,
             "pos0": np.asarray([slot.n_past], np.int32),
@@ -4261,26 +3855,17 @@ class LLMEngine:
             for r, m in zip(rows, masks):
                 full[r] = m
             masks = full
-        if self._ragged or not identity:
-            # ragged: ONE full-width variant per (B, bucket) shape —
-            # the kernel (or full-width gather fallback) is ragged over
-            # live context, so no window ladder exists to pick from
-            window = self.max_seq
-        else:
-            # window follows the MEMBERS' live context (parked rows are
-            # no-op writes at pos 0, so they place no demand on it):
-            # 1024 -> 256 on a fresh wave cuts the dispatch's attention
-            # traffic 4x. Prefer an already-compiled window >= need —
-            # max_seq is always warmed, so nothing compiles mid-request.
-            need = max(int(pos0[r]) for r in rows) + bucket + 1
-            window = self._window_bucket(need)
-            compiled = [k[1] for k in self._decode_k_fns
-                        if k[0] == "prefill_final" and len(k) > 2
-                        and k[2] and window <= k[1]]
-            if compiled:
-                window = min(compiled)
-            else:
-                window = self.max_seq
+        window = self.max_seq
+        if identity:
+            # a dense window follows the MEMBERS' live context (parked
+            # rows are no-op writes at pos 0, so they place no demand
+            # on it): 1024 -> 256 on a fresh wave cuts the dispatch's
+            # attention traffic 4x
+            window = self._route.window(
+                max(int(pos0[r]) for r in rows) + bucket + 1,
+                "prefill_final",
+                (k[1] for k in self._decode_k_fns
+                 if k[0] == "prefill_final" and k[2]))
         payload = {
             "toks": toks, "pos0": pos0, "slot_ids": slot_ids,
             "n_chunk": n_chunk, "tails": tails, "tail_lens": tail_lens,
@@ -4653,7 +4238,7 @@ class LLMEngine:
         (decode / prefill chunk / prefill final / spec verify) —
         engine_ragged_rows_total, the series proving every row kind
         actually flows through the one-kernel dispatch discipline."""
-        if self._ragged and n > 0:
+        if self._paged and n > 0:
             tm.ENGINE_RAGGED_ROWS.labels(
                 model=self._mlabel, kind=kind).inc(n)
 
@@ -4953,28 +4538,14 @@ class LLMEngine:
                 k = min(k, kb)
 
         S = self.n_slots
-        if self._use_kernel or self._ragged:
-            # the fused Pallas kernel is ragged (reads only valid
-            # pages) and ragged mode pins tables to full width even on
-            # the XLA fallback: one compiled variant for all contexts
-            window = self.max_seq
-        else:
-            # live-context window bucket for this dispatch (_decode_k_fn)
-            # window must cover EVERY non-free slot position plus the
-            # tokens already in flight
-            need = max(s.n_past for s in self.slots
-                       if s.state in (SlotState.DECODE,
-                                      SlotState.PENDING_FIRST)) \
-                + in_flight + k + 1
-            window = self._window_bucket(need)
-            # prefer an already-compiled window >= need over compiling a
-            # new exact bucket (a cold jit costs seconds; reading a
-            # slightly larger window costs microseconds)
-            compiled = [key[2] for key in self._decode_k_fns
-                        if key[0] == "decode" and key[1] == k
-                        and window <= key[2]]
-            if compiled:
-                window = min(compiled)
+        # the window must cover EVERY non-free slot position plus the
+        # tokens already in flight
+        window = self._route.window(
+            max(s.n_past for s in self.slots
+                if s.state in (SlotState.DECODE, SlotState.PENDING_FIRST))
+            + in_flight + k + 1, "decode",
+            (key[2] for key in self._decode_k_fns
+             if key[0] == "decode" and key[1] == k))
 
         if self._paged:
             # page capacity for the scan's write span ([n_past +

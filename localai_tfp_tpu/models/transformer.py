@@ -615,18 +615,16 @@ def forward_hidden(
     # identity prefill park non-member rows at pos 0 without corrupting
     # their live prefixes, which in turn lets the dispatch window follow
     # the MEMBER rows' live context instead of max_seq.
-    page_table: Optional[jax.Array] = None,  # paged KV pool (kernel
-    # decode path only): ``cache`` is the [L, n_pages, page, F] arena
-    # and this [B, max_pages] int32 table maps each row's logical page
-    # index to its physical arena page. The current rows append through
-    # the table and the fused kernel DMAs pages by table lookup. The
-    # paged XLA path instead gathers a dense window OUTSIDE this
-    # function (gather_kv_pages/scatter_kv_pages), so it never sees the
-    # arena.
+    page_table: Optional[jax.Array] = None,  # the engine's RAGGED
+    # route (engine/cache_route.py), and only it, sets this (with
+    # kv_page, q_lens and write_table): ``cache`` is the [L, n_pages,
+    # page, F] arena and this [B, max_pages] int32 table maps each
+    # row's logical page index to its physical arena page. The paged
+    # XLA route instead gathers a dense view OUTSIDE this function
+    # (gather_kv_pages/scatter_kv_pages), so it never sees the arena.
     kv_page: int = 0,  # pool page size (tokens) when page_table is set
-    q_lens: Optional[jax.Array] = None,  # RAGGED kernel mode (with
-    # page_table + write_table): per-row valid token counts — 1 for
-    # decode rows, the chunk length for prefill rows, k+1 for
+    q_lens: Optional[jax.Array] = None,  # per-row valid token counts —
+    # 1 for decode rows, the chunk length for prefill rows, kd for
     # spec-decode verify rows. Every row kind flows through ONE
     # ragged-paged-attention kernel invocation per layer
     # (ops/ragged_paged_attention.py): the chunk's K/V rows scatter
@@ -677,9 +675,9 @@ def forward_hidden(
         # serving shapes — measured 3-4x the decode roofline on v5e).
         x, ck_all, cv_all, ks_all, vs_all = carry
         l, lp = scanned
-        use_ragged = (q_lens is not None and write_table is not None
-                      and page_table is not None and identity
-                      and win is None)  # uniform windows only
+        # a page table IS the ragged route; per-layer windows never get
+        # here (LLMEngine._kernel_ineligible rules them out first)
+        use_ragged = page_table is not None and win is None
         use_kernel = use_ragged or (
             decode_kernel and identity and x.shape[1] == 1
             and win is None)
@@ -817,24 +815,14 @@ def forward_hidden(
                 )
                 return (res[0][:, None, :].astype(x.dtype),
                         tuple(res[1:]))
-            if page_table is not None:
-                # paged arena: route the append through the page table
-                # (physical page of each row's write position). The
-                # host guarantees the target page is privately owned —
-                # or the trash page for parked rows, whose garbage
-                # append is never read.
-                w_rows = page_table[rows, pos0 // kv_page]
-                w_offs = pos0 % kv_page
-            else:
-                w_rows, w_offs = rows, pos0
-            ck_new = ck_all.at[l, w_rows, w_offs, :].set(
+            ck_new = ck_all.at[l, rows, pos0, :].set(
                 kq_row.astype(ck_all.dtype), mode="promise_in_bounds")
-            cv_new = cv_all.at[l, w_rows, w_offs, :].set(
+            cv_new = cv_all.at[l, rows, pos0, :].set(
                 vq_row.astype(cv_all.dtype), mode="promise_in_bounds")
             if quant:
-                ks_new = ks_all.at[l, w_rows, w_offs].set(
+                ks_new = ks_all.at[l, rows, pos0].set(
                     ks_row, mode="promise_in_bounds")
-                vs_new = vs_all.at[l, w_rows, w_offs].set(
+                vs_new = vs_all.at[l, rows, pos0].set(
                     vs_row, mode="promise_in_bounds")
             else:
                 ks_new = vs_new = None
@@ -843,8 +831,6 @@ def forward_hidden(
                 spec.n_kv_heads, scale=scale,
                 sliding_window=spec.sliding_window,
                 cache_k_scale=ks_new, cache_v_scale=vs_new,
-                page_table=page_table,
-                page=(kv_page if page_table is not None else None),
             )
             if quant:
                 return (out[:, None, :].astype(x.dtype),

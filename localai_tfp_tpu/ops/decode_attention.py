@@ -13,11 +13,10 @@ kernel; this module keeps the decode-shaped entry points as thin
 wrappers over it:
 
 - ``fused_decode_attention`` (T == 1, current rows seeded from VMEM so
-  an int8 cache attends the EXACT current row): the paged arena mode
-  passes straight through; the dense ``[L, S, SEQ, F]`` mode VIEWS the
-  cache as a page arena (free reshape) under an identity page table —
-  the paged/dense split this file used to implement twice is now one
-  kernel behind two table constructions.
+  an int8 cache attends the EXACT current row): VIEWS the dense
+  ``[L, S, SEQ, F]`` cache as a page arena (free reshape) under an
+  identity page table. The paged pool calls the ragged kernel itself
+  (models/transformer.py ``ragged_attn``).
 - ``sharded_append_attend``: the shard_map wrapper for meshed serving
   (append + per-shard kernel call), unchanged in contract.
 
@@ -78,7 +77,7 @@ def extract_head_bands(out: jax.Array, n_kv_heads: int,
 
 
 # ---------------------------------------------------------------------------
-# decode wrapper: T == 1 ragged attention, paged or dense-viewed-as-paged
+# decode wrapper: T == 1 ragged attention over the dense cache viewed as pages
 # ---------------------------------------------------------------------------
 
 
@@ -87,8 +86,7 @@ def fused_decode_attention(
     new_k: jax.Array,  # [S, F] post-rope current-token K rows
     new_v: jax.Array,  # [S, F]
     cache_k: jax.Array,  # [L, S, SEQ, F] FULL stacked cache, already
-    # containing the current rows at lengths-1 (caller scatter-appends) —
-    # or, with ``page_table``, the [L, n_pages, page, F] paged arena
+    # containing the current rows at lengths-1 (caller scatter-appends)
     cache_v: jax.Array,
     layer: jax.Array,  # [] i32 layer index
     lengths: jax.Array,  # [S] valid positions INCLUDING current token
@@ -96,48 +94,37 @@ def fused_decode_attention(
     *,
     scale: float,
     sliding_window: Optional[int] = None,
-    page: Optional[int] = None,
     cache_k_scale: Optional[jax.Array] = None,  # [L, S, SEQ] f32 when the
     # cache is int8 (per-row symmetric scales — models/transformer.py
-    # _quantize_rows; ref: llama.cpp cache_type_k/v q8_0) — paged:
-    # [L, n_pages, page] f32
+    # _quantize_rows; ref: llama.cpp cache_type_k/v q8_0)
     cache_v_scale: Optional[jax.Array] = None,
-    page_table: Optional[jax.Array] = None,  # [S, max_pages] i32: paged
-    # KV pool mode — each slot's logical pages resolve to physical arena
-    # pages through this table (scalar-prefetch operand, so DMA source
-    # addresses are computable before the body runs). Entries beyond a
-    # slot's allocation point at the trash page; its garbage is masked.
 ) -> jax.Array:
     """Ragged decode attention over ``[0, lengths)`` of layer ``layer``;
     the current token's K/V contribution is taken from ``new_k``/``new_v``
     in VMEM (its HBM copy is masked out). Returns attn [S, H*Dh].
 
     Thin wrapper over ``ragged_paged_attention`` with T == 1 seeded
-    queries: the dense cache mode is the SAME kernel behind an identity
-    page table over a reshaped ``[L, S*(SEQ//page), page, F]`` view of
-    the stacked cache (a free relayout-less reshape — pages are
-    contiguous row runs)."""
+    queries: the SAME kernel behind an identity page table over a
+    reshaped ``[L, S*(SEQ//PAGE), PAGE, F]`` view of the stacked cache
+    (a free relayout-less reshape — pages are contiguous row runs)."""
     from .ragged_paged_attention import ragged_paged_attention
 
-    if page is None:
-        page = PAGE
-    if page_table is None:
-        L, S, SEQ, F = cache_k.shape
-        assert SEQ % page == 0, (SEQ, page)
-        npg = SEQ // page
-        cache_k = cache_k.reshape(L, S * npg, page, F)
-        cache_v = cache_v.reshape(L, S * npg, page, F)
-        if cache_k_scale is not None:
-            cache_k_scale = cache_k_scale.reshape(L, S * npg, page)
-            cache_v_scale = cache_v_scale.reshape(L, S * npg, page)
-        page_table = (
-            jnp.arange(S, dtype=jnp.int32)[:, None] * npg
-            + jnp.arange(npg, dtype=jnp.int32)[None, :]
-        )
+    L, S, SEQ, F = cache_k.shape
+    assert SEQ % PAGE == 0, (SEQ, PAGE)
+    npg = SEQ // PAGE
+    cache_k = cache_k.reshape(L, S * npg, PAGE, F)
+    cache_v = cache_v.reshape(L, S * npg, PAGE, F)
+    if cache_k_scale is not None:
+        cache_k_scale = cache_k_scale.reshape(L, S * npg, PAGE)
+        cache_v_scale = cache_v_scale.reshape(L, S * npg, PAGE)
+    page_table = (
+        jnp.arange(S, dtype=jnp.int32)[:, None] * npg
+        + jnp.arange(npg, dtype=jnp.int32)[None, :]
+    )
     out = ragged_paged_attention(
         q[:, None, :, :], cache_k, cache_v, layer, page_table,
         jnp.maximum(lengths - 1, 0), jnp.ones_like(lengths),
-        n_kv_heads, scale=scale, page=page,
+        n_kv_heads, scale=scale, page=PAGE,
         sliding_window=sliding_window,
         cache_k_scale=cache_k_scale, cache_v_scale=cache_v_scale,
         seed_kv=(new_k, new_v),

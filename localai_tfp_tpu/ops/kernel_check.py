@@ -105,17 +105,17 @@ def check_decode_attention(quantized: bool = False,
 def check_paged_gather(quantized: bool = False, seed: int = 0) -> float:
     """Paged-path parity: scatter a dense ragged cache into a paged
     arena under a shuffled page table, then compare BOTH paged reads —
-    the XLA gather (models.transformer.gather_kv_pages, the fallback
-    serving path) and the page-table-indirect fused kernel — against
-    the dense reference. The gather must be EXACT (pure indexing); the
-    kernel must match the dense-kernel tolerance. Returns the max abs
-    error across both."""
+    the XLA gather (models.transformer.gather_kv_pages, the gather
+    route) and the ragged kernel at T == 1 through the page table (the
+    ragged route's decode rows) — against the dense reference. The
+    gather must be EXACT (pure indexing); the kernel must match the
+    dense-kernel tolerance. Returns the max abs error across both."""
     import jax.numpy as jnp
 
     from ..models.transformer import (
         KVCache, _quantize_rows, gather_kv_pages,
     )
-    from .decode_attention import fused_decode_attention
+    from .ragged_paged_attention import ragged_paged_attention
 
     rng = np.random.default_rng(seed)
     L, S, SEQ, n_kv, dh, H = 2, 8, 512, 8, 128, 32
@@ -149,6 +149,19 @@ def check_paged_gather(quantized: bool = False, seed: int = 0) -> float:
         jnp.float32)
     scale = 1.0 / np.sqrt(dh)
     pt_j = jnp.asarray(pt)
+
+    def paged_decode(arena):
+        # the decode rows of models/transformer.py ragged_attn: one
+        # query a row at lengths-1, the current row seeded from VMEM
+        ln = jnp.asarray(lengths)
+        return ragged_paged_attention(
+            q.astype(jnp.bfloat16)[:, None], arena.k, arena.v, layer,
+            pt_j, ln - 1, jnp.ones_like(ln), n_kv, scale=scale,
+            page=page, cache_k_scale=arena.k_scale,
+            cache_v_scale=arena.v_scale,
+            seed_kv=(new_k.astype(jnp.bfloat16),
+                     new_v.astype(jnp.bfloat16)))[:, 0]
+
     if quantized:
         kq, ks = _quantize_rows(jnp.asarray(cache_k, jnp.float32))
         vq, vs = _quantize_rows(jnp.asarray(cache_v, jnp.float32))
@@ -176,13 +189,7 @@ def check_paged_gather(quantized: bool = False, seed: int = 0) -> float:
         )
         if gerr > 0:
             return gerr  # indexing bug: report it, skip the kernel leg
-        got = fused_decode_attention(
-            q.astype(jnp.bfloat16), new_k.astype(jnp.bfloat16),
-            new_v.astype(jnp.bfloat16), arena.k, arena.v, layer,
-            jnp.asarray(lengths), n_kv, scale=scale, page=page,
-            cache_k_scale=arena.k_scale, cache_v_scale=arena.v_scale,
-            page_table=pt_j,
-        )
+        got = paged_decode(arena)
         deq_k = kq.astype(jnp.float32) * ks[..., None]
         deq_v = vq.astype(jnp.float32) * vs[..., None]
         want = _ref_decode_attention(
@@ -197,12 +204,7 @@ def check_paged_gather(quantized: bool = False, seed: int = 0) -> float:
             win.k.astype(jnp.float32) - dense_k.astype(jnp.float32))))
         if gerr > 0:
             return gerr
-        got = fused_decode_attention(
-            q.astype(jnp.bfloat16), new_k.astype(jnp.bfloat16),
-            new_v.astype(jnp.bfloat16), arena.k, arena.v, layer,
-            jnp.asarray(lengths), n_kv, scale=scale, page=page,
-            page_table=pt_j,
-        )
+        got = paged_decode(arena)
         want = _ref_decode_attention(
             q, dense_k, dense_v, 1, jnp.asarray(lengths), n_kv, scale)
     return float(jnp.max(jnp.abs(got - want)))

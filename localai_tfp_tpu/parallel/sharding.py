@@ -172,7 +172,7 @@ def shard_engine_state(cache, sampling, mesh: Mesh, paged: bool = False):
         # dispatch must stay data-sharded — it anchors GSPMD to the
         # dense path's (correct) partitioning of the forward. The paged
         # dispatches additionally pin their gathered windows to the
-        # same layout (engine._pin_win_sharding).
+        # same layout (cache_route._pin_win_sharding).
         spec = P(*(("data",) + (None,) * (leaf.ndim - 1))) if leaf.ndim \
             else P()
         out.append(put(leaf, spec))

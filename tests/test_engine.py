@@ -310,11 +310,11 @@ def test_kernel_engine_matches_xla_engine(monkeypatch):
     assert all(len(t) > 0 for t in kernel_out)
 
 
-def test_warmup_variant_count_drops_with_ragged(model):
-    """Ragged paged attention collapses the warmup-precompiled jit
-    variant set: legacy mode compiles a bucket x window ladder
-    (pruned of never-dispatchable rungs, but still a ladder), ragged
-    mode exactly one variant per token-budget shape. The count is also
+def test_warmup_variant_count_drops_with_ragged(model, monkeypatch):
+    """Full-width page tables collapse the warmup-precompiled jit
+    variant set: the dense cache compiles a bucket x window ladder
+    (pruned of never-dispatchable rungs, but still a ladder), the pool
+    exactly one variant per token-budget shape. The count is also
     exported as engine_dispatch_compile_variants_count. The dispatch
     layer is stubbed: the assertion is about the variant PLAN (which
     shapes warmup would compile), and every planned dispatch kind is
@@ -324,16 +324,16 @@ def test_warmup_variant_count_drops_with_ragged(model):
 
     spec, params, tk = model
 
-    def warm(ragged):
-        # max_seq ABOVE the 256 window floor so legacy mode has a real
-        # bucket x window ladder to collapse; the 512 bucket makes the
-        # dead-rung prune observable (an identity bucket-512 final can
-        # only ever dispatch at window 1024)
+    def warm(paged):
+        # max_seq ABOVE the 256 window floor so the dense cache has a
+        # real bucket x window ladder to collapse; the 512 bucket makes
+        # the dead-rung prune observable (an identity bucket-512 final
+        # can only ever dispatch at window 1024)
+        monkeypatch.setenv("LOCALAI_PAGED_KV", "on" if paged else "off")
         eng = LLMEngine(spec, params, tk, n_slots=2, max_seq=1024,
                         prefill_buckets=(8, 512), decode_steps=4,
                         cache_dtype=jnp.float32, autostart=False)
-        assert eng._paged
-        eng._ragged = ragged
+        assert eng._paged == paged
         planned = []
 
         def record(kind, payload):
@@ -364,10 +364,10 @@ def test_warmup_variant_count_drops_with_ragged(model):
     assert 0 < n_on < n_off, (n_on, n_off)
     assert g_on == n_on and g_off == n_off
     assert n_on == len(plan_on) and n_off == len(plan_off)
-    # ragged: every windowed dispatch is planned at FULL width — one
+    # the pool: every windowed dispatch is planned at FULL width — one
     # variant per token-budget shape
     assert all(r["window"] in (None, 1024) for r in plan_on), plan_on
-    # legacy dead-rung prune: an identity bucket-512 final covers at
+    # the dense ladder's dead-rung prune: an identity bucket-512 final covers at
     # least pos0 + 512 + 1 positions, so windows 256/512 can never be
     # dispatched for it — warmup must not compile them…
     id512 = [r for r in plan_off if r["kind"] == "prefill_final"

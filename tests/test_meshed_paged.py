@@ -8,8 +8,8 @@ of EVERY page) while the allocator and its int32 page tables stay
 host-owned and global. Covered here:
 
 - paged+ragged meshed serving is byte-identical to the dense meshed
-  path (greedy AND seeded sampling), and LOCALAI_PAGED_KV=off /
-  LOCALAI_RAGGED_ATTN=off restore today's behavior byte-identically
+  path (greedy AND seeded sampling), which LOCALAI_PAGED_KV=off
+  selects
 - prefix page-sharing/COW and ``leak_check`` hold under churn on a
   meshed engine (allocator state never left the host, so sharding the
   arena must not perturb it)
@@ -108,8 +108,8 @@ def _serve(eng, prompts):
 def test_meshed_paged_on_off_byte_identity(model, monkeypatch):
     """The tentpole contract: a meshed engine with the sharded page
     arena (and the ragged full-width dispatch shapes) streams the SAME
-    BYTES as the dense meshed engine — greedy and seeded sampling —
-    and each kill switch restores the previous path byte-identically."""
+    BYTES as the dense meshed engine (LOCALAI_PAGED_KV=off) — greedy
+    and seeded sampling."""
     from localai_tfp_tpu.parallel.sharding import PAGED_KV_SPEC
 
     monkeypatch.setenv("LOCALAI_KV_PAGE", "16")
@@ -117,25 +117,24 @@ def test_meshed_paged_on_off_byte_identity(model, monkeypatch):
                list(range(1, 20)), [3, 1, 4, 1, 5]]
     mesh = _mesh()
     outs = {}
-    for paged, ragged in (("on", "on"), ("on", "off"), ("off", "on")):
+    for paged in ("on", "off"):
         monkeypatch.setenv("LOCALAI_PAGED_KV", paged)
-        monkeypatch.setenv("LOCALAI_RAGGED_ATTN", ragged)
         eng = _engine(model, mesh=mesh)
         assert eng._paged == (paged == "on")
-        assert eng._ragged == (paged == "on" and ragged == "on")
+        assert eng.attention_path == (
+            "paged_xla_gather" if eng._paged else "dense_xla")
         try:
             if eng._paged:
                 # the arena actually lives sharded on the mesh
                 sh = eng.cache.k.sharding
                 assert sh.spec == PAGED_KV_SPEC, sh
                 eng._pool.leak_check()
-            outs[(paged, ragged)] = _serve(eng, prompts)
+            outs[paged] = _serve(eng, prompts)
             if eng._paged:
                 eng._pool.leak_check()
         finally:
             eng.close()
-    assert outs[("on", "on")] == outs[("off", "on")]
-    assert outs[("on", "off")] == outs[("off", "on")]
+    assert outs["on"] == outs["off"]
 
 
 # slow tier: meshed int8 numerics stay tier-1 via the kernel parity
@@ -150,7 +149,6 @@ def test_meshed_paged_int8_byte_identity(model, monkeypatch):
     int8 cache must stream the same bytes as the dense meshed int8
     engine, greedy and seeded."""
     monkeypatch.setenv("LOCALAI_KV_PAGE", "16")
-    monkeypatch.setenv("LOCALAI_RAGGED_ATTN", "on")
     prompts = [list(range(1, 20)), [9, 8, 7, 6, 5],
                list(range(1, 20)), [3, 1, 4, 1, 5]]
     mesh = _mesh()

@@ -5,10 +5,10 @@ through one unified path, collapsing the bucket x window jit-variant
 ladder to one variant per token-budget shape.
 
 Invariants enforced here:
-- an identical request schedule produces BYTE-IDENTICAL outputs with
-  ragged mode on vs off (LOCALAI_RAGGED_ATTN escape hatch), seeded
-  sampling included — ragged is a dispatch-shape change, not a math
-  change;
+- an identical request schedule produces BYTE-IDENTICAL outputs on
+  the pool and on the dense cache (the reference, windowed along its
+  ladder), seeded sampling included — full-width page tables are a
+  dispatch-shape change, not a math change;
 - ragged dispatches really are full-width (page tables span
   max_seq // page entries for every kind) and the
   engine_ragged_rows_total counter attributes rows by kind;
@@ -38,20 +38,18 @@ def model():
     return spec, params, tk
 
 
-def _engine(model, ragged=True, prefix=False, **kw):
+def _engine(model, prefix=False, **kw):
     spec, params, tk = model
     kw.setdefault("n_slots", 4)
-    # max_seq ABOVE the window floor (256): legacy mode genuinely
-    # windows its dispatches at 256 while ragged pins full width, so
-    # the on/off comparison exercises different dispatch shapes — not
-    # two identical programs
+    # max_seq ABOVE the window floor (256): the dense reference
+    # genuinely windows its dispatches at 256 while the pool pins full
+    # width, so the comparison exercises different dispatch shapes —
+    # not two identical programs
     kw.setdefault("max_seq", 512)
     kw.setdefault("prefill_buckets", (8, 32, 128))
     kw.setdefault("cache_dtype", jnp.float32)
     kw.setdefault("autostart", True)
     eng = LLMEngine(spec, params, tk, **kw)
-    assert eng._paged  # ragged rides the paged pool
-    eng._ragged = ragged  # pre-dispatch override of LOCALAI_RAGGED_ATTN
     # prefix reuse is timing-dependent (which donor is resident when a
     # request admits varies with scheduling interleave); the dedicated
     # shared-page test below controls it explicitly
@@ -161,19 +159,28 @@ def _schedule(eng, tk):
     return {n: (fin.generated[reqs[n].id], out[n]) for n in out}
 
 
-def test_ragged_on_off_byte_identical(model):
-    """The escape-hatch invariant: LOCALAI_RAGGED_ATTN=off restores the
-    legacy windowed paths byte-identically (greedy AND seeded sampling)
-    even though the two modes dispatch different window shapes. The
-    ragged run also carries the dispatch-shape and row-counter
-    assertions (full-width tables; engine_ragged_rows_total by kind)."""
+def test_ragged_on_off_byte_identical(model, monkeypatch):
+    """The pool's full-width dispatches stream the same bytes as the
+    dense cache (greedy AND seeded sampling) even though the two
+    dispatch different window shapes. The pool run also carries the
+    dispatch-shape and row-counter assertions (full-width tables;
+    engine_ragged_rows_total by kind)."""
     spec, params, tk = model
-    eng_off = _engine(model, ragged=False)
+    monkeypatch.setenv("LOCALAI_PAGED_KV", "off")
+    eng_off = _engine(model)
+    monkeypatch.delenv("LOCALAI_PAGED_KV")
+    assert not eng_off._paged
     try:
+        spy_off = DispatchSpy(eng_off)
         want = _schedule(eng_off, tk)
     finally:
         eng_off.close()
-    eng_on = _engine(model, ragged=True)
+    # the reference really ran windowed: nothing it dispatched carried
+    # page tables
+    assert spy_off.records
+    assert not any("pt_pages" in r for r in spy_off.records)
+    eng_on = _engine(model)
+    assert eng_on._paged
     snap = REGISTRY.snapshot()
     try:
         spy = DispatchSpy(eng_on)
@@ -203,24 +210,6 @@ def test_ragged_on_off_byte_identical(model):
     assert cnt("prefill") >= 1  # the 200-token prompt's chunk rows
 
 
-def test_ragged_off_env_knob(model, monkeypatch):
-    spec, params, tk = model
-    monkeypatch.setenv("LOCALAI_RAGGED_ATTN", "off")
-    eng = LLMEngine(spec, params, tk, n_slots=2, max_seq=512,
-                    cache_dtype=jnp.float32, autostart=False)
-    try:
-        assert eng._paged and not eng._ragged
-    finally:
-        eng.close()
-    monkeypatch.setenv("LOCALAI_RAGGED_ATTN", "on")
-    eng = LLMEngine(spec, params, tk, n_slots=2, max_seq=512,
-                    cache_dtype=jnp.float32, autostart=False)
-    try:
-        assert eng._ragged
-    finally:
-        eng.close()
-
-
 def test_grammar_and_logit_bias_through_ragged_rows(model):
     """Host-interactive slots (grammar constraint, logit-bias ban)
     drain correctly while another stream decodes through ragged
@@ -229,7 +218,7 @@ def test_grammar_and_logit_bias_through_ragged_rows(model):
 
     spec, params, tk = model
     prompt = tk.encode("tool call now")
-    eng = _engine(model, ragged=True)
+    eng = _engine(model)
     try:
         # greedy continuation to ban below — generated on the SAME
         # engine (a second engine would recompile every dispatch fn)
@@ -277,7 +266,7 @@ def test_shared_and_cow_pages_read_through_ragged(model, monkeypatch):
         # A decodes while B admits: B lands on a DIFFERENT slot, so the
         # prefix cache serves it by zero-copy page shares from the
         # active donor (same-slot resident reuse would need no shares)
-        eng = _engine(model, ragged=True, prefix=prefix_enabled)
+        eng = _engine(model, prefix=prefix_enabled)
         try:
             qa = eng.submit(GenRequest(
                 prompt_ids=shared + tail_a, max_tokens=16,

@@ -2,7 +2,8 @@
 
 PR 12's hardest bug class: GSPMD miscompiles the paged
 gather -> forward -> scatter program unless every fallback branch pins
-the gathered window's layout (``engine._pin_win_sharding``) — jit vs
+the gathered window's layout (``engine/cache_route.py``
+``_pin_win_sharding``) — jit vs
 eager silently diverges on the written pages, O(1)-wrong hidden states,
 no error anywhere. This rule makes that class un-reintroducible, plus
 two adjacent layout contracts:

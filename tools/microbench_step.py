@@ -107,11 +107,13 @@ def main():
                                        lambda: sampler_scan(sampling))
 
     # --- forward only (argmax): same window slicing as the real scan
-    from localai_tfp_tpu.engine.engine import _window_cache
+    from localai_tfp_tpu.engine.cache_route import (
+        _restore_window, _window_cache,
+    )
 
     @partial(jax.jit, donate_argnums=(2,))
-    def fwd_scan(params, tokens, cache, pos0):
-        cache, restore = _window_cache(cache, W)
+    def fwd_scan(params, tokens, full, pos0):
+        cache = _window_cache(full, W)
 
         def step(carry, _):
             tokens, pos, cache = carry
@@ -123,7 +125,7 @@ def main():
 
         (t2, p2, cache), seq = lax.scan(
             step, (tokens, pos0, cache), None, length=K)
-        return seq.T, restore(cache)
+        return seq.T, _restore_window(full, cache)
 
     cache = eng.cache
 
