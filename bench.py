@@ -580,7 +580,6 @@ def _mixed_itl_extra(eng, tok, n_tok=96) -> dict:
         if gaps else None,
         "max_gap_ms": round(max(max_gaps), 1) if max_gaps else None,
         "burst_ttft_p50_ms": round(tt[len(tt) // 2], 1) if tt else None,
-        "mixed_dispatch": eng._mixed,
     }
 
 
@@ -889,12 +888,12 @@ def _bench_http(state, model, n_req, n_tok, runs=2, extra=None):
         def _traced(kind, payload):
             t = time.perf_counter()
             sh = (list(payload["toks"].shape)
-                  if kind.startswith("prefill") else payload.get("k"))
+                  if kind == "mixed" else payload.get("k"))
             tlog.append((kind, sh, t))
             return _orig_run(kind, payload)
 
         eng_t._run = _traced
-        _orig_pf = eng_t._complete_prefill_final
+        _orig_pf = eng_t._complete_mixed
         _orig_dk = eng_t._complete_decodek
 
         def _tpf(fl):
@@ -911,7 +910,7 @@ def _bench_http(state, model, n_req, n_tok, runs=2, extra=None):
                          round((time.perf_counter() - t) * 1e3, 1), t))
             return r
 
-        eng_t._complete_prefill_final = _tpf
+        eng_t._complete_mixed = _tpf
         eng_t._complete_decodek = _tdk
 
     def _trace_dump(label, t0, tts):
@@ -1053,7 +1052,7 @@ def _bench_http(state, model, n_req, n_tok, runs=2, extra=None):
         loop.close()
         if trace:
             eng_t._run = _orig_run
-            eng_t._complete_prefill_final = _orig_pf
+            eng_t._complete_mixed = _orig_pf
             eng_t._complete_decodek = _orig_dk
     return out["tok_s"], out["p50"], out["p95"], out["p50_steady"]
 

@@ -64,9 +64,6 @@ _knob("LOCALAI_PREFIX_CACHE_MIN", "8", "int",
 _knob("LOCALAI_PREFIX_CACHE_DEFER_MIN", "64", "int",
       "Minimum shared-prefix length before a same-wave request defers "
       "behind a wave-mate's prefill.")
-_knob("LOCALAI_MIXED_DISPATCH", "on", "flag",
-      "Fused prefill+decode identity-batch dispatch; off restores the "
-      "alternating-phase scheduler.")
 _knob("LOCALAI_REQUEST_DEADLINE_S", "0", "float",
       "Default per-request deadline in seconds (0 = off; a request's "
       "own timeout_s overrides).")

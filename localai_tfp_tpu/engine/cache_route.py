@@ -20,6 +20,7 @@ from __future__ import annotations
 from typing import Any, Iterable
 
 import jax
+import jax.numpy as jnp
 from jax import lax
 
 from ..models.transformer import KVCache, gather_kv_pages, scatter_kv_pages
@@ -133,7 +134,7 @@ class RaggedRoute(_PoolRoute):
 
     def forward_kw(self, tables: tuple, q_lens: jax.Array, *,
                    slot_ids=None, write_mask=None, ring: bool = False,
-                   decode: bool = False) -> dict:
+                   decode: bool = False, row0=None) -> dict:
         phys, wb = tables
         return {"slot_ids": None, "mesh": self.mesh, "page_table": phys,
                 "kv_page": self.page, "q_lens": q_lens, "write_table": wb}
@@ -161,8 +162,11 @@ class GatherRoute(_PoolRoute):
 
     def forward_kw(self, tables: tuple, q_lens: jax.Array, *,
                    slot_ids=None, write_mask=None, ring: bool = False,
-                   decode: bool = False) -> dict:
-        return {"slot_ids": None}
+                   decode: bool = False, row0=None) -> dict:
+        # row0: the group's first row in a view opened for more rows
+        # than the group has (a mixed step's two groups share one view)
+        return {"slot_ids": None if row0 is None else row0 + jnp.arange(
+            q_lens.shape[0], dtype=jnp.int32)}
 
     def close(self, cache: KVCache, view: KVCache, tables: tuple) -> KVCache:
         if self.mesh is not None:
@@ -186,7 +190,7 @@ class DenseRoute:
 
     def forward_kw(self, tables: tuple, q_lens: jax.Array, *,
                    slot_ids=None, write_mask=None, ring: bool = False,
-                   decode: bool = False) -> dict:
+                   decode: bool = False, row0=None) -> dict:
         kw = {"slot_ids": slot_ids, "mesh": self.mesh,
               "ring_prefill": ring}
         if write_mask is not None:

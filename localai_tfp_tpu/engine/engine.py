@@ -29,13 +29,13 @@ TPU-first re-design rather than translation:
   best matching prefix held by ANY slot — free or active — with
   prefix-aware wave admission and LRU x length victim selection
   (see the README "Serving: cross-slot prefix KV cache" section).
-- Prefill and decode are NOT mutually exclusive: when both coexist, a
-  fused token-budgeted "mixed" dispatch advances prefill chunks and
-  decode rows in the SAME identity-batch device step (the ragged-batch
-  discipline of RTP-LLM / Ragged Paged Attention, PAPERS.md), so an
-  admission wave never stalls active streams. Escape hatch:
-  LOCALAI_MIXED_DISPATCH=off restores the legacy alternating scheduler
-  (see the README "Scheduling" section).
+- A prompt is admitted ONE way, whether or not a row decodes: the
+  "mixed" step carries the wave's prompt rows [R, bucket] and one
+  token for every decoding row [n_slots, 1] in the SAME device
+  dispatch (the ragged-batch discipline of RTP-LLM / Ragged Paged
+  Attention, PAPERS.md), sized to what it carries, and hands the
+  rows it admits to the decode carry on the device (see the README
+  "Scheduling" section).
 - Paged engines serve every row kind — decode rows, prefill chunks,
   prefill finals, spec-decode verify rows — through ONE ragged paged
   attention path (ops/ragged_paged_attention.py): page tables ride
@@ -67,9 +67,11 @@ from jax import lax
 
 from ..config import knobs
 from ..models.llm_spec import LLMSpec
-from ..models.transformer import KVCache, Params, forward, forward_hidden
+from ..models.transformer import (
+    KVCache, Params, Rows, forward, forward_hidden, forward_rows,
+)
 from ..ops.sampling import (
-    SamplingState, observe_tokens, sample, seed_windows,
+    SamplingState, sample, seed_windows,
 )
 from ..telemetry import costmodel, hbm_ledger
 from ..telemetry import metrics as tm
@@ -229,7 +231,7 @@ class _Flight:
     buffers, so flight N's arrays are always ready no later than flight
     N+1's."""
 
-    kind: str  # "prefill_final" | "decodek"
+    kind: str  # "mixed" | "decodek"
     arrays: list  # device arrays to harvest (copy_to_host_async started)
     meta: dict
     t_enqueue: float
@@ -295,11 +297,6 @@ class EngineMetrics:
     prefix_reused_tokens: int = 0
     prefill_tokens: int = 0
     prefix_copies: int = 0  # kvcopy dispatches enqueued
-
-
-# dispatch kinds whose programs take the sampler state as an argument
-_SAMPLING_KINDS = frozenset(
-    ("prefill_final", "mixed", "decode1", "decodek", "spec_s"))
 
 
 def _soft_expand(tokens: jax.Array, rows: jax.Array, brow: jax.Array,
@@ -549,6 +546,20 @@ class LLMEngine:
                     v_scale=(_put_arena(dc.v_scale, REPLICATED)
                              if dc.quantized else None),
                 )
+        # what a program returns is COMMITTED to its device, and a
+        # program lowers (and compiles) again for an argument that is
+        # not. So host-made state that stands in for a program's output
+        # is committed as it is put (no copy: it is on that device):
+        # the caches and the sampler state here (the first dispatch
+        # would otherwise load a variant nothing reuses), a dispatch's
+        # decode tokens and positions when it does not chain on the
+        # carry (_dev_exec) — ONE executable per shape, the one warmup
+        # compiled. A meshed engine (None) leaves the placement to
+        # GSPMD as before.
+        self._device = (None if mesh is not None
+                        else next(iter(self.cache.k.devices())))
+        self.cache, self.draft_cache, self.sampling = jax.device_put(
+            (self.cache, self.draft_cache, self.sampling), self._device)
         self.slots = [_Slot(i) for i in range(n_slots)]
         # "" when the Pallas kernel route is taken, else the condition
         # that ruled it out (surfaced by engine_stats)
@@ -587,15 +598,7 @@ class LLMEngine:
         self._prefix_defer_min = max(
             self._prefix_min_copy,
             knobs.int_("LOCALAI_PREFIX_CACHE_DEFER_MIN"))
-        # stall-free mixed prefill+decode dispatch: ONE fused identity-
-        # batch device step advances prefill chunks AND decode rows, so
-        # an admission wave never serializes against active streams
-        # (the legacy scheduler's _prefill_hold/_dispatch_decode sleep
-        # holds). LOCALAI_MIXED_DISPATCH=off restores the legacy
-        # alternating-phase scheduler (the escape hatch). Forced off
-        # when no prefill bucket fits the identity-batch token budget.
-        self._mixed = knobs.flag("LOCALAI_MIXED_DISPATCH")
-        # token budget per fused prefill/mixed dispatch: the XLA
+        # token budget of one admission step's prompt group: the XLA
         # prefill attention materializes [B, H, T, window] f32 scores,
         # so B*bucket must stay bounded or big-bucket groups OOM at
         # compile (measured: a 64x2048 group at 1B/2048-ctx needs
@@ -604,9 +607,6 @@ class LLMEngine:
         # change would dispatch never-warmed shapes.
         self._prefill_group_tokens = max(
             1, knobs.int_("LOCALAI_PREFILL_GROUP_TOKENS"))
-        if not any(b * n_slots <= self._prefill_group_tokens
-                   for b in self.prefill_buckets):
-            self._mixed = False
         self._prefix_index = PrefixIndex()
         # fleet-digest prefix gossip: top-k (hash, tokens) summary,
         # recomputed on the scheduler thread ~1/s (the index has no
@@ -722,41 +722,33 @@ class LLMEngine:
         # traces whatever the variant
         self._decode_k_fns: dict[tuple, Any] = {}  # ("decode", k, W) |
         # ("spec", kd, rounds) | ("draft_prefill",) | ("prefill", W) |
-        # ("prefill_final", W)
-        # device-resident decode state (tokens/pos/active) reused across
-        # dispatches while no slot changes; _epoch invalidates it
-        self._epoch = 0
-        self._dev_epoch = -1
-        self._dev_akey: Any = None  # advancing-set of the saved carry:
-        # with per-slot spec decoding the active set can change between
-        # dispatches WITHOUT an epoch bump, and a stale inactive row in
-        # the carry would stop writing K/V for a now-advancing slot
+        # ("mixed", W)
+        # device-resident decode state: the next token and position of
+        # every row the newest decode-advancing dispatch (a k-step scan
+        # or a mixed step) advanced or admitted; the next one chains on
+        # it without a host round trip. _dev_rows says whose they are
+        # (slot index -> the request the row held then): a dispatch
+        # takes the carry only when it holds every row it advances
+        # (_carry_for), so rows that finish simply drop out of the
+        # active mask and rows a mixed step admits join on the device
         self._dev_tokens: Any = None
         self._dev_pos: Any = None
-        self._dev_active: Any = None
+        self._dev_rows: dict[int, GenRequest] = {}
+        self._dev_window = 0  # context window of that dispatch: rows
+        # parked on the device stay where a chain's windows cover them
         # async dispatch pipeline (see step()): FIFO of in-flight device
         # dispatches awaiting host-side harvest
         self._flights: deque[_Flight] = deque()
         self._pipeline_depth = 2  # decode scans kept in flight
-        self._harvest_last: dict[int, int] = {}  # last token per slot of
-        # the most recently harvested scan (chained flights' prev_last)
+        self._harvest_last: dict[int, int] = {}  # per slot, the token
+        # the newest harvested flight sampled last (what a chained
+        # flight's first step consumed)
         self._last_harvest_t = 0.0
         self._last_arrival = 0.0  # submit time of the newest request —
-        # decode scheduling yields briefly to an admission burst
-        self._hold_start = 0.0  # when the current admission-burst hold
-        # began (0 = not holding); bounds hold duration
+        # decode scheduling keeps scans short around an arrival
         self._step_ms = 0.0  # EWMA of device ms per decode step,
         # measured at scan harvest; _latency_k sizes open-capacity
         # scans from it
-        self._arrivals: deque[float] = deque(maxlen=8)  # lint: guarded-by self._lock  # submit-call
-        # timestamps (one per submit/submit_many); _prefill_hold reads
-        # their spread to tell a still-landing burst from a lone
-        # arrival or a single batched wave
-        self._prefill_hold0 = 0.0  # when the current prefill-formation
-        # hold began (0 = not holding); bounds hold duration.
-        # _hold_start/_prefill_hold0 are LEGACY-ONLY state: the mixed
-        # dispatcher has no hold loops (its decode/prefill fusion is
-        # what the holds were approximating)
         self._last_decode_adv = 0.0  # perf_counter of the last dispatch
         # that advanced >=1 decode row; gaps between consecutive ones
         # while a slot decodes feed engine_decode_stall_seconds
@@ -1197,14 +1189,13 @@ class LLMEngine:
         self._decode_k_fns[key] = dispatch_spec_s
         return dispatch_spec_s
 
-    def _prefill_fn(self, window: int, ring: bool = False):
-        """Jitted prompt-chunk prefill over a ``window``-sliced cache
-        (attention + KV writes scale with the live-context bucket).
-        ``ring=True``: the chunk's attention runs as seq-parallel ring
-        attention over the mesh's "seq" axis (first chunk of a long
-        prompt on a seq-sharded serving mesh — VERDICT r3: long-context
-        must flow through the SERVING path, not just exist as an op)."""
-        key = ("prefill", window, ring)
+    def _prefill_fn(self, window: int):
+        """The FIRST chunk of a long prompt on a seq-sharded serving
+        mesh: the chunk's attention runs as seq-parallel ring attention
+        over the mesh's "seq" axis (VERDICT r3: long-context must flow
+        through the SERVING path, not just exist as an op). K/V writes
+        only; every other chunk of every prompt rides the mixed step."""
+        key = ("prefill", window)
         fn = self._decode_k_fns.get(key)
         if fn is not None:
             return fn
@@ -1213,191 +1204,197 @@ class LLMEngine:
 
         @partial(jax.jit, donate_argnums=(2,))
         def dispatch_prefill(params, tokens, cache, pos0, slot_ids,
-                             *tables, soft=None):
-            # non-final chunk: only the K/V writes matter —
-            # materializing [B, T, V] logits would waste bucket*V f32
-            # of HBM per row. On the pool the view holds only this
-            # dispatch's rows, so the slot mapping lives in the tables
-            # instead of slot_ids
-            if soft is not None:
-                soft = _soft_expand(tokens, *soft)
+                             *tables):
+            # only the K/V writes matter — materializing [B, T, V]
+            # logits would waste bucket*V f32 of HBM per row
             view = route.open(cache, tables, window)
             # chunk dispatches are always full-bucket wide
             qlens = jnp.full(tokens.shape[:1], tokens.shape[1], jnp.int32)
             _, view = forward_hidden(
-                spec, params, tokens, pos0, view, soft=soft,
+                spec, params, tokens, pos0, view,
                 **route.forward_kw(tables, qlens, slot_ids=slot_ids,
-                                   ring=ring))
+                                   ring=True))
             return route.close(cache, view, tables)
 
         self._decode_k_fns[key] = dispatch_prefill
         return dispatch_prefill
 
-    def _prefill_final_fn(self, window: int, identity: bool = False):
-        """Final prompt chunks for a BATCH of slots + penalty-window seed
-        + first-token sample in ONE dispatch — concurrent prompts share
-        the round trip instead of paying one each, and TTFT pays one RTT,
-        not three (SURVEY.md §7 hard part #2). The cache is windowed like
-        the decode path: full-seq prefill attention measured ~7s/wave at
-        1B/2048-seq shapes, windowed ~100ms.
-
-        ``identity``: the batch spans EVERY slot in cache-row order
-        (row b == slot b), so the K/V write takes forward_hidden's
-        per-row DUS hot path instead of the whole-layer gather/scatter a
-        cross-slot mapping forces — measured 234 -> 153 ms on the
-        [64, 4] 8B int8 dispatch (tools/microbench_step.py r5).
-        ``slot_ids`` still arrives for the SAMPLER scatters: non-member
-        rows carry the out-of-bounds sentinel so their reset/seed/sample
-        writes drop.
-
-        tokens [B, bucket]; slot_ids/pos0/n_chunk/tail_lens [B];
-        tails [B, W]."""
-        key = ("prefill_final", window, identity)
-        fn = self._decode_k_fns.get(key)
-        if fn is not None:
-            return fn
-        spec = self.spec
-        n_slots = self.n_slots
-        route = self._route
-
-        @partial(jax.jit, donate_argnums=(2, 4))
-        def dispatch_prefill_final(params, tokens, cache, pos0, sampling,
-                                   slot_ids, n_chunk, tails, tail_lens,
-                                   masks, reset, *tables, soft=None):
-            if soft is not None:
-                soft = _soft_expand(tokens, *soft)
-            view = route.open(cache, tables, window)
-            # n_chunk IS the per-row ragged query length (pad rows carry
-            # 1). On the pool, rows map to slots through the tables, and
-            # parked and pad rows never write back (their pages are
-            # trash). The dense identity batch parks non-members at
-            # pos 0 with a no-op write (write_mask), so the window can
-            # track the MEMBERS' live context instead of max_seq
-            hidden, view = forward_hidden(
-                spec, params, tokens, pos0, view, soft=soft,
-                **route.forward_kw(
-                    tables, n_chunk,
-                    slot_ids=None if identity else slot_ids,
-                    write_mask=(slot_ids < n_slots) if identity
-                    else None))
-            cache = route.close(cache, view, tables)
-            # sampler reset rides THIS dispatch (admission used to pay a
-            # separate reset_batch dispatch before the prefill — one
-            # dispatch off TTFT for singles and waves alike)
-            from ..models.transformer import _lm_head
-            from ..ops.sampling import reset_slots
-
-            sampling = reset_slots(sampling, slot_ids, *reset)
-            # closed-form penalty-window seed (scan-equivalent; the W
-            # sequential scatter steps dominated this dispatch's time)
-            sampling = seed_windows(sampling, slot_ids, tails, tail_lens)
-            # LM head on each row's LAST position only: full [B, T, V]
-            # logits would cost bucket*V f32 per row (a 64x2048 group at
-            # 32k vocab is 16 GB — instant OOM) for values the sampler
-            # never reads
-            last_h = jax.vmap(
-                lambda h, n: lax.dynamic_slice_in_dim(h, n - 1, 1, 0)[0]
-            )(hidden, n_chunk)  # [B, D] at each chunk's true last position
-            logits = _lm_head(spec, params, last_h[:, None, :])[:, 0]
-            with jax.named_scope("sample"):
-                toks, sampling = sample(sampling, slot_ids, logits,
-                                        mask=masks)
-            return toks, cache, sampling
-
-        self._decode_k_fns[key] = dispatch_prefill_final
-        return dispatch_prefill_final
-
     def _mixed_fn(self, window: int):
-        """Fused mixed-step dispatch: ONE identity-batch device function
-        ([n_slots, bucket], row b == slot b) that, per step, runs a
-        token-budgeted prefill chunk for PREFILL rows AND one decode
-        step for DECODE rows — the ragged-batch discipline production
-        engines converged on (RTP-LLM / Ragged Paged Attention,
-        PAPERS.md), expressed as a single static shape so the variant
-        set stays tiny (warmup-precompiled like the identity
-        prefill_final).
+        """The ONE step that admits prompts, sized to what it carries:
+        two row groups through the one cache route in one dispatch.
 
-        Row roles are encoded entirely in the per-row index vectors, so
-        one compiled variant serves every composition:
-        - decode rows: n_chunk=1 (their last sampled token at column
-          0), sample_sids = own idx, reset_sids = OOB sentinel (their
-          live sampler state must NOT be reset);
-        - prefill final-chunk rows: n_chunk = remaining prompt,
-          sample_sids = reset_sids = own idx — sampler reset, penalty-
-          window seed, and first-token sample ride this dispatch
-          exactly as in _prefill_final_fn;
-        - prefill non-final chunk rows: sample_sids = sentinel (K/V
-          writes only; their last-position logits are computed but the
-          sampler scatters drop);
-        - parked rows (FREE): write_mask False — a no-op re-write of
-          what is already at their positions, so resident prefixes
-          survive untouched (no tail clamping needed, unlike the
-          decode scan's inactive rows).
+        - the decode group ``[n_slots, 1]`` — the decodek step's
+          contract: identity rows (row b == slot b), an ``active``
+          mask, sampler state untouched for parked rows;
+        - the prompt group ``[R, bucket]`` with ``slot_ids`` (pad rows
+          carry the out-of-bounds sentinel ``n_slots``): ``final`` rows
+          reset their sampler state, seed the penalty window and sample
+          their first token in THIS dispatch (one round trip off TTFT
+          for singles and waves alike); the other rows are non-final
+          chunks of a prompt longer than the bucket and write K/V only.
 
-        Per-slot sampler math is IDENTICAL to the split paths (same
-        sample()/reset_slots/seed_windows calls, sentinel-id scatter
-        drops instead of active-mask merges), so an identical request
-        schedule produces byte-identical outputs with this path on or
-        off (test_mixed_dispatch.py enforces it)."""
+        No row decoding is the same program with the decode group all
+        inactive. It returns the decode carry (``tok_next``,
+        ``pos_next``) for every row that decodes from here on — the
+        rows that decoded and the finals — so the next decodek scan
+        chains on device state and newly admitted rows join it.
+
+        Per-slot sampler math is that of the decode scan (active-mask
+        merge) and of a solo admission (sentinel-id scatter drops), so a
+        request yields the tokens it yields with the engine to itself
+        (tests/test_mixed_dispatch.py)."""
         key = ("mixed", window)
         fn = self._decode_k_fns.get(key)
         if fn is not None:
             return fn
         spec = self.spec
         route = self._route
+        S = self.n_slots
 
-        @partial(jax.jit, donate_argnums=(2, 4))
-        def dispatch_mixed(params, tokens, cache, pos0, sampling,
-                           write_mask, n_chunk, sample_sids, reset_sids,
-                           tails, tail_lens, masks, reset, *tables,
+        @partial(jax.jit, donate_argnums=(1, 2))
+        def dispatch_mixed(params, cache, sampling, dtoks, dpos, active,
+                           toks, pos0, slot_ids, n_chunk, final, tails,
+                           tail_lens, dmasks, pmasks, reset, *tables,
                            soft=None):
-            if soft is not None:
-                soft = _soft_expand(tokens, *soft)
-            view = route.open(cache, tables, window)
-            # the ragged batch in one forward: decode rows (n_chunk 1),
-            # prefill chunks, finals and parked rows together — the
-            # unified dispatch RTP-LLM/Ragged-Paged-Attention converge
-            # on. On the pool per-row write spans live in the write
-            # table (parked rows and shared prefix pages are
-            # trash-redirected); the dense cache needs the write_mask
-            # no-op rewrite instead
-            hidden, view = forward_hidden(
-                spec, params, tokens, pos0, view, soft=soft,
-                **route.forward_kw(tables, n_chunk,
-                                   write_mask=write_mask))
-            cache = route.close(cache, view, tables)
             from ..models.transformer import _lm_head
             from ..ops.sampling import reset_slots
 
-            # same phase order as _prefill_final_fn: reset -> seed ->
-            # sample. Decode rows carry the sentinel in reset_sids, so
-            # the scatters leave their live sampler state untouched.
-            sampling = reset_slots(sampling, reset_sids, *reset)
-            sampling = seed_windows(sampling, reset_sids, tails,
-                                    tail_lens)
+            def rows(tokens, pos0, row0, q_lens, soft=None, **kw):
+                # page tables ride as ONE [S + R, pages] pair, the
+                # decode group's rows first: this group's share
+                tabs = tuple(t[row0:row0 + tokens.shape[0]]
+                             for t in tables)
+                kw = route.forward_kw(tabs, q_lens, row0=row0, **kw)
+                per = {k: kw.pop(k) for k in tuple(kw)
+                       if k in Rows._fields}
+                return Rows(tokens, pos0, soft=soft, **per), kw
+
+            # decode group. Parked rows: trash write pages on the pool,
+            # a no-op rewrite (write_mask) on the dense cache — their
+            # resident prefixes survive whatever position they carry
+            dgroup, pass_kw = rows(dtoks, dpos, 0,
+                                   jnp.ones((S,), jnp.int32),
+                                   write_mask=active)
+            # prompt group: n_chunk IS the per-row ragged query length
+            # (pad rows carry 1); rows map to slots through slot_ids on
+            # the dense cache and through the tables on the pool
+            if soft is not None:
+                soft = _soft_expand(toks, *soft)
+            pgroup, _ = rows(toks, pos0, S, n_chunk, soft=soft,
+                             slot_ids=slot_ids)
+            # ONE pass: every weight is read once for both groups
+            view = route.open(cache, tables, window)
+            (dhidden, hidden), view = forward_rows(
+                spec, params, (dgroup, pgroup), view, **pass_kw)
+            cache = route.close(cache, view, tables)
+            dsamp, sampling = _sample_masked(
+                sampling, jnp.arange(S, dtype=jnp.int32),
+                _lm_head(spec, params, dhidden)[:, -1], active, dmasks)
+            # reset -> closed-form penalty-window seed -> sample, for
+            # the final rows only (every other row's scatter drops)
+            fin = jnp.where(final, slot_ids, S)
+            sampling = reset_slots(sampling, fin, *reset)
+            sampling = seed_windows(sampling, fin, tails, tail_lens)
+            # LM head on each row's LAST position only: [R, T, V] logits
+            # would cost bucket*V f32 per row for values nobody reads
             last_h = jax.vmap(
                 lambda h, n: lax.dynamic_slice_in_dim(h, n - 1, 1, 0)[0]
-            )(hidden, n_chunk)  # [S, D] at each row's true last position
+            )(hidden, n_chunk)
             logits = _lm_head(spec, params, last_h[:, None, :])[:, 0]
             with jax.named_scope("sample"):
-                toks, sampling = sample(sampling, sample_sids, logits,
-                                        mask=masks)
-            return toks, cache, sampling
+                ptoks, sampling = sample(sampling, fin, logits,
+                                         mask=pmasks)
+            # the carry: decode rows step on, finals join at the end of
+            # their prompt, and every prompt row parks there (a later
+            # scan writes a parked row's K/V at its carried position,
+            # which has to lie beyond what the row holds)
+            tok_next = dsamp.at[fin].set(ptoks, mode="drop")[:, None]
+            pos_next = jnp.where(active, dpos + 1, dpos).at[slot_ids].set(
+                pos0 + n_chunk, mode="drop")
+            return (jnp.concatenate([dsamp, ptoks]), tok_next, pos_next,
+                    cache, sampling)
 
         self._decode_k_fns[key] = dispatch_mixed
         return dispatch_mixed
 
+    # the most prompt tokens a row takes in one step. A step's matmuls
+    # grow with every row they carry (v5e, int8 weights: 9.6 ms of
+    # weight fusions at 16 rows, 17.9 at 144; PERF §6 PR 37) while each
+    # decoding row stands still, so a chunk is no longer than this and
+    # the decoding rows get a token between chunks; a row ladder finer
+    # than this many tokens buys programs to compile, not time
+    _STEP_TOKENS = 128
+
+    def _row_ladder(self, bucket: int) -> tuple[int, ...]:
+        """Row counts a prompt group of this bucket is padded up to:
+        powers of two up to what the group-token budget
+        (LOCALAI_PREFILL_GROUP_TOKENS) and n_slots allow, the small
+        rungs merged into the first one worth a program."""
+        cap = max(1, min(self.n_slots,
+                         self._prefill_group_tokens // bucket))
+        rungs, r = [], 1
+        while r < cap:
+            if r * bucket >= self._STEP_TOKENS:
+                rungs.append(r)
+            r *= 2
+        return (*rungs, cap)
+
     @property
-    def _mixed_buckets(self) -> tuple[int, ...]:
-        """Prefill buckets whose identity-batch dispatch fits the
-        per-dispatch token budget (LOCALAI_PREFILL_GROUP_TOKENS): the
-        mixed step is always [n_slots, bucket], so n_slots*bucket bounds its
-        device work — decode rows are admitted first (they cost one
-        real token each) and the rest of the budget carries prefill
-        chunk tokens, which is what bounds decode ITL under admission
-        pressure."""
-        return tuple(b for b in self.prefill_buckets
-                     if b * self.n_slots <= self._prefill_group_tokens)
+    def _step_buckets(self) -> tuple[int, ...]:
+        """The prefill buckets a step's prompt group takes: up to the
+        first one that holds _STEP_TOKENS."""
+        bs = self.prefill_buckets
+        top = next((i for i, b in enumerate(bs)
+                    if b >= self._STEP_TOKENS), len(bs) - 1)
+        return bs[:top + 1]
+
+    def _mixed_shape(self, rems: list[int],
+                     budget_ms: float = 0.0,
+                     window_of: Any = None) -> tuple[int, int]:
+        """(rows, bucket) of the prompt group for a wave whose rows
+        have ``rems`` prompt tokens left — every row rides every step,
+        whatever its bucket: the smallest step bucket covering the
+        largest remainder, the row count rounded up that bucket's
+        ladder (rows beyond its cap ride the next step). A row longer
+        than the bucket takes a bucket-wide non-final chunk and rides
+        on: how a prompt is chunked hangs on its own length alone,
+        never on what rides beside it.
+
+        Cost scheduling (LOCALAI_COST_SCHED + LOCALAI_ITL_BUDGET_MS,
+        ``budget_ms`` > 0 while rows decode): no bucket over the
+        largest whose PREDICTED device time fits the budget; when none
+        fits, the smallest predicted one (progress beats stalling);
+        buckets with no prediction never constrain."""
+        buckets = self._step_buckets
+        bucket = next((b for b in buckets if b >= max(rems)), buckets[-1])
+
+        def rows_at(b):
+            ladder = self._row_ladder(b)
+            n = min(len(rems), ladder[-1])
+            return next(r for r in ladder if r >= n)
+
+        if budget_ms > 0.0:
+            pred = [(self._costmodel.predict_ms(
+                "mixed", ("mixed", (rows_at(b), b), window_of(b))), b)
+                for b in buckets if b <= bucket]
+            pred = [p for p in pred if p[0] is not None]
+            if pred:
+                fit = [b for ms, b in pred if ms <= budget_ms]
+                bucket = min(bucket, fit[-1] if fit else pred[0][1])
+        return rows_at(bucket), bucket
+
+    def _mixed_variants(self):
+        """Every (rows, bucket, window) a mixed step can be dispatched
+        at — warmup() compiles exactly these."""
+        prev = 0
+        for b in self._step_buckets:
+            # a step picks this bucket only when some row's chunk
+            # exceeds the previous one, so its window covers at least
+            # prev + 2: smaller rungs can never be dispatched
+            for w in self._route.ladder("mixed", prev + 2):
+                for rows in self._row_ladder(b):
+                    yield rows, b, w
+            prev = b
 
     def _itl_budget_ms(self) -> float:
         """The explicit inter-token-latency budget cost scheduling
@@ -1414,47 +1411,19 @@ class LLMEngine:
         return (self._costmodel is not None
                 and knobs.flag("LOCALAI_COST_SCHED"))
 
-    def _mixed_window(self, prefilling: list, decoding: list,
+    def _mixed_window(self, riding: list, decoding: list, ahead: dict,
                       bucket: int) -> int:
-        """Context window the mixed dispatch for this composition and
-        bucket would select — EXACTLY the choice _enqueue_mixed makes
-        (the route's window covering every advancing row), factored
-        out so the cost-packing pass can predict each candidate
-        bucket's true variant before any arrays are built."""
+        """Context window of a mixed step: the route's window covering
+        every advancing row — decode rows at their position on the
+        device (``ahead`` of the host's by the scans in flight), prompt
+        rows to the end of their chunk."""
         need_w = max(
-            [s.n_past + 1 for s in decoding]
+            [s.n_past + ahead.get(s.idx, 0) + 1 for s in decoding]
             + [s.n_past + min(s.n_prompt - s.n_past, bucket)
-               for s in prefilling]) + 1
+               for s in riding]) + 1
         return self._route.window(
             need_w, "mixed",
             (k[1] for k in self._decode_k_fns if k[0] == "mixed"))
-
-    def _cost_bucket(self, prefilling: list, decoding: list,
-                     cover: int, budget_ms: float) -> int:
-        """Predicted-device-time bucket choice for a mixed dispatch:
-        the largest candidate <= ``cover`` (the token-budget pick, so
-        cost packing only ever shrinks within the warmed variant set)
-        whose predicted device time fits ``budget_ms``. When every
-        predicted candidate exceeds the budget the smallest predicted
-        one dispatches anyway — progress beats stalling, and it is the
-        minimum-gap choice available. When NO candidate has a
-        prediction (variant never captured) the token-budget pick
-        stands."""
-        cm = self._costmodel
-        fit = smallest = None
-        for b in self._mixed_buckets:
-            if b > cover:
-                break
-            pred = cm.predict_ms(
-                "mixed", ("mixed", (self.n_slots, b),
-                          self._mixed_window(prefilling, decoding, b)))
-            if pred is None:
-                continue
-            if smallest is None:
-                smallest = b
-            if pred <= budget_ms:
-                fit = b  # ascending scan keeps the largest that fits
-        return fit or smallest or cover
 
     def _draft_prefill_fn(self):
         """Draft-model prefill (the draft cache must mirror the main
@@ -1658,7 +1627,7 @@ class LLMEngine:
         # spec advanced positions the decodek device-resident carry may
         # still hold stale copies of; a stale inactive-row position would
         # write K/V inside the advanced prefix
-        self._epoch += 1
+        self._dev_rows = {}
         dt = time.perf_counter() - t0
         if dt > 0 and emitted_total:
             self._note_tokens_per_second(emitted_total, dt)
@@ -1769,24 +1738,10 @@ class LLMEngine:
         ``sched:enqueue:<kind>`` phase (payload -> device arrays ->
         launch; a no-op off the scheduler thread) and the binding that
         lets a program load name this dispatch's full variant key."""
-        vkey = self._variant_key(kind, payload)
+        vkey = costmodel.variant_key(kind, payload)
         with self._phases.span("sched:enqueue:" + kind, {"key": vkey}), \
                 self._loads.watch(kind, vkey, self._in_warmup):
             return self._dev_exec(kind, payload)
-
-    def _variant_key(self, kind: str, payload: dict) -> tuple:
-        """``costmodel.variant_key`` plus the one selector that lives in
-        engine state, not in the payload: the sampler state as built at
-        construction is UNCOMMITTED, and every program that takes it
-        lowers again once a program's output (committed, like the cache
-        it ran with) has replaced it — so the first sampling dispatch of
-        a process loads a variant nothing reuses (seen on the chip as
-        one key loaded twice; a load's ``arg_sig`` told them apart)."""
-        key = costmodel.variant_key(kind, payload)
-        if kind in _SAMPLING_KINDS and not getattr(
-                self.sampling.rng, "committed", True):
-            key += (("sampling", "fresh"),)
-        return key
 
     def _dev_exec(self, kind: str, p: dict) -> Any:
         """Device-only work for one dispatch record. MUST be fully
@@ -1799,6 +1754,11 @@ class LLMEngine:
             if not self._paged:
                 return ()
             return (jnp.asarray(p["pt"]), jnp.asarray(p["wb"]))
+
+        def carry_in(x):
+            # host-fed decode tokens / positions, committed like the
+            # device carry they stand in for (see __init__)
+            return jax.device_put(x, self._device)
 
         def cap(fn, *args, **kw):
             # for the load watch, by reference: the jitted function
@@ -1817,24 +1777,28 @@ class LLMEngine:
             toks = jnp.asarray(p["toks"])
             pos0 = jnp.asarray(p["pos0"])
             sids = jnp.asarray(p["slot_ids"])
-            soft = self._soft_dense(p.get("soft"), *p["toks"].shape)
-            fn = self._prefill_fn(
-                p.get("window", self.max_seq), p.get("ring", False))
+            fn = self._prefill_fn(p.get("window", self.max_seq))
             tables = tabs()
-            cap(fn, self.params, toks, self.cache, pos0, sids, *tables,
-                soft=soft)
+            cap(fn, self.params, toks, self.cache, pos0, sids, *tables)
             self.cache = fn(self.params, toks, self.cache, pos0, sids,
-                            *tables, soft=soft)
+                            *tables)
             if self.draft is not None:
                 self.draft_cache = self._draft_prefill_fn()(
                     self.draft[1], toks, self.draft_cache, pos0, sids,
                     *tables, q_lens=jnp.full(
                         toks.shape[:1], toks.shape[1], jnp.int32))
             return None
-        if kind == "prefill_final":
+        if kind == "mixed":
+            # a pure device op with a scalar payload (token ids + per-
+            # row index vectors only), so multihost followers replay it
+            # like any other record; "carry": the decode group's
+            # tokens and positions are the device-resident carry of the
+            # dispatch before it, as on the decodek path
+            S = self.n_slots
             toks = jnp.asarray(p["toks"])
             pos0 = jnp.asarray(p["pos0"])
             sids = jnp.asarray(p["slot_ids"])
+            n_chunk = jnp.asarray(p["n_chunk"])
             masks = _unpack_masks(p["masks"])
             soft = self._soft_dense(p.get("soft"), *p["toks"].shape)
             reset = tuple(jnp.asarray(p["reset"][k]) for k in (
@@ -1842,54 +1806,28 @@ class LLMEngine:
                 "repeat_penalty", "freq_penalty", "presence_penalty",
                 "repeat_last_n", "seeds", "has_seed",
                 "typical_p", "mirostat", "mirostat_tau", "mirostat_eta"))
-            fn = self._prefill_final_fn(
-                p.get("window", self.max_seq), p.get("identity", False))
+            if p["carry"] and self._dev_tokens is not None:
+                dtoks, dpos = self._dev_tokens, self._dev_pos
+            else:
+                dtoks, dpos = carry_in(p["dtoks"]), carry_in(p["dpos"])
             tables = tabs()
-            args = [self.params, toks, self.cache, pos0, self.sampling,
-                    sids, jnp.asarray(p["n_chunk"]),
-                    jnp.asarray(p["tails"]), jnp.asarray(p["tail_lens"]),
-                    masks, reset, *tables]
-            cap(fn, *args, soft=soft)
-            toks_out, self.cache, self.sampling = fn(*args, soft=soft)
-            if self.draft is not None:
-                self.draft_cache = self._draft_prefill_fn()(
-                    self.draft[1], toks, self.draft_cache, pos0, sids,
-                    *tables, q_lens=jnp.asarray(p["n_chunk"]))
-            return toks_out
-        if kind == "mixed":
-            # fused mixed prefill+decode step: like prefill_final, a
-            # pure device op with a scalar payload (token ids + per-row
-            # index vectors only), so multihost followers replay it
-            # like any other record
-            toks = jnp.asarray(p["toks"])
-            pos0 = jnp.asarray(p["pos0"])
-            masks = _unpack_masks(p["masks"])
-            soft = self._soft_dense(p.get("soft"), *p["toks"].shape)
-            reset = tuple(jnp.asarray(p["reset"][k]) for k in (
-                "temperature", "top_k", "top_p", "min_p",
-                "repeat_penalty", "freq_penalty", "presence_penalty",
-                "repeat_last_n", "seeds", "has_seed",
-                "typical_p", "mirostat", "mirostat_tau", "mirostat_eta"))
-            tables = tabs()
-            args = [self.params, toks, self.cache, pos0, self.sampling,
-                    jnp.asarray(p["write_mask"]),
-                    jnp.asarray(p["n_chunk"]),
-                    jnp.asarray(p["sample_sids"]),
-                    jnp.asarray(p["reset_sids"]), jnp.asarray(p["tails"]),
-                    jnp.asarray(p["tail_lens"]), masks, reset, *tables]
+            args = [self.params, self.cache, self.sampling, dtoks, dpos,
+                    jnp.asarray(p["active"]), toks, pos0, sids, n_chunk,
+                    jnp.asarray(p["final"]), jnp.asarray(p["tails"]),
+                    jnp.asarray(p["tail_lens"]),
+                    None if masks is None else masks[:S],
+                    None if masks is None else masks[S:], reset, *tables]
             fn = self._mixed_fn(p.get("window", self.max_seq))
             cap(fn, *args, soft=soft)
-            toks_out, self.cache, self.sampling = fn(*args, soft=soft)
+            (toks_out, self._dev_tokens, self._dev_pos, self.cache,
+             self.sampling) = fn(*args, soft=soft)
             if self.draft is not None:
-                # mirror ONLY the prefill rows into the draft cache
-                # (decode rows advance without draft writes, exactly as
-                # on the decodek path): their own write table on the pool
-                dtabs = ((tables[0], jnp.asarray(p["wb_draft"]))
-                         if tables else ())
+                # the prompt rows mirror into the draft cache (decode
+                # rows advance without draft writes, exactly as on the
+                # decodek path): the prompt group's own tables
                 self.draft_cache = self._draft_prefill_fn()(
-                    self.draft[1], toks, self.draft_cache, pos0,
-                    jnp.asarray(p["prefill_sids"]), *dtabs,
-                    q_lens=jnp.asarray(p["n_chunk"]))
+                    self.draft[1], toks, self.draft_cache, pos0, sids,
+                    *(t[S:] for t in tables), q_lens=n_chunk)
             return toks_out
         if kind == "decode1":
             masks = _unpack_masks(p["masks"])
@@ -1903,13 +1841,11 @@ class LLMEngine:
         if kind == "decodek":
             fn = self._decode_k_fn(p["k"], p["window"])
             if p["carry"] and self._dev_tokens is not None:
-                tok_dev, pos_dev, act_dev = (
-                    self._dev_tokens, self._dev_pos, self._dev_active
-                )
+                tok_dev, pos_dev = self._dev_tokens, self._dev_pos
             else:
-                tok_dev = jnp.asarray(p["tokens"])
-                pos_dev = jnp.asarray(p["pos0"])
-                act_dev = jnp.asarray(p["active"])
+                tok_dev = carry_in(p["tokens"])
+                pos_dev = carry_in(p["pos0"])
+            act_dev = jnp.asarray(p["active"])
             extra = tabs()
             cap(fn, self.params, tok_dev, self.cache, pos_dev,
                 self._all_slot_ids, self.sampling, act_dev, *extra)
@@ -1920,9 +1856,7 @@ class LLMEngine:
                     self._all_slot_ids, self.sampling, act_dev, *extra,
                 )
                 batches.append(toks)
-            self._dev_tokens, self._dev_pos, self._dev_active = (
-                tok_dev, pos_dev, act_dev
-            )
+            self._dev_tokens, self._dev_pos = tok_dev, pos_dev
             return batches
         if kind == "spec":
             fn = self._spec_decode_fn(p["kd"], p["rounds"])
@@ -2072,7 +2006,6 @@ class LLMEngine:
             self.latency_target_ms, self.sampling.window,
             self._use_kernel, mesh_desc, jax.default_backend(),
             getattr(dev, "device_kind", ""), jax.__version__,
-            self._mixed,  # the mixed dispatcher adds its own variants
             # the paged pool changes every variant's cache geometry
             self._paged, self._page, self.kv_pages,
         ))
@@ -2177,123 +2110,59 @@ class LLMEngine:
                     cm.capturing = False
 
         W = self.sampling.window
+        S = self.n_slots
         pad_reset = self._reset_columns([], 1)
         # every window below comes from the route's ladder: full-width
         # page tables have NO ladder (one variant per token-budget
         # shape), a dense cache has its power-of-two rungs
         ladder = self._route.ladder
-        for bucket in self.prefill_buckets:
-            id_capable = (bucket * self.n_slots
-                          <= self._prefill_group_tokens)
-            # (B, window, identity) variants matching _enqueue's split:
-            # bursts -> ONE identity shape per live-context window (no
-            # (window, bucket) shape can cold-compile mid-request);
-            # trickles -> the small legacy sizes below the identity
-            # threshold at the pinned max_seq window
-            variants: list[tuple[int, int, bool]] = []
-            if id_capable:
-                # an identity final dispatch's window covers max(pos0)
-                # + bucket + 1, so the rungs below that can never be
-                # dispatched — compiling them was pure dead warmup cost
-                # (at 8B, seconds per variant)
-                variants += [(self.n_slots, w, True)
-                             for w in ladder("prefill_final", bucket + 1)]
-            cap = self._prefill_group_cap(bucket)
-            sizes = {cap}
-            b = 1
-            while b < cap:
-                sizes.add(b)
-                b *= 8
-            legacy_cap = (self._legacy_prefill_max if id_capable
-                          else cap)
-            variants += [(B, self.max_seq, False) for B in sorted(sizes)
-                         if B <= legacy_cap]
-            for B, win, identity in variants:
-                reset = {k: np.repeat(v, B, axis=0)
-                         for k, v in pad_reset.items()}
-                payload = {
-                    "toks": np.zeros((B, bucket), np.int32),
-                    "pos0": np.zeros((B,), np.int32),
-                    "slot_ids": np.full((B,), self.n_slots,
-                                        np.int32),
-                    "n_chunk": np.ones((B,), np.int32),
-                    "tails": np.zeros((B, W), np.int32),
-                    "tail_lens": np.zeros((B,), np.int32),
-                    "masks": None, "reset": reset, "soft": None,
-                    "window": win,
-                    "identity": identity,
-                }
-                if self._paged:
-                    # all-trash tables: garbage reads are masked,
-                    # writebacks drop — engine state stays untouched
-                    wp = win // self._page
-                    payload["pt"] = np.zeros((B, wp), np.int32)
-                    payload["wb"] = np.zeros((B, wp), np.int32)
-                _warm("prefill_final", payload)
-        if self.max_seq > self.prefill_buckets[-1]:
-            # long prompts chunk through the "prefill" fn at live-context
-            # window buckets — compile those too, or the first long
-            # prompt stalls on a mid-request jit. Chunk dispatches are
-            # always full-bucket wide, so their windows start at the
-            # bucket's own window bucket (window >= n_past + bucket).
-            seq_ax = (self.mesh.shape.get("seq", 1)
-                      if self.mesh is not None else 1)
-            rings = {False}
-            if (seq_ax > 1 and not self.spec.sliding_window
-                    and self.prefill_buckets[-1] % seq_ax == 0):
-                rings.add(True)  # the seq-sharded first-chunk variant
+        inactive = {
+            "tokens": np.zeros((S, 1), np.int32),
+            "pos0": np.zeros((S,), np.int32),
+            "active": np.zeros((S,), bool),
+        }
+        # the admission step: exactly the (rows, bucket, window) shapes
+        # _enqueue_mixed can ask for. All-pad prompt rows (sentinel
+        # slot ids) and an all-inactive decode group exercise the
+        # identical jit shapes without touching engine state.
+        for R, bucket, w in self._mixed_variants():
+            payload = {
+                "toks": np.zeros((R, bucket), np.int32),
+                "pos0": np.zeros((R,), np.int32),
+                "slot_ids": np.full((R,), S, np.int32),
+                "n_chunk": np.ones((R,), np.int32),
+                "final": np.zeros((R,), bool),
+                "tails": np.zeros((R, W), np.int32),
+                "tail_lens": np.zeros((R,), np.int32),
+                "masks": None, "soft": None,
+                "reset": {k: np.repeat(v, R, axis=0)
+                          for k, v in pad_reset.items()},
+                "window": w, "carry": False,
+                "dtoks": inactive["tokens"], "dpos": inactive["pos0"],
+                "active": inactive["active"],
+            }
+            if self._paged:
+                # all-trash tables: garbage reads are masked,
+                # writebacks drop — engine state stays untouched
+                wp = w // self._page
+                payload["pt"] = np.zeros((S + R, wp), np.int32)
+                payload["wb"] = np.zeros((S + R, wp), np.int32)
+            _warm("mixed", payload)
+        if self._ring_axis():
+            # a long prompt's first chunk on a seq-sharded mesh rides
+            # ring attention at live-context window buckets — compile
+            # those too, or the first long prompt stalls on a
+            # mid-request jit. Chunk dispatches are always full-bucket
+            # wide, so their windows start at the bucket's own window
+            # bucket (window >= n_past + bucket).
             for w in ladder("prefill", self.prefill_buckets[-1]):
-                for ring in sorted(rings):
-                    payload = {
-                        "toks": np.zeros((1, self.prefill_buckets[-1]),
-                                         np.int32),
-                        "pos0": np.zeros((1,), np.int32),
-                        "slot_ids": np.full((1,), self.n_slots,
-                                            np.int32),
-                        "soft": None, "window": w, "ring": ring,
-                    }
-                    if self._paged:
-                        wp = w // self._page
-                        payload["pt"] = np.zeros((1, wp), np.int32)
-                        payload["wb"] = np.zeros((1, wp), np.int32)
-                    _warm("prefill", payload)
-        if self._mixed:
-            # mixed prefill+decode step variants: one per (bucket that
-            # fits the identity budget, live-context window). All-pad
-            # rows (write_mask False, sentinel sids) exercise the
-            # identical jit shapes without touching engine state.
-            S = self.n_slots
-            prev_bucket = 0
-            for bucket in self._mixed_buckets:
-                reset = {k: np.repeat(v, S, axis=0)
-                         for k, v in pad_reset.items()}
-                # a mixed dispatch only selects this bucket when some
-                # prefill row's remainder EXCEEDS the previous bucket,
-                # so its window covers at least prev_bucket + 2 —
-                # smaller ladder rungs can never be dispatched for this
-                # bucket (dead compile cost pruned)
-                windows = ladder("mixed", prev_bucket + 2)
-                prev_bucket = bucket
-                for w in windows:
-                    payload = {
-                        "toks": np.zeros((S, bucket), np.int32),
-                        "pos0": np.zeros((S,), np.int32),
-                        "n_chunk": np.ones((S,), np.int32),
-                        "write_mask": np.zeros((S,), bool),
-                        "sample_sids": np.full((S,), S, np.int32),
-                        "reset_sids": np.full((S,), S, np.int32),
-                        "tails": np.zeros((S, W), np.int32),
-                        "tail_lens": np.zeros((S,), np.int32),
-                        "masks": None, "reset": reset, "soft": None,
-                        "prefill_sids": np.full((S,), S, np.int32),
-                        "window": w,
-                    }
-                    if self._paged:
-                        wp = w // self._page
-                        payload["pt"] = np.zeros((S, wp), np.int32)
-                        payload["wb"] = np.zeros((S, wp), np.int32)
-                        payload["wb_draft"] = np.zeros((S, wp), np.int32)
-                    _warm("mixed", payload)
+                _warm("prefill", {
+                    "toks": np.zeros((1, self.prefill_buckets[-1]),
+                                     np.int32),
+                    "pos0": np.zeros((1,), np.int32),
+                    "slot_ids": np.full((1,), S, np.int32),
+                    "window": w,
+                })
         if self._prefix_enabled:
             # cross-slot KV copy variants (cheap compiles — pure DUS,
             # no matmuls — but a mid-admission stall is still a stall);
@@ -2304,12 +2173,6 @@ class LLMEngine:
             else:
                 for w in ladder("kvcopy"):
                     _warm("kvcopy", {"src": 0, "dst": 0, "n": w})
-        S = self.n_slots
-        inactive = {
-            "tokens": np.zeros((S, 1), np.int32),
-            "pos0": np.zeros((S,), np.int32),
-            "active": np.zeros((S,), bool),
-        }
         ks = self._warm_ks
         for k in sorted(ks):
             if k > 1:
@@ -2329,7 +2192,7 @@ class LLMEngine:
             payload["pt"] = np.zeros((S, wp), np.int32)
             payload["wb"] = np.zeros((S, wp), np.int32)
         _warm("decode1", payload)
-        self._dev_epoch = -1  # warmup carries are not serving state
+        self._dev_rows = {}  # warmup carries are not serving state
         # block until every warmup compile retires so the first real
         # request measures serving, not the compiler
         jax.block_until_ready(self.cache.k)
@@ -2436,7 +2299,6 @@ class LLMEngine:
                 self._pending.extend(ok)
                 if ok:
                     self._last_arrival = now
-                    self._arrivals.append(self._last_arrival)
                 depth = len(self._pending)
                 self._lock.notify_all()
             for req, out in shed:
@@ -2882,116 +2744,119 @@ class LLMEngine:
         """Enqueue device work for the current slot states. Returns
         whether anything was enqueued.
 
-        Budget-based mixed scheduler (default): whenever prefill AND
-        decode work coexist, ONE fused mixed dispatch advances both —
-        decode rows first (they cost one token each), the remaining
-        token budget filled with prefill chunk tokens — so an
-        admission wave never stalls active streams and decode ITL is
-        bounded by the budget, not by prefill-group round trips. The
-        mixed step needs current host state (decode input tokens,
-        grammar masks), so it waits for in-flight dispatches to
-        harvest; a landing wave's requests keep joining the NEXT mixed
-        dispatch while one is in flight, which preserves the burst-
-        coalescing TTFT wins the legacy sleep-holds bought.
-
-        Single-phase work keeps the specialized paths: pure prefill
-        uses the grouped final/chunk dispatches (without the legacy
-        formation hold), pure decode the pipelined k-step scans.
-        LOCALAI_MIXED_DISPATCH=off restores the legacy alternating
-        scheduler, sleep-holds included."""
-        did = False
+        A prompt is admitted ONE way: the mixed step (_enqueue_mixed)
+        carries the wave's prompt rows and one token for every row that
+        decodes, whether or not any does — so an admission never stalls
+        active streams for more than the step, and the rows it admits
+        join the decode carry on the device. With no prompt waiting the
+        pipelined k-step scans run."""
         prefilling = [s for s in self.slots if s.state is SlotState.PREFILL]
-        decoding = [s for s in self.slots if s.state is SlotState.DECODE]
-        if self._mixed and prefilling and decoding and self._mixed_buckets:
-            if self._flights:
-                return False  # host state is current only once every
-                # in-flight dispatch harvests; _wait_for_event blocks
-                # on the oldest flight's readiness (no sleep-hold)
-            self._enqueue_mixed(prefilling, decoding)
-            return True
         if prefilling:
-            # batch final chunks of the same bucket together (one
-            # dispatch per admission wave); long prompts chunk ahead
-            finals: dict[int, list[_Slot]] = {}
+            ring = self._ring_axis()
             for s in prefilling:
-                rem = s.n_prompt - s.n_past
-                if rem <= self.prefill_buckets[-1]:
-                    finals.setdefault(self._bucket(rem), []).append(s)
-                else:
+                if (ring and s.n_past == 0 and s.request.soft_embeds is None
+                        and s.n_prompt > self.prefill_buckets[-1]):
                     self._prefill_step(s)  # enqueue-only, no result
-                    did = True
-            if finals and not self._mixed and self._prefill_hold():
-                # LEGACY-ONLY formation hold: the mixed dispatcher
-                # coalesces at dispatch granularity instead
-                finals = {}
-                did = True  # keep the loop spinning through the hold
-            for bucket in sorted(finals, key=lambda b: -len(finals[b])):
-                group = finals[bucket]
-                cap = self._prefill_group_cap(bucket)
-                while group:
-                    self._enqueue_prefill_final(group[:cap], bucket)
-                    group = group[cap:]
-                    did = True
-        if decoding:
-            did = self._dispatch_decode(decoding) or did
-        return did
+            # every step of the wave at once, chained on the carry: the
+            # host's own tick (admit, the KV tier) never stands between
+            # two chunks
+            did = False
+            while prefilling and self._enqueue_mixed(prefilling):
+                did = True
+                prefilling = [s for s in self.slots
+                              if s.state is SlotState.PREFILL]
+            return did
+        decoding = self._decode_rows()
+        return bool(decoding) and self._dispatch_decode(decoding)
 
-    def _prefill_hold(self) -> bool:
-        """Delay prefill dispatch while an admission burst is STILL
-        LANDING, so the burst forms one wide group instead of
-        fragmenting. Without a gate, a 64-deep HTTP wave fragments
-        into ~10 ragged serialized groups (p50 first-token past two
-        seconds, measured r5); the r5 harvest-window variant of this
-        gate (gather behind an in-flight flight until ITS harvest) left
-        a premature 2-request group in the air and made the other 62
-        wait out its whole ~230 ms round trip (tools/profile_http.py:
-        big-group prefill at t+118 ms of a burst fully submitted by
-        t+53).
+    def _ring_axis(self) -> int:
+        """The mesh's "seq" axis when a long prompt's first chunk can
+        ride ring attention over it (_prefill_fn), else 0."""
+        seq_ax = (self.mesh.shape.get("seq", 1)
+                  if self.mesh is not None else 1)
+        if (seq_ax > 1 and not self.spec.sliding_window
+                and self.max_seq > self.prefill_buckets[-1]
+                and self.prefill_buckets[-1] % seq_ax == 0):
+            return seq_ax
+        return 0
 
-        "Still landing" is evidence-based: requests queued but not yet
-        admitted, >=2 distinct submit EVENTS with the newest <12 ms
-        old (loop-serialized HTTP arrivals land ~0.6 ms apart and keep
-        refreshing this; a submit_many wave is ONE event however large,
-        so a lone wave dispatches immediately — two separate waves
-        inside 12 ms pay a short bounded hold), or a single <3 ms-old
-        first arrival (grace while its burst-mates are still on the
-        wire). The total hold is bounded so a steady drip can never
-        starve prefill."""
-        now = time.perf_counter()
-        with self._lock:
-            # prefix-deferred requests are waiting ON a forming prefill,
-            # not waiting to JOIN the group being held — they must not
-            # hold their own donor's dispatch hostage
-            pending = any(r.id not in self._deferred
-                          for r, _ in self._pending)
-            recent = [t for t in self._arrivals if now - t < 0.04]
-        if pending and not any(not s.active for s in self.slots):
-            # a queued request with ZERO free slots can never join the
-            # group being held — under sustained saturation the pending
-            # clause would otherwise tax every occupied slot's final
-            # chunk with the full hold for no coalescing gain
-            pending = False
-        landing = pending or (
-            # >=2 DISTINCT submit events in the window: concurrent
-            # arrivals (a submit_many wave is ONE event regardless of
-            # size, so it never trips this — loop-serialized HTTP
-            # arrivals land ~0.6 ms apart and do)
-            len(recent) >= 2 and now - recent[-1] < 0.012
-        ) or (
-            # first-arrival grace: the very first submit of a burst has
-            # no spread evidence yet, and its premature 1-2 row group
-            # cost the other 62 a full extra round trip (profile_http:
-            # p50 292 with the split vs ~255 one-group). A lone steady
-            # arrival pays only these 3 ms on its ~245 ms TTFT.
-            len(recent) == 1 and now - recent[-1] < 0.003)
-        if landing:
-            if self._prefill_hold0 == 0.0:
-                self._prefill_hold0 = now
-            if now - self._prefill_hold0 < 0.06:
-                time.sleep(1e-3)
-                return True
-        self._prefill_hold0 = 0.0
-        return False
+    def _decode_rows(self) -> list[_Slot]:
+        """The rows that decode: DECODE slots, and PENDING_FIRST slots
+        whose first token the device carry holds (sampled by a mixed
+        step still in flight)."""
+        return [s for s in self.slots
+                if s.state is SlotState.DECODE
+                or (s.state is SlotState.PENDING_FIRST
+                    and self._dev_rows.get(s.idx) is s.request)]
+
+    def _ahead(self) -> tuple[dict[int, int], dict[int, int]]:
+        """How far the device is ahead of the host, per slot index:
+        cache positions written and tokens sampled by the dispatches
+        still in flight (a final's first token is sampled, its
+        positions were counted at enqueue)."""
+        pos: dict[int, int] = {}
+        gen: dict[int, int] = {}
+        for fl in self._flights:
+            for s, req, p, g in fl.meta["ahead"]:
+                if s.request is req:
+                    pos[s.idx] = pos.get(s.idx, 0) + p
+                    gen[s.idx] = gen.get(s.idx, 0) + g
+        return pos, gen
+
+    def _carry_for(self, decoding: list[_Slot]) -> Optional[bool]:
+        """Whether a decode-advancing dispatch for these rows takes its
+        tokens and positions from the device carry. True: the carry
+        holds every row's next token (each was advanced, or admitted,
+        by the dispatch that made it). False: the host's are current.
+        None: neither — dispatches are in flight and some row's token
+        is only on the device, or (grammar / logit-bias rows) the
+        host's own state has to catch up first — wait for the harvest."""
+        if not decoding:
+            return False  # parked rows: the host knows where they stand
+        held = self._dev_tokens is not None and all(
+            self._dev_rows.get(s.idx) is s.request for s in decoding)
+        if not self._flights:
+            return held
+        if held and not any(
+                s.request.constraint or s.request.logit_bias
+                for s in decoding):
+            return True
+        return None
+
+    def _decode_inputs(self, advancing: list[_Slot], ahead: dict,
+                       window: int) -> tuple:
+        """The decode group as the host knows it: (tokens [S, 1],
+        pos0 [S], active [S]), advancing rows at their next position
+        and every other row parked where its K/V write does no harm."""
+        S = self.n_slots
+        tokens = np.zeros((S, 1), np.int32)
+        pos0 = np.zeros((S,), np.int32)
+        active = np.zeros((S,), bool)
+        adv = {s.idx for s in advancing}
+        for s in self.slots:
+            if s.idx in adv:
+                tokens[s.idx, 0] = (s.generated[-1] if s.generated
+                                    else s.request.prompt_ids[-1])
+                pos0[s.idx] = s.n_past + ahead.get(s.idx, 0)
+                active[s.idx] = True
+            elif s.active:
+                # spec-advanced, first-token-pending or prefilling
+                # slots ride inactive at their own tail; the window
+                # covers it, so no trimming
+                pos0[s.idx] = s.n_past
+            else:
+                # park inactive rows at their own tail: K/V write lands past
+                # the valid prefix, preserving it for prefix reuse. In the
+                # windowed path, a row whose prefix out-sizes the window
+                # gets clamped: its reusable prefix is truncated to what
+                # the window keeps. Paged rows never write back (their wb
+                # pages are trash), so the resident prefix survives at
+                # full length — only the in-dispatch position is clamped.
+                if s.n_past >= window and not self._paged:
+                    s.n_past = window - 1
+                    s.cache_tokens = s.cache_tokens[: window - 1]
+                pos0[s.idx] = min(s.n_past, window - 1, self.max_seq - 1)
+        return tokens, pos0, active
 
     def _wait_for_event(self) -> None:
         """Nothing to enqueue and nothing ready: block until the oldest
@@ -3041,9 +2906,7 @@ class LLMEngine:
                 self._costmodel.on_harvest(
                     fl.kind, fl.meta.get("cost"), dur, predicted_ms=pred)
             with self._phases.span("sched:emit"):
-                if fl.kind == "prefill_final":
-                    self._complete_prefill_final(fl)
-                elif fl.kind == "mixed":
+                if fl.kind == "mixed":
                     self._complete_mixed(fl)
                 else:
                     self._complete_decodek(fl)
@@ -3212,16 +3075,13 @@ class LLMEngine:
             return True
         return False
 
-    def _reset_columns(self, group: list[_Slot], pad_to: int,
-                       rows: Optional[list[int]] = None) -> dict:
-        """Per-slot sampler-reset columns for a prefill_final group. The
-        reset rides the prefill dispatch (a separate reset_batch dispatch
-        costs one extra dispatch per admission wave, straight on burst
-        TTFT). ``rows`` places each group member at an explicit
-        batch row (the identity dispatch, where row == slot idx); without
-        it members occupy the leading rows. Unoccupied rows pad with
-        zeros; their scatter targets the out-of-bounds sentinel slot, so
-        the writes are dropped."""
+    def _reset_columns(self, group: list[_Slot], pad_to: int) -> dict:
+        """Per-slot sampler-reset columns for a mixed step's prompt
+        group. The reset rides the admission dispatch (a separate
+        reset_batch dispatch costs one extra dispatch per admission
+        wave, straight on burst TTFT). Members occupy the leading rows;
+        the other rows pad with neutral values — their scatter targets
+        the out-of-bounds sentinel slot, so the writes are dropped."""
         W = self.sampling.window
         cols: dict[str, list] = {k: [] for k in (
             "temperature", "top_k", "top_p", "min_p",
@@ -3229,10 +3089,7 @@ class LLMEngine:
             "repeat_last_n", "seeds", "has_seed",
             "typical_p", "mirostat", "mirostat_tau", "mirostat_eta")}
         pad = _PadReq()
-        layout: list[Optional[_Slot]] = [None] * pad_to
-        for i, s in enumerate(group):
-            layout[rows[i] if rows is not None else i] = s
-        for s in layout:
+        for s in group + [None] * (pad_to - len(group)):
             r = s.request if s is not None else pad
             assert r is not None
             cols["temperature"].append(r.temperature)
@@ -3486,7 +3343,6 @@ class LLMEngine:
         slot.cache_loaded = (path, n)
         if self._prefix_enabled:
             self._prefix_index.set_tokens(slot.idx, slot.cache_tokens)
-        self._epoch += 1
         return done("restored")
 
     def _maybe_save_prompt_cache(self, slot: _Slot) -> None:
@@ -3618,8 +3474,6 @@ class LLMEngine:
         slot.constraint_state = (
             req.constraint.initial_state() if req.constraint else None
         )
-        self._epoch += 1  # sampler reset rides the slot's prefill_final
-        # dispatch (_reset_columns), before its first sample
 
     def _bucket(self, n: int) -> int:
         for b in self.prefill_buckets:
@@ -3628,75 +3482,41 @@ class LLMEngine:
         return self.prefill_buckets[-1]
 
     def _prefill_step(self, slot: _Slot) -> None:
-        """Process one prompt chunk for one slot (chunked prefill,
-        ref: grpc-server.cpp:1993-2002 n_batch chunking)."""
+        """The first chunk of a long prompt on a seq-sharded mesh, as
+        ring attention over the "seq" axis (_prefill_fn): the chunk
+        attends only to itself at pos0 == 0, pad included — there is
+        none, the chunk is a whole largest bucket. Enqueue-only; the
+        rest of the prompt rides the mixed step."""
         req = slot.request
         assert req is not None
         t0 = time.perf_counter()
-        remaining = req.prompt_ids[slot.n_past:]
-        chunk = remaining[: self.prefill_buckets[-1]]
-        bucket = self._bucket(len(chunk))
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, : len(chunk)] = chunk
-        # first chunk of a long prompt on a seq-sharded mesh: ring
-        # attention (the chunk attends only to itself at pos0 == 0, pad
-        # included — padded columns sit beyond the valid prefix and get
-        # overwritten, same invariant as the dense path)
-        seq_ax = (self.mesh.shape.get("seq", 1)
-                  if self.mesh is not None else 1)
-        ring = (seq_ax > 1 and slot.n_past == 0
-                and not self.spec.sliding_window
-                and bucket % seq_ax == 0
-                and req.soft_embeds is None)
-        # note: positions beyond len(chunk) write garbage K/V at
-        # [n_past+len(chunk), n_past+bucket) — harmless: they're beyond the
-        # valid prefix and get overwritten when real tokens arrive (causal
-        # mask keeps them invisible to attention reads at these positions).
-        window = self._route.window(slot.n_past + bucket, "prefill")
-        payload = {
-            "toks": toks,
-            "pos0": np.asarray([slot.n_past], np.int32),
+        bucket = self.prefill_buckets[-1]
+        chunk = req.prompt_ids[:bucket]
+        window = self._route.window(bucket, "prefill")
+        self._run("prefill", {
+            "toks": np.asarray([chunk], np.int32),
+            "pos0": np.zeros((1,), np.int32),
             "slot_ids": np.asarray([slot.idx], np.int32),
-            "soft": self._soft_payload([slot], [slot.n_past], bucket),
             "window": window,
-            "ring": ring,
-        }
-        if self._paged:
-            if not self._pool_ensure(slot, slot.n_past + len(chunk)):
-                self._finish(slot, "length")
-                return
-            payload["pt"] = self._phys_rows([slot.idx], window)
-            payload["wb"] = self._wb_rows(
-                [(slot.idx, (slot.n_past, slot.n_past + len(chunk)))],
-                window)
-        self._run("prefill", payload)
+        })
         n = len(chunk)
-        self._note_dispatch_tokens(
-            "prefill", n, bucket, n * slot.n_past + n * (n - 1) // 2)
+        self._note_dispatch_tokens("prefill", n, bucket, n * (n - 1) // 2)
         slot.n_past += n
         slot.cache_tokens.extend(chunk)
         if slot.t_prefill_t0 == 0.0:
             slot.t_prefill_t0 = t0
-        # _run only ENQUEUES: charging its wall time to t_prefill_ms
-        # made chunked prompts report near-zero prompt processing.
-        # Device time is attributed at harvest of the covering flight
-        # (_complete_prefill_final / _complete_mixed); the host-side
-        # enqueue cost is tracked as its own phase component.
+        # _run only ENQUEUES: device time is attributed at harvest of
+        # the covering flight (_complete_mixed); the host-side enqueue
+        # cost is tracked as its own phase component
         slot.t_prefill_enq_ms += (time.perf_counter() - t0) * 1e3
         tm.ENGINE_MIXED_DISPATCH.labels(
             model=self._mlabel, composition="prefill_only").inc()
-        self._note_ragged_rows("prefill", 1)
-
-    @property
-    def _group_cap(self) -> int:
-        return min(64, max(self.n_slots, 1))
 
     @property
     def _half_k(self) -> int:
-        """The half-length scan the steady-state arrival clamp snaps to:
-        the largest power of two <= decode_steps // 2 (floor 4). MUST be
-        in warmup()'s decode ks — a never-warmed k here would cold-jit
-        ~13 s on the latency path the clamp protects."""
+        """A half-length scan in warmup()'s decode ks: the largest power
+        of two <= decode_steps // 2 (floor 4), a rung for _latency_k and
+        the ITL budget to snap to when decode_steps is no power of two."""
         h = max(self.decode_steps // 2, 4)
         while h & (h - 1):
             h &= h - 1
@@ -3746,366 +3566,130 @@ class LLMEngine:
                 return k
         return self.decode_steps
 
-    @property
-    def _legacy_prefill_max(self) -> int:
-        """Identity/legacy prefill split point. warmup() precompiles
-        exactly the legacy shapes below it and _enqueue_prefill_final
-        dispatches identity at or above it — ONE definition, or a
-        trickle group lands on a never-warmed shape and eats a ~13 s
-        mid-request compile."""
-        return min(8, self.n_slots)
-
-    def _prefill_group_cap(self, bucket: int) -> int:
-        return max(1, min(self._group_cap,
-                          self._prefill_group_tokens // max(bucket, 1)))
-
     # lint: region hot_path
-    def _enqueue_prefill_final(self, group: list[_Slot],
-                               bucket: int) -> None:
-        """Enqueue a batch of same-bucket final prompt chunks: one fused
-        dispatch runs the chunks, seeds the penalty windows, and samples
-        each slot's first token — harvested later as a _Flight (the
-        scheduler never blocks on the result). The group is padded UP
-        with sentinel rows pointing at the out-of-bounds slot id
-        ``n_slots``: JAX drops out-of-bounds scatter updates and clamps
-        out-of-bounds gathers, so a pad row is pure discarded compute
-        that never touches engine state. (Rounding DOWN and deferring
-        the remainder turned one ragged 63-request wave into SIX
-        dispatches of six distinct jit shapes; under HTTP arrival
-        raggedness that compile churn collapsed endpoint throughput.)
-        Group sizes come from powers of 8 {1, 8, 64} capped at
-        min(64, n_slots) — a non-member n_slots cap introduces ONE
-        extra variant (ADVICE r3 #3). At 8B-class sizes one compile
-        costs ~13s, so the variant set must stay tiny (Engine.warmup
-        precompiles it) — these sizes cover any admission pattern at
-        <=8x padded compute, and padded rows are bandwidth-free (no new
-        weights are read).
+    def _enqueue_mixed(self, prefilling: list[_Slot]) -> bool:
+        """Enqueue ONE mixed step (_mixed_fn) for the waiting prompts
+        and every row that decodes; False when it has to wait for a
+        harvest first (_carry_for).
 
-        Small buckets instead dispatch IDENTITY full-batch (row b ==
-        slot b, every slot a row): the cross-slot K/V scatter was ~35%
-        of the whole [64, 4] 8B dispatch (microbench r5: 234 -> 153 ms
-        with the per-row-DUS identity path), and one [n_slots, bucket]
-        shape replaces the {1, 8, 64}-row variant zoo. Non-member rows
-        park their K/V write beyond the valid prefix, exactly like
-        decode's inactive rows.
+        The prompt group is sized to what it carries (_mixed_shape):
+        rows padded up the bucket's row ladder with sentinel rows
+        pointing at the out-of-bounds slot id ``n_slots`` — JAX drops
+        out-of-bounds scatter updates and clamps out-of-bounds gathers,
+        so a pad row is pure discarded compute that never touches
+        engine state; rows whose remainder exceeds the bucket take a
+        bucket-wide non-final chunk and continue next dispatch, as do
+        rows beyond the bucket's row cap. Decode rows ride every step,
+        one token each, so their inter-token gap is bounded by one
+        step's device work (cost scheduling bounds it in ms).
 
-        Slot bookkeeping that later dispatches read (n_past,
-        cache_tokens) advances HERE — device execution order equals
-        enqueue order, so the chunk is on device before anything
-        enqueued after it. The first-token emission happens at
-        harvest."""
-        cap = self._prefill_group_cap(bucket)
-        group = group[:cap]
-        if self._paged:
-            # page capacity for each member's full prompt; a member the
-            # pool cannot serve even after reclaim ends here (the paged
-            # counterpart of the dense context wall)
-            kept = []
-            for s in group:
-                if self._pool_ensure(s, s.n_prompt):
-                    kept.append(s)
-                else:
-                    self._finish(s, "length")
-            group = kept
-            if not group:
-                return
-        # identity full-batch pays the whole [n_slots, bucket] forward —
-        # a huge win for burst groups (no cross-slot scatter, one jit
-        # shape) but a ~75 ms steady-state TTFT tax on a LONE arrival,
-        # whose [1, bucket] legacy dispatch reads the same weights with
-        # a fraction of the attention/sampler traffic. Split by group
-        # size at the largest warmed legacy shape: trickles stay small,
-        # a group reaching it is a genuine burst and goes identity.
-        identity = (bucket * self.n_slots <= self._prefill_group_tokens
-                    and len(group) >= self._legacy_prefill_max)
-        if identity:
-            B = self.n_slots
-            rows = [s.idx for s in group]
-        else:
-            B = 1
-            while B < len(group):
-                B *= 8
-            B = min(B, cap)
-            rows = list(range(len(group)))
-        t0 = time.perf_counter()
-        W = self.sampling.window
-        toks = np.zeros((B, bucket), np.int32)
-        pos0 = np.zeros((B,), np.int32)
-        slot_ids = np.full((B,), self.n_slots, np.int32)  # OOB sentinel
-        n_chunk = np.ones((B,), np.int32)
-        tails = np.zeros((B, W), np.int32)
-        tail_lens = np.zeros((B,), np.int32)
-        # identity non-member rows stay at pos0 == 0 with a no-op write
-        # (write_mask False re-writes what is already there), so their
-        # prefixes survive untouched and the window below is free to
-        # follow the members' live context
-        for r, s in zip(rows, group):
-            req = s.request
-            chunk = req.prompt_ids[s.n_past:]
-            toks[r, : len(chunk)] = chunk
-            pos0[r] = s.n_past
-            slot_ids[r] = s.idx
-            n_chunk[r] = len(chunk)
-            tail = req.prompt_ids[-W:]
-            tails[r, : len(tail)] = tail
-            tail_lens[r] = len(tail)
-        masks = self._constraint_mask_rows(group)
-        if masks is not None:
-            full = np.ones((B, masks.shape[1]), bool)
-            for r, m in zip(rows, masks):
-                full[r] = m
-            masks = full
-        window = self.max_seq
-        if identity:
-            # a dense window follows the MEMBERS' live context (parked
-            # rows are no-op writes at pos 0, so they place no demand
-            # on it): 1024 -> 256 on a fresh wave cuts the dispatch's
-            # attention traffic 4x
-            window = self._route.window(
-                max(int(pos0[r]) for r in rows) + bucket + 1,
-                "prefill_final",
-                (k[1] for k in self._decode_k_fns
-                 if k[0] == "prefill_final" and k[2]))
-        payload = {
-            "toks": toks, "pos0": pos0, "slot_ids": slot_ids,
-            "n_chunk": n_chunk, "tails": tails, "tail_lens": tail_lens,
-            "masks": masks,
-            "reset": self._reset_columns(group, B, rows),
-            "soft": self._soft_payload(group, pos0, bucket, rows),
-            "window": window,
-            "identity": identity,
-        }
-        if self._paged:
-            # batch row -> slot mapping: identity rows ARE slot indices;
-            # legacy rows are the leading group members, pads get trash
-            row_slots: list = ([i for i in range(B)] if identity
-                               else [None] * B)
-            spans: list = [(si, None) for si in row_slots]
-            for r, s in zip(rows, group):
-                row_slots[r] = s.idx
-                spans[r] = (s.idx, (int(pos0[r]),
-                                    int(pos0[r]) + int(n_chunk[r])))
-            payload["pt"] = self._phys_rows(row_slots, window)
-            payload["wb"] = self._wb_rows(spans, window)
-        toks_out = self._run("prefill_final", payload)
-        toks_out.copy_to_host_async()
-        t_disp = time.perf_counter()
-        enq_ms = (t_disp - t0) * 1e3
-        real = context = 0
-        for s in group:
-            req = s.request
-            chunk_len = len(req.prompt_ids) - s.n_past
-            real += chunk_len
-            context += (chunk_len * s.n_past
-                        + chunk_len * (chunk_len - 1) // 2)
-            s.cache_tokens.extend(req.prompt_ids[s.n_past:])
-            s.n_past += chunk_len
-            s.state = SlotState.PENDING_FIRST
-            if s.t_prefill_t0 == 0.0:
-                s.t_prefill_t0 = t0
-            s.t_prefill_enq_ms += enq_ms
-            TRACER.event(req.id, "prefill_dispatch", t=t_disp)
-        tm.ENGINE_MIXED_DISPATCH.labels(
-            model=self._mlabel, composition="prefill_only").inc()
-        self._note_ragged_rows("final", len(group))
-        self._note_dispatch_tokens("prefill_final", real, B * bucket,
-                                   context)
-        ckey = costmodel.dispatch_key("prefill_final", payload)
-        self._flights.append(_Flight(
-            kind="prefill_final", arrays=[toks_out],
-            meta={"pairs": [(s, s.request) for s in group], "rows": rows,
-                  # cost-model variant key: accounted at harvest, where
-                  # the flight's span is known
-                  "cost": ckey,
-                  "pred_ms": (self._costmodel.predict_ms(
-                      "prefill_final", ckey)
-                      if self._costmodel is not None else None),
-                  # timeline args for the flight recorder's harvest span
-                  "rec": {"rows": len(group), "bucket": bucket,
-                          "window": window}},
-            t_enqueue=t0,
-        ))
-
-    def _complete_prefill_final(self, fl: _Flight) -> None:
-        """Harvest a prefill flight: emit each slot's first token and
-        move it into the decode set."""
-        # lint: ignore[hot-path-sync] _harvest only hands over flights whose ready() is true — this host read is transfer-complete, not a sync
-        toks_host = np.asarray(fl.arrays[0])
-        now = time.perf_counter()
-        rows = fl.meta.get("rows") or range(len(fl.meta["pairs"]))
-        prompt_toks = first_toks = 0
-        for r, (s, req) in zip(rows, fl.meta["pairs"]):
-            if s.request is not req:  # cancelled mid-flight
-                continue
-            # device+queue prefill time from the slot's FIRST prefill
-            # dispatch (chunk dispatches have no flight of their own;
-            # device execution is serialized, so this flight's harvest
-            # bounds when every earlier chunk retired)
-            s.t_prefill_ms += (now - (s.t_prefill_t0
-                                      or fl.t_enqueue)) * 1e3
-            self.metrics.prompt_tokens_processed += s.n_prompt
-            # the Prometheus counter reports tokens that actually went
-            # THROUGH prefill — reused (resident/copied/restored)
-            # tokens are counted in engine_prefix_reused_tokens_total,
-            # so reused + prefilled == submitted prompt tokens
-            actual = max(0, s.n_prompt - s.n_reused)
-            self.metrics.prefill_tokens += actual
-            prompt_toks += actual
-            first_toks += 1
-            s.state = SlotState.DECODE
-            s.t_last = now
-            self._epoch += 1
-            self._emit_token(s, int(toks_host[r]))
-        if prompt_toks:
-            tm.ENGINE_PROMPT_TOKENS.labels(model=self._mlabel).inc(
-                prompt_toks)
-        if first_toks:
-            tm.ENGINE_GENERATED_TOKENS.labels(model=self._mlabel).inc(
-                first_toks)
-
-    def _enqueue_mixed(self, prefilling: list[_Slot],
-                       decoding: list[_Slot]) -> None:
-        """Enqueue ONE fused mixed prefill+decode step (_mixed_fn).
-
-        Budget policy: the dispatch is always [n_slots, bucket], so the
-        per-dispatch token budget (LOCALAI_PREFILL_GROUP_TOKENS) bounds
-        bucket to _mixed_buckets. Decode rows ride every dispatch (one
-        token each — decode priority, so their inter-token gap is
-        bounded by one budget's worth of device work); the bucket then
-        grows just enough to cover the largest remaining prompt, capped
-        by the budget — rows whose remainder exceeds it take a
-        bucket-wide non-final chunk and continue next dispatch.
-
-        Cost scheduling (LOCALAI_COST_SCHED + LOCALAI_ITL_BUDGET_MS):
-        when decode rows are riding and an explicit ITL budget is set,
-        the bucket is instead the LARGEST candidate whose PREDICTED
-        device time (costmodel.predict_ms over the exact variant this
-        composition would dispatch) fits the budget — the token budget
-        stays as the cap (candidates never exceed the warmed variant
-        set) and as the fallback when no candidate has a prediction.
-        Under a long-prompt flood this shrinks the chunk below the
-        token-budget choice, bounding decode ITL in milliseconds
-        instead of tokens.
-
-        Prefill bookkeeping (n_past/cache_tokens) advances HERE, like
-        _enqueue_prefill_final: device execution order equals enqueue
-        order, so anything enqueued later (kvcopy from a same-wave
-        prefix sharer included) sees this chunk committed. Decode rows
-        advance at harvest (_complete_mixed), exactly like the decode
-        scan path."""
+        Prefill bookkeeping (n_past/cache_tokens) advances HERE: device
+        execution order equals enqueue order, so anything enqueued
+        later (kvcopy from a same-wave prefix sharer included) sees
+        this chunk committed. Decode rows advance at harvest
+        (_complete_mixed), exactly like the decode scan path; the rows
+        whose final chunk rode join the device carry at once, so the
+        next scan is enqueued behind this step without waiting for it."""
         t0 = time.perf_counter()
         S = self.n_slots
         W = self.sampling.window
-        buckets = self._mixed_buckets
+        decoding = self._decode_rows()
+        carry = self._carry_for(decoding)
+        if carry is None:
+            return False
+        ahead, _ = self._ahead()
+        chunk = self._step_buckets[-1]
         if self._paged:
             # page capacity up front: decode rows append one token,
-            # prefill rows at most one bucket-wide chunk
+            # prompt rows at most one bucket-wide chunk
             for s in list(decoding):
-                if not self._pool_ensure(s, s.n_past + 1):
+                if not self._pool_ensure(
+                        s, s.n_past + ahead.get(s.idx, 0) + 1):
                     self._finish(s, "length")
                     decoding.remove(s)
             for s in list(prefilling):
                 rem = s.n_prompt - s.n_past
                 if not self._pool_ensure(
-                        s, s.n_past + min(rem, buckets[-1])):
+                        s, s.n_past + min(rem, chunk)):
                     self._finish(s, "length")
                     prefilling.remove(s)
-            if not prefilling or not decoding:
-                return  # composition changed: next iteration re-plans
-        need = min(max(s.n_prompt - s.n_past for s in prefilling),
-                   buckets[-1])
-        bucket = next(b for b in buckets if b >= need)
-        budget_ms = self._itl_budget_ms()
-        if budget_ms > 0.0 and decoding:
-            # ms-budget packing: decode rows ride regardless (their
-            # cost is inside every candidate's prediction); the bucket
-            # shrinks until the whole composition's predicted device
-            # time fits the ITL budget
-            bucket = self._cost_bucket(prefilling, decoding, bucket,
-                                       budget_ms)
-        toks = np.zeros((S, bucket), np.int32)
-        pos0 = np.zeros((S,), np.int32)
-        n_chunk = np.ones((S,), np.int32)
-        write_mask = np.zeros((S,), bool)
-        sample_sids = np.full((S,), S, np.int32)  # OOB sentinel
-        reset_sids = np.full((S,), S, np.int32)
-        prefill_sids = np.full((S,), S, np.int32)
-        tails = np.zeros((S, W), np.int32)
-        tail_lens = np.zeros((S,), np.int32)
-        rows: list[tuple] = []  # (role, slot, request, aux)
+            if not prefilling:
+                return True  # composition changed: next pass re-plans
+        R, bucket = self._mixed_shape(
+            [s.n_prompt - s.n_past for s in prefilling],
+            self._itl_budget_ms() if decoding else 0.0,
+            lambda b: self._mixed_window(prefilling, decoding, ahead, b))
+        riding = prefilling[:R]
+        window = self._mixed_window(riding, decoding, ahead, bucket)
+        if carry:
+            # rows parked on the device keep the position the chain's
+            # earlier windows covered: a window never shrinks under them
+            window = max(window, self._dev_window)
+        toks = np.zeros((R, bucket), np.int32)
+        pos0 = np.zeros((R,), np.int32)
+        slot_ids = np.full((R,), S, np.int32)  # OOB sentinel
+        n_chunk = np.ones((R,), np.int32)
+        final = np.zeros((R,), bool)
+        tails = np.zeros((R, W), np.int32)
+        tail_lens = np.zeros((R,), np.int32)
         finals: list[_Slot] = []
         chunk_tokens = 0
-        for s in decoding:
-            last_tok = (s.generated[-1] if s.generated
-                        else s.request.prompt_ids[-1])
-            toks[s.idx, 0] = last_tok
-            pos0[s.idx] = s.n_past
-            write_mask[s.idx] = True
-            sample_sids[s.idx] = s.idx
-            rows.append(("decode", s, s.request, last_tok))
-        for s in prefilling:
+        for r, s in enumerate(riding):
             req = s.request
             rem = s.n_prompt - s.n_past
             chunk = req.prompt_ids[s.n_past: s.n_past + min(rem, bucket)]
-            toks[s.idx, : len(chunk)] = chunk
-            pos0[s.idx] = s.n_past
-            n_chunk[s.idx] = len(chunk)
-            write_mask[s.idx] = True
-            prefill_sids[s.idx] = s.idx
+            toks[r, : len(chunk)] = chunk
+            pos0[r] = s.n_past
+            slot_ids[r] = s.idx
+            n_chunk[r] = len(chunk)
             chunk_tokens += len(chunk)
             if rem <= bucket:  # final chunk: reset+seed+sample ride
                 finals.append(s)
-                sample_sids[s.idx] = s.idx
-                reset_sids[s.idx] = s.idx
+                final[r] = True
                 tail = req.prompt_ids[-W:]
-                tails[s.idx, : len(tail)] = tail
-                tail_lens[s.idx] = len(tail)
-                rows.append(("final", s, req, None))
-            else:
-                rows.append(("chunk", s, req, None))
-        # parked (FREE) rows keep the zero defaults: pos0 == 0 with
-        # write_mask False is a pure no-op — their resident prefixes
-        # survive untouched (no tail clamping, unlike the decode scan)
-        masks = self._constraint_mask_rows(self.slots)
-        # ragged pins full width (the kernel's page walk — or the
-        # fallback's full-width gather — is ragged already); otherwise
-        # the smallest compiled window covering every advancing row.
-        # Shared with the cost-packing candidate scan above, so the
-        # predicted variant is the dispatched variant.
-        window = self._mixed_window(prefilling, decoding, bucket)
+                tails[r, : len(tail)] = tail
+                tail_lens[r] = len(tail)
+        dmask = self._constraint_mask_rows(decoding)
+        pmask = self._constraint_mask_rows(riding)
+        masks = None
+        if dmask is not None or pmask is not None:
+            masks = np.ones((S + R, self.spec.vocab_size), bool)
+            if dmask is not None:
+                masks[[s.idx for s in decoding]] = dmask
+            if pmask is not None:
+                masks[S: S + len(riding)] = pmask
+        dtoks, dpos, active = self._decode_inputs(decoding, ahead, window)
         payload = {
-            "toks": toks, "pos0": pos0, "n_chunk": n_chunk,
-            "write_mask": write_mask, "sample_sids": sample_sids,
-            "reset_sids": reset_sids, "tails": tails,
+            "toks": toks, "pos0": pos0, "slot_ids": slot_ids,
+            "n_chunk": n_chunk, "final": final, "tails": tails,
             "tail_lens": tail_lens, "masks": masks,
-            "reset": self._reset_columns(finals, S,
-                                         [s.idx for s in finals]),
-            "soft": self._soft_payload(prefilling, pos0, bucket,
-                                       [s.idx for s in prefilling]),
-            "prefill_sids": prefill_sids,
-            "window": window,
+            # (a non-final row's reset drops with its sentinel id)
+            "reset": self._reset_columns(riding, R),
+            "soft": self._soft_payload(riding, pos0, bucket),
+            "window": window, "carry": carry,
+            "dtoks": dtoks, "dpos": dpos, "active": active,
         }
         if self._paged:
             spans: list = [(i, None) for i in range(S)]
-            dspans: list = [(i, None) for i in range(S)]
             for s in decoding:
-                spans[s.idx] = (s.idx, (s.n_past, s.n_past + 1))
-            for s in prefilling:
-                span = (s.n_past, s.n_past + int(n_chunk[s.idx]))
-                spans[s.idx] = (s.idx, span)
-                dspans[s.idx] = (s.idx, span)  # draft mirrors prefill
-                # rows only — decode rows keep trash in the draft wb
-            payload["pt"] = self._phys_rows(list(range(S)), window)
+                at = int(dpos[s.idx])
+                spans[s.idx] = (s.idx, (at, at + 1))
+            spans += [(s.idx, (s.n_past, s.n_past + int(n_chunk[r])))
+                      for r, s in enumerate(riding)]
+            spans += [(None, None)] * (R - len(riding))
+            payload["pt"] = self._phys_rows([si for si, _ in spans],
+                                            window)
             payload["wb"] = self._wb_rows(spans, window)
-            payload["wb_draft"] = self._wb_rows(dspans, window)
         toks_out = self._run("mixed", payload)
         toks_out.copy_to_host_async()
         t_disp = time.perf_counter()
         enq_ms = (t_disp - t0) * 1e3
+        self._dev_rows = {s.idx: s.request for s in decoding + finals}
+        self._dev_window = window
         # a decode row reads its whole cache; a chunk its causal sum
-        context = sum(s.n_past for s in decoding)
-        for s in prefilling:
-            chunk_len = min(s.n_prompt - s.n_past, bucket)
+        context = sum(int(dpos[s.idx]) for s in decoding)
+        for r, s in enumerate(riding):
+            chunk_len = int(n_chunk[r])
             context += (chunk_len * s.n_past
                         + chunk_len * (chunk_len - 1) // 2)
             s.cache_tokens.extend(
@@ -4122,25 +3706,36 @@ class LLMEngine:
             composition="mixed" if decoding else "prefill_only").inc()
         self._note_ragged_rows("decode", len(decoding))
         self._note_ragged_rows("final", len(finals))
-        self._note_ragged_rows("prefill", len(prefilling) - len(finals))
+        self._note_ragged_rows("prefill", len(riding) - len(finals))
+        # a decode row is one real token; the shape is both groups'
         self._note_dispatch_tokens("mixed", len(decoding) + chunk_tokens,
-                                   S * bucket, context)
+                                   S + R * bucket, context)
         if decoding:
             self._note_decode_advance(t_disp)
         ckey = costmodel.dispatch_key("mixed", payload)
         self._flights.append(_Flight(
             kind="mixed", arrays=[toks_out],
-            meta={"rows": rows, "chunk_tokens": chunk_tokens,
-                  "cost": ckey,
-                  "pred_ms": (self._costmodel.predict_ms("mixed", ckey)
-                              if self._costmodel is not None else None),
-                  # timeline args for the flight recorder's harvest span
-                  "rec": {"decode": len(decoding),
-                          "prefill": len(prefilling) - len(finals),
-                          "finals": len(finals),
-                          "chunk_tokens": chunk_tokens}},
+            meta={
+                # a decode row's consumed token: the host's, or (carry)
+                # whatever the flight before this one sampled last
+                "decode": [(s, s.request,
+                            None if carry else int(dtoks[s.idx, 0]))
+                           for s in decoding],
+                "prompt": [(s, s.request, bool(final[r]))
+                           for r, s in enumerate(riding)],
+                "ahead": ([(s, s.request, 1, 1) for s in decoding]
+                          + [(s, s.request, 0, 1) for s in finals]),
+                "cost": ckey,
+                "pred_ms": (self._costmodel.predict_ms("mixed", ckey)
+                            if self._costmodel is not None else None),
+                # timeline args for the flight recorder's harvest span
+                "rec": {"decode": len(decoding),
+                        "prefill": len(riding) - len(finals),
+                        "finals": len(finals),
+                        "chunk_tokens": chunk_tokens}},
             t_enqueue=t0,
         ))
+        return True
 
     def _complete_mixed(self, fl: _Flight) -> None:
         """Harvest a mixed flight: decode rows emit their sampled token
@@ -4149,41 +3744,52 @@ class LLMEngine:
         set, non-final chunk rows only collect prefill-time
         attribution."""
         # lint: ignore[hot-path-sync] flight ready() verified by _harvest; the transfer already landed
-        toks_host = np.asarray(fl.arrays[0])  # [S]
+        toks_host = np.asarray(fl.arrays[0])  # [S + R]
+        S = self.n_slots
         now = time.perf_counter()
         dt_ms = (now - fl.t_enqueue) * 1e3
         # exemplar BEFORE the emit loop: a finishing slot deactivates
         # below, and its trace id is exactly the one worth linking
         exemplar = self._active_exemplar()
         decode_emitted = first_toks = prompt_toks = 0
-        for role, s, req, aux in fl.meta["rows"]:
+        last = self._harvest_last
+        for s, req, consumed in fl.meta["decode"]:
+            tok = int(toks_host[s.idx])
+            if consumed is None:
+                consumed = last[s.idx]
+            last[s.idx] = tok
+            if s.request is not req or s.state is not SlotState.DECODE:
+                continue  # finished/cancelled in an earlier flight
+            s.cache_tokens.append(consumed)
+            s.n_past += 1
+            s.t_decode_ms += dt_ms
+            decode_emitted += 1
+            self._emit_token(s, tok, defer=True)
+            if s.state is SlotState.DECODE:
+                self._flush_emit(s)
+        for r, (s, req, is_final) in enumerate(fl.meta["prompt"]):
+            # a non-final chunk's bookkeeping advanced at enqueue; its
+            # device time lands at the covering final's harvest
+            # (t_prefill_t0)
+            if not is_final:
+                continue
+            last[s.idx] = int(toks_host[S + r])
             if s.request is not req:  # cancelled mid-flight
                 continue
-            if role == "decode":
-                if s.state is not SlotState.DECODE:
-                    continue
-                s.cache_tokens.append(aux)
-                s.n_past += 1
-                s.t_decode_ms += dt_ms
-                decode_emitted += 1
-                self._emit_token(s, int(toks_host[s.idx]), defer=True)
-                if s.state is SlotState.DECODE:
-                    self._flush_emit(s)
-            elif role == "final":
-                s.t_prefill_ms += (now - (s.t_prefill_t0
-                                          or fl.t_enqueue)) * 1e3
-                self.metrics.prompt_tokens_processed += s.n_prompt
-                actual = max(0, s.n_prompt - s.n_reused)
-                self.metrics.prefill_tokens += actual
-                prompt_toks += actual
-                first_toks += 1
-                s.state = SlotState.DECODE
-                s.t_last = now
-                self._emit_token(s, int(toks_host[s.idx]))
-            # role == "chunk": bookkeeping advanced at enqueue; device
-            # time lands at the covering final's harvest (t_prefill_t0)
-        # decode rows advanced: any saved decodek device carry is stale
-        self._epoch += 1
+            s.t_prefill_ms += (now - (s.t_prefill_t0
+                                      or fl.t_enqueue)) * 1e3
+            self.metrics.prompt_tokens_processed += s.n_prompt
+            # the Prometheus counter reports tokens that actually went
+            # THROUGH prefill — reused (resident/copied/restored)
+            # tokens are counted in engine_prefix_reused_tokens_total,
+            # so reused + prefilled == submitted prompt tokens
+            actual = max(0, s.n_prompt - s.n_reused)
+            self.metrics.prefill_tokens += actual
+            prompt_toks += actual
+            first_toks += 1
+            s.state = SlotState.DECODE
+            s.t_last = now
+            self._emit_token(s, last[s.idx])
         m = self._mlabel
         if prompt_toks:
             tm.ENGINE_PROMPT_TOKENS.labels(model=m).inc(prompt_toks)
@@ -4259,16 +3865,14 @@ class LLMEngine:
             else (1.0 - self._TPS_ALPHA) * cur + self._TPS_ALPHA * inst)
 
     def _soft_payload(self, group: list[_Slot], pos0: Any,
-                      bucket: int,
-                      rows: Optional[list[int]] = None) -> Optional[list]:
-        """Compact multimodal rows for a prefill dispatch: [(batch row,
-        chunk-relative positions, embeds [k, D])] for every slot whose
-        soft tokens fall inside this chunk; None when text-only (the
-        common case pays nothing). ``rows`` maps group member i to its
-        batch row (identity dispatches); default: leading rows."""
+                      bucket: int) -> Optional[list]:
+        """Compact multimodal rows for a mixed step's prompt group
+        (member i is row i): [(batch row, chunk-relative positions,
+        embeds [k, D])] for every slot whose soft tokens fall inside
+        this chunk; None when text-only (the common case pays
+        nothing)."""
         out = []
-        for i, s in enumerate(group):
-            r = rows[i] if rows is not None else i
+        for r, s in enumerate(group):
             req = s.request
             if req is None or req.soft_embeds is None:
                 continue
@@ -4342,22 +3946,27 @@ class LLMEngine:
         return np.stack(rows)
 
     def _multi_step_k(
-        self, decoding: list[_Slot]
+        self, decoding: list[_Slot], ahead: dict, sampled: dict,
     ) -> tuple[int, int, int]:
         """(k, room, need): on-device step count — no grammar/logit-bias
         slot (those need a host-side mask per token), no slot may cross
         the end of its context row mid-scan, and k is capped by ``need``
         (the largest remaining token budget). ``room`` is the shared
-        context headroom that also gates pipeline depth."""
-        room = min(self.max_seq - 1 - s.n_past for s in decoding)
-        need = 1
+        context headroom that also gates pipeline depth. Both count
+        what the dispatches in flight already cover (``ahead``
+        positions, ``sampled`` tokens per slot)."""
+        room = min(self.max_seq - 1 - s.n_past - ahead.get(s.idx, 0)
+                   for s in decoding)
+        need = 0
+        host = False
         for s in decoding:
             req = s.request
             if req is not None and (req.constraint or req.logit_bias):
-                return 1, room, need
+                host = True
             if req is not None:
-                need = max(need, req.max_tokens - len(s.generated))
-        if self.decode_steps <= 1:
+                need = max(need, req.max_tokens - len(s.generated)
+                           - sampled.get(s.idx, 0))
+        if host or self.decode_steps <= 1:
             return 1, room, need
         # cap by the largest remaining budget: a short request must not
         # pay (or make the NEXT request wait behind) a full-length scan
@@ -4388,7 +3997,9 @@ class LLMEngine:
         normal path enqueues one k-step scan as a _Flight and keeps up
         to ``_pipeline_depth`` scans in flight, chained on the
         device-resident carry — the device never idles waiting for a
-        download, and downloads never serialize behind each other.
+        download, and downloads never serialize behind each other. A
+        scan chains behind a mixed step the same way: the rows that
+        step admitted are in the carry it returned.
         Tokens generated past a slot's EOS/stop are discarded host-side
         at harvest (the over-written tail K/V sits beyond the valid
         prefix, so it is never attended to)."""
@@ -4410,62 +4021,9 @@ class LLMEngine:
             if not decoding:
                 return True
         now = time.perf_counter()
-        waiting = sum(1 for s in self.slots
-                      if s.state in (SlotState.PREFILL,
-                                     SlotState.PENDING_FIRST))
-        if self._mixed:
-            if any(f.kind == "mixed" for f in self._flights):
-                # a mixed step's sampled tokens are still in flight:
-                # decode rows' next input tokens are unknown host-side,
-                # and a scan enqueued now would replay stale tokens
-                return False
-        else:
-            # LEGACY-ONLY burst hold (LOCALAI_MIXED_DISPATCH=off). The
-            # mixed dispatcher replaces this prefill/decode mutual
-            # exclusion with fusion: decode rows advance INSIDE the
-            # wave's dispatches, so there is nothing to hold against.
-            #
-            # A prefill flight serving MORE waiters than there are
-            # decoders counts as a burst even after the arrival window
-            # lapses: the flight's ~200ms round trip outlives the 0.15s
-            # freshness test, and a decode scan slipping into that gap
-            # queues ~450ms of device work between the flight and its
-            # harvest detection — measured r5: the 63-slot gathered
-            # group's observed latency went 497ms with scans trailing
-            # it vs 174ms clean. In steady state (decoders >> waiters)
-            # decode proceeds: holding every scan behind each lone
-            # arrival's prefill would halve throughput under
-            # continuous load.
-            gathering = (
-                waiting > len(decoding)
-                and any(f.kind == "prefill_final" for f in self._flights))
-            burst = bool(self._pending) or now - self._last_arrival < 0.15
-            if gathering or (burst and any(not s.active
-                                           or s.state is SlotState.PREFILL
-                                           for s in self.slots)):
-                # an admission burst is landing (free slots await
-                # requests, or assigned slots await their prefill — a
-                # gathered group held behind an in-flight prefill
-                # counts: r5 flight traces showed a 23-slot group
-                # queueing behind 900 ms of decode scans that slipped
-                # in the moment every slot was assigned): hold decode
-                # enqueues so the burst's prefill groups pipeline
-                # back-to-back on the device instead of each queueing
-                # behind hundreds of ms of scan work — under a
-                # 64-stream HTTP wave this is the difference between
-                # ~0.4 s and ~1.7 s p50 TTFT. Bounded from the hold's
-                # START so a steady trickle cannot starve decode.
-                if self._hold_start == 0.0:
-                    self._hold_start = now
-                if now - self._hold_start < 0.5:
-                    time.sleep(1e-3)
-                    return False
-            else:
-                self._hold_start = 0.0
         dflights = [f for f in self._flights if f.kind == "decodek"]
-        in_flight = sum(f.meta["k"] for f in dflights)
-        k, room, need_tokens = self._multi_step_k(decoding)
-        room -= in_flight
+        ahead, sampled = self._ahead()
+        k, room, need_tokens = self._multi_step_k(decoding, ahead, sampled)
         if k <= 1:
             # grammar/logit-bias slots need a host mask per token: the
             # blocking single-step path, and it needs the true current
@@ -4493,27 +4051,19 @@ class LLMEngine:
             depth = 1
         if len(dflights) >= depth or room < k:
             return False
-        if need_tokens <= in_flight:
+        if need_tokens <= 0:
             return False  # everything already covered by in-flight scans
         if (self._pending or now - self._last_arrival < 1.0) and free:
             # arrivals active with admissible room: a late request's
-            # prefill dispatch queues on the device BEHIND this scan —
+            # admission step queues on the device BEHIND this scan —
             # keep it short so burst TTFT is not hostage to a long
             # scan. (A flat k=4 on free slots ALONE throttled the 1B
             # drain to 1/4 throughput; the open-capacity case below
             # sizes k from measured step time instead.)
             k = min(k, 4)
-        elif waiting and now - self._last_arrival < 1.0:
-            # a fresh arrival's prefill is pending/in flight with every
-            # slot taken (so the clamp above is off): keep scans at half
-            # length so its first token is not hostage to a full k-scan
-            # already queued ahead — the steady-state TTFT counterpart
-            # of the burst clamp, at half the dispatch-overhead cost
-            # (_half_k is always in warmup's variant set)
-            k = min(k, self._half_k)
         elif free:
             # open capacity, no arrival in sight: an UNPREDICTED
-            # arrival's prefill queues behind whatever scans are in
+            # arrival's admission queues behind whatever scans are in
             # flight when it lands, so bound that queue in TIME (see
             # _latency_k for the balanced/latency-mode policies and
             # their measured effect).
@@ -4536,88 +4086,64 @@ class LLMEngine:
                 kb = (max(fits) if fits
                       else min(kk for kk in self._warm_ks if kk > 1))
                 k = min(k, kb)
+        carry = self._carry_for(decoding)
+        if carry is None:
+            # dispatches in flight and some row's next token is only on
+            # the device, outside the carry (a slot woken in DECODE by a
+            # migration, a blocking step's rows): wait for the harvest
+            return False
 
         S = self.n_slots
         # the window must cover EVERY non-free slot position plus the
         # tokens already in flight
         window = self._route.window(
-            max(s.n_past for s in self.slots
+            max(s.n_past + ahead.get(s.idx, 0) for s in self.slots
                 if s.state in (SlotState.DECODE, SlotState.PENDING_FIRST))
-            + in_flight + k + 1, "decode",
+            + k + 1, "decode",
             (key[2] for key in self._decode_k_fns
              if key[0] == "decode" and key[1] == k))
+        if carry:
+            # rows parked on the device keep the position the chain's
+            # earlier windows covered: a window never shrinks under them
+            window = max(window, self._dev_window)
 
         if self._paged:
             # page capacity for the scan's write span ([n_past +
-            # in_flight, + k) per advancing row) BEFORE the table
+            # ahead, + k) per advancing row) BEFORE the table
             # snapshots below
             for s in list(decoding):
-                if not self._pool_ensure(s, s.n_past + in_flight + k):
+                if not self._pool_ensure(
+                        s, s.n_past + ahead.get(s.idx, 0) + k):
                     self._finish(s, "length")
                     decoding.remove(s)
             if not decoding:
                 return True
         advancing = {s.idx for s in decoding}
-        tokens = np.zeros((S, 1), np.int32)
-        pos0 = np.zeros((S,), np.int32)
-        active = np.zeros((S,), bool)
-        for s in self.slots:
-            if s.idx in advancing:
-                last_tok = (s.generated[-1] if s.generated
-                            else s.request.prompt_ids[-1])
-                tokens[s.idx, 0] = last_tok
-                pos0[s.idx] = s.n_past
-                active[s.idx] = True
-            elif s.state in (SlotState.DECODE, SlotState.PENDING_FIRST):
-                # spec-advanced or first-token-pending slots ride
-                # inactive; window covers their positions (see `need`),
-                # so no trimming
-                pos0[s.idx] = s.n_past
-            else:
-                # park inactive rows at their own tail: K/V write lands past
-                # the valid prefix, preserving it for prefix reuse. In the
-                # windowed path, a row whose prefix out-sizes the window
-                # gets clamped: its reusable prefix is truncated to what
-                # the window keeps. Paged rows never write back (their wb
-                # pages are trash), so the resident prefix survives at
-                # full length — only the in-dispatch position is clamped.
-                if s.n_past >= window and not self._paged:
-                    s.n_past = window - 1
-                    s.cache_tokens = s.cache_tokens[: window - 1]
-                pos0[s.idx] = min(s.n_past, window - 1, self.max_seq - 1)
-
-        akey = active.tobytes()
-        carry_ok = (self._dev_epoch == self._epoch
-                    and self._dev_akey == akey)
-        if dflights and not carry_ok:
-            # scans in flight but the active set changed (a slot
-            # finished/joined at harvest): fresh host tokens would be
-            # stale until those scans land — wait for them
-            return False
+        tokens, pos0, active = self._decode_inputs(decoding, ahead, window)
         payload = {
-            "k": k, "window": window, "depth": 1, "carry": carry_ok,
+            "k": k, "window": window, "depth": 1, "carry": carry,
             "tokens": tokens, "pos0": pos0, "active": active,
         }
         if self._paged:
             payload["pt"] = self._phys_rows(list(range(S)), window)
             payload["wb"] = self._wb_rows(
-                [(i, ((self.slots[i].n_past + in_flight,
-                       self.slots[i].n_past + in_flight + k)
+                [(i, ((int(pos0[i]), int(pos0[i]) + k)
                       if i in advancing else None)) for i in range(S)],
                 window)
         self._note_ragged_rows("decode", len(decoding))
         # step j of the scan reads the row's cache as it stands then:
-        # n_past + the tokens of scans still in flight + j
+        # n_past + the tokens of dispatches still in flight + j
         self._note_dispatch_tokens(
             "decodek", len(decoding) * k, S * k,
-            sum(k * (s.n_past + in_flight) + k * (k - 1) // 2
+            sum(k * int(pos0[s.idx]) + k * (k - 1) // 2
                 for s in decoding), steps=k)
         batches = self._run("decodek", payload)
         toks = batches[0]
         toks.copy_to_host_async()
-        self._dev_epoch = self._epoch
-        self._dev_akey = akey
+        self._dev_rows = {s.idx: s.request for s in decoding}
+        self._dev_window = window
         dckey = costmodel.dispatch_key("decodek", payload)
+        chained = bool(self._flights)
         self._flights.append(_Flight(
             kind="decodek", arrays=[toks],
             meta={
@@ -4626,24 +4152,26 @@ class LLMEngine:
                 "pred_ms": (self._costmodel.predict_ms("decodek", dckey)
                             if self._costmodel is not None else None),
                 "pairs": [(s, s.request) for s in decoding],
-                # None for a chained scan: its predecessor's last tokens
-                # are unknown until that flight harvests (_harvest_last)
-                "prev_last": (None if dflights else
+                "ahead": [(s, s.request, k, k) for s in decoding],
+                # None for a chained scan: what the flight before it
+                # sampled last is unknown until that flight harvests
+                # (_harvest_last)
+                "prev_last": (None if chained else
                               {s.idx: int(tokens[s.idx, 0])
                                for s in decoding}),
                 # enqueued behind another DECODE scan: its harvest-to-
                 # harvest gap measures decode device time (the step
                 # EWMA's input). A scan enqueued onto an idle device
                 # measures device time + dispatch RTT, and one behind a
-                # prefill_final measures prefill time too (_last_harvest_t
-                # only advances on decode harvests) — neither may
-                # pollute the EWMA, so a prefill anywhere in the
-                # pipeline disqualifies the sample even when another
-                # decode scan is also in flight (ADVICE r5 #1: the 8x
-                # outlier guard alone let prefill-inflated samples
-                # through and mis-sized the k clamps)
+                # mixed step measures that step's time too
+                # (_last_harvest_t only advances on decode harvests) —
+                # neither may pollute the EWMA, so a mixed step
+                # anywhere in the pipeline disqualifies the sample even
+                # when another decode scan is also in flight (ADVICE r5
+                # #1: the 8x outlier guard alone let prefill-inflated
+                # samples through and mis-sized the k clamps)
                 "saturated": bool(dflights) and not any(
-                    f.kind == "prefill_final" for f in self._flights),
+                    f.kind == "mixed" for f in self._flights),
                 # timeline args for the flight recorder's harvest span
                 "rec": {"rows": len(decoding), "k": k, "window": window},
             },
@@ -4705,7 +4233,7 @@ class LLMEngine:
                                  defer=True)
             if s.state is SlotState.DECODE:
                 self._flush_emit(s)  # one event per slot per harvest
-        self._harvest_last = next_last
+        self._harvest_last.update(next_last)
         if dt_ms > 0 and emitted:
             self._note_tokens_per_second(emitted, dt_ms / 1e3)
             tm.ENGINE_GENERATED_TOKENS.labels(model=self._mlabel).inc(
@@ -4762,7 +4290,7 @@ class LLMEngine:
             s.t_decode_ms += dt_ms
             emitted += 1
             self._emit_token(s, int(toks_host[s.idx]))
-        self._epoch += 1  # device carry (if any) is now stale
+        self._dev_rows = {}  # device carry (if any) is now stale
         if dt_ms > 0 and emitted:
             self._note_tokens_per_second(emitted, dt_ms / 1e3)
             tm.ENGINE_GENERATED_TOKENS.labels(model=self._mlabel).inc(
@@ -4935,7 +4463,6 @@ class LLMEngine:
             slot.n_past = 0
             if self._paged:
                 self._pool.drop(slot.idx)
-        self._epoch += 1
         slot.state = SlotState.FREE
         slot.request = None
         slot.out = None

@@ -19,8 +19,8 @@ The relay, per disaggregated request:
    go straight to the decode engine — LOCALAI_DISAGG=off is
    byte-identical because the router is never constructed.
 2. A prefill PROBE (same request, ``max_tokens=1``, id + ":prefill",
-   same trace_id) runs on the prefill engine. Its prefill_final
-   dispatch samples the first token with the request's own seeded
+   same trace_id) runs on the prefill engine. Its final mixed
+   step samples the first token with the request's own seeded
    sampler columns — identical semantics to the single-engine path —
    and with max_tokens=1 the slot finishes before any decode dispatch,
    so its pages cover EXACTLY the prompt.
@@ -564,7 +564,6 @@ class Migrator:
         slot.t_last = now
         slot.constraint_state = (
             req.constraint.initial_state() if req.constraint else None)
-        eng._epoch += 1
         FLIGHT.transfer("migrate_in", t0, now - t0, npg, nbytes,
                         track=MIGRATE_TRACK)
         tm.ENGINE_KV_MIGRATED_PAGES.labels(

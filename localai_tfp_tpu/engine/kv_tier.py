@@ -638,7 +638,6 @@ class KVTierManager:
             vs = scatter(vs, dvs)
             handles += [dks, dvs]
         eng.cache = type(c)(k=ck, v=cv, k_scale=ks, v_scale=vs)
-        eng._epoch += 1
         nbytes = sum(int(h.nbytes) for h in handles)
         self._fwin.add((npg, nbytes, now), nbytes, tuple(handles))
         self._fetches[req.id] = _Fetch(ent, sid, n, now)

@@ -23,7 +23,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -587,6 +587,21 @@ def _lm_head(spec, params, x):
     return logits
 
 
+class Rows(NamedTuple):
+    """One rectangular group of query rows of a forward pass: what
+    ``forward_hidden`` takes per row (see there), bundled so that a
+    pass can carry more than one rectangle (``forward_rows``)."""
+
+    tokens: jax.Array  # [B, T] int32
+    pos0: jax.Array  # [B] int32
+    slot_ids: Optional[jax.Array] = None
+    soft: Optional[tuple] = None
+    write_mask: Optional[jax.Array] = None
+    page_table: Optional[jax.Array] = None
+    q_lens: Optional[jax.Array] = None
+    write_table: Optional[jax.Array] = None
+
+
 def forward_hidden(
     spec: LLMSpec,
     params: Params,
@@ -645,13 +660,71 @@ def forward_hidden(
     full slot batch. Writes the new K/V into ``cache`` at rows ``slot_ids``
     columns ``pos0 + [0..T)``.
     """
-    x = _embed_in(spec, params, tokens)  # gather: [B, T, D]
-    if soft is not None:
-        emb, emb_mask = soft
-        x = jnp.where(emb_mask[..., None], emb.astype(x.dtype), x)
-    B = tokens.shape[0]
-    positions = pos0[:, None] + jnp.arange(
-        tokens.shape[1], dtype=jnp.int32)[None, :]
+    (x,), cache = forward_rows(
+        spec, params,
+        (Rows(tokens, pos0, slot_ids, soft, write_mask, page_table,
+              q_lens, write_table),),
+        cache, decode_kernel=decode_kernel, mesh=mesh,
+        ring_prefill=ring_prefill, kv_page=kv_page)
+    return x, cache
+
+
+def forward_rows(
+    spec: LLMSpec,
+    params: Params,
+    groups: tuple,  # of Rows
+    cache: KVCache,
+    *,
+    decode_kernel: bool = False,
+    mesh: Any = None,
+    ring_prefill: bool = False,
+    kv_page: int = 0,
+) -> tuple[tuple, KVCache]:
+    """``forward_hidden`` for one or more rectangles of rows in ONE
+    pass: returns (one hidden [B, T, D] per group, updated cache).
+
+    With more than one group the rows ride the layer's matmuls as one
+    flat ``[1, sum(B*T), D]`` batch — each weight is read from HBM once
+    for all of them, which is the point: a step that decodes
+    ``[n_slots, 1]`` rows and admits ``[R, bucket]`` prompt rows costs
+    one weight read, not two — and only attention runs per group, in
+    order, each on the cache the group before it left. Every group
+    reaches the cache the same way (all ragged, or all through the XLA
+    contraction); a row's arithmetic is that of the same row in a pass
+    of its own."""
+    single = len(groups) == 1
+    g0 = groups[0]
+
+    def ungroup(a):
+        # [1, N, ...] back into each group's [B, T, ...] rectangle
+        out, lo = [], 0
+        for g in groups:
+            b, t = g.tokens.shape
+            out.append(a[0, lo:lo + b * t].reshape(b, t, *a.shape[2:]))
+            lo += b * t
+        return out
+
+    def embed(g):
+        x = _embed_in(spec, params, g.tokens)  # gather: [B, T, D]
+        if g.soft is not None:
+            emb, emb_mask = g.soft
+            x = jnp.where(emb_mask[..., None], emb.astype(x.dtype), x)
+        return x
+
+    def positions_of(g):
+        return g.pos0[:, None] + jnp.arange(
+            g.tokens.shape[1], dtype=jnp.int32)[None, :]
+
+    if single:
+        x = embed(g0)
+        positions = positions_of(g0)
+    else:
+        xs = [embed(g) for g in groups]
+        x = jnp.concatenate(
+            [a.reshape(1, -1, a.shape[-1]) for a in xs], axis=1)
+        positions = jnp.concatenate(
+            [positions_of(g).reshape(1, -1) for g in groups], axis=1)
+    page_table = g0.page_table
     inv_freq = rope_inv_freq(spec)
     rope_scale = rope_attn_scale(spec)
     stacked = {k: params[k] for k in params if k not in _NON_LAYER_KEYS}
@@ -664,7 +737,6 @@ def forward_hidden(
     dense_only = _layer_dense_only(spec)
     if dense_only is not None:
         stacked = {**stacked, "_dense_only": dense_only}
-    identity = slot_ids is None  # batch row b IS cache row b (decode path)
     quant = cache.quantized  # int8 rows + per-row scales
 
     def body(carry, scanned):
@@ -679,8 +751,8 @@ def forward_hidden(
         # here (LLMEngine._kernel_ineligible rules them out first)
         use_ragged = page_table is not None and win is None
         use_kernel = use_ragged or (
-            decode_kernel and identity and x.shape[1] == 1
-            and win is None)
+            decode_kernel and single and g0.slot_ids is None
+            and x.shape[1] == 1 and win is None)
         if use_kernel:
             ck = cv = ks = vs = None  # kernel addresses the full cache
         else:
@@ -692,7 +764,11 @@ def forward_hidden(
             else:
                 ks = vs = None
 
-        def ragged_attn(q, k, v):
+        # the three ways to the cache, each for ONE group ``g`` on the
+        # cache arrays ``st`` the group before it left (the stacked
+        # [L, ...] arrays for the kernels, this layer's slices for the
+        # XLA contraction); each returns (attn, the arrays it wrote)
+        def ragged_attn(g, st, q, k, v):
             # Ragged unified path (ops/ragged_paged_attention.py): the
             # chunk's K/V rows scatter into the arena through the WRITE
             # table (positions beyond a row's q_len redirect to the
@@ -707,7 +783,9 @@ def forward_hidden(
                 ragged_paged_attention,
             )
 
-            T = k.shape[1]
+            ck_all, cv_all, ks_all, vs_all = st
+            pos0, q_lens = g.pos0, g.q_lens
+            B, T = k.shape[0], k.shape[1]
             kf = k.reshape(B, T, spec.kv_dim)
             vf = v.reshape(B, T, spec.kv_dim)
             rows = jnp.arange(B, dtype=jnp.int32)
@@ -738,13 +816,13 @@ def forward_hidden(
                     ck_all, cv_all,
                     ks_all if quant else None,
                     vs_all if quant else None,
-                    l, page_table, write_table, pos0, q_lens,
+                    l, g.page_table, g.write_table, pos0, q_lens,
                     spec.n_kv_heads, scale=scale, page=kv_page,
                     sliding_window=spec.sliding_window,
                 )
                 return res[0].astype(x.dtype), tuple(res[1:])
             tpos = pos0[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
-            wpg = write_table[rows[:, None], tpos // kv_page]
+            wpg = g.write_table[rows[:, None], tpos // kv_page]
             # pad positions beyond the row's ragged length write trash
             wpg = jnp.where(
                 jnp.arange(T, dtype=jnp.int32)[None] < q_lens[:, None],
@@ -763,7 +841,7 @@ def forward_hidden(
                 ks_new = vs_new = None
             seed = ((kf[:, 0], vf[:, 0]) if T == 1 else None)
             out = ragged_paged_attention(
-                q, ck_new, cv_new, l, page_table, pos0, q_lens,
+                q, ck_new, cv_new, l, g.page_table, pos0, q_lens,
                 spec.n_kv_heads, scale=scale, page=kv_page,
                 sliding_window=spec.sliding_window,
                 cache_k_scale=ks_new, cache_v_scale=vs_new,
@@ -774,7 +852,7 @@ def forward_hidden(
                         (ck_new, cv_new, ks_new, vs_new))
             return out.astype(x.dtype), (ck_new, cv_new)
 
-        def kernel_attn(q, k, v):
+        def kernel_attn(g, st, q, k, v):
             # Fused Pallas path: the current K/V rows are appended via an
             # in-place scatter on the scan-CARRIED full cache (XLA keeps
             # carry scatters in place; single bf16 rows cannot be DMA'd
@@ -785,6 +863,8 @@ def forward_hidden(
             # dequantizes per page in VMEM (the bytes stay halved).
             from ..ops.decode_attention import fused_decode_attention
 
+            ck_all, cv_all, ks_all, vs_all = st
+            pos0, B = g.pos0, k.shape[0]
             kf = k.reshape(B, spec.kv_dim)
             vf = v.reshape(B, spec.kv_dim)
             rows = jnp.arange(B, dtype=jnp.int32)
@@ -837,10 +917,12 @@ def forward_hidden(
                         (ck_new, cv_new, ks_new, vs_new))
             return out[:, None, :].astype(x.dtype), (ck_new, cv_new)
 
-        def kv_from_cache(k, v):
+        def kv_from_cache(g, st, k, v):
             # cache rows are head-FLAT [seq, kv_dim] (see KVCache); heads are
             # re-split transiently for the attention contraction
-            T = k.shape[1]
+            ck, cv, ks, vs = st
+            pos0, slot_ids, write_mask = g.pos0, g.slot_ids, g.write_mask
+            B, T = k.shape[0], k.shape[1]
             kf = k.reshape(B, T, spec.kv_dim)
             vf = v.reshape(B, T, spec.kv_dim)
             if quant:
@@ -867,7 +949,7 @@ def forward_hidden(
             def one_scale(srow, val, off):
                 return lax.dynamic_update_slice(srow, val, (off,))
 
-            if identity:
+            if slot_ids is None:  # batch row b IS cache row b
                 # hot path: per-row dynamic_update_slice, no gather/scatter
                 # (a cross-slot scatter would copy the whole cache layer
                 # every decode step — ~GBs/step at serving shapes)
@@ -936,8 +1018,8 @@ def forward_hidden(
             return (split(ck2[slot_ids], None), split(cv2[slot_ids], None),
                     (ck2, cv2))
 
-        def xla_attn(q, k, v):
-            k_eff, v_eff, carry = kv_from_cache(k, v)
+        def xla_attn(g, st, q, k, v):
+            k_eff, v_eff, carry = kv_from_cache(g, st, k, v)
             if ring_prefill:
                 # seq-parallel exact attention over the chunk itself
                 # (caller guarantees pos0 == 0, so the cache holds no
@@ -955,14 +1037,28 @@ def forward_hidden(
                                      scale=scale)
                 B_, T_ = q.shape[0], q.shape[1]
                 return (out.reshape(B_, T_, -1).astype(x.dtype), carry)
-            return _attend(spec, q, k_eff, v_eff, positions,
+            return _attend(spec, q, k_eff, v_eff,
+                           positions if single else positions_of(g),
                            lp.get("_window")), carry
 
+        one = (ragged_attn if use_ragged
+               else (kernel_attn if use_kernel else xla_attn))
+        st0 = ((ck_all, cv_all, ks_all, vs_all) if use_kernel
+               else (ck, cv, ks, vs))
+
+        def attn_fn(q, k, v):
+            if single:
+                return one(g0, st0, q, k, v)
+            outs, st = [], st0
+            for g, qg, kg, vg in zip(groups, *map(ungroup, (q, k, v))):
+                o, wrote = one(g, st, qg, kg, vg)
+                st = tuple(wrote) + tuple(st[len(wrote):])
+                outs.append(o.reshape(1, -1, o.shape[-1]))
+            return (jnp.concatenate(outs, axis=1),
+                    st if quant else st[:2])
+
         x, out = _layer_body(
-            spec, x, lp, positions, inv_freq, rope_scale,
-            ragged_attn if use_ragged
-            else (kernel_attn if use_kernel else xla_attn),
-        )
+            spec, x, lp, positions, inv_freq, rope_scale, attn_fn)
         if use_kernel:
             # the fused kernel updated the FULL stacked cache in place
             if quant:
@@ -997,7 +1093,7 @@ def forward_hidden(
 
     if spec.final_norm:
         x = _norm(spec, x, params["final_norm_w"], params.get("final_norm_b"))
-    return x, new_cache
+    return ((x,) if single else tuple(ungroup(x))), new_cache
 
 
 def forward(

@@ -424,10 +424,10 @@ class Geometry:
     page: int = 256  # the engine's page size at this max_seq
     max_seq: int = 4096
     n_slots: int = 16  # decode and mixed dispatches are [n_slots, T]
-    chunk: int = 2048  # largest prefill bucket: rides the kernel as a
-    # [1, chunk] "prefill" row and [B, chunk] prefill_final rows
-    mixed: int = 512  # largest bucket with n_slots * bucket inside the
-    # mixed/identity token budget
+    chunk: int = 2048  # largest prefill bucket: the longest query row
+    # the kernel's tiling has to lay out (a [1, chunk] row)
+    mixed: int = 512  # a [n_slots, bucket] ragged batch inside the
+    # group-token budget (a mixed step's prompt group is narrower)
 
 
 # Mistral-7B-Instruct-v0.3 heads at context_size 4096 / 16 slots / the
@@ -450,7 +450,7 @@ def check_serving_rows(geom: Geometry, kind: str, cache: str,
       routes through the kernel);
     - ``mixed``: ``[n_slots, mixed]`` with decode rows (q_len 1), short
       and full chunks and a verify-sized row together, each at its own
-      context offset (mixed / identity prefill_final).
+      context offset (the row kinds a mixed step carries).
 
     ``cache`` is the arena dtype: "bf16" and "int8" under a bf16 model
     (queries and seed rows bf16), "f32" for an f32 model end to end.

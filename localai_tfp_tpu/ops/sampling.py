@@ -151,7 +151,7 @@ def reset_slots(
     mirostat_eta: jax.Array,  # [K] f32
 ) -> SamplingState:
     """Configure a BATCH of slots in one dispatch (it rides the
-    prefill_final dispatch — engine._reset_columns).
+    mixed dispatch — engine._reset_columns).
 
     ``reset_slot`` costs ~12 unbatched buffer copies per slot (including
     the [S, V] count matrix), which dominated admission waves. Padding rows point at the OUT-OF-BOUNDS

@@ -67,14 +67,10 @@ Record = Tuple[str, Any]
 # programs at runtime. (Plain literal on purpose: the linter reads it
 # from the AST without importing jax.)
 PAYLOAD_FIELDS = {
-    "prefill": ("toks", "pos0", "slot_ids", "soft", "window", "ring",
-                "pt", "wb"),
-    "prefill_final": ("toks", "pos0", "slot_ids", "n_chunk", "tails",
-                      "tail_lens", "masks", "reset", "soft", "window",
-                      "identity", "pt", "wb"),
-    "mixed": ("toks", "pos0", "n_chunk", "write_mask", "sample_sids",
-              "reset_sids", "tails", "tail_lens", "masks", "reset",
-              "soft", "prefill_sids", "window", "pt", "wb", "wb_draft"),
+    "prefill": ("toks", "pos0", "slot_ids", "window"),
+    "mixed": ("toks", "pos0", "slot_ids", "n_chunk", "final", "tails",
+              "tail_lens", "masks", "reset", "soft", "window", "carry",
+              "dtoks", "dpos", "active", "pt", "wb"),
     "decode1": ("tokens", "pos0", "active", "masks", "pt", "wb"),
     "decodek": ("k", "window", "depth", "carry", "tokens", "pos0",
                 "active", "pt", "wb"),

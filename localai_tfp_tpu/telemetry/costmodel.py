@@ -13,8 +13,8 @@ From then on the hot path never touches the device for accounting:
 every dispatch adds the captured flops/bytes of its variant to
 host-held totals (the flightrec contract — zero syncs, zero device
 work), exported as ``engine_device_flops_total{kind}`` and
-``engine_device_bytes_total{kind}``. Flight-shaped kinds (prefill_final
-/ mixed / decodek) account at HARVEST, where the flight's wall span is
+``engine_device_bytes_total{kind}``. Flight-shaped kinds (mixed /
+decodek) account at HARVEST, where the flight's wall span is
 known, and each harvest also feeds an EWMA MFU estimate:
 
     mfu = captured_flops / (span_seconds * peak_flops * n_devices)
@@ -63,7 +63,7 @@ __all__ = ["CostModel", "dispatch_key", "peak_rates",
 
 # kinds whose device work completes asynchronously as a _Flight; these
 # account at harvest (span known), everything else at dispatch
-FLIGHT_KINDS = frozenset({"prefill_final", "mixed", "decodek"})
+FLIGHT_KINDS = frozenset({"mixed", "decodek"})
 
 # (peak FLOP/s, peak HBM bytes/s) per device, by jax ``device_kind``.
 # These peaks feed predict_ms(), which sizes dispatches by default, so
@@ -120,19 +120,14 @@ def dispatch_key(kind: str, payload: dict) -> tuple:
     exactly when the engine's jit-cache key varies, so each captured
     cost row matches the executable the dispatch actually runs."""
     p = payload
-    if kind == "prefill_final":
-        toks = p["toks"]
-        return (kind, toks.shape[0], toks.shape[1],
-                p.get("window"), bool(p.get("identity")))
-    if kind == "mixed":
+    if kind == "mixed":  # the prompt group's [rows, bucket]
         toks = p["toks"]
         return (kind, tuple(toks.shape), p.get("window"))
     if kind == "decodek":
         return (kind, p["k"], p.get("window"), p.get("depth", 1))
     if kind == "prefill":
         toks = p["toks"]
-        return (kind, toks.shape[-1], p.get("window"),
-                bool(p.get("ring")))
+        return (kind, toks.shape[-1], p.get("window"))
     if kind in ("spec", "spec_s"):
         return (kind, p.get("kd"), p.get("rounds"))
     if kind == "kvcopy":
@@ -146,15 +141,17 @@ def variant_key(kind: str, payload: dict) -> tuple:
     """``dispatch_key`` plus the inputs that select another executable
     WITHOUT changing the cost row — what a program-load event has to
     name to tell two loads of one kind apart (telemetry/flightrec.py
-    LoadWatch). ``carry``: a decodek whose token/position/active inputs
-    are the device-resident carry of the previous scan (committed
-    arrays) and not fresh host arrays lowers again; ``masks``/``soft``:
+    LoadWatch). ``carry``: a decodek or mixed step whose token and
+    position inputs are the device-resident carry of the dispatch
+    before it (committed arrays) and not fresh host arrays lowers again
+    on a meshed engine (an unmeshed one commits what the host feeds:
+    one executable either way, LLMEngine.__init__); ``masks``/``soft``:
     None or an array is a different argument tree. Kept out of
     ``dispatch_key`` itself: the cost table is keyed by it, and the
     warmup pass captures rows with ``carry: False`` only."""
     p = payload
     key = dispatch_key(kind, p)
-    if kind == "decodek":
+    if kind in ("decodek", "mixed"):
         key += (("carry", bool(p.get("carry"))),)
     if "masks" in p:
         key += (("masks", p["masks"] is not None),)
@@ -428,9 +425,7 @@ class CostModel:
         best: Optional[float] = None
         for key in keys:
             kind = key[0]
-            if kind == "prefill_final":
-                tokens = int(key[1]) * int(key[2])
-            elif kind == "mixed":
+            if kind == "mixed":
                 tokens = int(key[1][0]) * int(key[1][1])
             elif kind == "prefill":
                 tokens = int(key[1])

@@ -389,7 +389,7 @@ FAULTS_INJECTED = REGISTRY.counter(
 ENGINE_DEVICE_STEP = REGISTRY.histogram(
     "engine_device_step_seconds",
     "Enqueue-to-ready wall time per harvested device flight by dispatch "
-    "kind (prefill_final/mixed/decodek) — host-timed at harvest, when "
+    "kind (mixed/decodek) — host-timed at harvest, when "
     "the flight's arrays are already ready, so the sample costs no "
     "device sync",
     labels=("model", "kind"), buckets=_STEP_BUCKETS,
@@ -442,9 +442,9 @@ ENGINE_DISPATCH_TOKENS = REGISTRY.counter(
     "engine_dispatch_tokens_total",
     "Token positions dispatched, by kind and part (real = positions "
     "that carry work: decode rows x steps + prompt-chunk tokens; "
-    "padded = positions of the program's shape: n_slots x bucket for "
-    "mixed, group rows x bucket for prefill_final, n_slots x k x "
-    "depth for decodek) — real / padded is how full a dispatch was",
+    "padded = positions of the program's shape: n_slots + group rows "
+    "x bucket for mixed, n_slots x k x depth for decodek) — real / "
+    "padded is how full a dispatch was",
     labels=("model", "kind", "part"),
 )
 ENGINE_ATTN_CONTEXT_TOKENS = REGISTRY.counter(

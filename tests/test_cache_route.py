@@ -133,8 +133,8 @@ def test_close_of_open_is_the_cache_bit_for_bit(name, dtype):
     ("decode", 300, (256, 1024, 2048), 1024, 2048),
     ("mixed", 300, (), 2048, 2048),
     ("mixed", 300, (1024, 2048), 1024, 1024),
-    ("prefill_final", 10, (256, 2048), 256, 256),
-    ("prefill_final", 5000, (256,), 2048, 2048),
+    ("mixed", 10, (256, 2048), 256, 256),
+    ("mixed", 5000, (256,), 2048, 2048),
     # chunk prefills are warmed along the whole ladder: the bucket
     ("prefill", 600, (2048,), 1024, 1024),
 ])

@@ -1,4 +1,4 @@
-"""Cost-model-driven scheduling (engine._cost_bucket / _itl_budget_ms
+"""Cost-model-driven scheduling (engine._mixed_shape / _itl_budget_ms
 + telemetry/costmodel.predict_ms): dispatch budgets expressed in
 PREDICTED device microseconds instead of token counts.
 
@@ -66,14 +66,14 @@ def test_cost_sched_on_off_byte_identical(model, monkeypatch):
     spec, params, tk = model
     monkeypatch.setenv("LOCALAI_ITL_BUDGET_MS", "5")
     monkeypatch.setenv("LOCALAI_COST_SCHED", "off")
-    eng_off = _engine(model, mixed=True)
+    eng_off = _engine(model)
     try:
         spy_off = PayloadKeySpy(eng_off)
         want = _mixed_schedule(eng_off, tk)
     finally:
         eng_off.close()
     monkeypatch.setenv("LOCALAI_COST_SCHED", "on")
-    eng_on = _engine(model, mixed=True)
+    eng_on = _engine(model)
     try:
         assert eng_on._itl_budget_ms() == 5.0
         spy_on = PayloadKeySpy(eng_on)
@@ -172,32 +172,33 @@ def test_no_decode_starvation_under_itl_budget(model, monkeypatch):
     spec, params, tk = model
     monkeypatch.setenv("LOCALAI_COST_SCHED", "on")
     monkeypatch.setenv("LOCALAI_ITL_BUDGET_MS", "25")
-    eng = _engine(model, mixed=True)
+    eng = _engine(model)
     try:
         assert eng._itl_budget_ms() == 25.0  # the budget really armed
         picks = []
-        orig_cost_bucket = eng._cost_bucket
+        orig_shape = eng._mixed_shape
 
-        def spy_cost_bucket(prefilling, decoding, cover, budget_ms):
-            b = orig_cost_bucket(prefilling, decoding, cover, budget_ms)
-            picks.append((cover, b, budget_ms))
-            return b
+        def spy_shape(rems, budget_ms=0.0, window_of=None):
+            shape = orig_shape(rems, budget_ms, window_of)
+            if budget_ms > 0.0:
+                picks.append((orig_shape(rems), shape, budget_ms))
+            return shape
 
-        eng._cost_bucket = spy_cost_bucket
+        eng._mixed_shape = spy_shape
         dspy = DispatchSpy(eng)
         results = _mixed_schedule(eng, tk)
-        warmed = set(eng._mixed_buckets)
+        warmed = {(r, b) for r, b, _ in eng._mixed_variants()}
     finally:
         eng.close()
     for name, (gen, ev) in results.items():
         assert ev.finish_reason == "length", (name, ev.error)
         assert len(gen) == ev.completion_tokens > 0
     # the packer actually ran against the armed budget...
-    assert picks, "ITL budget armed but _cost_bucket never consulted"
+    assert picks, "ITL budget armed but the packer never consulted"
     for cover, chosen, budget_ms in picks:
         assert budget_ms == 25.0
-        assert chosen <= cover, "cost packing may only shrink"
-        assert chosen in warmed, "picked a never-warmed bucket"
+        assert chosen[1] <= cover[1], "cost packing may only shrink"
+        assert chosen in warmed, "picked a never-warmed shape"
     # ...and decode never starved while prefill rode along
     carrying = [r for r in dspy.mixed()
                 if r["prefill_tokens"] and r["decoding"]]
@@ -236,10 +237,10 @@ def test_cost_sched_knob_parsing(monkeypatch):
 
 def test_engine_honors_prefill_group_knob(model, monkeypatch):
     """LOCALAI_PREFILL_GROUP_TOKENS is read once at construction and
-    sizes the identity-batch token budget; a value too small for any
-    bucket forces the mixed path off (never-warmed shapes must not
-    dispatch). Budget gating: a negative budget clamps to 0 and
-    LOCALAI_COST_SCHED=off zeroes the budget regardless."""
+    caps the prompt group's rows x bucket by lowering the row count; a
+    value under every bucket leaves one row a step (no geometry turns
+    the admission path off). Budget gating: a negative budget clamps to
+    0 and LOCALAI_COST_SCHED=off zeroes the budget regardless."""
     spec, params, tk = model
     monkeypatch.setenv("LOCALAI_PREFILL_GROUP_TOKENS", "64")
     eng = LLMEngine(spec, params, tk, n_slots=4, max_seq=256,
@@ -247,8 +248,9 @@ def test_engine_honors_prefill_group_knob(model, monkeypatch):
                     cache_dtype=jnp.float32, autostart=False)
     try:
         assert eng._prefill_group_tokens == 64
-        # 8*4=32 <= 64 fits, so mixed survives with the small budget
-        assert eng._mixed == knobs.flag("LOCALAI_MIXED_DISPATCH")
+        assert [eng._row_ladder(b) for b in (8, 32, 128)] == [
+            (4,), (2,), (1,)]
+        assert "LOCALAI_MIXED" + "_DISPATCH" not in knobs.REGISTRY
         monkeypatch.setenv("LOCALAI_ITL_BUDGET_MS", "-5")
         assert eng._itl_budget_ms() == 0.0  # negative clamps to off
         monkeypatch.setenv("LOCALAI_ITL_BUDGET_MS", "5")
@@ -262,9 +264,13 @@ def test_engine_honors_prefill_group_knob(model, monkeypatch):
                     prefill_buckets=(8, 32, 128),
                     cache_dtype=jnp.float32, autostart=False)
     try:
-        # no bucket fits 16 tokens across 4 slots: mixed forced off
+        # no bucket fits 16 tokens across 4 slots: the same path, one
+        # row at a time above the smallest bucket
         assert eng._prefill_group_tokens == 16
-        assert eng._mixed is False
+        assert [eng._row_ladder(b) for b in (8, 32, 128)] == [
+            (2,), (1,), (1,)]
+        assert {(r, b) for r, b, _ in eng._mixed_variants()} == {
+            (2, 8), (1, 32), (1, 128)}
     finally:
         eng.close()
 

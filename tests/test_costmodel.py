@@ -50,9 +50,7 @@ def served_engine(model):
 
 def test_dispatch_key_tracks_jit_cache_signature():
     toks = np.zeros((4, 32), np.int32)
-    assert costmodel.dispatch_key(
-        "prefill_final", {"toks": toks, "window": 128}) == \
-        ("prefill_final", 4, 32, 128, False)
+    # a mixed step is keyed by its prompt group's [rows, bucket]
     assert costmodel.dispatch_key(
         "mixed", {"toks": toks, "window": 64}) == ("mixed", (4, 32), 64)
     assert costmodel.dispatch_key(
@@ -60,14 +58,13 @@ def test_dispatch_key_tracks_jit_cache_signature():
         ("decodek", 4, 128, 1)
     assert costmodel.dispatch_key(
         "prefill", {"toks": np.zeros((8,), np.int32), "window": 128}) == \
-        ("prefill", 8, 128, False)
+        ("prefill", 8, 128)
     assert costmodel.dispatch_key("kvcopy", {"n": 3}) == ("kvcopy", 3)
     assert costmodel.dispatch_key("decode1", {"x": 1}) == ("decode1",)
-    # identity/ring flags fork the variant, so they fork the key
+    # the row count forks the variant, so it forks the key
     assert costmodel.dispatch_key(
-        "prefill_final", {"toks": toks, "window": 128, "identity": True}
-    ) != costmodel.dispatch_key(
-        "prefill_final", {"toks": toks, "window": 128})
+        "mixed", {"toks": toks[:2], "window": 64}
+    ) != costmodel.dispatch_key("mixed", {"toks": toks, "window": 64})
 
 
 def test_peak_rates_device_kind_table_and_overrides(monkeypatch):
@@ -98,10 +95,10 @@ def test_warmup_captures_every_variant(served_engine):
     cm = served_engine._costmodel
     assert cm is not None
     capt = cm.captured()
-    # the full dispatch ladder: 3 buckets x batch shapes + decode paths
-    assert len(capt) >= 10
+    # the full dispatch ladder: 3 buckets x row rungs + decode paths
+    assert len(capt) >= 7
     kinds = {k[0] for k in capt}
-    assert {"prefill_final", "mixed", "decodek"} <= kinds
+    assert {"mixed", "decodek"} <= kinds
     # every captured row carries a real bytes-accessed estimate
     assert all(by > 0 for _, by in capt.values())
 
@@ -165,8 +162,12 @@ def test_warmup_pads_are_not_traffic(model):
 
 def test_roofline_classifies_decode_vs_prefill(served_engine,
                                                monkeypatch):
-    monkeypatch.delenv("LOCALAI_PEAK_FLOPS", raising=False)
-    monkeypatch.delenv("LOCALAI_PEAK_HBM_GBS", raising=False)
+    # a device whose ridge (0.5 FLOP/byte) lies between the two: at this
+    # toy width the activations are half of a step's bytes, so a step
+    # that carries a whole bucket reads 0.97 — a hair under the CPU
+    # row's ridge of 1.0 — against 0.13 for a decode step
+    monkeypatch.setenv("LOCALAI_PEAK_FLOPS", "25e9")
+    monkeypatch.setenv("LOCALAI_PEAK_HBM_GBS", "50")
     roof = served_engine._costmodel.roofline()
     decode = {k: v for k, v in roof.items() if k.startswith("decode")}
     prefill = {k: v for k, v in roof.items()

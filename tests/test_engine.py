@@ -340,10 +340,9 @@ def test_warmup_variant_count_drops_with_ragged(model, monkeypatch):
             rec = {"kind": kind}
             if isinstance(payload, dict):
                 rec["window"] = payload.get("window")
-                rec["identity"] = payload.get("identity")
                 toks = payload.get("toks")
                 if toks is not None:
-                    rec["bucket"] = toks.shape[1]
+                    rec["rows"], rec["bucket"] = toks.shape
             planned.append(rec)
 
         eng._run = record
@@ -367,16 +366,20 @@ def test_warmup_variant_count_drops_with_ragged(model, monkeypatch):
     # the pool: every windowed dispatch is planned at FULL width — one
     # variant per token-budget shape
     assert all(r["window"] in (None, 1024) for r in plan_on), plan_on
-    # the dense ladder's dead-rung prune: an identity bucket-512 final covers at
-    # least pos0 + 512 + 1 positions, so windows 256/512 can never be
-    # dispatched for it — warmup must not compile them…
-    id512 = [r for r in plan_off if r["kind"] == "prefill_final"
-             and r.get("identity") and r.get("bucket") == 512]
-    assert id512 and all(r["window"] == 1024 for r in id512), id512
-    # …while the bucket-8 identity ladder stays fully warmed
-    id8 = [r for r in plan_off if r["kind"] == "prefill_final"
-           and r.get("identity") and r.get("bucket") == 8]
-    assert {r["window"] for r in id8} == {256, 512, 1024}, id8
+    # the dense ladder's dead-rung prune: a step picks bucket 512 only
+    # for a chunk over 8 tokens, so its window covers at least 8 + 2
+    # positions — and a bucket-8 step's at least 2: both ladders stay
+    # fully warmed here, each rung once per row count
+    b512 = [r for r in plan_off if r["kind"] == "mixed"
+            and r.get("bucket") == 512]
+    assert {r["window"] for r in b512} == {256, 512, 1024}, b512
+    assert {r["rows"] for r in b512} == {1, 2}, b512
+    # under a rung's worth of tokens the small row counts merge into
+    # the slot count
+    b8 = [r for r in plan_off if r["kind"] == "mixed"
+          and r.get("bucket") == 8]
+    assert {r["window"] for r in b8} == {256, 512, 1024}, b8
+    assert {r["rows"] for r in b8} == {2}, b8
     assert ({r["kind"] for r in plan_on}
             == {r["kind"] for r in plan_off})
 

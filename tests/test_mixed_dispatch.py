@@ -1,25 +1,26 @@
-"""Stall-free mixed prefill+decode dispatch (engine._enqueue_mixed /
-_mixed_fn): one fused identity-batch device step advances prompt
-chunks AND decode rows, replacing the legacy prefill/decode mutual
-exclusion (sleep-hold loops).
+"""The one admission step (engine._enqueue_mixed / _mixed_fn): ONE
+device dispatch carries a wave's prompt rows [R, bucket] and one token
+for every decoding row [n_slots, 1], whether or not a row decodes.
 
 Invariants enforced here:
-- an identical request schedule produces BYTE-IDENTICAL outputs with
-  the fused path on vs off (seeded sampling included — the mixed step
-  carries the same reset/seed/sample math as the split paths);
-- under mixed load (decoders active while a burst admits) no stream
-  starves or deadlocks, and every dispatch that carries prefill
-  tokens while a slot decodes also advances >=1 decode row
-  (decode-priority budget);
+- the oracle: admissions interleaved with decoding are a pure
+  scheduling matter, so every request of a schedule (greedy AND seeded
+  sampling) yields exactly the tokens it yields with the engine to
+  itself — for every composition the one program serves, on the three
+  cache routes, f32 and int8 KV;
+- under mixed load no stream starves or deadlocks, and every step that
+  carries prompt tokens while a slot decodes also advances every
+  decoding row (decode rows ride every step);
 - host-interactive slots (grammar constraints, logit-bias bans) keep
-  draining the pipeline correctly through mixed dispatches.
+  draining the pipeline correctly through mixed steps.
 """
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
-from localai_tfp_tpu.engine.engine import GenRequest, LLMEngine
+from localai_tfp_tpu.engine.engine import GenRequest, LLMEngine, SlotState
 from localai_tfp_tpu.engine.tokenizer import ByteTokenizer
 from localai_tfp_tpu.models.llm_spec import tiny_spec
 from localai_tfp_tpu.models.transformer import init_params
@@ -29,12 +30,15 @@ from localai_tfp_tpu.telemetry.registry import REGISTRY
 @pytest.fixture(scope="module")
 def model():
     tk = ByteTokenizer()
-    spec = tiny_spec(vocab_size=tk.vocab_size, max_position=512)
+    # kernel-eligible shapes (kv_dim % 128 == 0) so forcing the kernel
+    # is the only thing between the gather route and the ragged one
+    spec = tiny_spec(vocab_size=tk.vocab_size, max_position=512,
+                     n_heads=4, n_kv_heads=2, d_head=64)
     params = init_params(jax.random.PRNGKey(1), spec, dtype=jnp.float32)
     return spec, params, tk
 
 
-def _engine(model, mixed=True, **kw):
+def _engine(model, **kw):
     spec, params, tk = model
     kw.setdefault("n_slots", 4)
     kw.setdefault("max_seq", 256)
@@ -42,19 +46,18 @@ def _engine(model, mixed=True, **kw):
     kw.setdefault("cache_dtype", jnp.float32)
     kw.setdefault("autostart", True)
     eng = LLMEngine(spec, params, tk, **kw)
-    eng._mixed = mixed  # pre-dispatch override of LOCALAI_MIXED_DISPATCH
     # prefix reuse is timing-dependent (WHICH donor is resident when a
     # request admits varies with scheduling interleave) and orthogonal
-    # to the on/off comparison this file makes — disable it so byte-
-    # identity isolates the dispatch fusion itself
+    # to what this file compares — disable it so token identity
+    # isolates the admission step itself
     eng._prefix_enabled = False
     return eng
 
 
 class DispatchSpy:
     """Wraps engine._run recording, per dispatch, its kind plus the
-    decode-row/prefill-token composition of mixed payloads and the
-    slot states at enqueue time — the scheduling ground truth."""
+    decode-row/prompt-row composition of mixed payloads and the slot
+    states at enqueue time — the scheduling ground truth."""
 
     def __init__(self, eng):
         self.eng = eng
@@ -68,15 +71,14 @@ class DispatchSpy:
                "decoding": sum(1 for s in self.eng.slots
                                if s.state.name == "DECODE")}
         if kind == "mixed":
-            sample = payload["sample_sids"]
-            prefill = payload["prefill_sids"]
-            rec["decode_rows"] = int(sum(
-                1 for i in range(S)
-                if int(sample[i]) < S and int(prefill[i]) >= S))
-            rec["prefill_tokens"] = int(sum(
-                int(c) for sid, c in zip(prefill, payload["n_chunk"])
-                if int(sid) < S))
+            member = payload["slot_ids"] < S
+            rec["shape"] = payload["toks"].shape
+            rec["decode_rows"] = int(payload["active"].sum())
+            rec["prompt_rows"] = int(member.sum())
+            rec["chunks"] = int((member & ~payload["final"]).sum())
+            rec["prefill_tokens"] = int(payload["n_chunk"][member].sum())
             rec["masked"] = payload["masks"] is not None
+            rec["carry"] = payload["carry"]
         self.records.append(rec)
         return self._orig(kind, payload)
 
@@ -115,124 +117,188 @@ def _first_token(q, timeout=120):
             return ev
 
 
-def _schedule_requests(tk):
-    """The fixed request set: two long-lived streams and a burst of
-    three (one prompt longer than the largest bucket, so it needs a
-    non-final chunk). Prompts diverge at their FIRST characters:
-    shared leading tokens would legitimately engage slot-resident
-    prefix reuse, whose donor choice is interleave-dependent — not
-    what on/off compares."""
-    return {
-        "a": GenRequest(
-            prompt_ids=tk.encode("stream alpha stays live"), max_tokens=40,
-            temperature=0.9, top_k=12, seed=7, ignore_eos=True),
-        "b": GenRequest(
-            prompt_ids=tk.encode("stream beta stays live too"),
-            max_tokens=40, temperature=0.7, top_p=0.9, seed=11,
-            ignore_eos=True),
-        "c": GenRequest(prompt_ids=tk.encode("one burst request " * 9),
-                        max_tokens=6, temperature=0.8, seed=3,
-                        ignore_eos=True),
-        "d": GenRequest(prompt_ids=tk.encode("two burst request"),
-                        max_tokens=6, ignore_eos=True),
-        # longer than the largest bucket (128): needs a non-final chunk
-        "e": GenRequest(prompt_ids=tk.encode("three burst request " * 10),
-                        max_tokens=6, temperature=0.6, seed=5,
-                        ignore_eos=True),
-    }
+# ------------------------------------------------------------ the oracle
 
+# Prompts diverge at their FIRST characters: shared leading tokens
+# would legitimately engage slot-resident prefix reuse, whose donor
+# choice is interleave-dependent — not what the oracle compares.
+_REQUESTS = {
+    # long-lived streams
+    "d1": dict(prompt="alpha stream stays live", max_tokens=40,
+               temperature=0.9, top_k=12, seed=7),
+    "d2": dict(prompt="beta stream stays live too", max_tokens=40,
+               temperature=0.7, top_p=0.9, seed=11),
+    "d3": dict(prompt="gamma stream, the greedy one", max_tokens=40),
+    # admissions: bucket 32, bucket 128, longer than the largest
+    # bucket (a non-final chunk, then a final), bucket 32 again
+    "p1": dict(prompt="one burst request", max_tokens=6),
+    "p2": dict(prompt="two burst request " * 5, max_tokens=6,
+               temperature=0.8, seed=3),
+    "p3": dict(prompt="three burst request " * 10, max_tokens=6,
+               temperature=0.6, seed=5),
+    "p4": dict(prompt="four, the last of the burst", max_tokens=6),
+}
+
+# composition -> (rows decoding when the wave lands, the wave, the
+# group-token budget or None for the default)
+_COMPOSITIONS = {
+    "no_row_decodes": ((), ("p1", "p2", "p4"), None),
+    "one_prompt_beside_the_rest": (("d1", "d2", "d3"), ("p1",), None),
+    "the_rest_beside_one": (("d1",), ("p1", "p2", "p4"), None),
+    "two_buckets_one_wave": (("d1", "d2"), ("p1", "p2"), None),
+    "chunks_then_a_final": (("d1",), ("p3", "p1"), None),
+    "wave_over_the_token_cap": (("d1",), ("p1", "p2", "p4"), 64),
+}
+
+_ROUTES = {
+    "ragged": {"LOCALAI_DECODE_KERNEL": "1"},
+    "gather": {},
+    "dense": {"LOCALAI_PAGED_KV": "off"},
+}
+
+
+def _request(tk, name):
+    kw = dict(_REQUESTS[name])
+    return GenRequest(prompt_ids=tk.encode(kw.pop("prompt")),
+                      ignore_eos=True, **kw)
+
+
+def _pump(eng, until):
+    """Drive the scheduler from this thread until ``until()``."""
+    for _ in range(200000):
+        if until():
+            return
+        eng.step()
+    raise AssertionError("engine stalled")
+
+
+def _forget(eng):
+    """Drop every slot's resident prefix, so a request served a second
+    time on this engine is prefilled again in full."""
+    assert not eng._has_work()
+    for s in eng.slots:
+        s.cache_tokens = []
+        s.n_past = 0
+        if eng._paged:
+            eng._pool.drop(s.idx)
+
+
+def _serve(eng, tk, decoders, wave):
+    """Start ``decoders``, let each emit its first token, land ``wave``
+    as one submit, run everything to its end. Returns {name: tokens}."""
+    fin = FinishSpy(eng)
+    try:
+        reqs = {n: _request(tk, n) for n in (*decoders, *wave)}
+        if decoders:
+            eng.submit_many([reqs[n] for n in decoders])
+            _pump(eng, lambda: sum(
+                s.state is SlotState.DECODE for s in eng.slots)
+                == len(decoders))
+        eng.submit_many([reqs[n] for n in wave])
+        _pump(eng, lambda: not eng._has_work())
+    finally:
+        eng._finish = fin._orig
+    return {n: fin.generated[r.id] for n, r in reqs.items()}
+
+
+@pytest.fixture(scope="module", params=[
+    (r, d) for r in _ROUTES for d in ("f32", "int8")],
+    ids=lambda p: f"{p[0]}-{p[1]}")
+def routed(request, model):
+    """One engine per (route, KV dtype), driven by the test's own
+    thread, with each request's solo tokens: (engine, solo)."""
+    route, dtype = request.param
+    mp = pytest.MonkeyPatch()
+    for k, v in _ROUTES[route].items():
+        mp.setenv(k, v)
+    try:
+        eng = _engine(model, autostart=False, cache_dtype=(
+            jnp.float32 if dtype == "f32" else jnp.int8))
+    finally:
+        mp.undo()
+    assert eng.attention_path == {
+        "ragged": "ragged_paged_kernel", "gather": "paged_xla_gather",
+        "dense": "dense_xla"}[route]
+    solo = {}
+    for name in _REQUESTS:
+        _forget(eng)
+        solo.update(_serve(eng, model[2], (), (name,)))
+    yield eng, solo
+    eng.close()
+
+
+@pytest.mark.parametrize("composition", list(_COMPOSITIONS))
+def test_interleaved_schedule_yields_each_requests_solo_tokens(
+        model, routed, composition):
+    """The reference the one admission path is held to: every request
+    of the schedule yields exactly the tokens it yields when it has the
+    engine to itself, whatever rode the step beside it."""
+    eng, solo = routed
+    decoders, wave, cap = _COMPOSITIONS[composition]
+    _forget(eng)
+    spy = DispatchSpy(eng)
+    budget = eng._prefill_group_tokens
+    if cap is not None:
+        eng._prefill_group_tokens = cap
+    try:
+        got = _serve(eng, model[2], decoders, wave)
+    finally:
+        eng._run = spy._orig
+        eng._prefill_group_tokens = budget
+    for name, toks in got.items():
+        assert toks == solo[name], f"stream {name} diverged"
+    # the composition really ran: ONE kind admits, beside the rows
+    # that decode, at the shape the wave asks for
+    kinds = {r["kind"] for r in spy.records}
+    assert kinds <= {"mixed", "decodek"}, kinds
+    landed = [r for r in spy.mixed() if r["prompt_rows"]
+              and r["decode_rows"] >= len(decoders)]
+    assert landed and landed[0]["decode_rows"] == len(decoders), landed
+    if composition == "no_row_decodes":
+        assert landed[0]["prompt_rows"] == 3  # one dispatch, the wave
+        assert landed[0]["shape"] == (4, 128)
+    elif composition == "one_prompt_beside_the_rest":
+        assert landed[0]["shape"] == (4, 32)  # 17 tokens: under a rung
+    elif composition == "two_buckets_one_wave":
+        assert landed[0]["prompt_rows"] == 2  # no split by bucket
+    elif composition == "chunks_then_a_final":
+        assert any(r["chunks"] for r in landed)
+    elif composition == "wave_over_the_token_cap":
+        assert len(landed) >= 3 and all(
+            r["shape"][0] * r["shape"][1] <= 128 for r in landed)
+    if decoders:
+        # new rows join the carry: the step rode behind the scans in
+        # flight, and scans rode behind it
+        assert any(r["carry"] for r in landed)
+
+
+# ------------------------------------------------- scheduling under load
 
 def _mixed_schedule(eng, tk):
-    """One fixed request schedule: two streams decode, then a burst of
-    three admissions lands mid-stream. Returns {name: (generated token
-    ids, final event)}."""
+    """Two streams decode, then a burst of three admissions lands
+    mid-stream (threaded engine). Returns {name: (tokens, final event)}."""
     fin = FinishSpy(eng)
-    reqs = _schedule_requests(tk)
+    reqs = {n: _request(tk, n) for n in ("d1", "d2", "p2", "p1", "p3")}
     out = {}
-    qa, qb = eng.submit(reqs["a"]), eng.submit(reqs["b"])
+    qa, qb = eng.submit(reqs["d1"]), eng.submit(reqs["d2"])
     _first_token(qa)
     _first_token(qb)  # both rows are committed decoders
-    qs = eng.submit_many([reqs[n] for n in "cde"])
-    for name, q in zip("cde", qs):
+    burst = ("p2", "p1", "p3")
+    qs = eng.submit_many([reqs[n] for n in burst])
+    for name, q in zip(burst, qs):
         out[name] = _drain(q)
-    out["a"] = _drain(qa)
-    out["b"] = _drain(qb)
+    out["d1"] = _drain(qa)
+    out["d2"] = _drain(qb)
     return {n: (fin.generated[reqs[n].id], out[n]) for n in out}
-
-
-def _assert_same_streams(got, want):
-    for name in want:
-        assert got[name][0] == want[name][0], f"stream {name} diverged"
-        assert got[name][1].full_text == want[name][1].full_text
-        assert got[name][1].finish_reason == want[name][1].finish_reason
-
-
-@pytest.fixture(scope="module")
-def solo_streams(model):
-    """Each request of the schedule served with the engine to itself:
-    {name: (generated token ids, final event)}."""
-    eng = _engine(model)
-    try:
-        fin = FinishSpy(eng)
-        out = {}
-        for name, req in _schedule_requests(model[2]).items():
-            ev = eng.generate(req)
-            out[name] = (fin.generated[req.id], ev)
-        return out
-    finally:
-        eng.close()
-
-
-@pytest.mark.parametrize("mixed", [True, False],
-                         ids=["mixed", "alternating"])
-def test_interleaved_schedule_yields_each_requests_solo_tokens(
-        model, solo_streams, mixed):
-    """The reference BOTH admission paths are held to, whatever the
-    flag that chooses between them: admissions interleaved with
-    decoding are a pure scheduling matter, so every request of the
-    schedule (greedy AND seeded sampling) yields exactly the tokens it
-    yields when it has the engine to itself. On/off identity alone
-    would pass if both paths were wrong alike; PR 35 measured the two
-    on the chip and kept both, so a later change to either answers to
-    this."""
-    eng = _engine(model, mixed=mixed)
-    try:
-        spy = DispatchSpy(eng)
-        got = _mixed_schedule(eng, model[2])
-    finally:
-        eng.close()
-    assert bool(spy.mixed()) == mixed
-    _assert_same_streams(got, solo_streams)
-
-
-def test_mixed_on_off_byte_identical(model):
-    """The headline invariant: the fused path is a pure scheduling
-    change — an identical request schedule (greedy AND seeded sampling)
-    yields byte-identical streams with LOCALAI_MIXED_DISPATCH on/off."""
-    spec, params, tk = model
-    eng_off = _engine(model, mixed=False)
-    try:
-        want = _mixed_schedule(eng_off, tk)
-    finally:
-        eng_off.close()
-    eng_on = _engine(model, mixed=True)
-    try:
-        spy = DispatchSpy(eng_on)
-        got = _mixed_schedule(eng_on, tk)
-    finally:
-        eng_on.close()
-    assert spy.mixed(), "fused path never dispatched a mixed step"
-    _assert_same_streams(got, want)
 
 
 def test_mixed_load_no_starvation_decode_priority(model):
     """Decoders active while a burst admits: everything completes (no
-    deadlock), every mixed dispatch carrying prefill tokens while >=1
-    slot decoded also advanced >=1 decode row (decode priority), and
-    prefill NEVER went out on a prefill-only dispatch while a slot was
-    decoding (the mutual exclusion this PR deletes)."""
+    deadlock), every mixed step carrying prompt tokens while >=1 slot
+    decoded also advanced >=1 decode row (decode rows ride every
+    step), and no prompt went out on any other kind."""
     spec, params, tk = model
-    eng = _engine(model, mixed=True)
+    eng = _engine(model)
     snap = REGISTRY.snapshot()
     try:
         spy = DispatchSpy(eng)
@@ -245,22 +311,51 @@ def test_mixed_load_no_starvation_decode_priority(model):
         assert len(gen) == ev.completion_tokens > 0
     carrying = [r for r in spy.mixed()
                 if r["prefill_tokens"] and r["decoding"]]
-    assert carrying, "no mixed dispatch actually fused prefill+decode"
+    assert carrying, "no mixed step actually carried prompts and decode"
     for r in carrying:
         assert r["decode_rows"] >= 1, (
-            "mixed dispatch carried prefill tokens but advanced no "
-            f"decode row: {r}")
-    for r in spy.records:
-        if r["kind"] in ("prefill", "prefill_final"):
-            assert r["decoding"] == 0, (
-                "prefill-only dispatch while a slot was decoding — the "
-                f"legacy mutual exclusion is back: {r}")
+            "mixed step carried prompt tokens but advanced no decode "
+            f"row: {r}")
+    assert {r["kind"] for r in spy.records} <= {"mixed", "decodek"}
     delta = REGISTRY.delta(snap)
     assert delta.get(
         f'engine_mixed_dispatch_total{{model="{m}",'
         f'composition="mixed"}}', 0.0) >= len(carrying)
     assert delta.get(
         f'engine_decode_stall_seconds_count{{model="{m}"}}', 0.0) > 0
+    # the gauge's parts at the dispatch site: a decode row is one real
+    # token, the shape is both groups'
+    real = delta[f'engine_dispatch_tokens_total{{model="{m}",'
+                 f'kind="mixed",part="real"}}']
+    padded = delta[f'engine_dispatch_tokens_total{{model="{m}",'
+                   f'kind="mixed",part="padded"}}']
+    assert real == sum(r["decode_rows"] + r["prefill_tokens"]
+                       for r in spy.mixed())
+    assert padded == sum(eng.n_slots + r["shape"][0] * r["shape"][1]
+                         for r in spy.mixed())
+
+
+def test_a_lone_request_is_not_held(model):
+    """The alternating scheduler's burst hold parked a LONE request
+    0.15 s before its first scan (CHANGES, PR 35); with one admission
+    path nothing sleeps: first token and every scan follow at once."""
+    import time
+
+    spec, params, tk = model
+    eng = _engine(model)
+    try:
+        for warm in ("one burst request", "zulu warms the same shapes"):
+            # compile the shapes it rides (the first sampling dispatch
+            # of a process loads a variant of its own)
+            eng.generate(GenRequest(prompt_ids=tk.encode(warm),
+                                    max_tokens=6, ignore_eos=True))
+        t0 = time.perf_counter()
+        ev = eng.generate(_request(tk, "p4"))
+        dt = time.perf_counter() - t0
+    finally:
+        eng.close()
+    assert ev.finish_reason == "length"
+    assert dt < 0.15, f"a lone request took {dt:.3f} s"
 
 
 # slow tier: grammar + logit-bias through batched rows is tier-1 on
@@ -269,12 +364,12 @@ def test_mixed_load_no_starvation_decode_priority(model):
 def test_grammar_and_logit_bias_ride_mixed_dispatches(model):
     """Host-interactive slots (grammar constraint, logit-bias ban) keep
     draining correctly while another stream decodes: their masks ride
-    the fused dispatch per-row instead of forcing the blocking path."""
+    the mixed step per-row."""
     from localai_tfp_tpu.grammars.native import make_constraint
 
     spec, params, tk = model
     prompt = tk.encode("tool call now")
-    solo = _engine(model, mixed=True)
+    solo = _engine(model)
     try:
         free = solo.generate(GenRequest(prompt_ids=prompt, max_tokens=12,
                                         ignore_eos=True))
@@ -283,7 +378,7 @@ def test_grammar_and_logit_bias_ride_mixed_dispatches(model):
         solo.close()
     assert len(banned) >= 1
 
-    eng = _engine(model, mixed=True)
+    eng = _engine(model)
     try:
         spy = DispatchSpy(eng)
         fin = FinishSpy(eng)
@@ -316,13 +411,12 @@ def test_grammar_and_logit_bias_ride_mixed_dispatches(model):
 
 
 def test_chunked_prompt_prefill_timing_attribution(model):
-    """Satellite: chunked prompts must report real (device) prefill
-    time. _prefill_step only ENQUEUES, so charging its wall time to
-    t_prefill_ms made long prompts report near-zero prompt processing;
-    device time is now attributed at harvest of the covering flight,
-    with the host enqueue cost split into its own field."""
+    """Chunked prompts must report real (device) prefill time: a
+    non-final chunk only ENQUEUES, so device time is attributed at
+    harvest of the covering final's flight, with the host enqueue cost
+    split into its own field."""
     spec, params, tk = model
-    eng = _engine(model, mixed=True)
+    eng = _engine(model)
     try:
         # > largest bucket (128) so the prompt takes the chunked path
         prompt = tk.encode("a long prompt that must chunk " * 8)
@@ -353,7 +447,7 @@ def test_chunked_prompt_prefill_timing_attribution_disagg(model):
     spec, params, tk = model
     saved = os.environ.get("LOCALAI_DISAGG_MIN_PROMPT")
     os.environ["LOCALAI_DISAGG_MIN_PROMPT"] = "64"
-    decode = _engine(model, mixed=True)
+    decode = _engine(model)
     prefill = build_prefill_engine(spec, params, tk, decode=decode,
                                    cache_dtype=jnp.float32)
     router = DisaggRouter(prefill, decode)
@@ -384,10 +478,10 @@ def test_chunked_prompt_prefill_timing_attribution_disagg(model):
 
 
 def test_tokens_per_second_ewma_single_path(model):
-    """Satellite: metrics.tokens_per_second is ONE EWMA across every
-    decode flavor instead of three stores stomping each other with
-    instantaneous single-dispatch rates."""
-    eng = _engine(model, mixed=True, autostart=False)
+    """metrics.tokens_per_second is ONE EWMA across every decode flavor
+    instead of three stores stomping each other with instantaneous
+    single-dispatch rates."""
+    eng = _engine(model, autostart=False)
     try:
         assert eng.metrics.tokens_per_second == 0.0
         eng._note_tokens_per_second(10, 1.0)
@@ -406,9 +500,12 @@ def test_tokens_per_second_ewma_single_path(model):
 def test_mixed_dispatch_payload_is_scalar_only(model):
     """Multihost invariant: the mixed payload must contain only scalar
     host data (numpy arrays / python scalars), never device arrays —
-    followers replay the record like any other dispatch."""
+    followers replay the record like any other dispatch — and only the
+    fields the replay codec knows."""
+    from localai_tfp_tpu.parallel.multihost import PAYLOAD_FIELDS
+
     spec, params, tk = model
-    eng = _engine(model, mixed=True)
+    eng = _engine(model)
     try:
         captured = []
         orig = eng._run
@@ -429,6 +526,7 @@ def test_mixed_dispatch_payload_is_scalar_only(model):
     finally:
         eng.close()
     assert captured
+
     def leaves(x):
         if isinstance(x, dict):
             for v in x.values():
@@ -439,6 +537,9 @@ def test_mixed_dispatch_payload_is_scalar_only(model):
         else:
             yield x
     for p in captured:
+        assert set(p) <= set(PAYLOAD_FIELDS["mixed"])
         for leaf in leaves(p):
             assert not isinstance(leaf, jax.Array), (
                 "device array in mixed payload — not replayable")
+            assert leaf is None or isinstance(
+                leaf, (np.ndarray, np.generic, int, float, bool, str))

@@ -350,7 +350,7 @@ def test_paged_dispatch_payloads_stay_replayable(model, monkeypatch):
         _drain(qa)
     finally:
         eng.close()
-    assert {"prefill_final"} <= {k for k, _ in spy.records}
+    assert {"mixed", "decodek"} <= {k for k, _ in spy.records}
     paged_kinds = set()
     for kind, payload in spy.records:
         if "pt" in payload:

@@ -55,19 +55,11 @@ class RunSpy:
         eng._run = self._run
 
     def _run(self, kind, payload):
-        if kind == "prefill_final":
+        if kind == "mixed":
+            # the prompt group's member rows carry the real prompt
+            # chunk tokens (pad rows hold the slot-id sentinel)
             self.prefill_tokens += int(sum(
                 int(c) for sid, c in zip(payload["slot_ids"],
-                                         payload["n_chunk"])
-                if int(sid) < self.eng.n_slots))
-        elif kind == "prefill":
-            self.prefill_tokens += payload["toks"].shape[1]
-        elif kind == "mixed":
-            # prefill rows of a fused mixed step carry real prompt
-            # chunk tokens too (decode/parked rows are excluded by the
-            # prefill_sids sentinel)
-            self.prefill_tokens += int(sum(
-                int(c) for sid, c in zip(payload["prefill_sids"],
                                          payload["n_chunk"])
                 if int(sid) < self.eng.n_slots))
         elif kind == "kvcopy":

@@ -268,10 +268,9 @@ def test_engine_loads_name_the_full_variant_key(model):
         recent = st["recent"]
         assert st["total"] == len(recent) <= LoadWatch.KEEP
         kinds = [e["kind"] for e in recent]
-        assert "prefill_final" in kinds and "decodek" in kinds
-        dk = [e for e in recent if e["kind"] == "decodek"]
+        assert "mixed" in kinds and "decodek" in kinds
         # the key says what dispatch_key lacks: host inputs or the carry
-        assert all("('carry', " in e["key"] for e in dk)
+        assert all("('carry', " in e["key"] for e in recent)
         assert len({e["key"] for e in recent}) == len(recent)
         assert all(not e["in_warmup"] and e["seconds"] > 0 for e in recent)
     finally:
@@ -288,30 +287,45 @@ def test_variant_key_extends_dispatch_key_without_changing_it():
     assert costmodel.variant_key("decodek", dict(p, carry=False)) != \
         costmodel.variant_key("decodek", p)
     mp = {"toks": np.zeros((4, 8), np.int32), "window": 256,
-          "masks": None, "soft": None}
+          "carry": False, "masks": None, "soft": None}
     assert costmodel.variant_key("mixed", mp) == (
-        "mixed", (4, 8), 256, ("masks", False), ("soft", False))
+        "mixed", (4, 8), 256, ("carry", False), ("masks", False),
+        ("soft", False))
     assert costmodel.variant_key(
-        "mixed", dict(mp, masks=np.ones((4, 3), bool)))[3] == ("masks", True)
+        "mixed", dict(mp, masks=np.ones((8, 3), bool)))[4] == ("masks", True)
 
 
-def test_engine_key_says_when_the_sampler_state_is_still_fresh(model):
+def test_serving_loads_no_program_that_warmup_compiled(model):
     """Found on the chip: one key loaded twice, the two loads' arg_sig
-    differing in the sampler state's committed-ness alone."""
-    import dataclasses
-    import types
-
-    eng = _engine(model, tag="fresh")
+    differing in an argument's committed-ness alone (the sampler state
+    as constructed; a scan's tokens fed by the host or chained on the
+    device carry) — a program lowers again for it. The engine commits
+    what it makes on the host, so warmup's executables are serving's:
+    a stream decoding through chained scans while a burst is admitted
+    loads nothing."""
+    spec, params, tk = model
+    params = jax.device_put(params, jax.devices()[0])  # as a loader's
+    eng = _engine((spec, params, tk), tag="committed", autostart=True)
     try:
-        p = {"k": 8, "window": 256, "depth": 1, "carry": False}
-        base = costmodel.variant_key("decodek", p)
-        for committed, extra in ((False, (("sampling", "fresh"),)),
-                                 (True, ())):
-            eng.sampling = dataclasses.replace(
-                eng.sampling, rng=types.SimpleNamespace(committed=committed))
-            assert eng._variant_key("decodek", p) == base + extra
-            # a kind that never sees the sampler state says nothing
-            assert eng._variant_key("kvcopy", {"n": 256}) == ("kvcopy", 256)
+        assert eng.cache.k.committed and eng.sampling.rng.committed
+        eng.warmup()
+        sizes = {k: f._cache_size() for k, f in eng._decode_k_fns.items()}
+        q0 = eng.submit(GenRequest(prompt_ids=tk.encode("a live stream"),
+                                   max_tokens=60, ignore_eos=True))
+        while q0.get(timeout=60).token_id is None:
+            pass
+        qs = eng.submit_many([
+            GenRequest(prompt_ids=tk.encode("burst one " * 5),
+                       max_tokens=6, ignore_eos=True),
+            GenRequest(prompt_ids=tk.encode("burst two"), max_tokens=6,
+                       ignore_eos=True)])
+        for q in (*qs, q0):
+            _drain(q)
+        assert sizes == {
+            k: f._cache_size() for k, f in eng._decode_k_fns.items()}
+        late = [e for e in eng._loads.stats()["recent"]
+                if not e["in_warmup"]]
+        assert not late, late
     finally:
         eng.close()
 
@@ -353,14 +367,16 @@ def test_mixed_and_kscan_counts_match_hand_computed_values(model):
         _step_until(eng, lambda: any(
             s.state.name == "DECODE" for s in eng.slots))
         (sa,) = [s for s in eng.slots if s.active]
-        # a lone prompt of n tokens rode a prefill_final of one row:
-        # real n, padded 1 x bucket 8, causal context n(n-1)/2
+        # a lone prompt of n tokens rode a mixed step with no row
+        # decoding: real n, padded 4 decode rows + a prompt group of
+        # [4, 8] (under a rung's worth of tokens the row counts merge
+        # into the slot count), causal context n(n-1)/2
         n = len(a.prompt_ids)
-        assert (_tok("prefill_final", "real"),
-                _tok("prefill_final", "padded")) == (n, 8)
-        assert _ctx("prefill_final") == n * (n - 1) // 2
+        assert (_tok("mixed", "real"), _tok("mixed", "padded")) == (
+            n, 4 + 4 * 8)
+        assert _ctx("mixed") == n * (n - 1) // 2
 
-        # B arrives while A decodes: ONE mixed program [4 slots, 8]
+        # B arrives while A decodes: ONE mixed step, behind A's scans
         b = GenRequest(prompt_ids=eng.tokenize("hello!"), max_tokens=200,
                        ignore_eos=True)
         nb = len(b.prompt_ids)
@@ -368,23 +384,37 @@ def test_mixed_and_kscan_counts_match_hand_computed_values(model):
         qb = eng.submit(b)
         _step_until(eng, lambda: any(f.kind == "mixed"
                                      for f in eng._flights))
-        ca = sa.n_past  # A's cache as the mixed step was enqueued
-        assert _tok("mixed", "real") == 1 + nb       # A's row + B's chunk
-        assert _tok("mixed", "padded") == 4 * 8
-        assert _ctx("mixed") == ca + nb * (nb - 1) // 2
+
+        def before(fl):  # positions A's row is ahead by at ``fl``
+            fls = list(eng._flights)
+            at = [f is fl for f in fls].index(True)
+            return sum(f.meta["k"] if f.kind == "decodek" else 1
+                       for f in fls[:at])
+
+        mx = eng._flights[-1]
+        assert mx.kind == "mixed"
+        ca = sa.n_past + before(mx)  # A's cache as the step runs
+        assert _tok("mixed", "real") - n == 1 + nb   # A's row + B's chunk
+        assert _tok("mixed", "padded") == 2 * (4 + 4 * 8)
+        assert _ctx("mixed") - n * (n - 1) // 2 == ca + nb * (nb - 1) // 2
         # so far only A's lone scans: one row a step
         steps0 = _value("engine_decode_steps_total", model="counts")
         real0, pad0, ctx0 = (_tok("decodek", "real"),
                              _tok("decodek", "padded"), _ctx("decodek"))
         assert steps0 == real0 > 0 and pad0 == 4 * real0
 
-        # both decode: the next k-scan has two rows at known contexts
-        _step_until(eng, lambda: any(f.kind == "decodek"
-                                     for f in eng._flights))
-        (fl,) = eng._flights
+        # both decode: the next k-scan, chained behind the step, has
+        # two rows at known contexts — B joined the carry on the device
+        _step_until(eng, lambda: any(
+            f.kind == "decodek" and len(f.meta["pairs"]) == 2
+            for f in eng._flights))
+        fl = eng._flights[-1]
+        # (behind the step if it is still in the air: a chained scan
+        # reads its rows' first tokens from the carry)
+        assert (fl.meta["prev_last"] is None) == (len(eng._flights) > 1)
         k = fl.meta["k"]
-        ctxs = [s.n_past for s in eng.slots if s.active]
-        assert len(ctxs) == 2 and ctxs[0] == ca + 1
+        ctxs = [sa.n_past + before(fl), nb]
+        assert ctxs[0] == ca + 1
         assert _value("engine_decode_steps_total",
                       model="counts") - steps0 == k
         assert _tok("decodek", "real") - real0 == 2 * k

@@ -233,10 +233,10 @@ def test_replayer_joins_leader_trace_ids():
     tid_a, tid_b = mint_trace_id(), mint_trace_id()
     rp = Replayer()
     eng = FakeEngine()
-    rp.exec(eng, "prefill_final", {}, trace=(tid_a,))
+    rp.exec(eng, "mixed", {}, trace=(tid_a,))
     rp.exec(eng, "decodek", {}, trace=(tid_a, tid_b))
     rp.exec(eng, "decodek", {}, trace=(tid_b,))  # a's entry closes here
-    assert calls == ["prefill_final", "decodek", "decodek"]
+    assert calls == ["mixed", "decodek", "decodek"]
 
     rows_a = TRACER.lookup(tid_a)
     assert rows_a and rows_a[0]["request_id"] == "replay:" + tid_a[:16]
@@ -245,7 +245,7 @@ def test_replayer_joins_leader_trace_ids():
     assert rows_a[0]["status"] == "replayed"  # closed on departure
     kinds = [n["kind"] for n in rows_a[0]["span_events"]
              if n["name"] == "replay"]
-    assert kinds == ["prefill_final", "decodek"]
+    assert kinds == ["mixed", "decodek"]
 
     rows_b = TRACER.lookup(tid_b)
     assert rows_b and rows_b[0]["status"] == "active"  # still live

@@ -2,13 +2,13 @@
 
 r5 flight traces: a k=16 decode scan executes in ~450 ms on an idle
 chip (64 slots, int8 weights + int8 KV) — ~28 ms per step vs a ~10 ms
-weight-read roofline — and the [64, 4] prefill_final program takes
+weight-read roofline — and the [64, 4] admission program takes
 ~235 ms. This tool times the pieces in isolation on the real chip:
 
   forward-only scan  : k steps of forward + argmax (no sampler)
   full scan          : the engine's real _decode_k (forward + sampler)
   sampler-only scan  : k sampler calls on fixed logits
-  prefill_final      : the engine's real [64, W] prefill program
+  mixed              : the engine's real [64, W] admission step
 
 Usage: python tools/microbench_step.py
 """
@@ -160,7 +160,7 @@ def main():
     eng.cache = state["cache"]
     eng.sampling = state["sampling"]
 
-    # --- prefill_final [64, 4] (the burst-TTFT floor)
+    # --- the admission step at [64, 4] (the burst-TTFT floor)
     reset = {k: np.asarray(v) for k, v in {
         "temperature": np.full(S, 0.8, np.float32),
         "top_k": np.full(S, 40, np.int32),
@@ -177,7 +177,7 @@ def main():
         "mirostat_tau": np.full(S, 5.0, np.float32),
         "mirostat_eta": np.full(S, 0.1, np.float32),
     }.items()}
-    # decompose prefill_final: forward_hidden vs the sampler tail
+    # decompose the prompt group: forward_hidden vs the sampler tail
     from localai_tfp_tpu.models.transformer import _lm_head, forward_hidden
     from localai_tfp_tpu.ops.sampling import (reset_slots, sample,
                                               seed_windows)
@@ -250,18 +250,22 @@ def main():
             "toks": np.zeros((S, Wp), np.int32),
             "pos0": np.full((S,), 64, np.int32),
             "slot_ids": np.arange(S, dtype=np.int32),
-            "masks": None,
+            "masks": None, "soft": None,
             "n_chunk": np.full((S,), 1, np.int32),
+            "final": np.ones((S,), bool),
             "tails": np.zeros((S, eng.sampling.window), np.int32),
             "tail_lens": np.zeros((S,), np.int32),
             "reset": reset,
-            "window": W,
+            "window": W, "carry": False,
+            "dtoks": np.zeros((S, 1), np.int32),
+            "dpos": np.zeros((S,), np.int32),
+            "active": np.zeros((S,), bool),
         }
 
         def run_pf(payload=payload):
-            return (eng._dev_exec("prefill_final", payload),)
+            return (eng._dev_exec("mixed", payload),)
 
-        timeit(f"prefill_final [64,{Wp}]", run_pf)
+        timeit(f"mixed, no row decoding [{S},{Wp}]", run_pf)
 
 
 if __name__ == "__main__":

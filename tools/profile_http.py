@@ -8,7 +8,7 @@ wave (all times ms relative to the wave's t0):
   built   — PredictOptions ready in the producer thread (template
             render + tokenize done)
   submit  — engine.submit returned (admission queue)
-  prefill — the engine dispatched the wave's prefill_final group(s)
+  prefill — the engine dispatched the wave's mixed step(s)
   harvest — first tokens harvested (bridge put)
   write   — client saw the first CONTENT SSE event (TTFT)
 
@@ -33,7 +33,7 @@ Mixed-dispatch scenario (stall-free prefill+decode fusion):
 
 drives N sustained decode streams and injects K admission bursts of B
 requests mid-stream, with the fused mixed dispatcher ON and OFF
-(LOCALAI_MIXED_DISPATCH) — the headline numbers for the scheduler's
+(the mixed step) — the headline numbers for the scheduler's
 prefill/decode de-serialization: per-stream ITL p50/p95, the **max
 inter-token gap** any live stream saw while a burst was admitting
 (the legacy hold loops spike it to the prefill-group round trip), and
@@ -113,13 +113,11 @@ class _DispatchSpy:
         self.copies = 0
 
     def _run(self, kind, payload):
-        if kind == "prefill_final":
+        if kind == "mixed":
             self.prefill_tokens += int(sum(
                 int(c) for sid, c in zip(payload["slot_ids"],
                                          payload["n_chunk"])
                 if int(sid) < self.eng.n_slots))
-        elif kind == "prefill":
-            self.prefill_tokens += payload["toks"].shape[1]
         elif kind == "kvcopy":
             self.copies += 1
         return self._orig(kind, payload)
@@ -251,10 +249,9 @@ def shared_prefix_scenario(small: bool, n_req: int,
 
 def mixed_scenario(small: bool, n_streams: int, n_bursts: int,
                    burst_size: int) -> None:
-    """Sustained decode streams + admission bursts injected mid-stream,
-    fused mixed dispatch ON vs OFF. Reports per-stream inter-token
-    gaps (client-observed SSE event spacing — exactly the stall the
-    legacy prefill/decode mutual exclusion produced) and burst TTFT."""
+    """Sustained decode streams + admission bursts injected mid-stream
+    through the mixed step. Reports per-stream inter-token gaps
+    (client-observed SSE event spacing) and burst TTFT."""
     from aiohttp import ClientSession, ClientTimeout, TCPConnector, web
 
     from localai_tfp_tpu.server.app import build_app
@@ -352,8 +349,7 @@ def mixed_scenario(small: bool, n_streams: int, n_bursts: int,
                 await asyncio.gather(*streams, *burst_tasks)
                 return times, burst_ttfts
 
-            for mode in ("off", "on"):
-                eng._mixed = (mode == "on")
+            for mode in ("on",):  # one admission path (PR 37)
                 await run_once(f"warm-{mode}")  # untimed: compiles
                 snap = REGISTRY.snapshot()
                 times, burst_ttfts = await run_once(f"run-{mode}")
@@ -376,17 +372,9 @@ def mixed_scenario(small: bool, n_streams: int, n_bursts: int,
                         if k.startswith("engine_mixed_dispatch_total")
                         and 'composition="mixed"' in k)),
                 }
-        on, off = out["on"], out["off"]
         out["summary"] = {
             "streams": n_streams, "bursts": n_bursts,
             "burst_size": burst_size,
-            "max_gap_reduction_ms": round(
-                off["max_gap_max_ms"] - on["max_gap_max_ms"], 1),
-            "itl_p95_reduction_ms": round(
-                off["itl_p95_ms"] - on["itl_p95_ms"], 1),
-            "burst_ttft_ratio_on_vs_off": round(
-                on["burst_ttft_p50_ms"] / off["burst_ttft_p50_ms"], 3)
-            if off["burst_ttft_p50_ms"] else None,
         }
         return out
 
@@ -454,19 +442,19 @@ def main() -> None:
     orig_run = eng._run
 
     def stamped_run(kind, payload):
-        if kind == "prefill_final":
+        if kind == "mixed":
             stamps["prefill"].append(time.perf_counter() - t0_box[0])
         return orig_run(kind, payload)
 
     eng._run = stamped_run
 
-    orig_complete = eng._complete_prefill_final
+    orig_complete = eng._complete_mixed
 
     def stamped_complete(fl):
         stamps["harvest"].append(time.perf_counter() - t0_box[0])
         return orig_complete(fl)
 
-    eng._complete_prefill_final = stamped_complete
+    eng._complete_mixed = stamped_complete
 
     async def drive():
         runner = web.AppRunner(app)

@@ -101,8 +101,8 @@ def instrument_engine():
 
     orig_submit_many = em.LLMEngine.submit_many
     orig_assign = em.LLMEngine._assign
-    orig_enq = em.LLMEngine._enqueue_prefill_final
-    orig_cpf = em.LLMEngine._complete_prefill_final
+    orig_enq = em.LLMEngine._enqueue_mixed
+    orig_cpf = em.LLMEngine._complete_mixed
     orig_harvest = em.LLMEngine._harvest
 
     def _harvest(self):
@@ -110,13 +110,13 @@ def instrument_engine():
         while self._flights and self._flights[0].ready():
             fl = self._flights[0]
             detail = (f"k={fl.meta.get('k')}" if fl.kind == "decodek"
-                      else f"n={len(fl.meta.get('pairs', []))}")
+                      else f"n={len(fl.meta.get('prompt', []))}")
             FLIGHTS.append((fl.kind, detail, fl.t_enqueue,
                             time.perf_counter()))
             # delegate one completion at a time so we time each pop
             fl2 = self._flights.popleft()
-            if fl2.kind == "prefill_final":
-                self._complete_prefill_final(fl2)
+            if fl2.kind == "mixed":
+                self._complete_mixed(fl2)
             else:
                 self._complete_decodek(fl2)
             did = True
@@ -135,23 +135,24 @@ def instrument_engine():
         TL[req.id]["assign"] = time.perf_counter()
         return orig_assign(self, slot, req, out)
 
-    def _enqueue_prefill_final(self, group, bucket):
+    def _enqueue_mixed(self, prefilling):
         t = time.perf_counter()
-        for s in group:
+        for s in prefilling:
             if s.request is not None:
                 TL[s.request.id].setdefault("pf_dispatch", t)
-        return orig_enq(self, group, bucket)
+        return orig_enq(self, prefilling)
 
-    def _complete_prefill_final(self, fl):
+    def _complete_mixed(self, fl):
         t = time.perf_counter()
-        for _, (s, req) in enumerate(fl.meta["pairs"]):
-            TL[req.id]["pf_harvest"] = t
+        for s, req, is_final in fl.meta["prompt"]:
+            if is_final:
+                TL[req.id]["pf_harvest"] = t
         return orig_cpf(self, fl)
 
     em.LLMEngine.submit_many = submit_many
     em.LLMEngine._assign = _assign
-    em.LLMEngine._enqueue_prefill_final = _enqueue_prefill_final
-    em.LLMEngine._complete_prefill_final = _complete_prefill_final
+    em.LLMEngine._enqueue_mixed = _enqueue_mixed
+    em.LLMEngine._complete_mixed = _complete_mixed
 
 
 def pct(xs, p):

@@ -190,7 +190,7 @@ def main():
     def traced2(kind, payload):
         t0 = time.perf_counter()
         shape = None
-        if kind in ("prefill", "prefill_final"):
+        if kind in ("prefill", "mixed"):
             shape = list(payload["toks"].shape)
         out = orig2(kind, payload)
         http_log.append((kind, shape,
