@@ -348,6 +348,33 @@ def _sel_active(active, new, old):
     return jnp.where(a, new, old)
 
 
+def _expert_stats(experts):
+    """A step program's last output: forward_rows' expert statistics,
+    [E + 1] i32, or nothing to read ([0]) for a model without experts —
+    one arity whatever the model."""
+    return jnp.zeros((0,), jnp.int32) if experts is None else experts
+
+
+def _rows_kw(kw: dict) -> tuple[dict, dict]:
+    """A route's forward_kw split into what belongs to a group of rows
+    (``Rows`` fields) and what belongs to the whole pass."""
+    per = {k: v for k, v in kw.items() if k in Rows._fields}
+    return per, {k: v for k, v in kw.items() if k not in per}
+
+
+def _forward_step(spec, params, tokens, pos0, view, active, kw):
+    """One decode step of the whole slot batch through the cache route
+    (``kw``: the route's forward_kw): forward + LM head, with the rows
+    that do not decode marked not live. -> (logits [S, 1, V], view,
+    expert statistics)."""
+    from ..models.transformer import _lm_head
+
+    per, kw = _rows_kw(kw)
+    (hidden,), view, experts = forward_rows(
+        spec, params, (Rows(tokens, pos0, live=active, **per),), view, **kw)
+    return _lm_head(spec, params, hidden), view, _expert_stats(experts)
+
+
 def _sample_masked(sampling, slot_ids, logits, active, masks):
     with jax.named_scope("sample"):
         toks, new_sampling = sample(sampling, slot_ids, logits,
@@ -670,9 +697,11 @@ class LLMEngine:
         # strand its second tree. LOCALAI_WEIGHT_PAGING=off restores
         # the fully-resident path byte-identically (the pager never
         # touches eng.params while hot).
+        # Nor a tree of two layer stacks (spec.n_dense_layers): the
+        # pager's page li is row li of EVERY stacked leaf.
         self._pager = None
         if (channel is None and not follower and draft is None
-                and mesh is None
+                and mesh is None and not spec.n_dense_layers
                 and (knobs.flag("LOCALAI_WEIGHT_PAGING")
                      if weight_paging is None else weight_paging)):
             from .weight_pager import WeightPager
@@ -696,15 +725,16 @@ class LLMEngine:
             # (slot_ids feeds the sampler only), so a dense KV write is
             # a per-row DUS, not a cache-sized scatter
             view = route.open(cache, tables, max_seq)
-            logits, view = forward(
-                spec, params, tokens, pos0, view, **route.forward_kw(
+            logits, view, experts = _forward_step(
+                spec, params, tokens, pos0, view, active,
+                route.forward_kw(
                     tables, jnp.ones(tokens.shape[:1], jnp.int32),
                     decode=True))
             cache = route.close(cache, view, tables)
             last = logits[:, -1, :]
             toks, sampling = _sample_masked(sampling, slot_ids, last,
                                             active, masks)
-            return toks, cache, sampling
+            return toks, cache, sampling, experts
 
         @jax.jit
         def _sample_only(sampling, slot_ids, logits, masks):
@@ -770,6 +800,21 @@ class LLMEngine:
         self._loads = LoadWatch(self._mlabel)
         self._in_warmup = False
         self._tok_ctr: dict = {}  # kind -> its bound counter children
+        self._expert_ctr: dict = {}  # the same for the expert counters
+        self._expert_out: list = []  # expert statistics of the newest
+        # dispatch's programs (_dev_exec), until its flight takes them
+        # the layers that route (every layer of the main stack: a
+        # qwen2_moe dense-only layer still runs its zeroed router)
+        self._n_expert_layers = (
+            spec.n_layers - spec.n_dense_layers if spec.n_experts else 0)
+        # {window: layers that have it}; 0 = full attention
+        from ..models.transformer import _layer_windows
+
+        lw = _layer_windows(spec)
+        self._layer_windows: dict = (
+            {int(spec.sliding_window or 0): spec.n_layers} if lw is None
+            else {int(w): int(n) for w, n in zip(
+                *np.unique(np.asarray(lw), return_counts=True))})
         self._tick_t = 0.0  # last ~1 Hz gauge tick
         # warmup-captured XLA cost model: per-dispatch FLOPs/bytes
         # accounting + the MFU gauge (telemetry/costmodel.py). Host-held
@@ -822,7 +867,6 @@ class LLMEngine:
         (Mosaic compiles on this platform and the shapes qualify). Env
         override: LOCALAI_DECODE_KERNEL=0/1; forcing =1 also allows the
         (slow) interpret path so CPU tests exercise the kernel engine."""
-        from ..models.transformer import _layer_windows
         from ..ops.decode_attention import PAGE, _interpret
 
         env = knobs.str_("LOCALAI_DECODE_KERNEL")
@@ -878,11 +922,9 @@ class LLMEngine:
             return f"kv_dim {self.spec.kv_dim} % 128 != 0"
         if self.spec.attn_logit_softcap:
             return "attn_logit_softcap"
-        # decided HERE, before the route is chosen: forward_hidden keeps
-        # its own gate on this only as a guard (int8 caches qualify:
-        # the kernel reads int8 pages + per-row scales directly)
-        if _layer_windows(self.spec) is not None:
-            return "per-layer sliding windows"
+        # int8 caches qualify (the kernel reads int8 pages + per-row
+        # scales directly), and so do per-layer sliding windows (the
+        # window is the kernel's operand, carried by the layer scan)
         return ""
 
     # ------------------------------------------- paged KV pool (host side)
@@ -1259,32 +1301,32 @@ class LLMEngine:
             from ..models.transformer import _lm_head
             from ..ops.sampling import reset_slots
 
-            def rows(tokens, pos0, row0, q_lens, soft=None, **kw):
+            def rows(tokens, pos0, row0, q_lens, soft=None, live=None,
+                     **kw):
                 # page tables ride as ONE [S + R, pages] pair, the
                 # decode group's rows first: this group's share
                 tabs = tuple(t[row0:row0 + tokens.shape[0]]
                              for t in tables)
-                kw = route.forward_kw(tabs, q_lens, row0=row0, **kw)
-                per = {k: kw.pop(k) for k in tuple(kw)
-                       if k in Rows._fields}
-                return Rows(tokens, pos0, soft=soft, **per), kw
+                per, kw = _rows_kw(
+                    route.forward_kw(tabs, q_lens, row0=row0, **kw))
+                return Rows(tokens, pos0, soft=soft, live=live, **per), kw
 
             # decode group. Parked rows: trash write pages on the pool,
             # a no-op rewrite (write_mask) on the dense cache — their
             # resident prefixes survive whatever position they carry
             dgroup, pass_kw = rows(dtoks, dpos, 0,
                                    jnp.ones((S,), jnp.int32),
-                                   write_mask=active)
+                                   live=active, write_mask=active)
             # prompt group: n_chunk IS the per-row ragged query length
             # (pad rows carry 1); rows map to slots through slot_ids on
             # the dense cache and through the tables on the pool
             if soft is not None:
                 soft = _soft_expand(toks, *soft)
             pgroup, _ = rows(toks, pos0, S, n_chunk, soft=soft,
-                             slot_ids=slot_ids)
+                             live=slot_ids < S, slot_ids=slot_ids)
             # ONE pass: every weight is read once for both groups
             view = route.open(cache, tables, window)
-            (dhidden, hidden), view = forward_rows(
+            (dhidden, hidden), view, experts = forward_rows(
                 spec, params, (dgroup, pgroup), view, **pass_kw)
             cache = route.close(cache, view, tables)
             dsamp, sampling = _sample_masked(
@@ -1312,7 +1354,7 @@ class LLMEngine:
             pos_next = jnp.where(active, dpos + 1, dpos).at[slot_ids].set(
                 pos0 + n_chunk, mode="drop")
             return (jnp.concatenate([dsamp, ptoks]), tok_next, pos_next,
-                    cache, sampling)
+                    cache, sampling, _expert_stats(experts))
 
         self._decode_k_fns[key] = dispatch_mixed
         return dispatch_mixed
@@ -1322,19 +1364,44 @@ class LLMEngine:
     # weight fusions at 16 rows, 17.9 at 144; PERF §6 PR 37) while each
     # decoding row stands still, so a chunk is no longer than this and
     # the decoding rows get a token between chunks; a row ladder finer
-    # than this many tokens buys programs to compile, not time
+    # than this many tokens buys programs to compile, not time. An expert
+    # model's step hardly grows with its rows (the grouped matmul reads
+    # the experts that have tokens, most of them from 144 rows on:
+    # 2.96 ms a layer at 144 rows, 3.65 at 528; PERF §6 PR 38), so its
+    # chunk is four times as long and a prompt holds the rows that
+    # decode for a third of the steps — and that chunk is all the
+    # prompt tokens its STEP takes (_row_ladder): one long prompt a
+    # step, the next one after it. Prompts that ride together get
+    # their first token together, replies of equal length then end
+    # together and are replaced together, groups merge, and what a
+    # token costs hangs on how the slots have grouped (served: 14.1 ms
+    # in a group of four, 14.7 in a pair, 15.3 alone; PERF §6 PR 38);
+    # admitted one after the other they stay a prompt's steps apart
     _STEP_TOKENS = 128
+    _EXPERT_STEP_TOKENS = 512
+
+    @property
+    def _step_tokens(self) -> int:
+        return (self._EXPERT_STEP_TOKENS if self.spec.n_experts
+                else self._STEP_TOKENS)
+
+    # the most tokens one pass of the embeddings path takes (see
+    # _dev_exec "embed"); every prefill bucket above it is its multiple
+    _EMBED_CHUNK = 1024
 
     def _row_ladder(self, bucket: int) -> tuple[int, ...]:
         """Row counts a prompt group of this bucket is padded up to:
         powers of two up to what the group-token budget
-        (LOCALAI_PREFILL_GROUP_TOKENS) and n_slots allow, the small
-        rungs merged into the first one worth a program."""
+        (LOCALAI_PREFILL_GROUP_TOKENS) and n_slots allow — for an
+        expert model one chunk's worth of tokens (_step_tokens) — the
+        small rungs merged into the first one worth a program."""
         cap = max(1, min(self.n_slots,
                          self._prefill_group_tokens // bucket))
+        if self.spec.n_experts:
+            cap = max(1, min(cap, self._step_tokens // bucket))
         rungs, r = [], 1
         while r < cap:
-            if r * bucket >= self._STEP_TOKENS:
+            if r * bucket >= self._step_tokens:
                 rungs.append(r)
             r *= 2
         return (*rungs, cap)
@@ -1342,10 +1409,10 @@ class LLMEngine:
     @property
     def _step_buckets(self) -> tuple[int, ...]:
         """The prefill buckets a step's prompt group takes: up to the
-        first one that holds _STEP_TOKENS."""
+        first one that holds _step_tokens."""
         bs = self.prefill_buckets
         top = next((i for i, b in enumerate(bs)
-                    if b >= self._STEP_TOKENS), len(bs) - 1)
+                    if b >= self._step_tokens), len(bs) - 1)
         return bs[:top + 1]
 
     def _mixed_shape(self, rems: list[int],
@@ -1663,19 +1730,21 @@ class LLMEngine:
 
             def step(carry, _):
                 tokens, pos, view, sampling = carry
-                logits, view = forward(spec, params, tokens, pos, view,
-                                       **kw)
+                logits, view, experts = _forward_step(
+                    spec, params, tokens, pos, view, active, kw)
                 toks, sampling = _sample_masked(
                     sampling, slot_ids, logits[:, -1, :], active, None)
                 pos = jnp.where(active, pos + 1, pos)
-                return (toks[:, None], pos, view, sampling), toks
+                return (toks[:, None], pos, view, sampling), (toks, experts)
 
-            (tok_next, pos_next, view, sampling), toks_seq = lax.scan(
-                step, (tokens, pos0, view, sampling), None, length=k)
+            (tok_next, pos_next, view, sampling), (toks_seq, experts) = \
+                lax.scan(step, (tokens, pos0, view, sampling), None,
+                         length=k)
             # tok_next/pos_next are returned so the next dispatch can
             # chain on device state without a host round trip
             return (toks_seq.T, tok_next, pos_next,
-                    route.close(cache, view, tables), sampling)  # [S, k]
+                    route.close(cache, view, tables), sampling,
+                    jnp.sum(experts, axis=0))  # toks [S, k]
 
         self._decode_k_fns[("decode", k, window)] = dispatch_decodek
         return dispatch_decodek
@@ -1820,7 +1889,8 @@ class LLMEngine:
             fn = self._mixed_fn(p.get("window", self.max_seq))
             cap(fn, *args, soft=soft)
             (toks_out, self._dev_tokens, self._dev_pos, self.cache,
-             self.sampling) = fn(*args, soft=soft)
+             self.sampling, experts) = fn(*args, soft=soft)
+            self._expert_out = [experts]
             if self.draft is not None:
                 # the prompt rows mirror into the draft cache (decode
                 # rows advance without draft writes, exactly as on the
@@ -1836,7 +1906,9 @@ class LLMEngine:
                     self.sampling, jnp.asarray(p["active"]), masks,
                     *tabs()]
             cap(self._decode_fn, *args)
-            toks, self.cache, self.sampling = self._decode_fn(*args)
+            toks, self.cache, self.sampling, experts = self._decode_fn(
+                *args)
+            self._expert_out = [experts]
             return toks
         if kind == "decodek":
             fn = self._decode_k_fn(p["k"], p["window"])
@@ -1849,13 +1921,15 @@ class LLMEngine:
             extra = tabs()
             cap(fn, self.params, tok_dev, self.cache, pos_dev,
                 self._all_slot_ids, self.sampling, act_dev, *extra)
-            batches = []
+            batches, self._expert_out = [], []
             for _ in range(p["depth"]):
-                toks, tok_dev, pos_dev, self.cache, self.sampling = fn(
+                (toks, tok_dev, pos_dev, self.cache, self.sampling,
+                 experts) = fn(
                     self.params, tok_dev, self.cache, pos_dev,
                     self._all_slot_ids, self.sampling, act_dev, *extra,
                 )
                 batches.append(toks)
+                self._expert_out.append(experts)
             self._dev_tokens, self._dev_pos = tok_dev, pos_dev
             return batches
         if kind == "spec":
@@ -1894,13 +1968,23 @@ class LLMEngine:
                 self.cache = fn(self.cache, src, dst)
             return None
         if kind == "embed":
-            cache = KVCache.create(self.spec, 1, p["bucket"],
+            # a long prompt goes through in chunks on its throwaway
+            # cache: one [1, bucket] pass would hold a [heads, bucket,
+            # bucket] f32 score tensor beside the model (2 GB at 4096)
+            bucket = p["bucket"]
+            cache = KVCache.create(self.spec, 1, bucket,
                                    self.cache.k.dtype)
+            toks = jnp.asarray(p["toks"])
+            step = min(bucket, self._EMBED_CHUNK)
             zeros = jnp.zeros((1,), jnp.int32)
-            hidden, _ = self._hidden_fn(
-                self.params, jnp.asarray(p["toks"]), cache, zeros, zeros
-            )
-            return hidden
+            parts = []
+            for off in range(0, bucket, step):
+                hidden, cache = self._hidden_fn(
+                    self.params, toks[:, off:off + step], cache,
+                    zeros + off, zeros)
+                parts.append(hidden)
+            return (parts[0] if len(parts) == 1
+                    else jnp.concatenate(parts, axis=1))
         raise ValueError(f"unknown dispatch record kind: {kind!r}")
 
     # ------------------------------------------------------------------ API
@@ -2759,9 +2843,16 @@ class LLMEngine:
                     self._prefill_step(s)  # enqueue-only, no result
             # every step of the wave at once, chained on the carry: the
             # host's own tick (admit, the KV tier) never stands between
-            # two chunks
+            # two chunks — but what has landed meanwhile is streamed
+            # between them: a 20-chunk prompt's chain takes as long to
+            # enqueue as to run, and held every stream's tokens for it
             did = False
-            while prefilling and self._enqueue_mixed(prefilling):
+            while prefilling:
+                if did:
+                    with self._phases.span("sched:harvest"):
+                        self._harvest()
+                if not self._enqueue_mixed(prefilling):
+                    break
                 did = True
                 prefilling = [s for s in self.slots
                               if s.state is SlotState.PREFILL]
@@ -2910,6 +3001,7 @@ class LLMEngine:
                     self._complete_mixed(fl)
                 else:
                     self._complete_decodek(fl)
+                self._note_expert_stats(fl.kind, *fl.meta["experts"])
             did = True
         return did
 
@@ -3500,7 +3592,7 @@ class LLMEngine:
             "window": window,
         })
         n = len(chunk)
-        self._note_dispatch_tokens("prefill", n, bucket, n * (n - 1) // 2)
+        self._note_dispatch_tokens("prefill", n, bucket, [(0, n)])
         slot.n_past += n
         slot.cache_tokens.extend(chunk)
         if slot.t_prefill_t0 == 0.0:
@@ -3682,16 +3774,16 @@ class LLMEngine:
             payload["wb"] = self._wb_rows(spans, window)
         toks_out = self._run("mixed", payload)
         toks_out.copy_to_host_async()
+        experts = self._take_expert_stats()
         t_disp = time.perf_counter()
         enq_ms = (t_disp - t0) * 1e3
         self._dev_rows = {s.idx: s.request for s in decoding + finals}
         self._dev_window = window
         # a decode row reads its whole cache; a chunk its causal sum
-        context = sum(int(dpos[s.idx]) for s in decoding)
+        context = [(int(dpos[s.idx]), 1) for s in decoding]
         for r, s in enumerate(riding):
             chunk_len = int(n_chunk[r])
-            context += (chunk_len * s.n_past
-                        + chunk_len * (chunk_len - 1) // 2)
+            context.append((s.n_past, chunk_len))
             s.cache_tokens.extend(
                 s.request.prompt_ids[s.n_past: s.n_past + chunk_len])
             s.n_past += chunk_len
@@ -3714,8 +3806,9 @@ class LLMEngine:
             self._note_decode_advance(t_disp)
         ckey = costmodel.dispatch_key("mixed", payload)
         self._flights.append(_Flight(
-            kind="mixed", arrays=[toks_out],
+            kind="mixed", arrays=[toks_out, *experts],
             meta={
+                "experts": (experts, 1),
                 # a decode row's consumed token: the host's, or (carry)
                 # whatever the flight before this one sampled last
                 "decode": [(s, s.request,
@@ -3814,15 +3907,38 @@ class LLMEngine:
                 max(0.0, now - self._last_decode_adv))
         self._last_decode_adv = now
 
+    def _context_tokens(self, rows) -> tuple[float, int]:
+        """(read, held) context tokens of ``rows`` = (pos, n) pairs: a
+        row whose n consecutive queries start at position pos. Held is
+        the causal sum n*pos + n(n-1)/2; read is the mean over layers
+        of what each layer's sliding window (0: none) lets those
+        queries see, min(context, window) a query."""
+        read, held = 0, 0
+        for pos, n in rows:
+            full = n * pos + n * (n - 1) // 2
+            held += full
+            for w, layers in self._layer_windows.items():
+                if w <= 0 or pos + n - 1 <= w:
+                    got = full
+                elif pos >= w:
+                    got = n * w
+                else:  # the first w - pos queries still see everything
+                    m = w - pos
+                    got = m * pos + m * (m - 1) // 2 + (n - m) * w
+                read += got * layers
+        return read / self.spec.n_layers, held
+
     def _note_dispatch_tokens(self, kind: str, real: int, padded: int,
-                              context: int, steps: int = 0) -> None:
+                              rows, steps: int = 0) -> None:
         """Counts taken where the work is dispatched, from host scalars
         the enqueue already holds: token positions that carry work
         against those of the program's shape
         (engine_dispatch_tokens_total{part}), the context tokens the
-        attention rows have to read (engine_attn_context_tokens_total)
-        and, for decode-only programs, the token-steps
-        (engine_decode_steps_total). Children are bound once a kind."""
+        attention rows (``rows``: _context_tokens') have to read and
+        those they hold (engine_attn_context_tokens_total,
+        ..._held_tokens_total) and, for decode-only programs, the
+        token-steps (engine_decode_steps_total). Children are bound
+        once a kind."""
         ctr = self._tok_ctr.get(kind)
         if ctr is None:
             m = self._mlabel
@@ -3832,12 +3948,50 @@ class LLMEngine:
                 tm.ENGINE_DISPATCH_TOKENS.labels(
                     model=m, kind=kind, part="padded"),
                 tm.ENGINE_ATTN_CONTEXT_TOKENS.labels(model=m, kind=kind),
+                tm.ENGINE_ATTN_CONTEXT_HELD_TOKENS.labels(
+                    model=m, kind=kind),
                 tm.ENGINE_DECODE_STEPS.labels(model=m))
+        read, held = self._context_tokens(rows)
         ctr[0].inc(real)
         ctr[1].inc(padded)
-        ctr[2].inc(context)
+        ctr[2].inc(read)
+        ctr[3].inc(held)
         if steps:
-            ctr[3].inc(steps)
+            ctr[4].inc(steps)
+
+    def _take_expert_stats(self) -> list:
+        """The expert statistics of the step programs the newest
+        dispatch ran (one [E + 1] i32 array a program, _dev_exec left
+        them), their copy to the host started; [] for a model without
+        experts."""
+        out, self._expert_out = self._expert_out, []
+        if not self.spec.n_experts:
+            return []
+        for a in out:
+            a.copy_to_host_async()
+        return out
+
+    def _note_expert_stats(self, kind: str, stats: list,
+                           steps: int) -> None:
+        """A harvested dispatch's expert statistics onto the counters:
+        tokens by expert, the expert layer-steps it ran (``steps``
+        token-steps a program) and the experts those touched."""
+        if not stats:
+            return
+        m, E = self._mlabel, self.spec.n_experts
+        ctr = self._expert_ctr.get(kind)
+        if ctr is None:
+            ctr = self._expert_ctr[kind] = (
+                tm.ENGINE_EXPERT_LAYER_STEPS.labels(model=m, kind=kind),
+                tm.ENGINE_EXPERTS_TOUCHED.labels(model=m, kind=kind),
+                [tm.ENGINE_EXPERT_TOKENS.labels(model=m, expert=str(e))
+                 for e in range(E)])
+        # lint: ignore[hot-path-sync] the flight these ride was ready()
+        total = np.sum([np.asarray(a) for a in stats], axis=0)
+        ctr[0].inc(len(stats) * steps * self._n_expert_layers)
+        ctr[1].inc(int(total[E]))
+        for e in np.nonzero(total[:E])[0]:
+            ctr[2][e].inc(int(total[e]))
 
     def _note_ragged_rows(self, kind: str, n: int) -> None:
         """Rows advanced through the unified ragged path by kind
@@ -4135,19 +4289,20 @@ class LLMEngine:
         # n_past + the tokens of dispatches still in flight + j
         self._note_dispatch_tokens(
             "decodek", len(decoding) * k, S * k,
-            sum(k * int(pos0[s.idx]) + k * (k - 1) // 2
-                for s in decoding), steps=k)
+            [(int(pos0[s.idx]), k) for s in decoding], steps=k)
         batches = self._run("decodek", payload)
         toks = batches[0]
         toks.copy_to_host_async()
+        experts = self._take_expert_stats()
         self._dev_rows = {s.idx: s.request for s in decoding}
         self._dev_window = window
         dckey = costmodel.dispatch_key("decodek", payload)
         chained = bool(self._flights)
         self._flights.append(_Flight(
-            kind="decodek", arrays=[toks],
+            kind="decodek", arrays=[toks, *experts],
             meta={
                 "k": k,
+                "experts": (experts, k),
                 "cost": dckey,
                 "pred_ms": (self._costmodel.predict_ms("decodek", dckey)
                             if self._costmodel is not None else None),
@@ -4278,10 +4433,12 @@ class LLMEngine:
                  for s in self.slots], self.max_seq)
         self._note_dispatch_tokens(
             "decode1", len(decoding), S,
-            sum(s.n_past for s in decoding), steps=1)
+            [(s.n_past, 1) for s in decoding], steps=1)
         toks = self._run("decode1", payload)
+        experts = self._take_expert_stats()
         # lint: ignore[hot-path-sync] decode1 IS the blocking path: grammar masks / logit bias need every token on host before the next dispatch
         toks_host = np.asarray(toks)
+        self._note_expert_stats("decode1", experts, 1)
         dt_ms = (time.perf_counter() - t0) * 1e3
         emitted = 0
         for s in decoding:
@@ -4485,6 +4642,8 @@ class LLMEngine:
         ids = self.tokenizer.encode(text, add_bos=True) or [0]
         ids = ids[: self.max_seq]
         bucket = self._bucket(len(ids))
+        if len(ids) > bucket:  # past the last bucket: whole chunks
+            bucket = -(-len(ids) // self._EMBED_CHUNK) * self._EMBED_CHUNK
         toks = np.zeros((1, bucket), np.int32)
         toks[0, : len(ids)] = ids
         hidden = self._run("embed", {"toks": toks, "bucket": bucket})

@@ -18,7 +18,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from .llm_spec import LLMSpec, spec_from_hf_config
-from .transformer import _NON_LAYER_KEYS, Params
+from .transformer import _NON_LAYER_KEYS, DENSE_STACK, Params
+
+
+# leaves kept in float32 whatever the serving dtype (an expert layer's
+# selection bias decides near-ties between router scores)
+F32_LEAVES = ("router_bias",)
 
 
 def load_hf_state(model_dir: str) -> tuple[dict, Callable[[str], np.ndarray], list[str]]:
@@ -285,6 +290,9 @@ def load_params(
         p["lm_head_b"] = _cast(get("lm_head.bias"), dtype)
         return spec, p
 
+    if mt == "afmoe":
+        return spec, {**p, **_load_afmoe(spec, get, prefix, dtype, tcast)}
+
     fused_qkv = lp.format(i=0) + "self_attn.qkv_proj.weight" in names  # phi3
     fused_gate = lp.format(i=0) + "mlp.gate_up_proj.weight" in names
 
@@ -420,6 +428,72 @@ def load_params(
             object.__setattr__(spec, "tie_word_embeddings", True)
 
     return spec, p
+
+
+def _load_afmoe(spec: LLMSpec, get, prefix: str, dtype, tcast) -> dict:
+    """The leaves of an ``afmoe`` checkpoint (arcee Trinity; HF
+    AfmoeForCausalLM) beside the embedding: TWO stacks — the first
+    ``num_dense_layers`` layers (a dense SwiGLU MLP, leaves under
+    ``DENSE_STACK``) and the expert layers (router ``mlp.router.gate``
+    [E, D], selection bias ``mlp.expert_bias`` [E] kept in f32,
+    ``mlp.experts.{e}``, one always-on ``mlp.shared_experts``) — each
+    layer with four norms, q/k norms and the attention output gate
+    ``self_attn.gate_proj``; final norm and an untied head."""
+    E, L, Ld = spec.n_experts, spec.n_layers, spec.n_dense_layers
+
+    def name(i, tail):
+        return f"{prefix}layers.{i}.{tail}.weight"
+
+    def stack_of(layers, experts: bool) -> dict:
+        def vec(tail):
+            return _cast(np.stack([get(name(i, tail)) for i in layers]),
+                         dtype)
+
+        def mat(tail):
+            return tcast(lambda: np.stack(
+                [get(name(i, tail)) for i in layers]))
+
+        def expert_mats(proj):
+            return tcast(lambda: np.stack([np.stack(
+                [get(name(i, f"mlp.experts.{e}.{proj}")) for e in range(E)])
+                for i in layers]))
+
+        out = {
+            "wq": mat("self_attn.q_proj"), "wk": mat("self_attn.k_proj"),
+            "wv": mat("self_attn.v_proj"), "wo": mat("self_attn.o_proj"),
+            "w_attn_gate": mat("self_attn.gate_proj"),
+            "q_norm_w": vec("self_attn.q_norm"),
+            "k_norm_w": vec("self_attn.k_norm"),
+            "ln1_w": vec("input_layernorm"),
+            "ln_post_attn_w": vec("post_attention_layernorm"),
+            "ln2_w": vec("pre_mlp_layernorm"),
+            "ln_post_ffw_w": vec("post_mlp_layernorm"),
+        }
+        if not experts:
+            out.update(w_gate=mat("mlp.gate_proj"), w_up=mat("mlp.up_proj"),
+                       w_down=mat("mlp.down_proj"))
+            return out
+        out.update(
+            router=mat("mlp.router.gate"),
+            # the bias decides the selection in f32, whatever the dtype
+            router_bias=jnp.asarray(np.stack(
+                [np.asarray(get(f"{prefix}layers.{i}.mlp.expert_bias"),
+                            np.float32) for i in layers])),
+            moe_gate=expert_mats("gate_proj"), moe_up=expert_mats("up_proj"),
+            moe_down=expert_mats("down_proj"))
+        if spec.moe_shared_expert:
+            out.update(shared_gate=mat("mlp.shared_experts.gate_proj"),
+                       shared_up=mat("mlp.shared_experts.up_proj"),
+                       shared_down=mat("mlp.shared_experts.down_proj"))
+        return out
+
+    p = stack_of(range(Ld, L), True)
+    if Ld:
+        p.update({DENSE_STACK + k: v
+                  for k, v in stack_of(range(Ld), False).items()})
+    p["final_norm_w"] = _cast(get(f"{prefix}norm.weight"), dtype)
+    p["lm_head"] = tcast(lambda: get("lm_head.weight"))
+    return p
 
 
 def layer_pages(host_tree: dict, n_layers: int):

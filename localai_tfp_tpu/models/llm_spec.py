@@ -57,6 +57,19 @@ class LLMSpec:
     # use a plain dense MLP (stored in the shared-expert slots, gate
     # forced to 1, expert weights zeroed) instead of the sparse mixture
     moe_dense_layers: tuple[int, ...] = ()
+    # afmoe routing: scores are sigmoid(router·x) instead of a softmax;
+    # a per-expert selection bias (param "router_bias") is added to the
+    # scores for the top-k CHOICE only, never to the weight; the
+    # combined routed output is scaled by moe_route_scale
+    moe_score_func: str = "softmax"  # softmax | sigmoid
+    moe_select_bias: bool = False
+    moe_route_scale: float = 1.0
+    # False (afmoe): the shared expert has no gate of its own (always 1)
+    moe_shared_gated: bool = True
+    # afmoe num_dense_layers: the first n layers carry a plain dense MLP
+    # of width d_ff and live in a stack of their own ("dense.*" leaves,
+    # [n, ...]), scanned before the expert stack ([n_layers - n, ...])
+    n_dense_layers: int = 0
 
     # biases
     qkv_bias: bool = False  # qwen2, phi
@@ -70,6 +83,8 @@ class LLMSpec:
     final_norm: bool = True
     qk_norm: bool = False  # qwen3: per-head RMSNorm on q/k before rope
     sandwich_norms: bool = False  # gemma2/3: post-attn + pre/post-ffw norms
+    # afmoe: attention output times sigmoid(W_g h) before the o projection
+    attn_output_gate: bool = False
 
     # scaling oddities
     embedding_multiplier: float = 1.0  # gemma: sqrt(d_model)
@@ -87,6 +102,9 @@ class LLMSpec:
     layer_types: Optional[tuple[str, ...]] = None
     # gemma3: sliding layers rope on a separate (local) base frequency
     rope_local_base_freq: float = 0.0
+    # afmoe: rotary on sliding layers only; full layers carry no
+    # positional encoding
+    rope_sliding_only: bool = False
 
     extra: dict = field(default_factory=dict)
 
@@ -215,6 +233,40 @@ def spec_from_hf_config(cfg: dict[str, Any]) -> LLMSpec:
             # config.json, but the HF CLASS default for an omitted key is
             # False — mirror that so omitted-key configs stay bit-parity
             moe_norm_topk=bool(cfg.get("norm_topk_prob", False)),
+        )
+    elif mt == "afmoe":
+        # arcee Trinity (HF AfmoeForCausalLM): sigmoid-scored top-k
+        # experts chosen under a selection bias, weights renormalised
+        # and scaled; one always-on shared expert without a gate; the
+        # first num_dense_layers layers dense; four norms a layer; a
+        # sigmoid gate on the attention output; q/k norms; rotary on
+        # the sliding layers only; muP embedding scale
+        n_shared = int(cfg.get("num_shared_experts") or 0)
+        moe_ff = int(cfg.get("moe_intermediate_size") or d_ff)
+        if int(cfg.get("n_group") or 1) != 1 \
+                or int(cfg.get("topk_group") or 1) != 1:
+            raise NotImplementedError(
+                "afmoe with grouped expert selection (n_group / "
+                "topk_group != 1) is not supported yet")
+        kw.update(
+            qk_norm=True,
+            sandwich_norms=True,
+            attn_output_gate=True,
+            rope_sliding_only=True,
+            embedding_multiplier=(float(d_model) ** 0.5
+                                  if cfg.get("mup_enabled") else 1.0),
+            n_experts=int(cfg.get("num_experts") or 0),
+            experts_per_token=int(cfg.get("num_experts_per_tok") or 1),
+            moe_d_ff=moe_ff,
+            moe_shared_expert=n_shared > 0,
+            moe_shared_d_ff=moe_ff * max(n_shared, 1),
+            moe_shared_gated=False,
+            moe_norm_topk=bool(cfg.get("route_norm", True)),
+            moe_score_func=str(cfg.get("score_func") or "sigmoid"),
+            moe_select_bias=True,
+            moe_route_scale=float(cfg.get("route_scale") or 1.0),
+            n_dense_layers=min(int(cfg.get("num_dense_layers") or 0),
+                               n_layers),
         )
     elif mt == "phi":
         kw.update(

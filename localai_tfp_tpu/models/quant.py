@@ -32,7 +32,15 @@ class QTensor(NamedTuple):
 # stacked projection leaves worth quantizing (the decode bandwidth hogs);
 # MoE/shared-expert stacks are excluded: routing is precision-sensitive
 # and their einsums contract the expert dim separately
-QUANTIZABLE = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+QUANTIZABLE = ("wq", "wk", "wv", "wo", "w_attn_gate", "w_gate", "w_up",
+               "w_down")
+
+
+def quantizable(name: str) -> bool:
+    """Whether a parameter leaf is one of the projection stacks above —
+    in the main stack or in the leading dense layers' own
+    (models/transformer.py ``DENSE_STACK``)."""
+    return name.removeprefix("dense.") in QUANTIZABLE
 
 
 def quantize_tensor(w: jax.Array) -> QTensor:
@@ -79,8 +87,8 @@ def quantize_params(params: dict[str, Any],
     an 8B: the difference between batch 16 and batch 64 serving on one
     16 GB chip). Everything else passes through untouched."""
     out = dict(params)
-    for name in QUANTIZABLE:
-        if name in out and not isinstance(out[name], QTensor):
+    for name in out:
+        if quantizable(name) and not isinstance(out[name], QTensor):
             out[name] = quantize_tensor(out[name])
     if embeddings:
         if not isinstance(out.get("embed"), QTensor):

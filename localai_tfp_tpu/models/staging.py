@@ -33,8 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import knobs
-from .hf_loader import DeferredT
-from .quant import QTensor, QUANTIZABLE, quantize_raw_tensor
+from .hf_loader import F32_LEAVES, DeferredT
+from .quant import QTensor, quantizable, quantize_raw_tensor
 
 
 def _per_layer(fn, x: jax.Array):
@@ -207,7 +207,6 @@ def commit_deferred(
     """
     from .quant import quantize_embed
 
-    quant_names = set(QUANTIZABLE) if quantize else set()
     out: dict[str, Any] = {}
     jq = _jit_quant(dtype)
     jswap = _jit_swap(dtype)
@@ -274,7 +273,7 @@ def commit_deferred(
                 with timed("transfer_s"):
                     x = jax.device_put(raw, device)
                     del raw, leaf
-                    if name in quant_names or (
+                    if (quantize and quantizable(name)) or (
                         name == "lm_head" and quantize
                         and quantize_embeddings
                     ):
@@ -297,7 +296,8 @@ def commit_deferred(
                         and not isinstance(x, QTensor)):
                     out[name] = jax.jit(quantize_embed, donate_argnums=0)(
                         x.astype(dtype))
-                elif hasattr(x, "astype") and not isinstance(x, QTensor):
+                elif (hasattr(x, "astype") and not isinstance(x, QTensor)
+                      and name not in F32_LEAVES):
                     out[name] = jcast(x) if x.dtype != dtype else x
                 else:
                     out[name] = x

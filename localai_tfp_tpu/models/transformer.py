@@ -35,6 +35,11 @@ from .quant import mm as _mm  # plain or int8-QTensor matmul
 
 Params = dict[str, jax.Array]
 
+# Leaves of the LEADING dense-MLP layers of an expert model
+# (LLMSpec.n_dense_layers) carry this prefix and a leading dim of their
+# own: two homogeneous stacks, scanned in turn (``layer_stacks``)
+DENSE_STACK = "dense."
+
 
 @dataclass
 class KVCache:
@@ -176,54 +181,73 @@ def init_params(
         scale = scale or 1.0 / math.sqrt(shape[-2] if len(shape) > 1 else 1)
         return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
 
-    L, D, F, V = spec.n_layers, spec.d_model, spec.d_ff, spec.vocab_size
-    p: Params = {
-        "embed": dense(next(keys), (V, D), 0.02),
-        "wq": dense(next(keys), (L, D, spec.q_dim)),
-        "wk": dense(next(keys), (L, D, spec.kv_dim)),
-        "wv": dense(next(keys), (L, D, spec.kv_dim)),
-        "wo": dense(next(keys), (L, spec.q_dim, D)),
-        "ln1_w": jnp.ones((L, D), dtype),
-    }
-    if spec.n_experts:
-        E = spec.n_experts
-        Fm = spec.moe_d_ff or F
-        p["router"] = dense(next(keys), (L, D, E), 0.02)
-        p["moe_gate"] = dense(next(keys), (L, E, D, Fm))
-        p["moe_up"] = dense(next(keys), (L, E, D, Fm))
-        p["moe_down"] = dense(next(keys), (L, E, Fm, D))
-        if spec.moe_shared_expert:
-            Fs = spec.moe_shared_d_ff or F
-            p["shared_gate"] = dense(next(keys), (L, D, Fs))
-            p["shared_up"] = dense(next(keys), (L, D, Fs))
-            p["shared_down"] = dense(next(keys), (L, Fs, D))
-            p["shared_router"] = dense(next(keys), (L, D), 0.02)
-    else:
-        p["w_up"] = dense(next(keys), (L, D, F))
-        p["w_down"] = dense(next(keys), (L, F, D))
-        if spec.gated_mlp:
-            p["w_gate"] = dense(next(keys), (L, D, F))
-    if not spec.parallel_residual:
-        p["ln2_w"] = jnp.ones((L, D), dtype)
-    if spec.qk_norm:
-        p["q_norm_w"] = jnp.ones((L, spec.d_head), dtype)
-        p["k_norm_w"] = jnp.ones((L, spec.d_head), dtype)
-    if spec.sandwich_norms:
-        p["ln_post_attn_w"] = jnp.ones((L, D), dtype)
-        p["ln_post_ffw_w"] = jnp.ones((L, D), dtype)
-    if spec.norm_type == "layernorm":
-        p["ln1_b"] = jnp.zeros((L, D), dtype)
-        if "ln2_w" in p:
-            p["ln2_b"] = jnp.zeros((L, D), dtype)
-    if spec.qkv_bias:
-        p["bq"] = jnp.zeros((L, spec.q_dim), dtype)
-        p["bk"] = jnp.zeros((L, spec.kv_dim), dtype)
-        p["bv"] = jnp.zeros((L, spec.kv_dim), dtype)
-    if spec.o_bias:
-        p["bo"] = jnp.zeros((L, D), dtype)
-    if spec.mlp_bias:
-        p["b_up"] = jnp.zeros((L, F), dtype)
-        p["b_down"] = jnp.zeros((L, D), dtype)
+    D, F, V = spec.d_model, spec.d_ff, spec.vocab_size
+    Ld = spec.n_dense_layers if spec.n_experts else 0
+
+    def stack(L, experts, keys):
+        """One homogeneous stack of L layers: attention, norms, and a
+        dense or an expert MLP."""
+        p = {
+            "wq": dense(next(keys), (L, D, spec.q_dim)),
+            "wk": dense(next(keys), (L, D, spec.kv_dim)),
+            "wv": dense(next(keys), (L, D, spec.kv_dim)),
+            "wo": dense(next(keys), (L, spec.q_dim, D)),
+            "ln1_w": jnp.ones((L, D), dtype),
+        }
+        if spec.attn_output_gate:
+            p["w_attn_gate"] = dense(next(keys), (L, D, spec.q_dim))
+        if experts:
+            E = spec.n_experts
+            Fm = spec.moe_d_ff or F
+            p["router"] = dense(next(keys), (L, D, E), 0.02)
+            if spec.moe_select_bias:
+                p["router_bias"] = (jax.random.normal(
+                    next(keys), (L, E), jnp.float32) * 0.02)
+            p["moe_gate"] = dense(next(keys), (L, E, D, Fm))
+            p["moe_up"] = dense(next(keys), (L, E, D, Fm))
+            p["moe_down"] = dense(next(keys), (L, E, Fm, D))
+            if spec.moe_shared_expert:
+                Fs = spec.moe_shared_d_ff or F
+                p["shared_gate"] = dense(next(keys), (L, D, Fs))
+                p["shared_up"] = dense(next(keys), (L, D, Fs))
+                p["shared_down"] = dense(next(keys), (L, Fs, D))
+                if spec.moe_shared_gated:
+                    p["shared_router"] = dense(next(keys), (L, D), 0.02)
+        else:
+            p["w_up"] = dense(next(keys), (L, D, F))
+            p["w_down"] = dense(next(keys), (L, F, D))
+            if spec.gated_mlp:
+                p["w_gate"] = dense(next(keys), (L, D, F))
+        if not spec.parallel_residual:
+            p["ln2_w"] = jnp.ones((L, D), dtype)
+        if spec.qk_norm:
+            p["q_norm_w"] = jnp.ones((L, spec.d_head), dtype)
+            p["k_norm_w"] = jnp.ones((L, spec.d_head), dtype)
+        if spec.sandwich_norms:
+            p["ln_post_attn_w"] = jnp.ones((L, D), dtype)
+            p["ln_post_ffw_w"] = jnp.ones((L, D), dtype)
+        if spec.norm_type == "layernorm":
+            p["ln1_b"] = jnp.zeros((L, D), dtype)
+            if "ln2_w" in p:
+                p["ln2_b"] = jnp.zeros((L, D), dtype)
+        if spec.qkv_bias:
+            p["bq"] = jnp.zeros((L, spec.q_dim), dtype)
+            p["bk"] = jnp.zeros((L, spec.kv_dim), dtype)
+            p["bv"] = jnp.zeros((L, spec.kv_dim), dtype)
+        if spec.o_bias:
+            p["bo"] = jnp.zeros((L, D), dtype)
+        if spec.mlp_bias and not experts:
+            p["b_up"] = jnp.zeros((L, F), dtype)
+            p["b_down"] = jnp.zeros((L, D), dtype)
+        return p
+
+    p: Params = {"embed": dense(next(keys), (V, D), 0.02)}
+    p.update(stack(spec.n_layers - Ld, bool(spec.n_experts), keys))
+    if Ld:
+        # keys of its own: what the main stack draws stays what it drew
+        dkeys = iter(jax.random.split(jax.random.fold_in(rng, 1), 16))
+        p.update({DENSE_STACK + k: v
+                  for k, v in stack(Ld, False, dkeys).items()})
     if spec.final_norm:
         p["final_norm_w"] = jnp.ones((D,), dtype)
         if spec.norm_type == "layernorm":
@@ -400,11 +424,16 @@ _NON_LAYER_KEYS = ("embed", "final_norm_w", "final_norm_b", "lm_head",
                    "lm_head_b")
 
 
-def _layer_body(spec, x, lp, positions, inv_freq, rope_scale, attn_fn):
+def _layer_body(spec, x, lp, positions, inv_freq, rope_scale, attn_fn,
+                valid=None, experts=None):
     """One transformer layer, shared by the serving (KV-cached), training
     (cache-free) and Pallas-kernel decode paths. ``attn_fn(q, k, v) ->
     (attn [B, T, H*Dh], carry)`` owns both where K/V live and the
-    attention contraction."""
+    attention contraction. ``valid`` [B, T] bool marks the positions
+    that carry a token (None: all): an expert layer routes only those.
+    ``experts``: the stack's WHOLE expert matrices and this layer's
+    place in them (``_moe_mlp``), where a layer scan calls this.
+    Returns (x, carry, per-expert token counts [E] i32 | None)."""
     B, T = x.shape[0], x.shape[1]
     h = _norm(spec, x, lp["ln1_w"], lp.get("ln1_b"))
     q = _mm(h, lp["wq"])
@@ -419,9 +448,15 @@ def _layer_body(spec, x, lp, positions, inv_freq, rope_scale, attn_fn):
         q = _norm(spec, q, lp["q_norm_w"], None)
         k = _norm(spec, k, lp["k_norm_w"], None)
     inv_f = lp.get("_inv_freq", inv_freq)  # gemma3: dual rope bases
-    q = apply_rope(q, positions, inv_f, spec.rotary_dim, rope_scale)
-    k = apply_rope(k, positions, inv_f, spec.rotary_dim, rope_scale)
-    attn, carry = attn_fn(q, k, v)
+    qr = apply_rope(q, positions, inv_f, spec.rotary_dim, rope_scale)
+    kr = apply_rope(k, positions, inv_f, spec.rotary_dim, rope_scale)
+    if "_rope_on" in lp:  # afmoe: no positional encoding on full layers
+        on = lp["_rope_on"] > 0
+        qr, kr = jnp.where(on, qr, q), jnp.where(on, kr, k)
+    attn, carry = attn_fn(qr, kr, v)
+    if "w_attn_gate" in lp:  # afmoe: sigmoid gate on the heads' output
+        attn = attn * jax.nn.sigmoid(
+            _mm(h, lp["w_attn_gate"]).astype(jnp.float32)).astype(attn.dtype)
     attn = _mm(attn, lp["wo"])
     if "bo" in lp:
         attn = attn + lp["bo"]
@@ -431,8 +466,9 @@ def _layer_body(spec, x, lp, positions, inv_freq, rope_scale, attn_fn):
     if not spec.parallel_residual:
         x = x + attn
         mlp_in = _norm(spec, x, lp["ln2_w"], lp.get("ln2_b"))
-    if "router" in lp:  # mixture of experts (mixtral)
-        mlp = _moe_mlp(spec, lp, mlp_in)
+    counts = None
+    if "router" in lp:  # mixture of experts
+        mlp, counts = _moe_mlp(spec, lp, mlp_in, valid, experts)
     else:
         up = _mm(mlp_in, lp["w_up"])
         if "b_up" in lp:
@@ -447,53 +483,122 @@ def _layer_body(spec, x, lp, positions, inv_freq, rope_scale, attn_fn):
     if "ln_post_ffw_w" in lp:  # gemma2 sandwich
         mlp = _norm(spec, mlp, lp["ln_post_ffw_w"], None)
     out = (x + attn + mlp) if spec.parallel_residual else (x + mlp)
-    return out, carry
+    return out, carry, counts
 
 
-def _moe_mlp(spec, lp, x):
-    """Top-k mixture of experts (ref: the reference serves Mixtral/Qwen-MoE
-    via its vLLM/llama.cpp backends). Dense formulation: every expert is
-    evaluated and combined with the top-k router weights — exact,
-    compiler-friendly, and correct for any k; a dispatch/capacity kernel
-    is the planned optimization for large E (dense costs E/k extra FLOPs).
-    Router math in f32 (routing is precision-sensitive).
+def _route(spec, lp, x):
+    """Router of an expert layer in f32 (routing is precision-
+    sensitive): x [N, D] -> (expert ids [N, K] i32, weights [N, K] f32).
+
+    - softmax, renormalised (mixtral, qwen3_moe): softmax over the k
+      largest logits;
+    - softmax, raw (qwen2_moe norm_topk_prob=false): the k largest of
+      the probabilities over all E, as they are;
+    - sigmoid (afmoe): the k largest of sigmoid(logits) + the selection
+      bias; the weight is the score WITHOUT the bias, divided by the
+      selected scores' sum where the spec renormalises.
+    The weights carry the routed scale."""
+    K = spec.experts_per_token
+    logits = jnp.einsum(
+        "nd,de->ne", x.astype(jnp.float32),
+        lp["router"].astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,  # near-tie routing must not be
+        # decided by bf16 truncation (same convention as _attend)
+    )
+    if spec.moe_score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        choose = scores
+        if "router_bias" in lp:
+            choose = scores + lp["router_bias"].astype(jnp.float32)
+        _, idx = lax.top_k(choose, K)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+        if spec.moe_norm_topk:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    elif spec.moe_norm_topk:
+        vals, idx = lax.top_k(logits, K)
+        w = jax.nn.softmax(vals, axis=-1)  # renormalize over the selected k
+    else:
+        w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), K)
+    if spec.moe_route_scale != 1.0:
+        w = w * spec.moe_route_scale
+    return idx.astype(jnp.int32), w
+
+
+# an expert layer's matrices: never sliced out of their [n, E, ...] stack
+EXPERT_LEAVES = ("moe_gate", "moe_up", "moe_down")
+
+
+def _moe_mlp(spec, lp, x, valid, experts):
+    """Top-k mixture of experts as a ROUTED dispatch: only the experts
+    that have tokens are read, and each token costs k expert MLPs, not E.
+
+    router (f32) -> top-k -> the N*K (token, expert) assignments sorted
+    by expert -> one grouped matmul per projection over the sorted rows
+    (``lax.ragged_dot``: on TPU XLA's own grouped-matmul kernel, ops
+    named ``ragged-dot*`` in a capture; group e = expert e's rows) ->
+    back to token order -> weighted sum over k, in f32 -> + shared
+    expert. ``valid`` [B, T] bool: positions that carry no token are
+    routed nowhere (they sort past the last group and read no expert).
+    Returns (out [B, T, D], tokens per expert [E] i32).
+
+    ``experts`` = (the stack's EXPERT_LEAVES as [n, E, ...] arrays, this
+    layer's index in them): inside a layer scan the grouped matmul takes
+    the WHOLE stack as n * E groups of which only this layer's E have
+    rows — a kernel's operand cannot be a slice of the stack without
+    XLA copying the slice out first (0.5 GB a matrix a layer at 128
+    experts of 2048 x 1024; the attention kernel takes the whole cache
+    and a layer scalar for the same reason).
 
     qwen2_moe extras: a shared expert scaled by sigmoid(x·g) added to the
     mixture, un-renormalized top-k weights (norm_topk_prob=false), and
     dense-only layers (``_dense_only`` flag) where the shared slot holds a
     plain MLP whose gate is forced to 1 and the expert term is dropped."""
     E, K = spec.n_experts, spec.experts_per_token
-    logits = jnp.einsum(
-        "btd,de->bte", x.astype(jnp.float32),
-        lp["router"].astype(jnp.float32),
-        precision=lax.Precision.HIGHEST,  # near-tie routing must not be
-        # decided by bf16 truncation (same convention as _attend)
-    )
-    if spec.moe_norm_topk:
-        vals, idx = lax.top_k(logits, K)  # [B,T,K]
-        w = jax.nn.softmax(vals, axis=-1)  # renormalize over the selected k
-    else:
-        probs = jax.nn.softmax(logits, axis=-1)
-        w, idx = lax.top_k(probs, K)  # raw probabilities, sum < 1
-    gate = jnp.sum(jax.nn.one_hot(idx, E, dtype=jnp.float32)
-                   * w[..., None], axis=-2)  # [B,T,E]
-    g = jnp.einsum("btd,edf->btef", x, lp["moe_gate"])
-    u = jnp.einsum("btd,edf->btef", x, lp["moe_up"])
-    y = jnp.einsum("btef,efd->bted", _act(spec, g) * u, lp["moe_down"])
-    out = jnp.einsum("bted,bte->btd", y, gate.astype(y.dtype))
+    B, T, D = x.shape
+    N = B * T
+    xf = x.reshape(N, D)
+    idx, w = _route(spec, lp, xf)
+    flat = idx.reshape(N * K)
+    if valid is not None:
+        # the sentinel E sorts last and is counted in no group
+        flat = jnp.where(jnp.repeat(valid.reshape(N), K), flat, E)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)  # [N*K]
+    counts = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
+    xs = xf[order // K]  # [N*K, D] rows in expert order
+    whole, li = experts
+    w_gate, w_up, w_down = (
+        whole[k].reshape(-1, *whole[k].shape[2:]) for k in EXPERT_LEAVES)
+    sizes = lax.dynamic_update_slice(
+        jnp.zeros((w_gate.shape[0],), jnp.int32), counts, (li * E,))
+    g = lax.ragged_dot(xs, w_gate, sizes)
+    u = lax.ragged_dot(xs, w_up, sizes)
+    y = lax.ragged_dot((_act(spec, g) * u).astype(x.dtype),
+                       w_down, sizes)  # [N*K, D]
+    if valid is not None:
+        # rows past the last group are whatever the kernel left there
+        y = jnp.where(
+            jnp.arange(N * K, dtype=jnp.int32)[:, None] < jnp.sum(counts),
+            y, 0)
+    inv = jnp.zeros((N * K,), jnp.int32).at[order].set(
+        jnp.arange(N * K, dtype=jnp.int32))
+    out = jnp.einsum("nkd,nk->nd",
+                     y[inv].reshape(N, K, D).astype(jnp.float32), w)
+    out = out.reshape(B, T, D)
     if "shared_gate" in lp:
         s = (_act(spec, x @ lp["shared_gate"]) * (x @ lp["shared_up"])) \
             @ lp["shared_down"]
-        sg = jax.nn.sigmoid(jnp.einsum(
-            "btd,d->bt", x.astype(jnp.float32),
-            lp["shared_router"].astype(jnp.float32),
-        ))[..., None]  # [B,T,1]
+        sg = 1.0
+        if "shared_router" in lp:  # qwen2_moe: the shared expert's gate
+            sg = jax.nn.sigmoid(jnp.einsum(
+                "btd,d->bt", x.astype(jnp.float32),
+                lp["shared_router"].astype(jnp.float32),
+            ))[..., None]  # [B,T,1]
         dense_only = lp.get("_dense_only")  # per-layer scalar via the scan
         if dense_only is not None:
             sg = jnp.where(dense_only > 0, 1.0, sg)
             out = out * (1.0 - dense_only)
         out = out + s.astype(jnp.float32) * sg
-    return out.astype(x.dtype)
+    return out.astype(x.dtype), counts
 
 
 def _layer_dense_only(spec) -> Optional[jnp.ndarray]:
@@ -543,6 +648,45 @@ def _layer_inv_freqs(spec):
     )
     global_ = rope_inv_freq(spec)
     return jnp.stack([local if s else global_ for s in sliding])
+
+
+def _layer_rope_on(spec):
+    """[L] i32 flags for models that rotate on their sliding layers only
+    (afmoe: full-attention layers carry no positional encoding); None
+    when every layer rotates."""
+    sliding = _layer_is_sliding(spec)
+    if sliding is None or not spec.rope_sliding_only:
+        return None
+    return jnp.asarray([1 if s else 0 for s in sliding], jnp.int32)
+
+
+def layer_stacks(spec, params) -> list:
+    """The model's homogeneous layer stacks in layer order, each as
+    (index of its first layer, its layers n, {leaf: [n, ...]} to scan
+    over, {expert leaf: [n, E, ...]} kept whole — ``_moe_mlp``): the
+    leading dense-MLP layers of an expert model (``DENSE_STACK``
+    leaves) when it has them, then every other layer. What differs by
+    layer INSIDE a stack rides along as per-layer values (``_window``,
+    ``_inv_freq``, ``_rope_on``, ``_dense_only``), sliced to the
+    stack's layers."""
+    per_layer = {"_window": _layer_windows(spec),
+                 "_inv_freq": _layer_inv_freqs(spec),
+                 "_rope_on": _layer_rope_on(spec),
+                 "_dense_only": _layer_dense_only(spec)}
+    per_layer = {k: v for k, v in per_layer.items() if v is not None}
+    main = {k: params[k] for k in params
+            if k not in _NON_LAYER_KEYS and not k.startswith(DENSE_STACK)}
+    lead = {k[len(DENSE_STACK):]: params[k] for k in params
+            if k.startswith(DENSE_STACK)}
+    n_lead = spec.n_dense_layers if lead else 0
+    whole = {k: main.pop(k) for k in EXPERT_LEAVES if k in main}
+    stacks = []
+    if lead:
+        stacks.append((0, n_lead, {**lead, **{
+            k: v[:n_lead] for k, v in per_layer.items()}}, {}))
+    stacks.append((n_lead, spec.n_layers - n_lead, {**main, **{
+        k: v[n_lead:] for k, v in per_layer.items()}}, whole))
+    return stacks
 
 
 def _embed_in(spec, params, tokens):
@@ -600,6 +744,10 @@ class Rows(NamedTuple):
     page_table: Optional[jax.Array] = None
     q_lens: Optional[jax.Array] = None
     write_table: Optional[jax.Array] = None
+    live: Optional[jax.Array] = None  # [B] bool: rows that carry a
+    # token this pass (None: all). What a parked row computes is thrown
+    # away, so an expert layer routes it nowhere and reads no expert
+    # for it; nothing else looks at this.
 
 
 def forward_hidden(
@@ -660,7 +808,7 @@ def forward_hidden(
     full slot batch. Writes the new K/V into ``cache`` at rows ``slot_ids``
     columns ``pos0 + [0..T)``.
     """
-    (x,), cache = forward_rows(
+    (x,), cache, _ = forward_rows(
         spec, params,
         (Rows(tokens, pos0, slot_ids, soft, write_mask, page_table,
               q_lens, write_table),),
@@ -679,9 +827,13 @@ def forward_rows(
     mesh: Any = None,
     ring_prefill: bool = False,
     kv_page: int = 0,
-) -> tuple[tuple, KVCache]:
+) -> tuple[tuple, KVCache, Optional[jax.Array]]:
     """``forward_hidden`` for one or more rectangles of rows in ONE
-    pass: returns (one hidden [B, T, D] per group, updated cache).
+    pass: returns (one hidden [B, T, D] per group, updated cache,
+    expert statistics [E + 1] i32 — the tokens each expert took summed
+    over the expert layers, then in the last place the experts that had
+    a token, summed over those layers — or None for a model without
+    experts).
 
     With more than one group the rows ride the layer's matmuls as one
     flat ``[1, sum(B*T), D]`` batch — each weight is read from HBM once
@@ -727,32 +879,41 @@ def forward_rows(
     page_table = g0.page_table
     inv_freq = rope_inv_freq(spec)
     rope_scale = rope_attn_scale(spec)
-    stacked = {k: params[k] for k in params if k not in _NON_LAYER_KEYS}
-    win = _layer_windows(spec)
-    if win is not None:
-        stacked = {**stacked, "_window": win}
-    freqs = _layer_inv_freqs(spec)
-    if freqs is not None:
-        stacked = {**stacked, "_inv_freq": freqs}
-    dense_only = _layer_dense_only(spec)
-    if dense_only is not None:
-        stacked = {**stacked, "_dense_only": dense_only}
     quant = cache.quantized  # int8 rows + per-row scales
 
-    def body(carry, scanned):
+    def valid_of(g):
+        # the positions of a group that carry a token: within the row's
+        # ragged length, on a live row
+        b, t = g.tokens.shape
+        ok = jnp.ones((b, t), bool)
+        if g.q_lens is not None:
+            ok &= jnp.arange(t, dtype=jnp.int32)[None] < g.q_lens[:, None]
+        if g.live is not None:
+            ok &= g.live[:, None]
+        return ok
+
+    valid = None  # only an expert layer asks which rows are real
+    if spec.n_experts and any(g.q_lens is not None
+                              or g.live is not None for g in groups):
+        valid = (valid_of(g0) if single else jnp.concatenate(
+            [valid_of(g).reshape(1, -1) for g in groups], axis=1))
+
+    def body(whole, carry, scanned):
         # cache rides as the scan CARRY (not xs/ys): XLA aliases loop
         # carries in place, so the per-layer update is a true in-place
         # write of the touched rows. As xs/ys the whole cache would be
         # copied through the ys stack every step (~GBs/step read+write at
         # serving shapes — measured 3-4x the decode roofline on v5e).
         x, ck_all, cv_all, ks_all, vs_all = carry
-        l, lp = scanned
-        # a page table IS the ragged route; per-layer windows never get
-        # here (LLMEngine._kernel_ineligible rules them out first)
-        use_ragged = page_table is not None and win is None
+        l, li, lp = scanned
+        # a page table IS the ragged route. The layer's window rides the
+        # scan as a scalar (0 = full attention) into the kernels, a
+        # uniform one is the spec's own number
+        window = lp.get("_window", spec.sliding_window)
+        use_ragged = page_table is not None
         use_kernel = use_ragged or (
             decode_kernel and single and g0.slot_ids is None
-            and x.shape[1] == 1 and win is None)
+            and x.shape[1] == 1)
         if use_kernel:
             ck = cv = ks = vs = None  # kernel addresses the full cache
         else:
@@ -818,7 +979,7 @@ def forward_rows(
                     vs_all if quant else None,
                     l, g.page_table, g.write_table, pos0, q_lens,
                     spec.n_kv_heads, scale=scale, page=kv_page,
-                    sliding_window=spec.sliding_window,
+                    window=window,
                 )
                 return res[0].astype(x.dtype), tuple(res[1:])
             tpos = pos0[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
@@ -843,7 +1004,7 @@ def forward_rows(
             out = ragged_paged_attention(
                 q, ck_new, cv_new, l, g.page_table, pos0, q_lens,
                 spec.n_kv_heads, scale=scale, page=kv_page,
-                sliding_window=spec.sliding_window,
+                window=window,
                 cache_k_scale=ks_new, cache_v_scale=vs_new,
                 seed_kv=seed,
             )  # [B, T, H*Dh]
@@ -891,7 +1052,7 @@ def forward_rows(
                     ks_all if quant else None,
                     vs_all if quant else None,
                     l, pos0, spec.n_kv_heads, scale=scale,
-                    sliding_window=spec.sliding_window,
+                    window=window,
                 )
                 return (res[0][:, None, :].astype(x.dtype),
                         tuple(res[1:]))
@@ -909,7 +1070,7 @@ def forward_rows(
             out = fused_decode_attention(
                 q[:, 0], kf, vf, ck_new, cv_new, l, pos0 + 1,
                 spec.n_kv_heads, scale=scale,
-                sliding_window=spec.sliding_window,
+                window=window,
                 cache_k_scale=ks_new, cache_v_scale=vs_new,
             )
             if quant:
@@ -1057,8 +1218,9 @@ def forward_rows(
             return (jnp.concatenate(outs, axis=1),
                     st if quant else st[:2])
 
-        x, out = _layer_body(
-            spec, x, lp, positions, inv_freq, rope_scale, attn_fn)
+        x, out, counts = _layer_body(
+            spec, x, lp, positions, inv_freq, rope_scale, attn_fn, valid,
+            (whole, li) if whole else None)
         if use_kernel:
             # the fused kernel updated the FULL stacked cache in place
             if quant:
@@ -1075,16 +1237,23 @@ def forward_rows(
             ck2, cv2 = out
             ck_all = lax.dynamic_update_index_in_dim(ck_all, ck2, l, 0)
             cv_all = lax.dynamic_update_index_in_dim(cv_all, cv2, l, 0)
-        return (x, ck_all, cv_all, ks_all, vs_all), None
+        return (x, ck_all, cv_all, ks_all, vs_all), counts
 
-    layer_idx = jnp.arange(spec.n_layers, dtype=jnp.int32)
-    (x, new_k, new_v, new_ks, new_vs), _ = lax.scan(
-        body,
-        (x, cache.k, cache.v,
-         cache.k_scale if quant else jnp.zeros((), jnp.float32),
-         cache.v_scale if quant else jnp.zeros((), jnp.float32)),
-        (layer_idx, stacked),
-    )
+    # the stacks in turn, the cache's layer index running through them
+    carry = (x, cache.k, cache.v,
+             cache.k_scale if quant else jnp.zeros((), jnp.float32),
+             cache.v_scale if quant else jnp.zeros((), jnp.float32))
+    expert_tokens = None
+    for first, n, stacked, whole in layer_stacks(spec, params):
+        carry, counts = lax.scan(
+            partial(body, whole), carry,
+            (jnp.arange(first, first + n, dtype=jnp.int32),
+             jnp.arange(n, dtype=jnp.int32), stacked))
+        if counts is not None:  # [n, E] of an expert stack
+            expert_tokens = jnp.concatenate([
+                jnp.sum(counts, axis=0),
+                jnp.sum(counts > 0, dtype=jnp.int32)[None]])
+    x, new_k, new_v, new_ks, new_vs = carry
     if quant:
         new_cache = KVCache(k=new_k, v=new_v, k_scale=new_ks,
                             v_scale=new_vs)
@@ -1093,7 +1262,8 @@ def forward_rows(
 
     if spec.final_norm:
         x = _norm(spec, x, params["final_norm_w"], params.get("final_norm_b"))
-    return ((x,) if single else tuple(ungroup(x))), new_cache
+    return (((x,) if single else tuple(ungroup(x))), new_cache,
+            expert_tokens)
 
 
 def forward(
@@ -1147,27 +1317,20 @@ def forward_train(
     )
     inv_freq = rope_inv_freq(spec)
     rope_scale = rope_attn_scale(spec)
-    stacked = {k: params[k] for k in params if k not in _NON_LAYER_KEYS}
-    win = _layer_windows(spec)
-    if win is not None:
-        stacked = {**stacked, "_window": win}
-    freqs = _layer_inv_freqs(spec)
-    if freqs is not None:
-        stacked = {**stacked, "_inv_freq": freqs}
-    dense_only = _layer_dense_only(spec)
-    if dense_only is not None:
-        stacked = {**stacked, "_dense_only": dense_only}
 
-    @jax.checkpoint
-    def body(x, lp):
-        x, _ = _layer_body(
+    def body(whole, x, scanned):
+        li, lp = scanned
+        x, _, _ = _layer_body(
             spec, x, lp, positions, inv_freq, rope_scale,
             lambda q, k, v: (
                 _attend(spec, q, k, v, positions, lp.get("_window")), None),
+            experts=(whole, li) if whole else None,
         )
         return x, None
 
-    x, _ = lax.scan(body, x, stacked)
+    for _, n, stacked, whole in layer_stacks(spec, params):
+        x, _ = lax.scan(jax.checkpoint(partial(body, whole)), x,
+                        (jnp.arange(n, dtype=jnp.int32), stacked))
     if spec.final_norm:
         x = _norm(spec, x, params["final_norm_w"], params.get("final_norm_b"))
     return _lm_head(spec, params, x)

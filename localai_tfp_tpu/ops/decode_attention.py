@@ -93,7 +93,7 @@ def fused_decode_attention(
     n_kv_heads: int,
     *,
     scale: float,
-    sliding_window: Optional[int] = None,
+    window=None,  # the layer's sliding window (ragged_paged_attention's)
     cache_k_scale: Optional[jax.Array] = None,  # [L, S, SEQ] f32 when the
     # cache is int8 (per-row symmetric scales — models/transformer.py
     # _quantize_rows; ref: llama.cpp cache_type_k/v q8_0)
@@ -125,7 +125,7 @@ def fused_decode_attention(
         q[:, None, :, :], cache_k, cache_v, layer, page_table,
         jnp.maximum(lengths - 1, 0), jnp.ones_like(lengths),
         n_kv_heads, scale=scale, page=PAGE,
-        sliding_window=sliding_window,
+        window=window,
         cache_k_scale=cache_k_scale, cache_v_scale=cache_v_scale,
         seed_kv=(new_k, new_v),
     )
@@ -173,7 +173,7 @@ def sharded_append_attend(
     n_kv_heads: int,
     *,
     scale: float,
-    sliding_window: Optional[int] = None,
+    window=None,
 ) -> tuple:
     """Append + ragged attend under ``shard_map`` on a ("data", "model")
     serving mesh — the meshed counterpart of the caller-side scatter +
@@ -194,6 +194,7 @@ def sharded_append_attend(
         BATCH_SPEC, DENSE_Q_SPEC, DENSE_ROW_SPEC, DENSE_SCALE_SPEC,
         KV_CACHE_SPEC, REPLICATED,
     )
+    from .ragged_paged_attention import _window_operand
 
     tp = mesh.shape.get("model", 1)
     quant = cache_k_scale is not None
@@ -209,10 +210,10 @@ def sharded_append_attend(
         row_spec, row_spec,  # new_k, new_v
         row_spec, row_spec,  # kq_row, vq_row
         cache_spec, cache_spec,  # cache_k, cache_v
-        REPLICATED, BATCH_SPEC,  # layer, pos0
+        REPLICATED, REPLICATED, BATCH_SPEC,  # layer, window, pos0
     ]
     operands = [q, new_k, new_v, kq_row, vq_row, cache_k, cache_v,
-                layer, pos0]
+                layer, _window_operand(window), pos0]
     if quant:
         in_specs += [scale_row_spec, scale_row_spec,
                      scale_cache_spec, scale_cache_spec]
@@ -222,7 +223,7 @@ def sharded_append_attend(
     else:
         out_specs = (row_spec, cache_spec, cache_spec)
 
-    def body(q_l, nk_l, nv_l, kq_l, vq_l, ck, cv, lay, p0,
+    def body(q_l, nk_l, nv_l, kq_l, vq_l, ck, cv, lay, win, p0,
              ksr=None, vsr=None, ksc=None, vsc=None):
         B = q_l.shape[0]
         rows = jnp.arange(B, dtype=jnp.int32)
@@ -235,7 +236,7 @@ def sharded_append_attend(
             vsc = vsc.at[lay, rows, p0].set(vsr, mode="promise_in_bounds")
         out = fused_decode_attention(
             q_l, nk_l, nv_l, ck, cv, lay, p0 + 1, n_kv_local,
-            scale=scale, sliding_window=sliding_window,
+            scale=scale, window=win[0],
             cache_k_scale=ksc if quant else None,
             cache_v_scale=vsc if quant else None,
         )

@@ -439,7 +439,7 @@ SMALL = Geometry(n_heads=4, n_kv_heads=2, d_head=128, page=16,
 
 
 def check_serving_rows(geom: Geometry, kind: str, cache: str,
-                       seed: int = 0) -> float:
+                       seed: int = 0, window: "int | None" = None) -> float:
     """Max abs error of ONE ragged kernel invocation at the engine's
     shapes against the dense oracle, for one dispatch kind:
 
@@ -455,7 +455,9 @@ def check_serving_rows(geom: Geometry, kind: str, cache: str,
     ``cache`` is the arena dtype: "bf16" and "int8" under a bf16 model
     (queries and seed rows bf16), "f32" for an f32 model end to end.
     The arena sits behind a shuffled page table with the trash page in
-    every unallocated entry."""
+    every unallocated entry. ``window``: the layer's sliding window,
+    handed to the kernel as a layer scan hands it — a traced scalar
+    operand (0 = full attention, which must equal ``None``)."""
     from ..models.transformer import _quantize_rows
     from .ragged_paged_attention import (
         ragged_attention_reference, ragged_paged_attention,
@@ -517,10 +519,11 @@ def check_serving_rows(geom: Geometry, kind: str, cache: str,
                         act),
             jnp.asarray(rng.standard_normal((B, F), np.float32) * 0.5,
                         act))
-    got = ragged_paged_attention(
+    got = jax.jit(lambda w: ragged_paged_attention(
         q.astype(act), ak, av, layer, pt_j, pos_j, len_j, n_kv,
-        scale=scale, page=page, cache_k_scale=ks, cache_v_scale=vs,
-        seed_kv=seed_kv)
+        scale=scale, page=page, window=w, cache_k_scale=ks,
+        cache_v_scale=vs, seed_kv=seed_kv))(
+            None if window is None else jnp.asarray(window, jnp.int32))
     if not bool(jnp.all(jnp.isfinite(got))):
         return float("inf")  # pad queries are garbage, never non-finite
 
@@ -528,7 +531,8 @@ def check_serving_rows(geom: Geometry, kind: str, cache: str,
     def oracle(q1, ak, av, ks, vs, pt1, pos1, len1, seed1):
         return ragged_attention_reference(
             q1, ak, av, 1, pt1, pos1, len1, n_kv, scale=scale,
-            page=page, cache_k_scale=ks, cache_v_scale=vs, seed_kv=seed1)
+            page=page, window=window or None, cache_k_scale=ks,
+            cache_v_scale=vs, seed_kv=seed1)
 
     err = 0.0
     for b in range(B):  # one row at a time: the oracle materializes
@@ -633,6 +637,14 @@ def run_kernel_checks(geom: Geometry = SERVING) -> dict[str, Any]:
             check_serving_rows(geom, kind, "bf16"), _TOL_FP)
         leg(f"serving_{kind}_int8_max_err",
             check_serving_rows(geom, kind, "int8"), _TOL_INT8)
+    # a layer with a sliding window (the kernel's operand): the pages
+    # wholly below it are skipped, the boundary falls inside a page
+    win = geom.max_seq // 2 + geom.page // 2
+    for kind in ("decode", "mixed"):
+        leg(f"serving_{kind}_window_max_err",
+            check_serving_rows(geom, kind, "bf16", window=win), _TOL_FP)
+        leg(f"serving_{kind}_window_int8_max_err",
+            check_serving_rows(geom, kind, "int8", window=win), _TOL_INT8)
     # an f32 model (dtype: float32): f32 queries, seed rows and pages
     for kind in ("decode", "mixed"):
         leg(f"serving_{kind}_f32_max_err",

@@ -101,10 +101,10 @@ def _q_tiling(T: int, n_heads: int, group: int) -> tuple[int, int]:
     return tq_cap, -(-T // tq_cap) * tq_cap
 
 
-def _ragged_kernel(*refs, scale: float, sliding_window: Optional[int],
-                   page: int, tq: int, group: int, n_kv_heads: int,
-                   d_head: int, quantized: bool, seeded: bool):
-    qlen_ref, pos_ref, layer_ref, pt_ref, q_ref, *rest = refs
+def _ragged_kernel(*refs, scale: float, page: int, tq: int, group: int,
+                   n_kv_heads: int, d_head: int, quantized: bool,
+                   seeded: bool):
+    qlen_ref, pos_ref, layer_ref, win_ref, pt_ref, q_ref, *rest = refs
     if seeded:
         newk_ref, newv_ref, *rest = rest
     ck_in, cv_in, *rest = rest
@@ -114,6 +114,7 @@ def _ragged_kernel(*refs, scale: float, sliding_window: Optional[int],
     b = pl.program_id(0)
     t0 = pl.program_id(1) * tq  # first query token of this block
     layer = layer_ref[0]
+    window = win_ref[0]  # this layer's sliding window; 0 = full attention
     qlen = qlen_ref[b]
     p0 = pos_ref[b]
     R = tq * group  # query rows per kv head: row = t_local*group + g
@@ -130,13 +131,12 @@ def _ragged_kernel(*refs, scale: float, sliding_window: Optional[int],
     t_end = jnp.minimum(qlen, t0 + tq)
     n_hbm = p0 + t_end - (1 if seeded else 0)
     n_pages = jnp.where(t_end > t0, lax.div(n_hbm + page - 1, page), 0)
-    if sliding_window is not None:
-        # pages wholly below the block's EARLIEST query window are never
-        # read; the per-query mask below handles the ragged boundary
-        first_page = lax.div(
-            jnp.maximum(p0 + t0 + 1 - sliding_window, 0), page)
-    else:
-        first_page = 0
+    # pages wholly below the block's EARLIEST query window are never
+    # read; the per-query mask below handles the ragged boundary
+    first_page = jnp.where(
+        window > 0, lax.div(jnp.maximum(p0 + t0 + 1 - window, 0), page), 0)
+    # the last position BELOW each query's window (none: -1)
+    below = jnp.where(window > 0, qpos - window, -1)  # [R, 1]
 
     def band(h):
         return slice(h * d_head, (h + 1) * d_head)
@@ -206,9 +206,7 @@ def _ragged_kernel(*refs, scale: float, sliding_window: Optional[int],
         vp.wait()
         kvrow = p * page + jax.lax.broadcasted_iota(
             jnp.int32, (R, page), 1)
-        valid = (kvrow <= hi) & q_valid
-        if sliding_window is not None:
-            valid &= kvrow > qpos - sliding_window
+        valid = (kvrow <= hi) & (kvrow > below) & q_valid
         if quantized:
             # per-row page scales, [1, page]: the k scale multiplies
             # logits on the kv axis, the v scale folds into pexp
@@ -249,6 +247,12 @@ def _ragged_kernel(*refs, scale: float, sliding_window: Optional[int],
         ).astype(out_ref.dtype)
 
 
+def _window_operand(window) -> jax.Array:
+    """A layer's window as the kernel takes it: [1] i32, 0 = full."""
+    return jnp.asarray(0 if window is None else window,
+                       jnp.int32).reshape(1)
+
+
 def ragged_paged_attention(
     q: jax.Array,  # [B, T, H, Dh] post-rope queries (T static; rows pad
     # their tail queries beyond q_lens — outputs there are garbage the
@@ -265,7 +269,9 @@ def ragged_paged_attention(
     *,
     scale: float,
     page: int,
-    sliding_window: Optional[int] = None,
+    window=None,  # the layer's sliding window, an OPERAND of the kernel:
+    # an int, or an i32 scalar a layer scan carries (a model whose layers
+    # differ); None or 0 = full attention
     cache_k_scale: Optional[jax.Array] = None,  # [L, n_pages, page] f32
     cache_v_scale: Optional[jax.Array] = None,
     seed_kv: Optional[tuple] = None,  # (new_k [B, F], new_v [B, F]):
@@ -291,7 +297,7 @@ def ragged_paged_attention(
     q3 = jnp.pad(q, ((0, 0), (0, Tp - T), (0, 0), (0, 0))).reshape(
         B, Tp, n_kv_heads, group, Dh).transpose(0, 2, 1, 3, 4).reshape(
         B, n_kv_heads, Tp * group, Dh)
-    nsp = 4  # q_lens, pos0, layer, page_table
+    nsp = 5  # q_lens, pos0, layer, window, page_table
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
 
     def _row_spec(shape):
@@ -301,7 +307,8 @@ def ragged_paged_attention(
 
     q_spec = pl.BlockSpec((1, n_kv_heads, R, Dh),
                           lambda b, qi, *_: (b, 0, qi, 0))
-    operands = [q_lens, pos0, layer[None], page_table, q3]
+    operands = [q_lens, pos0, layer[None], _window_operand(window),
+                page_table, q3]
     in_specs = [q_spec]
     if seeded:
         new_k, new_v = seed_kv
@@ -335,9 +342,9 @@ def ragged_paged_attention(
         ],
     )
     kernel = functools.partial(
-        _ragged_kernel, scale=scale, sliding_window=sliding_window,
-        page=page, tq=tq, group=group, n_kv_heads=n_kv_heads, d_head=Dh,
-        quantized=quantized, seeded=seeded,
+        _ragged_kernel, scale=scale, page=page, tq=tq, group=group,
+        n_kv_heads=n_kv_heads, d_head=Dh, quantized=quantized,
+        seeded=seeded,
     )
     out = pl.pallas_call(
         kernel,
@@ -396,7 +403,7 @@ def sharded_ragged_append_attend(
     *,
     scale: float,
     page: int,
-    sliding_window: Optional[int] = None,
+    window=None,  # as ragged_paged_attention's
 ) -> tuple:
     """Table-scatter append + ragged attend under ``shard_map`` on a
     serving mesh — the meshed counterpart of the caller-side scatter +
@@ -433,10 +440,12 @@ def sharded_ragged_append_attend(
         row_spec, row_spec,  # new_k, new_v
         row_spec, row_spec,  # kq, vq
         arena_spec, arena_spec,  # cache_k, cache_v
-        rep, rep, rep, rep, rep,  # layer, pt, wt, pos0, q_lens
+        rep, rep, rep, rep, rep, rep,  # layer, window, pt, wt, pos0,
+        # q_lens
     ]
     operands = [q, new_k, new_v, kq, vq, cache_k, cache_v,
-                layer, page_table, write_table, pos0, q_lens]
+                layer, _window_operand(window), page_table, write_table,
+                pos0, q_lens]
     if quant:
         in_specs += [rep, rep, rep, rep]
         operands += [ksc, vsc, cache_k_scale, cache_v_scale]
@@ -444,8 +453,8 @@ def sharded_ragged_append_attend(
     else:
         out_specs = (row_spec, arena_spec, arena_spec)
 
-    def body(q_l, nk_l, nv_l, kq_l, vq_l, ck, cv, lay, pt, wt, p0, qls,
-             ksr=None, vsr=None, ksp=None, vsp=None):
+    def body(q_l, nk_l, nv_l, kq_l, vq_l, ck, cv, lay, win, pt, wt, p0,
+             qls, ksr=None, vsr=None, ksp=None, vsp=None):
         B, T = kq_l.shape[:2]
         rows = jnp.arange(B, dtype=jnp.int32)
         tpos = p0[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
@@ -466,7 +475,7 @@ def sharded_ragged_append_attend(
         seed = (nk_l[:, 0], nv_l[:, 0]) if T == 1 else None
         out = ragged_paged_attention(
             q_l, ck, cv, lay, pt, p0, qls, n_kv_local,
-            scale=scale, page=page, sliding_window=sliding_window,
+            scale=scale, page=page, window=win[0],
             cache_k_scale=ksp if quant else None,
             cache_v_scale=vsp if quant else None,
             seed_kv=seed,
@@ -486,7 +495,7 @@ def sharded_ragged_append_attend(
 
 def ragged_attention_reference(
     q, cache_k, cache_v, layer, page_table, pos0, q_lens, n_kv_heads,
-    *, scale, page, sliding_window=None, cache_k_scale=None,
+    *, scale, page, window=None, cache_k_scale=None,
     cache_v_scale=None, seed_kv=None,
 ) -> jax.Array:
     """Dense XLA oracle: gather each row's pages into a contiguous
@@ -521,8 +530,8 @@ def ragged_attention_reference(
     qpos = (pos0[:, None] + jnp.arange(T)[None, :])[:, None, :, None]
     mask = (kv_pos <= qpos) & (
         jnp.arange(T)[None, None, :, None] < q_lens[:, None, None, None])
-    if sliding_window is not None:
-        mask &= kv_pos > qpos - sliding_window
+    if window is not None:  # an int or a traced scalar; 0 = full
+        mask &= (jnp.asarray(window) <= 0) | (kv_pos > qpos - window)
     logits = jnp.where(mask, logits, NEG_INF)
     # fully-masked pad queries: keep softmax finite, zero the output
     probs = jax.nn.softmax(logits, axis=-1)

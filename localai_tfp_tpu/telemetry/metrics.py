@@ -452,8 +452,43 @@ ENGINE_ATTN_CONTEXT_TOKENS = REGISTRY.counter(
     "Context tokens the attention rows of a dispatch had to read from "
     "the KV cache, summed over rows (a decode row at context c in a "
     "k-step scan adds c + (c+1) + ... + (c+k-1); a prompt chunk of n "
-    "tokens at position p adds its causal sum n*p + n(n-1)/2) — with "
+    "tokens at position p adds its causal sum n*p + n(n-1)/2; where "
+    "layers have sliding windows, the mean over layers of what each "
+    "layer's window lets a query read) — with "
     "engine_decode_steps_total the mean context per decode step",
+    labels=("model", "kind"),
+)
+ENGINE_ATTN_CONTEXT_HELD_TOKENS = REGISTRY.counter(
+    "engine_attn_context_held_tokens_total",
+    "engine_attn_context_tokens_total with every layer's window "
+    "ignored: the context the rows HELD. Equal to it for a model "
+    "without sliding windows; their ratio is the share of the held "
+    "context that the windows let the attention read",
+    labels=("model", "kind"),
+)
+ENGINE_EXPERT_TOKENS = REGISTRY.counter(
+    "engine_expert_tokens_total",
+    "Tokens routed to each expert, summed over the expert layers of "
+    "the step programs harvested (decode rows that decode, prompt "
+    "positions within a row's chunk; experts_per_token for each)",
+    labels=("model", "expert"),
+    # a label set an expert: the widest published layers have 512, and
+    # the default cap (64) would fold most of 128 into expert="other"
+    max_label_sets=2048,
+    overflow={"expert": "other"},
+)
+ENGINE_EXPERT_LAYER_STEPS = REGISTRY.counter(
+    "engine_expert_layer_steps_total",
+    "Expert layers run by the step programs harvested: the model's "
+    "expert layers x the token-steps of a program (k of a k-step scan, "
+    "1 of a mixed or single step), by program kind",
+    labels=("model", "kind"),
+)
+ENGINE_EXPERTS_TOUCHED = REGISTRY.counter(
+    "engine_experts_touched_total",
+    "Experts that had at least one token, summed over the expert "
+    "layer-steps of engine_expert_layer_steps_total: the expert "
+    "weights a step had to read, in experts",
     labels=("model", "kind"),
 )
 ENGINE_DECODE_STEPS = REGISTRY.counter(

@@ -76,7 +76,7 @@ def test_fused_decode_attention_matches_dense(window):
     cv2 = cv.at[1, rows, lengths - 1, :].set(new_v)
     out = fused_decode_attention(
         q, new_k, new_v, ck2, cv2, jnp.asarray(1, jnp.int32), lengths,
-        HKV, scale=scale, sliding_window=window,
+        HKV, scale=scale, window=window,
     )
     ref = _reference(q, ck2[1], cv2[1], lengths, scale, window)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-4, atol=2e-4)

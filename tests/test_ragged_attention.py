@@ -297,3 +297,77 @@ def test_shared_and_cow_pages_read_through_ragged(model, monkeypatch):
 # decode scans, prefill chunks, finals, and mixed steps all pass
 # through it, so a device array leaking into any ragged payload fails
 # test_ragged_on_off_byte_identical directly.
+
+
+# ----------------------------- the layer's window as the kernel's operand
+
+
+@pytest.mark.parametrize("cache", ["f32", "int8"])
+@pytest.mark.parametrize("kind", ["decode", "mixed"])
+@pytest.mark.parametrize("window", [0, 40])
+def test_window_operand_matches_the_reference(kind, cache, window):
+    """The sliding window rides into the kernel as a traced scalar (a
+    layer scan's per-layer value; 0 = full attention): rows whose
+    context crosses it — the boundary inside a 16-token page, whole
+    pages below it skipped — read what ``ragged_attention_reference``
+    reads, on raw and on int8 pages."""
+    from localai_tfp_tpu.ops.kernel_check import SMALL, check_serving_rows
+
+    err = check_serving_rows(SMALL, kind, cache, seed=3, window=window)
+    assert err < (5e-2 if cache == "int8" else 2e-4), err
+
+
+def test_window_zero_is_full_attention_and_a_window_is_not():
+    """0 and None are the same program result; a window changes it for
+    a row whose context is longer than the window."""
+    import numpy as np
+
+    from localai_tfp_tpu.ops.ragged_paged_attention import (
+        ragged_paged_attention,
+    )
+
+    rng = np.random.default_rng(0)
+    page, n_kv, dh, H = 16, 2, 128, 4
+    ak = jnp.asarray(rng.standard_normal((1, 9, page, n_kv * dh)),
+                     jnp.float32)
+    av = jnp.asarray(rng.standard_normal((1, 9, page, n_kv * dh)),
+                     jnp.float32)
+    q = jnp.asarray(rng.standard_normal((1, 4, H, dh)), jnp.float32)
+    pt = jnp.arange(1, 9, dtype=jnp.int32)[None]
+
+    def run(window):
+        return ragged_paged_attention(
+            q, ak, av, jnp.asarray(0, jnp.int32), pt,
+            jnp.asarray([100], jnp.int32), jnp.asarray([4], jnp.int32),
+            n_kv, scale=dh ** -0.5, page=page, window=window)
+
+    full = run(None)
+    np.testing.assert_array_equal(np.asarray(run(0)), np.asarray(full))
+    np.testing.assert_array_equal(
+        np.asarray(run(jnp.asarray(0, jnp.int32))), np.asarray(full))
+    # a window wider than the context changes nothing, a narrow one does
+    np.testing.assert_allclose(np.asarray(run(4096)), np.asarray(full),
+                               rtol=1e-6, atol=1e-6)
+    assert float(jnp.max(jnp.abs(run(24) - full))) > 1e-3
+
+
+def test_per_layer_windows_take_the_kernel_route(model, monkeypatch):
+    """A model whose layers differ in their windows is no longer ruled
+    out of the kernel route (the window is the kernel's operand)."""
+    import dataclasses
+
+    spec, _, tk = model
+    wspec = dataclasses.replace(
+        spec, d_head=64, sliding_window=32,  # kv_dim 128: whole lanes
+        layer_types=("sliding_attention", "full_attention"))
+    params = init_params(jax.random.PRNGKey(2), wspec, dtype=jnp.float32)
+    monkeypatch.setenv("LOCALAI_DECODE_KERNEL", "1")
+    eng = LLMEngine(wspec, params, tk, n_slots=2, max_seq=128,
+                    prefill_buckets=(8, 32), cache_dtype=jnp.float32,
+                    autostart=False)
+    try:
+        assert eng.kernel_ineligible == ""
+        assert eng.attention_path == "ragged_paged_kernel"
+        assert eng._layer_windows == {0: 1, 32: 1}
+    finally:
+        eng.close()

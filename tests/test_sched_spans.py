@@ -429,6 +429,54 @@ def test_mixed_and_kscan_counts_match_hand_computed_values(model):
         eng.close()
 
 
+def test_a_long_prompts_chain_streams_between_its_chunks(model):
+    """A prompt of many chunks is enqueued as ONE chain, and what lands
+    meanwhile is harvested between two chunks: the rows that decode
+    beside it are not held to the chain's end (on the chip a 20-chunk
+    chain takes 0.35 s to enqueue)."""
+    eng = _engine(model, tag="chain", decode_steps=2,
+                  prefill_buckets=(8, 32))
+    try:
+        a = GenRequest(prompt_ids=eng.tokenize("abcd"), max_tokens=200,
+                       ignore_eos=True)
+        qa = eng.submit(a)
+        _step_until(eng, lambda: any(
+            s.state.name == "DECODE" for s in eng.slots))
+        order = []
+        enq, harvest, dispatch = (eng._enqueue_mixed, eng._harvest,
+                                  eng._dispatch)
+
+        def wrap(tag, fn):
+            def inner(*args):
+                out = fn(*args)
+                order.append(tag if out is not False else tag.lower())
+                return out
+            return inner
+
+        eng._enqueue_mixed = wrap("E", enq)
+        eng._harvest = wrap("H", harvest)
+        eng._dispatch = wrap("|", dispatch)
+        b = GenRequest(prompt_ids=[1 + i % 200 for i in range(5 * 32)],
+                       max_tokens=4, ignore_eos=True)
+        qb = eng.submit(b)
+        _step_until(eng, lambda: order.count("E") == 5)
+        # the five chunks rode chains, one chain a _dispatch call; a
+        # chain breaks only where a step had to wait for a harvest ("e")
+        chains = [c for c in "".join(order).upper().split("|") if "E" in c]
+        assert sum(c.count("E") for c in chains) == 5
+        assert any(c.count("E") > 1 for c in chains)
+        for c in chains:
+            # step()'s own harvest, then E (H E)*: never two chunks
+            # enqueued with no harvest between them
+            assert "EE" not in c and not c.endswith("EH"), order
+        eng.cancel(a.id)
+        _step_until(eng, lambda: not eng._has_work())
+        assert _drain(qb).completion_tokens == 4
+        _drain(qa)
+    finally:
+        eng.close()
+
+
 # ------------------------------------------------------- FLIGHT repairs
 
 
