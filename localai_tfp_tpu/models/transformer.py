@@ -747,7 +747,8 @@ class Rows(NamedTuple):
     live: Optional[jax.Array] = None  # [B] bool: rows that carry a
     # token this pass (None: all). What a parked row computes is thrown
     # away, so an expert layer routes it nowhere and reads no expert
-    # for it; nothing else looks at this.
+    # for it, and the ragged kernel is given length 0 for it and reads
+    # no page; nothing else looks at this.
 
 
 def forward_hidden(
@@ -946,6 +947,11 @@ def forward_rows(
 
             ck_all, cv_all, ks_all, vs_all = st
             pos0, q_lens = g.pos0, g.q_lens
+            # a parked row attends nothing: at length 0 the kernel
+            # walks none of the pages under the position it carries
+            # (its K/V rows go to the trash page either way)
+            attend = (q_lens if g.live is None
+                      else jnp.where(g.live, q_lens, 0))
             B, T = k.shape[0], k.shape[1]
             kf = k.reshape(B, T, spec.kv_dim)
             vf = v.reshape(B, T, spec.kv_dim)
@@ -977,7 +983,7 @@ def forward_rows(
                     ck_all, cv_all,
                     ks_all if quant else None,
                     vs_all if quant else None,
-                    l, g.page_table, g.write_table, pos0, q_lens,
+                    l, g.page_table, g.write_table, pos0, attend,
                     spec.n_kv_heads, scale=scale, page=kv_page,
                     window=window,
                 )
@@ -1002,7 +1008,7 @@ def forward_rows(
                 ks_new = vs_new = None
             seed = ((kf[:, 0], vf[:, 0]) if T == 1 else None)
             out = ragged_paged_attention(
-                q, ck_new, cv_new, l, g.page_table, pos0, q_lens,
+                q, ck_new, cv_new, l, g.page_table, pos0, attend,
                 spec.n_kv_heads, scale=scale, page=kv_page,
                 window=window,
                 cache_k_scale=ks_new, cache_v_scale=vs_new,

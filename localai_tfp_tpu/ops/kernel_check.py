@@ -13,6 +13,10 @@ reference on identical random inputs and reports the max abs error. The
 serving legs run at the head geometry, page size and row shapes the
 engine dispatches for one model (``Geometry``; the default is the
 chip_smoke model at its serving settings).
+
+``--sweep`` is the kernel's stopwatch, not a check: the ragged kernel
+alone at the geometry's decode shapes over pages a row, parked rows and
+arena dtypes, one JSON line a dtype (``sweep_decode_kernel``).
 """
 
 from __future__ import annotations
@@ -444,7 +448,8 @@ def check_serving_rows(geom: Geometry, kind: str, cache: str,
     shapes against the dense oracle, for one dispatch kind:
 
     - ``decode``: ``[n_slots, 1]`` seeded rows at ragged context lengths
-      (decode1 / decodek) — the T == 1 tiling case;
+      (decode1 / decodek) — the T == 1 tiling case; ``decode_parked``:
+      the same with three of the rows parked (length 0);
     - ``chunk``: a ``[1, chunk]`` full-width prompt chunk deep in the
       context (the "prefill" kind; the longest query row the engine
       routes through the kernel);
@@ -468,12 +473,17 @@ def check_serving_rows(geom: Geometry, kind: str, cache: str,
                          geom.page)
     F = n_kv * dh
     max_pages = geom.max_seq // page
-    if kind == "decode":
+    if kind in ("decode", "decode_parked"):
         B, T = geom.n_slots, 1
         q_lens = np.ones(B, np.int32)
         pos0 = rng.integers(0, geom.max_seq - 1, B).astype(np.int32)
         pos0[0] = 0  # a first decode step: the seed row alone
         pos0[-1] = geom.max_seq - 1  # the last position of the context
+        if kind == "decode_parked":
+            # rows with no stream (length 0, any position): the walk
+            # hands its next page over them, two in a row where the
+            # batch has the room (the first and last rows stay live)
+            q_lens[sorted({1, B // 2, B // 2 + 1} - {0, B - 1})] = 0
     elif kind == "chunk":
         B, T = 1, geom.chunk
         q_lens = np.full(B, T, np.int32)
@@ -511,7 +521,7 @@ def check_serving_rows(geom: Geometry, kind: str, cache: str,
         ak, av = jnp.asarray(arena_k, act), jnp.asarray(arena_v, act)
         ks = vs = None
     seed_kv = None
-    if kind == "decode":
+    if T == 1:
         # the current token's exact rows ride in VMEM; the HBM copy at
         # pos0 is what the caller scatter-appended (masked in-kernel)
         seed_kv = (
@@ -519,10 +529,13 @@ def check_serving_rows(geom: Geometry, kind: str, cache: str,
                         act),
             jnp.asarray(rng.standard_normal((B, F), np.float32) * 0.5,
                         act))
-    got = jax.jit(lambda w: ragged_paged_attention(
+    # (the arena rides as an argument: closed over, its GBs are
+    # compiled into the program as a constant)
+    got = jax.jit(lambda ak, av, ks, vs, w: ragged_paged_attention(
         q.astype(act), ak, av, layer, pt_j, pos_j, len_j, n_kv,
         scale=scale, page=page, window=w, cache_k_scale=ks,
         cache_v_scale=vs, seed_kv=seed_kv))(
+            ak, av, ks, vs,
             None if window is None else jnp.asarray(window, jnp.int32))
     if not bool(jnp.all(jnp.isfinite(got))):
         return float("inf")  # pad queries are garbage, never non-finite
@@ -537,6 +550,8 @@ def check_serving_rows(geom: Geometry, kind: str, cache: str,
     err = 0.0
     for b in range(B):  # one row at a time: the oracle materializes
         # [H, T, max_seq] f32 scores
+        if not q_lens[b]:
+            continue  # a parked row: finite (above), otherwise unread
         sl = slice(b, b + 1)
         want = oracle(q[sl], ak, av, ks, vs, pt_j[sl], pos_j[sl],
                       len_j[sl],
@@ -604,6 +619,129 @@ def check_forward_parity(geom: Geometry, seed: int = 0) -> float:
     return worst
 
 
+def _kernel_times_us(trace_dir: str) -> list[float]:
+    """Device time of every ``ragged_paged_attention`` call in the one
+    capture under ``trace_dir``, in the order the calls ran."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    calls = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            if line.name == "XLA Ops":
+                calls += [(e.start_ns, e.duration_ns) for e in line.events
+                          if "ragged_paged_attention" in e.name]
+    return [d / 1e3 for _, d in sorted(calls)]
+
+
+def sweep_decode_kernel(geom: Geometry, cache: str, window: int = 0, *,
+                        pages=(1, 2, 3, 4, 9, 12), parked=(0, 1, 8),
+                        parked_pages: int = 3, calls: int = 64,
+                        seed: int = 0) -> dict[str, Any]:
+    """Time the kernel ALONE at the decode shapes of ``geom``:
+    ``[n_slots, 1]`` seeded rows, every row holding the same number of
+    pages (the last one half full), for each entry of ``pages``; then at
+    ``parked_pages`` a row with some rows parked (length 0: the rows
+    read nothing), spread evenly through the batch. One program serves
+    every point (context lengths are data), each point is ``calls``
+    invocations in one scan over the layers, and a call's time is the
+    median DEVICE time of its profiler events — chip only, no host
+    clock. ``a_us`` / ``b_us`` fit ``us_row = a + b * pages`` over the
+    points with no parked row; ``roof_share`` is the live rows' context
+    (K and V data bytes, as ``attn_kernel_roofline_counted`` counts it)
+    over peak HBM bytes per second over the call's time."""
+    import tempfile
+
+    from ..telemetry.costmodel import peak_rates
+    from .ragged_paged_attention import ragged_paged_attention
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit("--sweep times the compiled kernel: chip only")
+    hbm = peak_rates(dev.device_kind)[1]
+    H, n_kv, dh, page, B = (geom.n_heads, geom.n_kv_heads, geom.d_head,
+                            geom.page, geom.n_slots)
+    F, L = n_kv * dh, 2
+    max_pages = geom.max_seq // page
+    n_pages = B * max_pages + 1
+    keys = jax.random.split(jax.random.PRNGKey(seed), 8)
+    act = jnp.float32 if cache == "f32" else jnp.bfloat16
+    shape = (L, n_pages, page, F)
+    if cache == "int8":
+        ak = jax.random.randint(keys[0], shape, -127, 128, jnp.int8)
+        av = jax.random.randint(keys[1], shape, -127, 128, jnp.int8)
+        ks = jax.random.uniform(keys[2], shape[:3], jnp.float32,
+                                0.002, 0.006)
+        vs = jax.random.uniform(keys[3], shape[:3], jnp.float32,
+                                0.002, 0.006)
+    else:
+        ak = (jax.random.normal(keys[0], shape, jnp.float32) * 0.5
+              ).astype(act)
+        av = (jax.random.normal(keys[1], shape, jnp.float32) * 0.5
+              ).astype(act)
+        ks = vs = None
+    q = (jax.random.normal(keys[4], (B, 1, H, dh), jnp.float32) * 0.3
+         ).astype(act)
+    seed_kv = tuple(
+        (jax.random.normal(k, (B, F), jnp.float32) * 0.5).astype(act)
+        for k in keys[5:7])
+    pt = jnp.asarray(np.random.default_rng(seed).permutation(
+        np.arange(1, n_pages)).reshape(B, max_pages).astype(np.int32))
+    win = jnp.asarray(window, jnp.int32)
+
+    @jax.jit
+    def run(ak, av, ks, vs, pos0, q_lens):  # (the arena rides as an
+        # argument: closed over, it is compiled in as a constant)
+        def one(acc, i):
+            out = ragged_paged_attention(
+                q, ak, av, i % L, pt, pos0, q_lens, n_kv,
+                scale=dh ** -0.5, page=page, window=win,
+                cache_k_scale=ks, cache_v_scale=vs, seed_kv=seed_kv)
+            return acc + out, None
+        return jax.lax.scan(one, jnp.zeros((B, 1, H * dh), jnp.float32),
+                            jnp.arange(calls, dtype=jnp.int32))[0]
+
+    points = [(p, 0) for p in pages] + [
+        (parked_pages, k) for k in parked if k]
+    args = []
+    for p, k in points:
+        q_lens = np.ones(B, np.int32)
+        q_lens[np.linspace(0, B - 1, k).round().astype(int)] = 0
+        args.append((jnp.full((B,), (p - 1) * page + page // 2, jnp.int32),
+                     jnp.asarray(q_lens)))
+    arena = (ak, av, ks, vs)
+    run(*arena, *args[0]).block_until_ready()  # compile + warm, untraced
+    with tempfile.TemporaryDirectory() as tmp:
+        with jax.profiler.trace(tmp):
+            for a in args:
+                run(*arena, *a).block_until_ready()
+        times = _kernel_times_us(tmp)
+    assert len(times) == calls * len(points), (len(times), calls)
+    rows_out = []
+    for i, ((p, k), (pos0, _)) in enumerate(zip(points, args)):
+        us = float(np.median(times[i * calls:(i + 1) * calls]))
+        live = B - k
+        ctx_bytes = live * int(pos0[0]) * 2 * F * ak.dtype.itemsize
+        rows_out.append({
+            "rows": B, "pages": p, "parked": k,
+            "us_call": round(us, 2), "us_row": round(us / B, 3),
+            "roof_share": round(ctx_bytes / hbm / (us * 1e-6), 4)})
+    fit = [r for r in rows_out if not r["parked"]]
+    b_us, a_us = np.polyfit([r["pages"] for r in fit],
+                            [r["us_row"] for r in fit], 1)
+    return {
+        "device_kind": dev.device_kind, "cache": cache, "window": window,
+        "geometry": dataclasses.asdict(geom), "calls": calls,
+        "points": rows_out, "a_us": round(float(a_us), 3),
+        "b_us": round(float(b_us), 3),
+        "page_pair_dma_us": round(
+            2 * page * F * ak.dtype.itemsize / hbm * 1e6, 3)}
+
+
 # (max abs error) budgets: attention outputs are O(1) post-softmax and
 # bf16 inputs put parity at ~1e-2; int8 pages add their rounding
 _TOL_FP, _TOL_INT8 = 2e-2, 5e-2
@@ -632,7 +770,7 @@ def run_kernel_checks(geom: Geometry = SERVING) -> dict[str, Any]:
         budget[name] = tol
 
     # the engine's own row shapes through the ONE kernel
-    for kind in ("decode", "chunk", "mixed"):
+    for kind in ("decode", "decode_parked", "chunk", "mixed"):
         leg(f"serving_{kind}_max_err",
             check_serving_rows(geom, kind, "bf16"), _TOL_FP)
         leg(f"serving_{kind}_int8_max_err",
@@ -692,12 +830,25 @@ def main(argv: "list[str] | None" = None) -> int:
     for f in dataclasses.fields(Geometry):
         ap.add_argument("--" + f.name.replace("_", "-"), type=int,
                         default=None)
+    ap.add_argument("--sweep", action="store_true",
+                    help="time the kernel alone over pages a row and "
+                    "parked rows at the geometry's decode shapes (a "
+                    "tool: one JSON line a cache dtype; chip only)")
+    ap.add_argument("--cache", default="int8,bf16",
+                    help="--sweep: arena dtypes, comma-separated")
+    ap.add_argument("--window", type=int, default=0,
+                    help="--sweep: the layer's window operand (0 = full)")
     args = ap.parse_args(argv)
     geom = SMALL if args.small else SERVING
     geom = dataclasses.replace(geom, **{
         f.name: getattr(args, f.name)
         for f in dataclasses.fields(Geometry)
         if getattr(args, f.name) is not None})
+    if args.sweep:
+        for cache in args.cache.split(","):
+            print(json.dumps(
+                sweep_decode_kernel(geom, cache, args.window)), flush=True)
+        return 0
     res = run_kernel_checks(geom)
     print(json.dumps(res))
     return 0 if res["ok"] else 1

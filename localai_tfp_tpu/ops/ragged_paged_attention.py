@@ -24,19 +24,40 @@ Shapes:
   pos0[b] + t and attends positions [max(0, pos+1-window), pos].
 
 Design notes (see /opt/skills/guides/pallas_guide.md):
-- grid = (row, query block): an inner double-buffered manual-DMA loop
-  walks only the pages that block's queries can see (a grid over
-  max_seq pages pays a fixed per-page cost, valid or not). Query
-  blocking (``_q_tiling``) bounds the per-step VMEM footprint — a
-  2048-token chunk never holds an [n_heads * 2048, page] logits slab —
-  and blocks wholly beyond a row's q_len read nothing.
+- grid = (row, query block), run IN ORDER on one core
+  (``dimension_semantics`` says so): a step walks only the pages its
+  block's queries can see (a grid over max_seq pages pays a fixed
+  per-page cost, valid or not). Query blocking (``_q_tiling``) bounds
+  the per-step VMEM footprint — a 2048-token chunk never holds an
+  [n_heads * 2048, page] logits slab — and a block wholly beyond a
+  row's q_len, a row of length 0 (a parked row: the caller passes 0 for
+  a row that carries no stream) and a seeded row at position 0 read
+  nothing and cost a bare grid step (0.4 us on a v5e).
+- ONE page walk for the whole call, two page slots deep: while a page
+  is computed the next one is in flight, and from a step's last page on
+  "the next one" is the FIRST page of the next step that reads (found
+  on the scalar core from the prefetched lengths and positions). The
+  slot that page lands in is handed over in SMEM scratch, which
+  persists across grid steps; every DMA that is started is waited for
+  exactly once, by the step that reads it. Row by row, each row paid
+  its first page's issue + latency + transfer in the open: 0.9 us of a
+  5.4 us row at Mistral's 2.35 pages a row (PERF.md section 6, PR 39).
+- within a page the kv heads go through each phase together (every QK
+  matmul, every softmax update, every PV matmul, the stores), as many
+  heads at a time as keep their logits in half the vector registers
+  (``_PHASE_VREGS``). The MXU takes its matmuls in program order: head
+  after head, the heads' matmul -> reduce -> exp -> matmul chains ran
+  end to end, 1.72 us a page against 0.82 for 8 kv heads of int8 (the
+  page pair's DMA alone: 0.64 us).
 - queries ride as ``[B, Hkv, T*group, Dh]``: the kernel picks a kv head
   on a LEADING dim and contracts ``q_h [TQ*group, Dh] @ k_page_h.T`` on
   the MXU; the per-head k/v bands are 128-lane-aligned column slices of
   the head-flat page. No sublane slicing or stacking anywhere, so the
   T == 1 / group 4 decode case lays out as whole tiles.
 - flash state (m, l, acc) lives in VMEM scratch, one slab per kv head,
-  m/l lane-replicated so every load/store is a full vreg.
+  m/l lane-replicated so every load/store is a full vreg (reading a
+  statistic back as one lane column instead of a lane reduction was
+  measured SLOWER: 2.34 us a page against 1.72).
 - int8 k/v pages dequantize by PER-ROW scales that commute through the
   row-wise contractions: the k scale multiplies logits on the kv axis
   and the v scale folds into pexp before the pv matmul — the MXU never
@@ -73,10 +94,19 @@ NEG_INF = -1e30
 # query rows (n_heads * TQ) of f32 softmax state one grid step may hold.
 # At 32 heads this is TQ 64: per step ~1 MiB each of m/l/acc scratch,
 # a [256, page] f32 logits slab per kv head, and the double-buffered
-# q/out blocks — about 10 MiB, against which the limit below leaves the
-# compiler room for its own temporaries.
+# q/out blocks — about 10 MiB. Chosen in PR 22 so that a 2048-token
+# chunk compiles, and not tuned since: it does not reach decode rows
+# (T == 1 rides as ONE 16-row tile a kv head whatever this says), which
+# is where the cells' time is.
 _ROWS_PER_STEP = 2048
 _STAT_LANES = 128  # m/l scratch rows are lane-replicated (full vregs)
+# f32 vregs (8 x 128) of logits that one phase of the page loop keeps
+# live: half of the core's 64
+_PHASE_VREGS = 32
+# Room, not a tuned number: every serving shape (decode, [4, 128],
+# [16, 512], a [1, 2048] chunk; int8 / bf16 / f32 pages, 8 and 4 kv
+# heads) also compiles for a v5e at 12 MiB (PR 39, compile only); the
+# default scoped limit is 16 MiB of the core's 128.
 _VMEM_LIMIT_BYTES = 48 * 1024 * 1024
 
 
@@ -110,33 +140,66 @@ def _ragged_kernel(*refs, scale: float, page: int, tq: int, group: int,
     ck_in, cv_in, *rest = rest
     if quantized:
         ks_ref, vs_ref, *rest = rest
-    out_ref, kbuf, vbuf, rsem, m_ref, l_ref, acc_ref = rest
+    out_ref, kbuf, vbuf, rsem, hand_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
+    nq = pl.num_programs(1)
+    step = b * nq + pl.program_id(1)  # the grid runs in this order
+    n_steps = pl.num_programs(0) * nq
     t0 = pl.program_id(1) * tq  # first query token of this block
     layer = layer_ref[0]
     window = win_ref[0]  # this layer's sliding window; 0 = full attention
     qlen = qlen_ref[b]
     p0 = pos_ref[b]
     R = tq * group  # query rows per kv head: row = t_local*group + g
+    # heads whose [R, page] f32 logits are live at once (see the page
+    # loop): all of them for decode rows (4 vregs a head), one for a
+    # 64-token prompt block (64 vregs a head)
+    heads_per_phase = max(1, min(
+        n_kv_heads, _PHASE_VREGS // max(1, R * page // 1024)))
     # absolute position of each query row of the block
     row_i = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
     t_i = t0 + row_i // group
     qpos = p0 + t_i  # [R, 1]
     q_valid = t_i < qlen  # pad queries beyond the row's ragged length
     hi = qpos - (1 if seeded else 0)  # last HBM row each query attends
-    # context this block reads: through its LAST valid query (causal),
-    # nothing at all when the block lies wholly beyond q_len. Seeded
-    # mode keeps the current token in VMEM and masks its HBM copy (the
-    # decode kernel's contract)
-    t_end = jnp.minimum(qlen, t0 + tq)
-    n_hbm = p0 + t_end - (1 if seeded else 0)
-    n_pages = jnp.where(t_end > t0, lax.div(n_hbm + page - 1, page), 0)
-    # pages wholly below the block's EARLIEST query window are never
-    # read; the per-query mask below handles the ragged boundary
-    first_page = jnp.where(
-        window > 0, lax.div(jnp.maximum(p0 + t0 + 1 - window, 0), page), 0)
     # the last position BELOW each query's window (none: -1)
     below = jnp.where(window > 0, qpos - window, -1)  # [R, 1]
+
+    def pages_of(s):
+        """[first, end) logical pages grid step ``s`` reads: through its
+        block's LAST valid query (causal), from the page its EARLIEST
+        query's window reaches (the per-query mask handles the ragged
+        boundaries); nothing (end <= first) for a row of length 0 and
+        for a block wholly beyond q_len. Seeded mode keeps the current
+        token in VMEM and masks its HBM copy (the decode kernel's
+        contract), so a seeded row at position 0 reads nothing either."""
+        row, blk0 = lax.div(s, nq), lax.rem(s, nq) * tq
+        pos = pos_ref[row]
+        t_end = jnp.minimum(qlen_ref[row], blk0 + tq)
+        n_hbm = pos + t_end - (1 if seeded else 0)
+        end = jnp.where(t_end > blk0, lax.div(n_hbm + page - 1, page), 0)
+        first = jnp.where(
+            window > 0,
+            lax.div(jnp.maximum(pos + blk0 + 1 - window, 0), page), 0)
+        return row, first, end
+
+    _, first_page, n_pages = pages_of(step)
+    reads = first_page < n_pages
+
+    # the walk crosses grid steps: the step that follows this one AND
+    # reads a page (parked rows and blocks beyond q_len in between read
+    # none), found on the scalar core; a step that reads nothing looks
+    # for nothing and leaves the hand-over as it found it
+    def seek(c):
+        s, _, _, _ = c
+        row, first, end = pages_of(s)
+        hit = first < end
+        return jnp.where(hit, s, s + 1), row, first, ~hit
+
+    nxt, nxt_row, nxt_first, _ = lax.while_loop(
+        lambda c: c[3] & (c[0] < n_steps), seek,
+        (step + 1, jnp.int32(0), jnp.int32(0), reads))
+    has_next = reads & (nxt < n_steps)
 
     def band(h):
         return slice(h * d_head, (h + 1) * d_head)
@@ -177,8 +240,8 @@ def _ragged_kernel(*refs, scale: float, page: int, tq: int, group: int,
             l_ref[h] = jnp.zeros((R, _STAT_LANES), jnp.float32)
             acc_ref[h] = jnp.zeros((R, d_head), jnp.float32)
 
-    def get_dma(slot, p):
-        phys = pt_ref[b, p]
+    def get_dma(slot, row, p):
+        phys = pt_ref[row, p]
         return (
             pltpu.make_async_copy(ck_in.at[layer, phys],
                                   kbuf.at[slot], rsem.at[slot, 0]),
@@ -186,24 +249,39 @@ def _ragged_kernel(*refs, scale: float, page: int, tq: int, group: int,
                                   vbuf.at[slot], rsem.at[slot, 1]),
         )
 
-    @pl.when(first_page < n_pages)
+    def start(slot, row, p):
+        for dma in get_dma(slot, row, p):
+            dma.start()
+
+    # hand_ref (SMEM scratch, kept from one grid step to the next): the
+    # slot in which the FIRST page of the next step that reads is in
+    # flight, started by the reading step before it; -1 = none
+    @pl.when(step == 0)
     def _():
-        k0, v0 = get_dma(0, first_page)
-        k0.start()
-        v0.start()
+        hand_ref[0] = -1
+
+    handed = hand_ref[0] >= 0
+    slot0 = jnp.maximum(hand_ref[0], 0)
+
+    @pl.when(reads & ~handed)
+    def _():  # the call's first reading step: nobody fetched for it
+        start(0, b, first_page)
 
     def body(p, carry):
-        slot = lax.rem(p - first_page, 2)
+        slot = lax.rem(slot0 + p - first_page, 2)
+        last = p + 1 == n_pages
 
-        @pl.when(p + 1 < n_pages)
+        # the other slot's page was consumed one trip ago: fill it with
+        # this step's next page or, from its last page on, with the next
+        # reading step's first — that fetch runs under this page's
+        # compute, the output's write-back and the next step's prologue
+        @pl.when(~last | has_next)
         def _():
-            kn, vn = get_dma(1 - slot, p + 1)
-            kn.start()
-            vn.start()
+            start(1 - slot, jnp.where(last, nxt_row, b),
+                  jnp.where(last, nxt_first, p + 1))
 
-        kp, vp = get_dma(slot, p)
-        kp.wait()
-        vp.wait()
+        for dma in get_dma(slot, b, p):
+            dma.wait()
         kvrow = p * page + jax.lax.broadcasted_iota(
             jnp.int32, (R, page), 1)
         valid = (kvrow <= hi) & (kvrow > below) & q_valid
@@ -212,35 +290,54 @@ def _ragged_kernel(*refs, scale: float, page: int, tq: int, group: int,
             # logits on the kv axis, the v scale folds into pexp
             ks_row = ks_ref[0, pl.ds(p, 1), :]
             vs_row = vs_ref[0, pl.ds(p, 1), :]
-        for h in range(n_kv_heads):
-            qh = q_ref[0, h]  # [R, Dh]
-            kh = widen(kbuf[slot, :, band(h)])  # [page, Dh]
-            logits = jax.lax.dot_general(
-                qh, kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [R, page]
-            if quantized:
-                logits = logits * ks_row
-            logits = jnp.where(valid, logits, NEG_INF)
-            m_prev = stat(m_ref, h)
-            m_new = jnp.maximum(
-                m_prev, jnp.max(logits, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            pexp = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
-            l_new = stat(l_ref, h) * alpha + jnp.sum(
-                pexp, axis=1, keepdims=True)
-            if quantized:
-                pexp = pexp * vs_row
-            vh = widen(vbuf[slot, :, band(h)])  # [page, Dh]
-            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
-                pexp.astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_ref[h] = lanes(m_new)
-            l_ref[h] = lanes(l_new)
+        # the heads go through each phase TOGETHER, a block of heads at
+        # a time: every QK matmul, then every softmax update, then
+        # every PV matmul. The MXU runs its matmuls in program order:
+        # written head after head, a head's PV waits for its softmax
+        # with the next head's QK queued behind it (design notes)
+        for h0 in range(0, n_kv_heads, heads_per_phase):
+            hs = range(h0, min(h0 + heads_per_phase, n_kv_heads))
+            logits, m_new, alpha, l_new, pexp, pv = ({} for _ in range(6))
+            for h in hs:
+                kh = widen(kbuf[slot, :, band(h)])  # [page, Dh]
+                logits[h] = jax.lax.dot_general(
+                    q_ref[0, h], kh, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * scale  # [R, page]
+                if quantized:
+                    logits[h] = logits[h] * ks_row
+                logits[h] = jnp.where(valid, logits[h], NEG_INF)
+            for h in hs:
+                m_prev = stat(m_ref, h)
+                m_new[h] = jnp.maximum(
+                    m_prev, jnp.max(logits[h], axis=1, keepdims=True))
+                alpha[h] = jnp.exp(m_prev - m_new[h])
+                pexp[h] = jnp.where(
+                    valid, jnp.exp(logits[h] - m_new[h]), 0.0)
+                l_new[h] = stat(l_ref, h) * alpha[h] + jnp.sum(
+                    pexp[h], axis=1, keepdims=True)
+                if quantized:
+                    pexp[h] = pexp[h] * vs_row
+            for h in hs:
+                vh = widen(vbuf[slot, :, band(h)])  # [page, Dh]
+                pv[h] = jax.lax.dot_general(
+                    pexp[h].astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+            for h in hs:
+                acc_ref[h] = acc_ref[h] * alpha[h] + pv[h]
+                m_ref[h] = lanes(m_new[h])
+                l_ref[h] = lanes(l_new[h])
         return carry
 
     lax.fori_loop(first_page, n_pages, body, 0)
+
+    @pl.when(reads)
+    def _():  # every started DMA is waited for once: by this step's
+        # walk, or by the step this hands the last one over to
+        hand_ref[0] = jnp.where(
+            has_next, lax.rem(slot0 + n_pages - first_page, 2), -1)
+
     for h in range(n_kv_heads):
         out_ref[0, h] = (
             acc_ref[h] / jnp.maximum(stat(l_ref, h), 1e-30)
@@ -336,6 +433,7 @@ def ragged_paged_attention(
             pltpu.VMEM((2, page, F), cache_k.dtype),
             pltpu.VMEM((2, page, F), cache_v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),  # the cross-step hand-over
             pltpu.VMEM((n_kv_heads, R, _STAT_LANES), jnp.float32),  # m
             pltpu.VMEM((n_kv_heads, R, _STAT_LANES), jnp.float32),  # l
             pltpu.VMEM((n_kv_heads, R, Dh), jnp.float32),  # acc
@@ -352,6 +450,10 @@ def ragged_paged_attention(
         out_shape=jax.ShapeDtypeStruct(
             (B, n_kv_heads, Tp * group, Dh), jnp.float32),
         compiler_params=pltpu.CompilerParams(
+            # one page walk from the first grid step to the last: a
+            # step waits for the DMA the step before it started, so the
+            # grid must run in order on one core
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=_interpret(),
         name="ragged_paged_attention",

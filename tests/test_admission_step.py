@@ -255,17 +255,21 @@ _DECODE_ROUTES = {
 # returns one more value (the expert statistics, empty for this dense
 # model), the layer scan runs over ``layer_stacks`` (one stack here),
 # and the two kernel routes hand the kernel the layer's window as an
-# operand (0 here). What the pin is for is unchanged: a later PR that
-# touches none of that must leave these programs as they are.
+# operand (0 here). PR 39 re-pinned the two KERNEL routes only (the
+# ragged kernel's body changed on purpose: its page walk crosses grid
+# steps, its heads run phase by phase, and a parked row is given
+# length 0); the two XLA routes kept PR 38's digests. What the pin is
+# for is unchanged: a later PR that touches none of that must leave
+# these programs as they are.
 _PARENT = {
     ("paged_xla_gather", "float32"): ("95083fa5", "3xabc1ff24"),
     ("paged_xla_gather", "int8"): ("627b1606", "3xbca65819"),
-    ("ragged_paged_kernel", "float32"): ("5baf1855", "3x01c4b018"),
-    ("ragged_paged_kernel", "int8"): ("4cc2391d", "3xc1940c43"),
+    ("ragged_paged_kernel", "float32"): ("cc3f4a56", "3x1e299a30"),
+    ("ragged_paged_kernel", "int8"): ("d318572c", "3xd0ec8c7c"),
     ("dense_xla", "float32"): ("cedac285", "6xd25fe568"),
     ("dense_xla", "int8"): ("a67b9a93", "6xe0098b88"),
-    ("dense_decode_kernel", "float32"): ("52372a4b", "3xd01f4b63"),
-    ("dense_decode_kernel", "int8"): ("dcca2c74", "3xf7113731"),
+    ("dense_decode_kernel", "float32"): ("340c9733", "3x374afe15"),
+    ("dense_decode_kernel", "int8"): ("512213de", "3xf3f9dbb2"),
 }
 
 

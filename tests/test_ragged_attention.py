@@ -371,3 +371,169 @@ def test_per_layer_windows_take_the_kernel_route(model, monkeypatch):
         assert eng._layer_windows == {0: 1, 32: 1}
     finally:
         eng.close()
+
+
+# ------------------------- the page walk's hand-over from one row to the next
+
+
+def _handover_case(name):
+    """(kind, q_lens, pos0, window) of one batch at SMALL-like sizes
+    (page 16, 8 pages a row): ``decode`` rows are seeded ``[B, 1]``,
+    ``prompt`` rows ``[B, 48]`` walked in three query blocks of 16."""
+    return {
+        # a parked (length 0) row between two live rows
+        "parked_between": ("decode", [1, 0, 1], [70, 33, 20], 0),
+        "first_parked": ("decode", [0, 1, 1], [70, 33, 20], 0),
+        "last_parked": ("decode", [1, 1, 0], [70, 33, 20], 0),
+        "all_parked": ("decode", [0, 0, 0], [70, 33, 20], 0),
+        "single_row": ("decode", [1], [70], 0),
+        # a seeded row at position 0 reads no HBM page at all
+        "pos0_zero_then_long": ("decode", [1, 1], [0, 127], 0),
+        # a full-attention-length row, then one whose window moves its
+        # first page past 0 (and a short one the window does not touch)
+        "window_after_full": ("decode", [1, 1, 1], [15, 120, 9], 40),
+        # several query blocks a row; the first row's last blocks lie
+        # wholly beyond its q_len, the second row fills every block
+        "prompt_blocks_beyond": ("prompt", [5, 48, 0, 20],
+                                 [60, 30, 11, 0], 0),
+        "prompt_window": ("prompt", [48, 3, 33], [64, 100, 0], 24),
+    }[name]
+
+
+@pytest.mark.parametrize("cache", ["bf16", "int8"])
+@pytest.mark.parametrize("case", [
+    "parked_between", "first_parked", "last_parked", "all_parked",
+    "single_row", "pos0_zero_then_long", "window_after_full",
+    "prompt_blocks_beyond", "prompt_window"])
+def test_page_walk_hands_over_across_rows(case, cache, monkeypatch):
+    """The kernel's page walk runs from the first grid step to the
+    last: a step starts the first page of the next step THAT READS and
+    that step waits for it. Whatever lies between two reading steps —
+    a parked row, a seeded row at position 0, a query block beyond
+    q_len — and wherever the next row's window puts its first page,
+    every live query reads what ``ragged_attention_reference`` reads,
+    and a parked row's output stays finite."""
+    import numpy as np
+
+    from localai_tfp_tpu.models.transformer import _quantize_rows
+    from localai_tfp_tpu.ops import ragged_paged_attention as rpa
+
+    kind, q_lens, pos0, window = _handover_case(case)
+    page, n_kv, dh, H, max_pages = 16, 2, 128, 4, 8
+    if kind == "prompt":
+        # three query blocks a row at this width (one at the default)
+        monkeypatch.setattr(rpa, "_ROWS_PER_STEP", 64)
+    B = len(q_lens)
+    T = 1 if kind == "decode" else 48
+    F = n_kv * dh
+    rng = np.random.default_rng(len(case))
+    n_pages = B * max_pages + 1
+    pt = rng.permutation(np.arange(1, n_pages)).reshape(
+        B, max_pages).astype(np.int32)
+    for b in range(B):  # unallocated entries point at the trash page
+        pt[b, -(-(pos0[b] + max(q_lens[b], 1)) // page):] = 0
+    arena = rng.standard_normal((2, 2, n_pages, page, F), np.float32) * 0.5
+    act = jnp.bfloat16
+    if cache == "int8":
+        (ak, ks), (av, vs) = (_quantize_rows(jnp.asarray(a)) for a in arena)
+    else:
+        ak, av = (jnp.asarray(a, act) for a in arena)
+        ks = vs = None
+    q = jnp.asarray(rng.standard_normal((B, T, H, dh), np.float32) * 0.3,
+                    act)
+    seed_kv = None
+    if kind == "decode":
+        seed_kv = tuple(jnp.asarray(
+            rng.standard_normal((B, F), np.float32) * 0.5, act)
+            for _ in range(2))
+    if kind == "prompt":
+        assert rpa._q_tiling(T, H, H // n_kv)[0] == 16  # nq == 3
+    kw = dict(scale=dh ** -0.5, page=page,
+              window=jnp.asarray(window, jnp.int32), cache_k_scale=ks,
+              cache_v_scale=vs, seed_kv=seed_kv)
+    args = (q, ak, av, jnp.asarray(1, jnp.int32), jnp.asarray(pt),
+            jnp.asarray(pos0, jnp.int32), jnp.asarray(q_lens, jnp.int32),
+            n_kv)
+    got = np.asarray(rpa.ragged_paged_attention(*args, **kw))
+    want = np.asarray(rpa.ragged_attention_reference(*args, **kw))
+    assert np.isfinite(got).all()
+    tol = 5e-2 if cache == "int8" else 2e-2
+    for b, n in enumerate(q_lens):
+        if n:
+            np.testing.assert_allclose(got[b, :n], want[b, :n],
+                                       atol=tol, rtol=0)
+
+
+def test_a_parked_row_reads_no_page_through_forward_rows():
+    """``forward_rows`` hands the kernel length 0 for a row that is not
+    live: what such a row computes no longer depends on the pages under
+    the position it carries (with no ``live`` mask it does), and the
+    live rows compute what they computed."""
+    import numpy as np
+
+    from localai_tfp_tpu.models.llm_spec import LLMSpec
+    from localai_tfp_tpu.models.transformer import (
+        KVCache, Rows, forward_rows,
+    )
+
+    page, B, max_pages = 16, 3, 4
+    spec = LLMSpec(vocab_size=64, d_model=128, n_layers=2, n_heads=2,
+                   n_kv_heads=1, d_head=128, d_ff=128, max_position=64)
+    params = init_params(jax.random.PRNGKey(0), spec, dtype=jnp.float32)
+    rng = np.random.default_rng(0)
+    n_pages = B * max_pages + 1
+    pt = jnp.asarray(np.arange(1, n_pages).reshape(B, max_pages),
+                     jnp.int32)
+    trash = jnp.zeros_like(pt)
+    live = jnp.asarray([True, False, True])
+    wt = jnp.where(live[:, None], pt, trash)  # parked rows write trash
+    arena = rng.standard_normal(
+        (2, spec.n_layers, n_pages, page, spec.kv_dim)).astype(np.float32)
+    other = arena.copy()  # the same but under the parked row's pages
+    other[:, :, np.asarray(pt[1])] += 1.0
+    toks = jnp.asarray(rng.integers(0, 64, (B, 1)), jnp.int32)
+    pos0 = jnp.asarray([20, 37, 9], jnp.int32)
+
+    def run(a, live):
+        rows = Rows(toks, pos0, page_table=pt, write_table=wt,
+                    q_lens=jnp.ones((B,), jnp.int32), live=live)
+        (h,), _, _ = forward_rows(
+            spec, params, (rows,),
+            KVCache(k=jnp.asarray(a[0]), v=jnp.asarray(a[1])),
+            kv_page=page)
+        return np.asarray(h)
+
+    masked, masked_other = run(arena, live), run(other, live)
+    np.testing.assert_array_equal(masked, masked_other)
+    assert np.isfinite(masked).all()
+    unmasked, unmasked_other = run(arena, None), run(other, None)
+    assert np.abs(unmasked[1] - unmasked_other[1]).max() > 1e-3
+    np.testing.assert_array_equal(masked[[0, 2]], unmasked[[0, 2]])
+
+
+def test_sweep_fits_row_cost_from_kernel_times(monkeypatch):
+    """``kernel_check --sweep`` (the kernel's stopwatch, chip only):
+    given a device and the kernel's event times it reports µs a call, µs
+    a row, the fit ``us_row = a + b * pages`` over the points with no
+    parked row, and the live context's share of the HBM roof."""
+    import types
+
+    from localai_tfp_tpu.ops import kernel_check as kc
+
+    dev = types.SimpleNamespace(platform="tpu", device_kind="cpu")
+    monkeypatch.setattr(kc.jax, "devices", lambda *a: [dev])
+    geom, calls, pages = kc.SMALL, 2, (1, 2, 4)
+    per_point = [geom.n_slots * (0.5 + 2.0 * p) for p in pages] + [7.0]
+    monkeypatch.setattr(
+        kc, "_kernel_times_us",
+        lambda _dir: [t for t in per_point for _ in range(calls)])
+    res = kc.sweep_decode_kernel(geom, "int8", pages=pages, parked=(0, 1),
+                                 parked_pages=2, calls=calls)
+    assert (res["a_us"], res["b_us"]) == (0.5, 2.0)
+    assert [(p["pages"], p["parked"]) for p in res["points"]] == [
+        (1, 0), (2, 0), (4, 0), (2, 1)]
+    assert res["points"][-1]["us_call"] == 7.0
+    # 2 live rows x 24 tokens x K and V x 256 int8 bytes, at 50 GB/s
+    assert res["points"][-1]["roof_share"] == round(
+        2 * 24 * 2 * 256 / 50e9 / 7e-6, 4)
+    assert res["page_pair_dma_us"] == round(2 * 16 * 256 / 50e9 * 1e6, 3)
