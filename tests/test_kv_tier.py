@@ -363,3 +363,191 @@ def test_leak_check_clean_under_churn(eng):
         _leak_checks(eng)
     finally:
         tier.host_budget = saved
+
+
+# ---------------------------------------------------------------------------
+# the device half of a spill: b pages read, b written, nothing else
+
+
+_PLANES = {  # a K / V plane in each served dtype, and a scale plane
+    "int8": ((3, 19, 8, 128), jnp.int8),
+    "bfloat16": ((2, 19, 8, 64), jnp.bfloat16),
+    "scale": ((3, 19, 8), jnp.float32),
+}
+
+
+def _plane(shape, dtype):
+    rng = np.random.default_rng(sum(shape))
+    if dtype == jnp.int8:
+        return jnp.asarray(rng.integers(-128, 128, shape, np.int8))
+    return jnp.asarray(rng.standard_normal(shape, np.float32)).astype(dtype)
+
+
+def _spill_table(b, n_pages):
+    """What ``_spill`` hands the gather: page ids in any order, the
+    tail padded to a power of two with the trash page — and one id
+    twice, which the gather must not mind."""
+    from localai_tfp_tpu.engine.kv_pool import TRASH_PAGE
+
+    ids = np.random.default_rng(b).permutation(
+        np.arange(1, n_pages))[:b].astype(np.int32)
+    if b >= 4:
+        ids[1] = ids[0]
+        ids[-(b // 4):] = TRASH_PAGE
+    return ids
+
+
+@pytest.mark.parametrize("b", [1, 4, 16])
+@pytest.mark.parametrize("plane", sorted(_PLANES))
+def test_gather_pages_is_bit_equal_to_plain_indexing(plane, b):
+    from localai_tfp_tpu.engine.kv_tier import _gather_pages
+
+    shape, dtype = _PLANES[plane]
+    arr = _plane(shape, dtype)
+    ids = _spill_table(b, shape[1])
+    got = _gather_pages(arr, jnp.asarray(ids))
+    want = arr[:, ids]
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert np.array_equal(np.asarray(got).view(np.uint8),
+                          np.asarray(want).view(np.uint8))
+
+
+@pytest.mark.parametrize("plane", sorted(_PLANES))
+def test_gather_pages_lowers_to_slices_not_a_gather(plane):
+    """``arr[:, tbl]`` lowers to ONE gather over the pool plane, which
+    the v5e compiler turns into a copy of the whole plane; the spill's
+    program must hold none."""
+    from localai_tfp_tpu.engine.kv_tier import _gather_pages
+
+    shape, dtype = _PLANES[plane]
+    args = (jax.ShapeDtypeStruct(shape, dtype),
+            jax.ShapeDtypeStruct((4,), jnp.int32))
+    text = _gather_pages.lower(*args).as_text()
+    assert "stablehlo.gather" not in text
+    assert "stablehlo.dynamic_gather" not in text
+    assert text.count("stablehlo.dynamic_slice") == 4
+    # the test's own reading of what it guards against
+    assert "stablehlo.gather" in jax.jit(
+        lambda a, t: a[:, t]).lower(*args).as_text()
+
+
+@pytest.mark.parametrize("cache_dtype", ["module-engine", "int8"])
+def test_spilled_host_pages_equal_the_pool_pages(model, eng, cache_dtype,
+                                                 monkeypatch):
+    """Engine level: what a spill leaves in host RAM is, plane by plane
+    and bit for bit, the pool's pages of that session (int8: K, V and
+    both scale planes — four gathers a spill)."""
+    if cache_dtype == "module-engine":
+        e = eng
+    else:
+        spec, params, tk = model
+        monkeypatch.setenv("LOCALAI_KV_PAGE", "16")
+        monkeypatch.setenv("LOCALAI_KV_TIER", "on")
+        e = LLMEngine(spec, params, tk, n_slots=2, max_seq=128,
+                      prefill_buckets=(8, 32, 128),
+                      cache_dtype=cache_dtype)
+    try:
+        tier = e._tier
+        prompt = f"bit equal session {cache_dtype} " + "p " * 14 + "end"
+        _serve_wave(e, [prompt])
+        _settle(e)
+        head = e.tokenize(prompt)[:8]
+        slot = next(s for s in e.slots
+                    if s.cache_tokens and s.cache_tokens[:8] == head)
+        n = len(slot.cache_tokens)
+        table = e._pool.table(slot.idx)[:e._pool.pages_for(n)]
+        assert len(table) >= 3  # 3 pages pad to 4: the trash page rides
+        c = e.cache
+        planes = {"k": c.k, "v": c.v}
+        if c.quantized:
+            planes.update(k_scale=c.k_scale, v_scale=c.v_scale)
+        want = {nm: np.asarray(a)[:, table] for nm, a in planes.items()}
+        assert tier._spill(slot, urgent=True, now=time.perf_counter())
+        _settle(e)
+        ent = next(x for x in tier._entries.values()
+                   if x.tokens[:8] == head and x.n == n)
+        assert len(ent.hpids) == len(table)
+        for j, hpid in enumerate(ent.hpids):
+            arrays = tier._host[hpid].arrays
+            assert set(arrays) == set(planes)
+            for nm, a in arrays.items():
+                assert a.dtype == want[nm].dtype
+                assert np.array_equal(a, want[nm][:, j]), (nm, j)
+        _leak_checks(e)
+    finally:
+        if e is not eng:
+            e.close()
+
+
+def test_profile_kv_gather_mode_rehearsal_on_the_cpu():
+    """tools/profile_kv.py --gather at its smoke planes: every point
+    bit-equal to plain indexing, and no CPU time under a device
+    metric's name."""
+    from tools.profile_kv import _SMALL_PLANES, gather_alone
+
+    rep = gather_alone(_SMALL_PLANES, calls=2)
+    assert rep["ok"] and len(rep["points"]) == 4
+    assert rep["device"]["platform"] == "cpu"
+    for p in rep["points"]:
+        assert p["bit_equal"] and p["roof_us"] is None
+        assert p["_gather_pages"] == {"us_call": "not measured"}
+
+
+@pytest.fixture(scope="module")
+def one_v5e():
+    """One described (not attached) v5e chip: the TPU's compiler is
+    installed in the sandbox. Made inside a fixture, never at import —
+    only one process may hold libtpu."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    with pytest.MonkeyPatch.context() as mp:
+        if "TPU_LOG_DIR" not in os.environ:  # or libtpu logs under /tmp
+            mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2")
+        except Exception as e:  # no libtpu here, or another process's
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+
+
+def _cell_planes():
+    """tools/profile_kv.py's planes — the benchmark cells' pool planes
+    with the page counts their spills pad to — one case a page count.
+    Not among them: a scale plane with b = 1, where the compiler
+    prefetches the whole 8 MB parameter (10 us) for any form."""
+    from tools.profile_kv import _CELL_PLANES, parse_plane
+
+    for spec in _CELL_PLANES:
+        dt, shape, bs = parse_plane(spec)
+        for b in bs:
+            yield pytest.param(shape, dt, b,
+                               id=f"{spec.rsplit(':', 1)[0]}:{b}")
+
+
+@pytest.mark.parametrize("shape,dtype,b", _cell_planes())
+def test_gather_compiled_for_a_v5e_moves_only_its_pages(one_v5e, shape,
+                                                        dtype, b):
+    """At the cells' plane shapes the compiled program touches at most
+    4 x the bytes it has to move (b pages read, b written) and holds
+    fewer temporaries than its output: a compiler or a refactor that
+    brings the whole-pool copy back (2.2 GB of temporaries, 68 x the
+    bytes at Mistral's plane) fails here, not on a ledger line. The
+    compiler's estimates, no timing."""
+    from localai_tfp_tpu.engine.kv_tier import _gather_pages
+
+    compiled = _gather_pages.lower(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e),
+        jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_v5e)).compile()
+    out_bytes = b * int(np.prod(shape)) // shape[1] \
+        * jnp.dtype(dtype).itemsize
+    cost = compiled.cost_analysis()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= out_bytes
+    assert cost["bytes accessed"] <= 4 * 2 * out_bytes, cost
+    # (a scale plane's pages change layout on the way out: one more
+    # copy of the 128 KB moved, nothing of the 8 MB plane)
+    room = 2 if len(shape) == 3 else 1
+    assert mem.temp_size_in_bytes < room * mem.output_size_in_bytes, (
+        mem.temp_size_in_bytes, mem.output_size_in_bytes)

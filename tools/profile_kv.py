@@ -34,6 +34,22 @@ prefetched back from host RAM. Reports resident-session capacity
 avoided, and re-prefill tokens paid on hits (must be ZERO — a hit
 promotes the full covered prefix by reference).
 
+  python tools/profile_kv.py --gather [--small] \
+      [--plane DTYPE:L,PAGES,...:B[,B...]]...
+
+times the device half of a spill ALONE (engine/kv_tier.py
+``_gather_pages``; no engine, no model): for each pool plane and page
+count it runs the shipped program beside the plain ``arr[:, tbl]`` it
+has to equal bit for bit, and reports the device time a call (the
+profiler's module events — chip only; "not measured" on a CPU) against
+the bytes the call has to move (``b`` pages read, ``b`` written) over
+the chip's HBM peak. The default planes are the two benchmark cells'
+(Mistral: int8 K/V + float32 scale planes; Trinity: bfloat16 K/V). Run
+it on the chip for a new pool shape before trusting a spill's cost. (At
+ONE page both forms lower to the same program, which the compile cache
+loads under the shipped form's name: the reference then reads "not
+measured" and the shipped form counts both forms' events.)
+
 ``--small`` runs the tiny CPU config (smoke) with a 16-token page so
 page-granular sharing is visible at toy prompt lengths.
 """
@@ -338,6 +354,128 @@ def returning_users_shape(small: bool, n_users: int) -> dict:
     return out
 
 
+# the benchmark cells' pool planes and the page counts their spills pad
+# to (PERF.md section 5): Mistral int8 K/V + scale planes, Trinity bf16
+_CELL_PLANES = ("int8:32,257,256,1024:2,4", "float32:32,257,256:4",
+                "bfloat16:8,257,256,512:16")
+_SMALL_PLANES = ("int8:2,33,16,128:1,4", "float32:2,33,16:4",
+                 "bfloat16:2,33,16,64:16")
+
+
+def _take_pages(arr, tbl):
+    """What a spill's gather has to equal: plain indexing (the form the
+    tier shipped until PR 42, which copied the whole plane on a v5e)."""
+    return arr[:, tbl]
+
+
+def _module_times_us(trace_dir: str, name: str) -> list:
+    """Device time of every run of the program ``jit_<name>`` in the
+    one capture under ``trace_dir`` — the benchmark's own reduction
+    (benchmark/lib/trace.py), so ``breakdown`` reads the same events."""
+    import glob
+
+    from benchmark.lib import trace as T
+
+    (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    tr = T.dump(path)
+    if not T.chip_planes(tr):  # a CPU capture has no device plane
+        return []
+    return [e[2] / 1e3 for e in T.module_events(tr, (f"jit_{name}",))]
+
+
+def parse_plane(spec: str):
+    """``DTYPE:L,PAGES,...:B[,B...]`` -> (dtype name, shape, page
+    counts)."""
+    dt, shape, bs = spec.split(":")
+    return (dt, tuple(int(x) for x in shape.split(",")),
+            tuple(int(x) for x in bs.split(",")))
+
+
+def gather_alone(planes, calls: int = 10) -> dict:
+    """Each plane's gather alone: shipped form against the reference
+    form — outputs bit-equal, device microseconds a call, and the
+    share of the HBM roof (bytes moved / peak / time)."""
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from localai_tfp_tpu.engine.kv_pool import TRASH_PAGE
+    from localai_tfp_tpu.engine.kv_tier import _gather_pages
+    from localai_tfp_tpu.telemetry.costmodel import peak_rates
+
+    dev = jax.devices()[0]
+    on_chip = dev.platform == "tpu"
+    hbm = peak_rates(dev.device_kind)[1] if on_chip else None
+    forms = {"_gather_pages": _gather_pages,
+             "_take_pages": jax.jit(_take_pages)}
+    out: dict = {"device": {"platform": dev.platform,
+                            "kind": dev.device_kind},
+                 "hbm_bytes_per_s": hbm, "calls": calls, "points": []}
+    for spec in planes:
+        dt, shape, bs = parse_plane(spec)
+        dtype = jnp.dtype(dt)
+        # one layer of random pages, shifted by the layer's number (a
+        # 2 GB plane drawn whole would need 4 x that in temporaries)
+        key = jax.random.PRNGKey(len(out["points"]))
+        if dtype == jnp.int8:
+            base = jax.random.randint(key, shape[1:], -128, 128, jnp.int8)
+        else:
+            base = jax.random.normal(key, shape[1:],
+                                     jnp.float32).astype(dtype)
+        layer = jnp.arange(shape[0]).astype(dtype).reshape(
+            (-1,) + (1,) * (len(shape) - 1))
+        arr = jax.block_until_ready(jax.jit(jnp.add)(base[None], layer))
+        del base
+        rng = np.random.default_rng(0)
+        for b in bs:
+            # a spill's table: distinct page ids, the tail padded with
+            # the trash page (3 real ids of 4, as _pow2 pads), and one
+            # id twice where there is room (the gather does not care)
+            ids = rng.choice(np.arange(1, shape[1]), size=b,
+                             replace=False).astype(np.int32)
+            if b >= 4:
+                ids[-1] = TRASH_PAGE
+                ids[1] = ids[0]
+            tbl = jnp.asarray(ids)
+            moved = 2 * b * int(np.prod(shape)) // shape[1] \
+                * dtype.itemsize
+            point = {"plane": f"{dt}{list(shape)}", "pages": b,
+                     "bytes_moved": moved,
+                     "roof_us": (round(moved / hbm * 1e6, 2)
+                                 if hbm else None)}
+            outs = {}
+            for name, fn in forms.items():
+                outs[name] = jax.block_until_ready(fn(arr, tbl))  # warm
+            ref = np.asarray(outs["_take_pages"]).view(np.uint8)
+            point["bit_equal"] = all(
+                np.array_equal(np.asarray(o).view(np.uint8), ref)
+                for o in outs.values())
+            del outs, ref
+            with tempfile.TemporaryDirectory() as tmp:
+                with jax.profiler.trace(tmp):
+                    for fn in forms.values():
+                        for _ in range(calls):
+                            jax.block_until_ready(fn(arr, tbl))
+                for name in forms:
+                    us = _module_times_us(tmp, name)
+                    if on_chip and us:
+                        med = float(np.median(us))
+                        point[name] = {
+                            "us_call": round(med, 2), "events": len(us),
+                            "roof_share": round(moved / hbm / med * 1e6,
+                                                4)}
+                    else:
+                        point[name] = {"us_call": "not measured"}
+            out["points"].append(point)
+        del arr
+    stats = dev.memory_stats() or {}
+    out["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
+    out["ok"] = all(p["bit_equal"] for p in out["points"])
+    return out
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--small", action="store_true",
@@ -348,6 +486,13 @@ def main() -> None:
                     help="sustained streams + admission bursts")
     ap.add_argument("--returning-users", action="store_true",
                     help="session churn + return: KV tiering on vs off")
+    ap.add_argument("--gather", action="store_true",
+                    help="time the tier's spill gather alone")
+    ap.add_argument("--plane", action="append", default=None,
+                    metavar="DTYPE:SHAPE:PAGES",
+                    help="a pool plane for --gather, e.g. "
+                    "int8:32,257,256,1024:2,4 (repeatable; default: "
+                    "the benchmark cells' planes)")
     ap.add_argument("--users", type=int, default=16)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prefix-tokens", type=int, default=96)
@@ -355,9 +500,15 @@ def main() -> None:
     ap.add_argument("--bursts", type=int, default=3)
     ap.add_argument("--burst-size", type=int, default=4)
     args = ap.parse_args()
+    if args.gather:
+        # no engine, no model: the gather's program alone
+        rep = gather_alone(args.plane or (
+            _SMALL_PLANES if args.small else _CELL_PLANES))
+        print(json.dumps(rep, indent=1), flush=True)
+        sys.exit(0 if rep["ok"] else 1)
     if not (args.shared_prefix or args.mixed or args.returning_users):
-        ap.error("pick a traffic shape: --shared-prefix, --mixed "
-                 "and/or --returning-users")
+        ap.error("pick a traffic shape: --shared-prefix, --mixed, "
+                 "--returning-users or --gather")
     report: dict = {}
     if args.shared_prefix:
         report["shared_prefix"] = shared_prefix_shape(
