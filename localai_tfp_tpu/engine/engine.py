@@ -75,6 +75,7 @@ from ..ops.sampling import (
 )
 from ..telemetry import costmodel, hbm_ledger
 from ..telemetry import metrics as tm
+from ..telemetry import flightrec
 from ..telemetry.flightrec import (
     FLIGHT, LoadWatch, PhaseClock, name_os_thread, note_jit,
 )
@@ -822,7 +823,15 @@ class LLMEngine:
         # scans from it
         self._last_decode_adv = 0.0  # perf_counter of the last dispatch
         # that advanced >=1 decode row; gaps between consecutive ones
-        # while a slot decodes feed engine_decode_stall_seconds
+        # while a slot decodes feed engine_decode_stall_seconds, and a
+        # gap that is a stall (flightrec.STALL_MIN_S / STALL_RATIO) is
+        # named from what the scheduler's spans, its CPU clock, the
+        # load watch and the collector added since that dispatch
+        self._gaps = flightrec.GapMean()  # running mean of those gaps
+        self._adv_spans: dict[str, float] = {}
+        self._adv_cpu = self._adv_gc = self._adv_load = 0.0
+        self._starved_t0 = 0.0  # when _harvest emptied the flight
+        # queue with work left; 0.0 while a step is queued
         self.warmup_reused = False  # True when warmup() was skipped
         # because an identical variant set is already in the persistent
         # compile cache (see warmup docstring); surfaced in the load
@@ -834,10 +843,25 @@ class LLMEngine:
         # children bound once; program loads attributed to the dispatch
         # that stood still for them; token counts at the dispatch sites
         self._phases = PhaseClock()
-        self._phase_pub = dict.fromkeys(self._phases.totals, 0.0)
         self._phase_ctr = {
             ph: tm.ENGINE_SCHED_PHASE.labels(model=self._mlabel, phase=ph)
-            for ph in self._phases.totals}
+            for ph in flightrec.PHASES}
+        # the same seconds by span name; a dispatch kind's
+        # sched:enqueue:<kind> joins on first use
+        self._span_pub = dict.fromkeys(self._phases.by_name, 0.0)
+        self._span_ctr = {
+            name: tm.ENGINE_SCHED_SPAN.labels(model=self._mlabel,
+                                              span=name)
+            for name in self._phases.by_name}
+        # bound at start, so a window without a stall scrapes a 0
+        self._stall_ctr = {
+            c: (tm.ENGINE_SCHED_STALLS.labels(model=self._mlabel, cause=c),
+                tm.ENGINE_SCHED_STALL_SECONDS.labels(model=self._mlabel,
+                                                     cause=c))
+            for c in flightrec.STALL_CAUSES}
+        self._starved_ctr = tm.ENGINE_DEVICE_STARVED.labels(
+            model=self._mlabel)
+        flightrec.watch_gc()
         self._loads = LoadWatch(self._mlabel)
         self._in_warmup = False
         self._tok_ctr: dict = {}  # kind -> its bound counter children
@@ -1737,6 +1761,7 @@ class LLMEngine:
         self._note_ragged_rows("verify", len(decoding))
         D, Mt, J = self._run("spec_s" if mode == "sampled" else "spec",
                              payload)
+        self._note_enqueue()
         # lint: ignore[hot-path-sync] spec verify is a deliberately blocking dispatch: emission needs J/D/Mt on host before the next spec round is sized
         D = np.asarray(D)  # [rounds, S, kd-1] draft candidates
         # lint: ignore[hot-path-sync] same blocking spec harvest (see D above)
@@ -2823,8 +2848,7 @@ class LLMEngine:
         with span("sched:gauges", root=True):
             self._update_gauges()
         if not (harvested or dispatched):
-            with span("sched:wait", root=True):
-                self._wait_for_event()
+            self._wait_for_event()
 
     def _guards(self) -> None:
         """Request lifecycle guards, ahead of admission."""
@@ -2865,13 +2889,23 @@ class LLMEngine:
         # step:/load:/sched: spans for minutes
         FLIGHT.sample("queue_depth", "scheduler", len(self._pending))
         FLIGHT.sample("slots_busy", "scheduler", busy)
-        # phase self times the spans added since the last pass
-        pub = self._phase_pub
-        for ph, total in self._phases.totals.items():
-            d = total - pub[ph]
+        # self times the spans added since the last pass, under their
+        # names and under their phases
+        pub = self._span_pub
+        for name, total in self._phases.by_name.items():
+            d = total - pub.get(name, 0.0)
             if d > 0.0:
-                pub[ph] = total
-                self._phase_ctr[ph].inc(d)
+                pub[name] = total
+                ctr = self._span_ctr.get(name)
+                if ctr is None:
+                    ctr = self._span_ctr[name] = tm.ENGINE_SCHED_SPAN.labels(
+                        model=m, span=name)
+                ctr.inc(d)
+                self._phase_ctr[self._phases.phase_of[name]].inc(d)
+        if self._starved_t0 and not self._has_work():
+            # the work left when the queue drained is gone (a cancel, a
+            # deadline): nothing is starved of it
+            self._starved_t0 = 0.0
         used = sum(s.n_past for s in self.slots if s.active)
         tm.ENGINE_KV_UTIL.labels(model=m).set(
             used / float(self.n_slots * self.max_seq))
@@ -3080,18 +3114,25 @@ class LLMEngine:
         alone is not an event — with every slot busy a queued request
         can't be dispatched, and returning on it would hot-spin the
         scheduler for the length of every in-flight scan), or a cancel
-        fires."""
+        fires. All of it is ``sched:wait`` — left and entered again
+        when a /debug/profile capture starts or stops under it: an
+        annotation cannot be backdated, and this is the span most
+        likely to be open when the flag flips."""
         while True:
-            with self._lock:
-                if self._stop or self._cancelled:
-                    return
-                if self._pending and any(not s.active for s in self.slots):
-                    return
-            if not self._flights:
-                return
-            if self._flights[0].ready():
-                return
-            time.sleep(5e-4)
+            cap = flightrec.capturing()
+            with self._phases.span("sched:wait", root=True):
+                while cap == flightrec.capturing():
+                    with self._lock:
+                        if self._stop or self._cancelled:
+                            return
+                        if self._pending and any(
+                                not s.active for s in self.slots):
+                            return
+                    if not self._flights:
+                        return
+                    if self._flights[0].ready():
+                        return
+                    time.sleep(5e-4)
 
     def _harvest(self) -> bool:
         """Complete ready flights in FIFO order (device execution is
@@ -3128,6 +3169,11 @@ class LLMEngine:
                     self._complete_decodek(fl)
                 self._note_expert_stats(fl.kind, *fl.meta["experts"])
             did = True
+        if (did and not self._flights and not self._starved_t0
+                and self._has_work()):
+            # the device has no step queued and the engine has work:
+            # starved until the next enqueue (_note_enqueue)
+            self._starved_t0 = time.perf_counter()
         return did
 
     # lint: endregion hot_path
@@ -3136,16 +3182,24 @@ class LLMEngine:
     # to a GLOBAL prefix cache: radix index over every slot's resident
     # prefix + on-device cross-slot row copies)
     def _admit(self) -> None:
-        if self._pager is not None:
-            # weight-pager hook: work arriving while a demotion's D2H
-            # stream is aloft flips its abort flag — never blocks
-            self._pager.tick()
-        if self._tier is not None:
-            # tier policy tick rides the admission pass: harvest landed
-            # spill/fetch DMAs, apply background IO results, expire
-            # stale stages, run the watermark demotion scan. Entirely
-            # non-blocking (TransferWindow.reap + is_ready polling).
-            self._tier.tick()
+        """One admission pass. Inside ``sched:admit`` (step()) its
+        parts are spans of their own, ``sched:admit:<part>``
+        (flightrec.ADMIT_PARTS): each adds to phase ``admit`` and to
+        its own name, so the pass reads by cause; what is left under
+        the bare name is the queue swap and the cancel handling."""
+        span = self._phases.span
+        with span("sched:admit:tier"):
+            if self._pager is not None:
+                # weight-pager hook: work arriving while a demotion's
+                # D2H stream is aloft flips its abort flag — never blocks
+                self._pager.tick()
+            if self._tier is not None:
+                # tier policy tick rides the admission pass: harvest
+                # landed spill/fetch DMAs, apply background IO results,
+                # expire stale stages, run the watermark demotion scan.
+                # Entirely non-blocking (TransferWindow.reap + is_ready
+                # polling).
+                self._tier.tick()
         with self._lock:
             pending, self._pending = self._pending, []
         if not pending:
@@ -3160,19 +3214,22 @@ class LLMEngine:
                 self._pending[:0] = pending
             time.sleep(0.002)
             return
-        if self._prefix_enabled:
-            # lazy re-register: decode appends / window clamps since the
-            # last wave are diffed in (extension is the common case)
-            self._prefix_index.sync(
-                (s.idx, s.cache_tokens) for s in self.slots)
-        # prompts admitted but whose prefill has NOT yet dispatched:
-        # their KV is uncommitted, so the index cannot serve them yet —
-        # same-wave sharers defer one iteration behind them instead
-        # (one prefix prefill + N copies serves the whole wave)
-        forming = [s.request.prompt_ids for s in self.slots
-                   if s.state is SlotState.PREFILL
-                   and s.request is not None
-                   and s.request.soft_embeds is None]
+        with span("sched:admit:prefix"):
+            if self._prefix_enabled:
+                # lazy re-register: decode appends / window clamps since
+                # the last wave are diffed in (extension is the common
+                # case)
+                self._prefix_index.sync(
+                    (s.idx, s.cache_tokens) for s in self.slots)
+            # prompts admitted but whose prefill has NOT yet dispatched:
+            # their KV is uncommitted, so the index cannot serve them
+            # yet — same-wave sharers defer one iteration behind them
+            # instead (one prefix prefill + N copies serves the whole
+            # wave)
+            forming = [s.request.prompt_ids for s in self.slots
+                       if s.state is SlotState.PREFILL
+                       and s.request is not None
+                       and s.request.soft_embeds is None]
         requeue: list[tuple[GenRequest, queue.SimpleQueue]] = []
         now = time.perf_counter()
         for req, out in pending:
@@ -3199,25 +3256,10 @@ class LLMEngine:
                 tm.ENGINE_CANCELLATIONS.labels(model=self._mlabel,
                                                reason="client").inc()
                 continue
-            if req.disagg is None and self._defer_for_prefix(
-                    req, forming, now):
-                requeue.append((req, out))
-                continue
-            if (self._tier is not None and req.soft_embeds is None
-                    and req.disagg is None
-                    and self._tier.plan(req, now)):
-                # the session's KV is in the cold tier and its disk
-                # load is inside the deadline window: hold admission
-                # (overlapped with queue wait) instead of re-prefilling
-                requeue.append((req, out))
-                continue
-            slot = self._pick_slot(req)
+            with span("sched:admit:place"):
+                slot = self._place(req, forming, now)
             if slot is None:
-                requeue.append((req, out))  # no free slot
-                continue
-            if self._paged and not self._page_headroom(req):
-                requeue.append((req, out))  # pool full of ACTIVE state:
-                # wait for a release instead of admit-then-kill thrash
+                requeue.append((req, out))
                 continue
             self._deferred.pop(req.id, None)
             if req.disagg is not None and self._migrator is not None:
@@ -3232,8 +3274,12 @@ class LLMEngine:
                 # falls through to _assign below — an ordinary
                 # re-prefill, correct just slower.
                 if self._tier is not None and req.soft_embeds is None:
-                    self._tier.capture(slot, req)
-                if self._migrator.assign_migrated(slot, req, out):
+                    with span("sched:admit:spill"):
+                        self._tier.capture(slot, req)
+                with span("sched:admit:assign"):
+                    migrated = self._migrator.assign_migrated(
+                        slot, req, out)
+                if migrated:
                     continue
                 req.disagg = None
             if self._tier is not None and req.soft_embeds is None:
@@ -3244,14 +3290,38 @@ class LLMEngine:
                 # resident prefix becomes the fetched session (share by
                 # reference), so _assign's ordinary prefix-reuse path
                 # skips those tokens — a prefetch hit re-prefills zero
-                self._tier.capture(slot, req)
-                self._tier.adopt(slot, req)
-            self._assign(slot, req, out)
+                with span("sched:admit:spill"):
+                    self._tier.capture(slot, req)
+                    self._tier.adopt(slot, req)
+            with span("sched:admit:assign"):
+                self._assign(slot, req, out)
             if req.soft_embeds is None:
                 forming.append(req.prompt_ids)
         if requeue:
             with self._lock:  # preserve arrival order over new arrivals
                 self._pending[:0] = requeue
+
+    def _place(self, req: GenRequest, forming: list,
+               now: float) -> Optional[_Slot]:
+        """The slot ``req`` is admitted onto in this pass, or None when
+        it waits for the next one."""
+        if req.disagg is None and self._defer_for_prefix(
+                req, forming, now):
+            return None
+        if (self._tier is not None and req.soft_embeds is None
+                and req.disagg is None and self._tier.plan(req, now)):
+            # the session's KV is in the cold tier and its disk load is
+            # inside the deadline window: hold admission (overlapped
+            # with queue wait) instead of re-prefilling
+            return None
+        slot = self._pick_slot(req)
+        if slot is None:
+            return None  # no free slot
+        if self._paged and not self._page_headroom(req):
+            # pool full of ACTIVE state: wait for a release instead of
+            # admit-then-kill thrash
+            return None
+        return slot
 
     def _defer_for_prefix(self, req: GenRequest, forming: list,
                           now: float) -> bool:
@@ -3987,6 +4057,7 @@ class LLMEngine:
                                             window)
             payload["wb"] = self._wb_rows(spans, window)
         toks_out = self._run("mixed", payload)
+        self._note_enqueue()
         toks_out.copy_to_host_async()
         experts = self._take_expert_stats()
         t_disp = time.perf_counter()
@@ -4113,16 +4184,70 @@ class LLMEngine:
         self.metrics.slots_busy = sum(1 for s in self.slots if s.active)
     # lint: endregion hot_path
 
+    def _note_enqueue(self) -> None:
+        """A step is queued again: what _harvest found starved ends."""
+        t0 = self._starved_t0
+        if t0:
+            self._starved_t0 = 0.0
+            self._starved_ctr.inc(time.perf_counter() - t0)
+
     def _note_decode_advance(self, now: float) -> None:
         """Stall accounting: observe the gap between consecutive
         decode-advancing dispatches while >=1 slot decodes
         (engine_decode_stall_seconds — the series the legacy holds
         spiked and the mixed dispatcher bounds). _update_gauges resets
-        the clock whenever no slot is decoding."""
-        if self._last_decode_adv:
-            tm.ENGINE_DECODE_STALL.labels(model=self._mlabel).observe(
-                max(0.0, now - self._last_decode_adv))
+        the clock whenever no slot is decoding. A gap of at least
+        STALL_MIN_S and STALL_RATIO x the time-weighted running mean
+        of the gaps before it (flightrec.GapMean) is a STALL, counted and named where it happened (_note_stall); what
+        naming it needs is kept at every advance: one copy of the
+        clock's per-span seconds, one thread_time()."""
+        spans = self._phases.snapshot(time.perf_counter())
+        cpu = time.thread_time()
+        last = self._last_decode_adv
+        if last:
+            gap = max(0.0, now - last)
+            tm.ENGINE_DECODE_STALL.labels(model=self._mlabel).observe(gap)
+            mean = self._gaps.mean
+            if self._gaps.note(gap):
+                self._note_stall(gap, mean, spans, cpu)
         self._last_decode_adv = now
+        self._adv_spans, self._adv_cpu = spans, cpu
+        self._adv_gc = flightrec.gc_seconds()
+        self._adv_load = self._loads.blocked_s
+
+    def _note_stall(self, gap: float, mean: float, spans: dict,
+                    cpu: float) -> None:
+        """Count one stall under its cause, log its whole split, mark
+        it on the timeline. Runs only when a stall happened."""
+        was = self._adv_spans
+        split = {name: sec - was.get(name, 0.0)
+                 for name, sec in spans.items()
+                 if sec - was.get(name, 0.0) >= 1e-4}
+        load_s = self._loads.blocked_s - self._adv_load
+        cause = flightrec.stall_cause(split, gap, load_s)
+        n, sec = self._stall_ctr[cause]
+        n.inc()
+        sec.inc(gap)
+        args = {
+            "gap_s": round(gap, 4),
+            # CPU << wall inside a working span: the thread was blocked
+            # or starved of the GIL, not computing
+            "cpu_s": round(cpu - self._adv_cpu, 4),
+            "gc_s": round(flightrec.gc_seconds() - self._adv_gc, 4),
+            "load_s": round(load_s, 4),
+            "queue_depth": len(self._pending),
+            "slots_busy": sum(1 for s in self.slots if s.active),
+            "split": {k: round(v, 4) for k, v in sorted(
+                split.items(), key=lambda kv: -kv[1])},
+        }
+        FLIGHT.instant("stall:" + cause, flightrec.SCHED_TRACK, args)
+        log.warning(
+            "decode stall (%s): %.3fs between two decode-advancing "
+            "dispatches, cause=%s wall_s=%.3f cpu_s=%.3f gc_s=%.3f "
+            "load_s=%.3f mean_gap_s=%.4f queue_depth=%d slots_busy=%d "
+            "split=%s", self._mlabel, gap, cause, gap, args["cpu_s"],
+            args["gc_s"], load_s, mean, args["queue_depth"],
+            args["slots_busy"], args["split"])
 
     def _context_tokens(self, rows) -> tuple[float, int]:
         """(read, held) context tokens of ``rows`` = (pos, n) pairs: a
@@ -4516,6 +4641,7 @@ class LLMEngine:
             "decodek", len(decoding) * k, S * k,
             [(int(pos0[s.idx]), k) for s in decoding], steps=k)
         batches = self._run("decodek", payload)
+        self._note_enqueue()
         toks = batches[0]
         toks.copy_to_host_async()
         experts = self._take_expert_stats()
@@ -4660,6 +4786,7 @@ class LLMEngine:
             "decode1", len(decoding), S,
             [(s.n_past, 1) for s in decoding], steps=1)
         toks = self._run("decode1", payload)
+        self._note_enqueue()
         experts = self._take_expert_stats()
         # lint: ignore[hot-path-sync] decode1 IS the blocking path: grammar masks / logit bias need every token on host before the next dispatch
         toks_host = np.asarray(toks)

@@ -30,14 +30,20 @@ Two further pieces live here because they write to the same ring and
 share its cost discipline:
 
 - ``PhaseClock`` — the scheduler's phase spans (``sched:guards``,
-  ``sched:admit``, ``sched:harvest`` > ``sched:emit``, ``sched:dispatch``
-  > ``sched:enqueue:<kind>``, ``sched:gauges``, ``sched:wait``). A span
-  always adds its SELF time to a plain float (published by the engine
-  as ``engine_sched_phase_seconds_total``); it enters the ring only
+  ``sched:admit`` > ``sched:admit:<part>`` (``ADMIT_PARTS``: ``tier``,
+  ``prefix``, ``place``, ``spill``, ``assign``), ``sched:harvest`` >
+  ``sched:emit``, ``sched:dispatch`` > ``sched:enqueue:<kind>``,
+  ``sched:state``, ``sched:gauges``, ``sched:wait``; a name's SECOND
+  field is its phase, one of ``PHASES``). A span always adds its SELF
+  time to a plain float under its name (published by the engine as
+  ``engine_sched_span_seconds_total`` and, summed by phase, as
+  ``engine_sched_phase_seconds_total``); it enters the ring only
   when it lasted >= 1 ms; and only while a ``/debug/profile`` capture
   runs (``set_capturing``) is it also a ``jax.profiler.TraceAnnotation``
   — which puts it in the host plane of the capture's own ``.xplane.pb``,
   on the clock of the device's ``XLA Modules``/``XLA Ops`` lines.
+  ``PhaseClock.snapshot`` is what a decode stall is named from
+  (``stall_cause``): the per-span seconds between two dispatches.
 - ``LoadWatch`` — program loads. The first execution in this process of
   a (program, input signature) traces, lowers, and compiles or fetches
   the executable from the persistent cache while the calling thread
@@ -51,6 +57,7 @@ share its cost discipline:
 from __future__ import annotations
 
 import collections
+import gc
 import logging
 import threading
 import time
@@ -288,6 +295,18 @@ SCHED_TRACK = "scheduler"
 PHASES = ("guards", "admit", "harvest", "emit", "dispatch", "enqueue",
           "state",
           "gauges", "wait")
+# the parts of an admission pass, ``sched:admit:<part>``: a closed set
+# too. Each adds to phase ``admit`` and to its own name
+ADMIT_PARTS = ("tier", "prefix", "place", "spill", "assign")
+# the span names every scheduler publishes from its start (a dispatch
+# kind's ``sched:enqueue:<kind>`` joins on first use)
+SPAN_NAMES = tuple("sched:" + ph for ph in PHASES) + tuple(
+    "sched:admit:" + part for part in ADMIT_PARTS)
+# what a decode stall can be blamed on (the ``cause`` label of
+# engine_sched_stalls_total): a phase, a part of the admission pass,
+# program loads, or nothing the clock covers
+STALL_CAUSES = PHASES + tuple("admit:" + p for p in ADMIT_PARTS) + (
+    "load", "unnamed")
 # a span shorter than this stays out of the ring: /debug/timeline shows
 # a stall by name, not microsecond noise
 RING_MIN_S = 1e-3
@@ -317,10 +336,15 @@ class _Span:
     def __init__(self, clock: "PhaseClock", stack: list,
                  name: str) -> None:
         self.clock, self.stack, self.name = clock, stack, name
-        self.phase = name.split(":")[1]
-        if self.phase not in PHASES:
-            # the counter's label values are a closed set
+        parts = name.split(":")
+        self.phase = parts[1]
+        if self.phase not in PHASES or (
+                self.phase == "admit" and len(parts) > 2
+                and parts[2] not in ADMIT_PARTS):
+            # the counters' label values are closed sets
             raise ValueError(f"unknown scheduler phase in {name!r}")
+        clock.phase_of[name] = self.phase
+        clock.by_name.setdefault(name, 0.0)
         self.args: Optional[dict] = None
         self.t0 = 0.0
         self.child = 0.0  # seconds covered by child spans
@@ -344,7 +368,7 @@ class _Span:
         stack.pop()
         if stack:
             stack[-1].child += dur
-        self.clock.totals[self.phase] += dur - self.child
+        self.clock.by_name[self.name] += dur - self.child
         if dur >= RING_MIN_S:
             FLIGHT.span(self.name, self.clock.track, self.t0, dur,
                         self.args)
@@ -355,16 +379,44 @@ class PhaseClock:
     """Scheduler phase spans, balanced by construction: the only way in
     is ``with clock.span(name):``. Spans nest by the ``with`` nesting on
     their thread (stacks are per thread); a span's SELF time — its
-    duration minus its children's — goes to ``totals[phase]``, so the
-    phases tile the thread's wall time. A span asked for on a thread
-    with no open root returns a no-op unless ``root=True``: dispatches
-    from other threads (``embed``, ``warmup``) are not scheduler time.
+    duration minus its children's — goes to ``by_name[name]``, so the
+    names, and the phases they sum to (``totals``), tile the thread's
+    wall time. A span asked for on a thread with no open root returns a
+    no-op unless ``root=True``: dispatches from other threads
+    (``embed``, ``warmup``) are not scheduler time.
     """
 
     def __init__(self, track: str = SCHED_TRACK) -> None:
         self.track = track
-        self.totals: dict[str, float] = dict.fromkeys(PHASES, 0.0)
+        self.by_name: dict[str, float] = dict.fromkeys(SPAN_NAMES, 0.0)
+        self.phase_of: dict[str, str] = {
+            name: name.split(":")[1] for name in SPAN_NAMES}
         self._tls = threading.local()
+
+    @property
+    def totals(self) -> dict[str, float]:
+        """Self seconds by phase: ``by_name`` summed over each phase's
+        names."""
+        out = dict.fromkeys(PHASES, 0.0)
+        # (a copy: /backend/monitor reads this from another thread
+        # while the scheduler may be adding a dispatch kind's name)
+        for name, sec in list(self.by_name.items()):
+            out[self.phase_of[name]] += sec
+        return out
+
+    def snapshot(self, now: float) -> dict[str, float]:
+        """``by_name`` as it would read if every span open on the
+        calling thread closed at ``now``: a copy, plus each open span's
+        self time so far — so the difference of two snapshots tiles the
+        time between them, whatever was open at either end."""
+        out = dict(self.by_name)
+        below = now
+        for sp in reversed(getattr(self._tls, "stack", ())):
+            # the span above this one opened at ``below``: what it has
+            # covered since is not yet in this one's ``child``
+            out[sp.name] += below - sp.t0 - sp.child
+            below = sp.t0
+        return out
 
     def span(self, name: str, args: Optional[dict] = None,
              root: bool = False):
@@ -380,6 +432,105 @@ class PhaseClock:
             sp = spans[name] = _Span(self, stack, name)
         sp.args = args
         return sp
+
+
+# ---------------------------------------------------- decode stalls
+
+# ONE fixed rule (engine.py ``_note_decode_advance``): the gap between
+# two decode-advancing dispatches is a stall when it lasted at least
+# STALL_MIN_S AND at least STALL_RATIO x the running mean of the gaps
+# before it, once those have covered STALL_HORIZON_S. The mean is
+# weighted by TIME: a model whose admission enqueues a chain of prompt
+# steps milliseconds apart and then waits 0.3 s for the device to run
+# them has many short gaps and one long one a cycle, and its ordinary
+# long gap is no stall
+STALL_MIN_S = 0.25
+STALL_RATIO = 3.0
+STALL_HORIZON_S = 2.0
+
+
+class GapMean:
+    """The running mean gap, each gap counted by the time it covered:
+    a gap moves the mean ``gap / STALL_HORIZON_S`` of the way to itself
+    (all the way for a longer one) — the mean of the gap a moment of
+    the last seconds fell in. Stalls count into it too, so a regime
+    whose ordinary gap has grown stops reading as stalls."""
+
+    __slots__ = ("mean", "seen")
+
+    def __init__(self) -> None:
+        self.mean = 0.0
+        self.seen = 0.0  # seconds of gaps the mean has taken in
+
+    def note(self, gap: float) -> bool:
+        """Whether ``gap`` is a stall against the gaps before it; then
+        it is one of them."""
+        stall = (self.seen >= STALL_HORIZON_S and gap >= STALL_MIN_S
+                 and gap >= STALL_RATIO * self.mean)
+        if self.seen:
+            self.mean += min(1.0, gap / STALL_HORIZON_S) * (gap - self.mean)
+        else:
+            self.mean = gap
+        self.seen += gap
+        return stall
+
+
+def cause_of(name: str) -> str:
+    """``sched:admit:tier`` -> ``admit:tier``; any other span -> its
+    phase (``sched:enqueue:mixed`` -> ``enqueue``)."""
+    parts = name.split(":")
+    if parts[1] == "admit" and len(parts) > 2:
+        return "admit:" + parts[2]
+    return parts[1]
+
+
+def stall_cause(split: dict[str, float], gap: float,
+                load_s: float) -> str:
+    """Name a stall of ``gap`` seconds from the self seconds each span
+    added over it (the difference of two ``PhaseClock.snapshot``s):
+    ``load`` when program loads that closed inside the gap took half of
+    it or more (a load hides inside the span that stood still for it),
+    else the cause with the largest share, ``unnamed`` when what no
+    span covers is larger than any."""
+    if load_s >= 0.5 * gap:
+        return "load"
+    by: dict[str, float] = {}
+    for name, sec in split.items():
+        c = cause_of(name)
+        by[c] = by.get(c, 0.0) + sec
+    best = max(by, key=by.__getitem__, default="unnamed")
+    if gap - sum(by.values()) > by.get(best, 0.0):
+        return "unnamed"
+    return best
+
+
+# seconds this PROCESS has spent in garbage collections (a collection
+# holds the GIL whichever thread set it off, so every thread stands
+# still for it) and when the one under way began; written by the
+# callback below, two clock reads a collection
+_GC = [0.0, 0.0]
+_gc_watched = False
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        _GC[1] = time.perf_counter()
+    elif _GC[1]:
+        _GC[0] += time.perf_counter() - _GC[1]
+        _GC[1] = 0.0
+
+
+def watch_gc() -> None:
+    """Start counting ``gc_seconds`` (idempotent)."""
+    global _gc_watched
+    with _LISTENERS_LOCK:
+        if not _gc_watched:
+            gc.callbacks.append(_on_gc)
+            _gc_watched = True
+
+
+def gc_seconds() -> float:
+    return _GC[0]
 
 
 # ---------------------------------------------------- program loads
@@ -550,6 +701,10 @@ class LoadWatch:
         self._recent: collections.deque = collections.deque(
             maxlen=self.KEEP)  # lint: guarded-by self._lock
         self._total = 0  # lint: guarded-by self._lock
+        # seconds of every load recorded so far: a plain float the
+        # scheduler reads with no lock to see whether a stall's gap
+        # held a load (it stood still for its own loads itself)
+        self.blocked_s = 0.0
 
     def watch(self, kind: str, key: tuple,
               in_warmup: bool = False) -> _Load:
@@ -595,6 +750,7 @@ class LoadWatch:
         with self._lock:
             self._recent.append(entry)
             self._total += n
+            self.blocked_s += dur
 
     def stats(self) -> dict:
         with self._lock:

@@ -413,12 +413,48 @@ TIMELINE_RING_EVENTS = REGISTRY.gauge(
 ENGINE_SCHED_PHASE = REGISTRY.counter(
     "engine_sched_phase_seconds_total",
     "Scheduler-thread SELF time per phase (guards = cancellations + "
-    "deadlines, admit, harvest, emit = token emission inside harvest, "
+    "deadlines, admit = the admission pass with its sched:admit:<part> "
+    "sub-spans, harvest, emit = token emission inside harvest, "
     "dispatch = payload building, enqueue = payload -> device arrays "
-    "-> launch, gauges, wait = nothing ready and nothing to enqueue) — "
+    "-> launch, state = a recurrent state's snapshot taken or "
+    "restored, gauges, wait = nothing ready and nothing to enqueue) — "
     "the phases tile the scheduler's wall time while it has work, so "
     "host time per phase reads over any window with no capture",
     labels=("model", "phase"),
+)
+ENGINE_SCHED_SPAN = REGISTRY.counter(
+    "engine_sched_span_seconds_total",
+    "Scheduler-thread SELF time per span NAME (sched:<phase>; "
+    "sched:admit:tier = weight pager + KV tier tick, :prefix = prefix "
+    "index sync, :place = deferral, tier plan, slot choice, page "
+    "headroom, :spill = the tier's capture + adopt, :assign; "
+    "sched:enqueue:<kind>) — the same seconds as the phase counter, "
+    "split by the full name: summed over a phase's spans they give "
+    "that phase",
+    labels=("model", "span"),
+)
+ENGINE_SCHED_STALLS = REGISTRY.counter(
+    "engine_sched_stalls_total",
+    "Decode stalls — a gap between two decode-advancing dispatches of "
+    ">= 0.25 s and >= 3 x the time-weighted running mean gap — by "
+    "cause: the span "
+    "that held most of the gap (a phase, admit:<part>), load when "
+    "program loads took half of it or more, unnamed when no span did",
+    labels=("model", "cause"),
+)
+ENGINE_SCHED_STALL_SECONDS = REGISTRY.counter(
+    "engine_sched_stall_seconds_total",
+    "Seconds of the gaps counted by engine_sched_stalls_total, by the "
+    "same cause",
+    labels=("model", "cause"),
+)
+ENGINE_DEVICE_STARVED = REGISTRY.counter(
+    "engine_device_starved_seconds_total",
+    "Seconds the device had NO step queued while the engine had work: "
+    "from the harvest that emptied the flight queue to the next "
+    "enqueue — a lower bound of device idle time, read over any window "
+    "with no capture",
+    labels=("model",),
 )
 ENGINE_PROGRAM_LOADS = REGISTRY.counter(
     "engine_program_loads_total",

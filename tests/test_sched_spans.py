@@ -11,14 +11,20 @@ PhaseClock / LoadWatch, the dispatch-site counters in engine.py, the
 - FLIGHT.sample records on change only, so the ring keeps its spans;
 - no TraceAnnotation is built unless a capture runs, and a real short
   capture's host plane holds ``sched:*`` on the ``llm-engine`` line;
+- ``sched:admit`` is split by part, every span keeps its seconds by
+  name, a stall is counted and named where it happens, the time the
+  device had no step queued is counted, and ``sched:wait`` is entered
+  again when a capture starts under it;
 - /debug/profile's stop does not hold the event loop.
 """
 
 import asyncio
 import glob
 import json
+import logging
 import threading
 import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -165,6 +171,428 @@ def test_phase_counters_tile_the_loop_wall_time(model):
         assert by["emit"] > 0.0 and by["gauges"] > 0.0
     finally:
         eng.close()
+
+
+# ---------------------------------- admission by part, spans by name
+
+
+def test_admit_parts_add_to_the_phase_and_to_their_own_name():
+    clock = PhaseClock("test-sched")
+    t0 = time.perf_counter()
+    with clock.span("sched:admit", root=True):
+        time.sleep(0.002)
+        with clock.span("sched:admit:tier"):
+            time.sleep(0.004)
+        with clock.span("sched:admit:assign"):
+            time.sleep(0.003)
+            with clock.span("sched:enqueue:kvcopy"):
+                time.sleep(0.002)
+    wall = time.perf_counter() - t0
+    n = clock.by_name
+    assert n["sched:admit:tier"] >= 0.004
+    assert n["sched:admit:assign"] >= 0.003 and n["sched:admit"] >= 0.002
+    # a part's seconds are the phase's too, a child's are its own
+    assert clock.totals["admit"] == pytest.approx(
+        n["sched:admit"] + n["sched:admit:tier"] + n["sched:admit:assign"])
+    assert clock.totals["enqueue"] == n["sched:enqueue:kvcopy"] >= 0.002
+    # by name as by phase, the self times tile the root's duration
+    assert sum(n.values()) == pytest.approx(sum(clock.totals.values()))
+    assert wall - 1e-3 <= sum(n.values()) <= wall
+    # every name of the closed sets is there from the start, at 0
+    assert set(flightrec.SPAN_NAMES) <= set(n)
+    assert n["sched:admit:spill"] == 0.0
+    # the parts are a closed set, like the phases
+    with pytest.raises(ValueError):
+        clock.span("sched:admit:nonsense", root=True)
+    with pytest.raises(ValueError):
+        clock.span("sched:nonsense", root=True)
+
+
+def test_a_snapshot_counts_open_spans_up_to_now():
+    clock = PhaseClock("test-sched")
+    assert clock.snapshot(time.perf_counter()) == clock.by_name
+    with clock.span("sched:dispatch", root=True):
+        time.sleep(0.003)
+        with clock.span("sched:enqueue:mixed"):
+            time.sleep(0.002)
+        t_mid = time.perf_counter()
+        a = clock.snapshot(t_mid)
+        with clock.span("sched:enqueue:decodek"):
+            time.sleep(0.004)
+            t_in = time.perf_counter()
+            b = clock.snapshot(t_in)
+    # nothing was written by looking
+    assert clock.by_name["sched:dispatch"] > 0.0
+    assert a["sched:enqueue:mixed"] >= 0.002
+    # (a dispatch kind's name joins on first use)
+    assert a["sched:dispatch"] >= 0.003 and "sched:enqueue:decodek" not in a
+    # between the two snapshots only the open child ran: the
+    # difference tiles the time between them
+    d = {k: b[k] - a.get(k, 0.0) for k in b}
+    assert d["sched:enqueue:decodek"] >= 0.004
+    assert sum(d.values()) == pytest.approx(t_in - t_mid, abs=2e-4)
+    # and closed, the totals agree with the last snapshot's view
+    assert clock.by_name["sched:enqueue:decodek"] >= b[
+        "sched:enqueue:decodek"]
+
+
+def test_span_counters_tile_the_loop_and_sum_to_their_phase(model):
+    eng = _engine(model, tag="spans")
+    try:
+        def by_span():
+            out = {}
+            for ln in REGISTRY.render().splitlines():
+                if ln.startswith('engine_sched_span_seconds_total{'
+                                 'model="spans"'):
+                    out[ln.split('span="')[1].split('"')[0]] = float(
+                        ln.rsplit(" ", 1)[1])
+            return out
+
+        # every closed-set name is scraped from the start, at 0
+        assert set(flightrec.SPAN_NAMES) <= set(by_span())
+        q = eng.submit(GenRequest(prompt_ids=eng.tokenize("warm the jits"),
+                                  max_tokens=12, ignore_eos=True))
+        _step_until(eng, lambda: not eng._has_work())
+        _drain(q)
+        eng._update_gauges()
+        base = by_span()
+        q = eng.submit(GenRequest(prompt_ids=eng.tokenize("now measured"),
+                                  max_tokens=48, ignore_eos=True))
+        t0 = time.perf_counter()
+        _step_until(eng, lambda: not eng._has_work())
+        wall = time.perf_counter() - t0
+        eng._update_gauges()
+        _drain(q)
+        got = by_span()
+        d = {k: v - base.get(k, 0.0) for k, v in got.items()}
+        assert 0.85 * wall <= sum(d.values()) <= 1.001 * wall, (d, wall)
+        # the two enqueue kinds come apart, the admission's parts too
+        assert d["sched:enqueue:mixed"] > 0 and d["sched:enqueue:decodek"] > 0
+        assert d["sched:admit:assign"] > 0 and d["sched:admit:place"] > 0
+        assert d["sched:admit:tier"] > 0 and d["sched:admit:prefix"] > 0
+        # summed over a phase's names the span counter IS the phase
+        for ph in flightrec.PHASES:
+            names = [k for k in got if k.split(":")[1] == ph]
+            assert sum(got[k] for k in names) == pytest.approx(
+                _value("engine_sched_phase_seconds_total", model="spans",
+                       phase=ph), abs=1e-9), ph
+    finally:
+        eng.close()
+
+
+# ------------------------------------------------------ decode stalls
+
+
+@pytest.mark.parametrize("split,gap,load_s,want", [
+    ({"sched:admit:tier": 0.4, "sched:wait": 0.05}, 0.46, 0.0,
+     "admit:tier"),
+    # the enqueue kinds are one cause; the bare admit span is its own
+    ({"sched:enqueue:mixed": 0.2, "sched:enqueue:decodek": 0.2,
+      "sched:wait": 0.3}, 0.7, 0.0, "enqueue"),
+    ({"sched:admit": 0.3, "sched:admit:place": 0.1}, 0.4, 0.0, "admit"),
+    # loads of half the gap or more: the span they hid in is not blamed
+    ({"sched:enqueue:mixed": 1.0}, 1.0, 0.6, "load"),
+    ({"sched:enqueue:mixed": 1.0}, 1.0, 0.4, "enqueue"),
+    # what no span covers outweighs every span
+    ({"sched:wait": 0.1}, 0.5, 0.0, "unnamed"),
+    ({}, 0.3, 0.0, "unnamed"),
+])
+def test_stall_cause(split, gap, load_s, want):
+    assert flightrec.stall_cause(split, gap, load_s) == want
+    assert want in flightrec.STALL_CAUSES
+
+
+def _after(cycle, seconds, gaps=None):
+    """(stalls seen, the GapMean) after ``seconds`` of a cycle of gaps."""
+    gaps = gaps or flightrec.GapMean()
+    stalls, t = [], 0.0
+    while t < seconds:
+        for gap in cycle:
+            if gaps.note(gap):
+                stalls.append(gap)
+            t += gap
+    return stalls, gaps
+
+
+@pytest.mark.parametrize("cycle,extra,want", [
+    # a dense model's k-scans 0.1 s apart: 0.26 s is no stall (< 3 x
+    # the mean), 0.45 s is
+    ([0.1], 0.26, False),
+    ([0.1], 0.45, True),
+    # found on the chip (olmohybrid_docs_closed, PERF §5): an admission
+    # enqueues five prompt steps 3 ms apart, the device runs them for
+    # 0.3 s, two k-scans follow 0.13 s apart. Counted gap by gap the
+    # mean is 0.07 s and every admission read as a stall; weighted by
+    # time it is 0.2 s and the ordinary 0.32 s gap is none, 0.9 s is
+    ([0.003] * 5 + [0.32, 0.13, 0.13], 0.32, False),
+    ([0.003] * 5 + [0.32, 0.13, 0.13], 0.9, True),
+    # under a quarter of a second nothing is a stall, whatever the mean
+    ([0.002], 0.2, False),
+])
+def test_the_stall_rule_weights_the_mean_gap_by_time(cycle, extra, want):
+    stalls, gaps = _after(cycle, 30.0)
+    assert stalls == []  # the ordinary cycle counts none, from the start
+    mean = gaps.mean
+    assert gaps.note(extra) is want
+    # nothing is a stall before the mean has taken in a horizon of gaps
+    fresh = flightrec.GapMean()
+    assert not fresh.note(5.0) and fresh.mean == 5.0
+    fresh = flightrec.GapMean()
+    fresh.note(0.01)
+    assert not fresh.note(1.9) and fresh.note(0.09) is False
+    assert fresh.note(6.0)  # ... and after it, it is
+    # a stall counts into the mean too (one longer than the horizon IS
+    # the mean) and is forgotten in a few horizons of ordinary gaps
+    gaps.note(3.0)
+    assert gaps.mean == 3.0
+    _stalls, gaps = _after(cycle, 8 * flightrec.STALL_HORIZON_S, gaps)
+    assert gaps.mean == pytest.approx(mean, rel=0.05, abs=0.005)
+
+
+@pytest.fixture()
+def tiered(model, monkeypatch):
+    """An engine with the KV tier on, stepped by hand."""
+    for knob, v in (("LOCALAI_KV_PAGE", "16"), ("LOCALAI_KV_TIER", "on"),
+                    ("LOCALAI_KV_TIER_IDLE_S", "0")):
+        monkeypatch.setenv(knob, v)
+    eng = _engine(model, tag="stalls")
+    assert eng._tier is not None
+    yield eng
+    eng.close()
+
+
+def _stalls(model_label):
+    return {c: _value("engine_sched_stalls_total", model=model_label,
+                      cause=c) for c in flightrec.STALL_CAUSES}
+
+
+def test_a_sleep_in_the_tiers_tick_is_one_stall_named_admit_tier(
+        tiered, monkeypatch, caplog):
+    eng = tiered
+    # every cause is scraped from the start, at 0
+    text = REGISTRY.render()
+    for c in flightrec.STALL_CAUSES:
+        assert ('engine_sched_stalls_total{model="stalls",cause="%s"}'
+                % c) in text
+        assert ('engine_sched_stall_seconds_total{model="stalls",'
+                'cause="%s"}' % c) in text
+
+    def serve(n_tokens, during=None):
+        q = eng.submit(GenRequest(prompt_ids=eng.tokenize("a stream"),
+                                  max_tokens=n_tokens, ignore_eos=True))
+        if during is not None:
+            during()
+        _step_until(eng, lambda: not eng._has_work())
+        _drain(q)
+
+    # first uses load programs for seconds on the CPU (cause "load"):
+    # the same traffic first, until it has reached every variant
+    for _ in range(3):
+        serve(64)
+    # ... and nothing is a stall before the running mean has taken in
+    # a horizon's worth of gaps
+    while eng._gaps.seen < flightrec.STALL_HORIZON_S:
+        serve(64)
+    # an ordinary run counts none (once more if the machine hiccuped)
+    for attempt in range(2):
+        before = _stalls("stalls")
+        serve(64)
+        if _stalls("stalls") == before:
+            break
+    else:
+        raise AssertionError((before, _stalls("stalls")))
+
+    tick = eng._tier.tick
+    fired = []
+
+    def slow_tick():
+        if fired == ["armed"]:
+            fired.append("slept")
+            time.sleep(0.4)
+        return tick()
+
+    def arm_while_decoding():
+        # between two decode dispatches: the stream decodes and a few
+        # gaps have set the running mean
+        _step_until(eng, lambda: eng._last_decode_adv > 0
+                    and eng._gaps.seen > 0)
+        fired.append("armed")
+
+    monkeypatch.setattr(eng._tier, "tick", slow_tick)
+    ring0 = len(_ring("stall:"))
+    sec0 = _value("engine_sched_stall_seconds_total", model="stalls",
+                  cause="admit:tier")
+    with caplog.at_level(logging.WARNING,
+                         logger="localai_tfp_tpu.engine.engine"):
+        serve(200, during=arm_while_decoding)
+    assert fired == ["armed", "slept"]
+    after = _stalls("stalls")
+    assert after["admit:tier"] - before["admit:tier"] == 1
+    # (on a shared CPU the "device" may be late once more right after:
+    # a gap spent waiting for it is its own cause, and no host stall)
+    others = {c: after[c] - before[c] for c in after
+              if c not in ("admit:tier", "wait") and after[c] != before[c]}
+    assert others == {}, (before, after)
+    gap = _value("engine_sched_stall_seconds_total", model="stalls",
+                 cause="admit:tier") - sec0
+    assert 0.4 <= gap < 2.0
+    # ONE log line with the whole split, wall against CPU seconds
+    lines = [r.getMessage() for r in caplog.records
+             if "decode stall" in r.getMessage()
+             and "cause=wait" not in r.getMessage()]
+    assert len(lines) == 1, lines
+    assert "cause=admit:tier" in lines[0] and "'sched:admit:tier'" in lines[0]
+    for field in ("wall_s=", "cpu_s=", "gc_s=", "load_s=", "queue_depth=",
+                  "slots_busy="):
+        assert field in lines[0]
+    # ONE instant on the scheduler track, between the step: flights
+    (ev,) = [e for e in _ring("stall:")[ring0:]
+             if e["name"] != "stall:wait"]
+    assert ev["name"] == "stall:admit:tier" and ev["ph"] == "i"
+    assert ev["args"]["gap_s"] == pytest.approx(gap, abs=1e-3)
+    # a sleep computes nothing: CPU seconds far under the wall's
+    assert ev["args"]["cpu_s"] < 0.5 * ev["args"]["gap_s"]
+    assert ev["args"]["split"]["sched:admit:tier"] >= 0.4
+    assert ev["args"]["slots_busy"] == 1
+    tracks = {e["tid"]: e["args"]["name"]
+              for e in FLIGHT.export_chrome_trace()["traceEvents"]
+              if e["name"] == "thread_name"}
+    assert tracks[ev["tid"]] == "scheduler"
+    # the histogram beside it is fed as before
+    assert _value("engine_decode_stall_seconds_count", model="stalls") > 0
+
+
+def test_the_offline_viewer_draws_a_stall_instant_on_its_track():
+    """tools/trace_viewer.py needs no line for it: an instant is a lane
+    of its own on its track, between the spans."""
+    import io
+
+    from tools import trace_viewer
+
+    fr = FlightRecorder(capacity=16)
+    t = time.perf_counter()
+    fr.span("step:decodek", "device", t, 0.010)
+    fr.span("sched:admit:tier", flightrec.SCHED_TRACK, t + 0.010, 0.400)
+    fr.record("i", "stall:admit:tier", flightrec.SCHED_TRACK, t + 0.411,
+              0.0, {"gap_s": 0.41})
+    fr.span("step:decodek", "device", t + 0.412, 0.010)
+    out = io.StringIO()
+    assert trace_viewer.render(fr.export_chrome_trace(), out) == 0
+    text = out.getvalue()
+    sched = text.split("track scheduler")[1].split("track ")[0]
+    assert "1 spans, 1 instants" in sched
+    (lane,) = [ln for ln in sched.splitlines() if "stall:admit:tier" in ln]
+    assert lane.count("█") == 1
+
+
+# -------------------------------------- the device with no step queued
+
+
+def test_starved_seconds_are_the_time_with_no_step_queued(model):
+    eng = _engine(model, tag="starved", decode_steps=4)
+
+    def starved():
+        return _value("engine_device_starved_seconds_total",
+                      model="starved")
+
+    try:
+        for _ in range(2):  # load every variant first
+            q = eng.submit(GenRequest(prompt_ids=eng.tokenize("abcd"),
+                                      max_tokens=40, ignore_eos=True))
+            _step_until(eng, lambda: not eng._has_work())
+            _drain(q)
+        a = GenRequest(prompt_ids=eng.tokenize("abcd"), max_tokens=400,
+                       ignore_eos=True)
+        qa = eng.submit(a)
+        _step_until(eng, lambda: len(eng._flights) >= 2 and all(
+            f.kind == "decodek" for f in eng._flights))
+        # a flight is always queued: the newest stays in the air while
+        # the one before it is harvested, and nothing is starved
+        for fl in eng._flights:
+            jax.block_until_ready(fl.arrays)
+        s0 = starved()
+        newest = eng._flights[-1]
+        ready = type(newest).ready
+        type(newest).ready = lambda self: self is not newest and ready(self)
+        try:
+            assert eng._harvest() is True
+            assert list(eng._flights) == [newest]
+            assert eng._starved_t0 == 0.0
+            assert eng._dispatch() is True
+        finally:
+            type(newest).ready = ready
+        assert starved() == s0
+        # the queue drains with work pending: starved from that harvest
+        # to the next enqueue
+        for fl in eng._flights:
+            jax.block_until_ready(fl.arrays)
+        assert eng._harvest() is True and not eng._flights
+        assert eng._starved_t0 > 0.0 and eng._has_work()
+        time.sleep(0.2)
+        assert eng._dispatch() is True and eng._starved_t0 == 0.0
+        assert 0.2 <= starved() - s0 < 0.6
+        # with no work left nothing is starved: a drained queue whose
+        # request was cancelled opens no interval that the next
+        # request would close hours later
+        for fl in eng._flights:
+            jax.block_until_ready(fl.arrays)
+        eng._harvest()
+        assert eng._starved_t0 > 0.0
+        s1 = starved()
+        eng.cancel(a.id)
+        eng.step()
+        _step_until(eng, lambda: not eng._has_work())
+        _drain(qa)
+        assert eng._starved_t0 == 0.0
+        assert starved() - s1 < 0.05
+    finally:
+        eng.close()
+
+
+# ----------------------------------------- sched:wait across a capture
+
+
+@pytest.mark.parametrize("flip", [True, False])
+def test_wait_reenters_its_span_when_the_capture_flag_flips(
+        model, monkeypatch, flip):
+    built = []
+    real = flightrec._annotation
+    monkeypatch.setattr(flightrec, "_annotation", lambda name, args: (
+        built.append(name), real(name, args))[1])
+    eng = _engine(model, tag="rewait")
+    polled, landed = threading.Event(), threading.Event()
+    eng._flights.append(types.SimpleNamespace(
+        ready=lambda: (polled.set(), landed.is_set())[1]))
+    t0 = (time.perf_counter() - flightrec.origin()) * 1e6
+    th = threading.Thread(target=eng._wait_for_event)
+    try:
+        assert not flightrec.capturing()
+        th.start()
+        assert polled.wait(10)  # the loop is inside its span
+        time.sleep(0.02)
+        if flip:
+            flightrec.set_capturing(True)
+            deadline = time.perf_counter() + 10
+            while not built and time.perf_counter() < deadline:
+                time.sleep(0.002)
+            time.sleep(0.02)
+        landed.set()
+        th.join(timeout=10)
+        assert not th.is_alive()
+    finally:
+        flightrec.set_capturing(False)
+        landed.set()
+        eng._flights.clear()
+        eng.close()
+    waits = [e for e in _ring("sched:wait") if e["ts"] >= t0]
+    if flip:
+        # left when the flag flipped, entered again under the capture:
+        # the second entry is the one the capture can hold
+        assert len(waits) == 2 and built == ["sched:wait"]
+        assert waits[0]["ts"] + waits[0]["dur"] <= waits[1]["ts"] + 1
+        assert eng._phases.by_name["sched:wait"] >= 0.03
+    else:
+        assert len(waits) == 1 and built == []
 
 
 # ------------------------------------------------------ program loads
@@ -565,7 +993,9 @@ def test_no_annotation_is_built_unless_a_capture_runs(model, monkeypatch):
         finally:
             flightrec.set_capturing(False)
         assert {"sched:admit", "sched:dispatch", "sched:harvest",
-                "sched:emit", "sched:wait"} <= set(built)
+                "sched:emit", "sched:wait", "sched:admit:tier",
+                "sched:admit:prefix", "sched:admit:place",
+                "sched:admit:assign"} <= set(built)
         assert any(n.startswith("sched:enqueue:") for n in built)
     finally:
         eng.close()

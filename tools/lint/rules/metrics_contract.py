@@ -95,6 +95,10 @@ REQUIRED_FAMILIES = {
     "device_hbm_used_bytes",
     "process_rss_bytes",
     "engine_sched_phase_seconds_total",
+    "engine_sched_span_seconds_total",
+    "engine_sched_stalls_total",
+    "engine_sched_stall_seconds_total",
+    "engine_device_starved_seconds_total",
     "engine_program_loads_total",
     "engine_program_load_seconds",
     "engine_dispatch_tokens_total",
