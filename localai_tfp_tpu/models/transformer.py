@@ -523,6 +523,15 @@ def _layer_body(spec, x, lp, positions, inv_freq, rope_scale, attn_fn,
         if spec.qk_norm_flat:  # olmo: over the projection, then split
             q = _norm(spec, q, lp["q_norm_w"], None)
             k = _norm(spec, k, lp["k_norm_w"], None)
+        # the split into heads must not reach the dots: folded into
+        # them, the TPU compiler wants each weight head-major and
+        # writes the layer's matrix out of its [L, in, out] stack,
+        # transposed, on every layer of every step (wq and wk in the
+        # decode program, wv too in the mixed one: 13.8 % of the chip
+        # in Mistral's cell; tools/step_hlo.py shows it, PR 44). Behind
+        # the barrier the dots read their weights in place. Values,
+        # gradients and sharding pass through unchanged
+        q, k, v = lax.optimization_barrier((q, k, v))
         q = q.reshape(B, T, spec.n_heads, spec.d_head)
         k = k.reshape(B, T, spec.n_kv_heads, spec.d_head)
         v = v.reshape(B, T, spec.n_kv_heads, spec.d_head)

@@ -417,10 +417,8 @@ def ragged_paged_attention(
         # per-row scale pages gathered through the table ([B, max_pages,
         # page] — logical page p of row b lands at row p, where the
         # kernel's page walk indexes it)
-        ks_g = lax.dynamic_index_in_dim(
-            cache_k_scale, layer, 0, keepdims=False)[page_table]
-        vs_g = lax.dynamic_index_in_dim(
-            cache_v_scale, layer, 0, keepdims=False)[page_table]
+        ks_g = cache_k_scale[layer, page_table]
+        vs_g = cache_v_scale[layer, page_table]
         operands += [ks_g, vs_g]
         in_specs += [_row_spec((1, max_pages, page)),
                      _row_spec((1, max_pages, page))]

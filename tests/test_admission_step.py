@@ -258,18 +258,23 @@ _DECODE_ROUTES = {
 # operand (0 here). PR 39 re-pinned the two KERNEL routes only (the
 # ragged kernel's body changed on purpose: its page walk crosses grid
 # steps, its heads run phase by phase, and a parked row is given
-# length 0); the two XLA routes kept PR 38's digests. What the pin is
+# length 0); the two XLA routes kept PR 38's digests. PR 44 re-pinned
+# ALL of them on purpose: every program's layers pass q, k, v through
+# an ``optimization_barrier`` before the split into heads
+# (models/transformer.py ``_layer_body``; values bit-equal:
+# tests/test_projection_barrier.py), and the ragged int8 route gathers
+# its scale planes over (layer, page) at once. What the pin is
 # for is unchanged: a later PR that touches none of that must leave
 # these programs as they are.
 _PARENT = {
-    ("paged_xla_gather", "float32"): ("95083fa5", "3xabc1ff24"),
-    ("paged_xla_gather", "int8"): ("627b1606", "3xbca65819"),
-    ("ragged_paged_kernel", "float32"): ("cc3f4a56", "3x1e299a30"),
-    ("ragged_paged_kernel", "int8"): ("d318572c", "3xd0ec8c7c"),
-    ("dense_xla", "float32"): ("cedac285", "6xd25fe568"),
-    ("dense_xla", "int8"): ("a67b9a93", "6xe0098b88"),
-    ("dense_decode_kernel", "float32"): ("340c9733", "3x374afe15"),
-    ("dense_decode_kernel", "int8"): ("512213de", "3xf3f9dbb2"),
+    ("paged_xla_gather", "float32"): ("55b97305", "3xb25c9ebd"),
+    ("paged_xla_gather", "int8"): ("c8552eaa", "3x9dc4f1e3"),
+    ("ragged_paged_kernel", "float32"): ("de80d6e6", "3x4095641c"),
+    ("ragged_paged_kernel", "int8"): ("0a240d06", "3x4c5f252b"),
+    ("dense_xla", "float32"): ("f373a359", "6x217c2668"),
+    ("dense_xla", "int8"): ("33d1bb98", "6xd5bcc7d0"),
+    ("dense_decode_kernel", "float32"): ("176f53ac", "3x44c047f9"),
+    ("dense_decode_kernel", "int8"): ("8dbd84df", "3x4a3a4572"),
 }
 
 

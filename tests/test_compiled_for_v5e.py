@@ -1,0 +1,119 @@
+"""Programs compiled for a described (not attached) v5e chip: the
+TPU's compiler is installed in the sandbox, so what it would write for
+the chip is checked here at the benchmark cells' real shapes, at no
+chip time. The compiler's estimates and its program text — never a
+time.
+
+ONE file on purpose: only one process may hold libtpu, a test file goes
+to one xdist worker, and a second file's fixture would skip in silence.
+
+- the KV tier's spill gather moves only its pages (PR 42);
+- the step programs' layer loops hold no op that slices a layer's
+  matrix out of its stack or copies it into another layout (PR 44).
+"""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def one_v5e():
+    """One described (not attached) v5e chip: the TPU's compiler is
+    installed in the sandbox. Made inside a fixture, never at import —
+    only one process may hold libtpu."""
+    from tools.step_hlo import describe_v5e
+
+    with pytest.MonkeyPatch.context() as mp:
+        if "TPU_LOG_DIR" not in os.environ:  # or libtpu logs under /tmp
+            mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            chip = describe_v5e()
+        except Exception as e:  # no libtpu here, or another process's
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield chip
+
+
+def _cell_planes():
+    """tools/profile_kv.py's planes — the benchmark cells' pool planes
+    with the page counts their spills pad to — one case a page count.
+    Not among them: a scale plane with b = 1, where the compiler
+    prefetches the whole 8 MB parameter (10 us) for any form."""
+    from tools.profile_kv import _CELL_PLANES, parse_plane
+
+    for spec in _CELL_PLANES:
+        dt, shape, bs = parse_plane(spec)
+        for b in bs:
+            yield pytest.param(shape, dt, b,
+                               id=f"{spec.rsplit(':', 1)[0]}:{b}")
+
+
+@pytest.mark.parametrize("shape,dtype,b", _cell_planes())
+def test_gather_compiled_for_a_v5e_moves_only_its_pages(one_v5e, shape,
+                                                        dtype, b):
+    """At the cells' plane shapes the compiled program touches at most
+    4 x the bytes it has to move (b pages read, b written) and holds
+    fewer temporaries than its output: a compiler or a refactor that
+    brings the whole-pool copy back (2.2 GB of temporaries, 68 x the
+    bytes at Mistral's plane) fails here, not on a ledger line. The
+    compiler's estimates, no timing."""
+    from localai_tfp_tpu.engine.kv_tier import _gather_pages
+
+    compiled = _gather_pages.lower(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e),
+        jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_v5e)).compile()
+    out_bytes = b * int(np.prod(shape)) // shape[1] \
+        * jnp.dtype(dtype).itemsize
+    cost = compiled.cost_analysis()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= out_bytes
+    assert cost["bytes accessed"] <= 4 * 2 * out_bytes, cost
+    # (a scale plane's pages change layout on the way out: one more
+    # copy of the 128 KB moved, nothing of the 8 MB plane)
+    room = 2 if len(shape) == 3 else 1
+    assert mem.temp_size_in_bytes < room * mem.output_size_in_bytes, (
+        mem.temp_size_in_bytes, mem.output_size_in_bytes)
+
+
+STEP_CONFIGS = ("mistral-7b-instruct-v0.3", "trinity-mini-pp4-stage",
+                "olmo-hybrid-7b-pp2-stage")
+
+
+@pytest.mark.parametrize("kind", ["decodek", "mixed"])
+@pytest.mark.parametrize("name", STEP_CONFIGS)
+def test_step_program_reads_its_weights_from_the_stack_in_place(
+        one_v5e, name, kind):
+    """The engine's own ``dispatch_decodek`` / ``dispatch_mixed`` at a
+    benchmark configuration's published widths, as it serves them
+    (abstract arrays, no weights), compiled for a v5e: no op of a layer
+    loop's body that is neither a dot / convolution fusion nor a Pallas
+    call moves a parameter leaf — 1 MB or more of it, sliced out of its
+    stack or copied into another layout. Before PR 44 the compiler
+    folded the projections' split into heads into their dots, wanted
+    ``wq`` / ``wk`` / ``wv`` head-major, and wrote each layer's matrix
+    out transposed on every layer of every step (13.8 % of the chip in
+    Mistral's cell; both Mistral programs fail here on that tree, by
+    ``constant_dynamic-slice_fusion`` over ``params['wk'].q``). It is a
+    compiler heuristic: it comes back silently with a reshape next to a
+    dot. ``tools/step_hlo.py`` prints the whole loop body."""
+    from tools.step_hlo import lower_program, offenders_of
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           f"{name}.json")) as f:
+        config = json.load(f)
+    # tests/conftest.py asks every matmul for HIGHEST precision (the CPU
+    # comparisons need it); the server asks for none, and neither the
+    # kernel nor the program compiled here is the served one under it
+    with jax.default_matmul_precision("default"):
+        text = lower_program(config, kind, one_v5e).compile().as_text()
+    bad = offenders_of(text, config)
+    if bad:
+        pytest.fail("a layer's weight moved by an op that is no matmul:\n"
+                    + "\n".join(f"  {leaf}: {op.line[:150]}"
+                                for _, op, leaf in bad), pytrace=False)

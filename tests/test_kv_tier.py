@@ -491,63 +491,3 @@ def test_profile_kv_gather_mode_rehearsal_on_the_cpu():
     for p in rep["points"]:
         assert p["bit_equal"] and p["roof_us"] is None
         assert p["_gather_pages"] == {"us_call": "not measured"}
-
-
-@pytest.fixture(scope="module")
-def one_v5e():
-    """One described (not attached) v5e chip: the TPU's compiler is
-    installed in the sandbox. Made inside a fixture, never at import —
-    only one process may hold libtpu."""
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-
-    with pytest.MonkeyPatch.context() as mp:
-        if "TPU_LOG_DIR" not in os.environ:  # or libtpu logs under /tmp
-            mp.setenv("TPU_LOG_DIR", "disabled")
-        try:
-            topo = topologies.get_topology_desc(
-                platform="tpu", topology_name="v5e:2x2")
-        except Exception as e:  # no libtpu here, or another process's
-            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-        yield SingleDeviceSharding(topo.devices[0])
-
-
-def _cell_planes():
-    """tools/profile_kv.py's planes — the benchmark cells' pool planes
-    with the page counts their spills pad to — one case a page count.
-    Not among them: a scale plane with b = 1, where the compiler
-    prefetches the whole 8 MB parameter (10 us) for any form."""
-    from tools.profile_kv import _CELL_PLANES, parse_plane
-
-    for spec in _CELL_PLANES:
-        dt, shape, bs = parse_plane(spec)
-        for b in bs:
-            yield pytest.param(shape, dt, b,
-                               id=f"{spec.rsplit(':', 1)[0]}:{b}")
-
-
-@pytest.mark.parametrize("shape,dtype,b", _cell_planes())
-def test_gather_compiled_for_a_v5e_moves_only_its_pages(one_v5e, shape,
-                                                        dtype, b):
-    """At the cells' plane shapes the compiled program touches at most
-    4 x the bytes it has to move (b pages read, b written) and holds
-    fewer temporaries than its output: a compiler or a refactor that
-    brings the whole-pool copy back (2.2 GB of temporaries, 68 x the
-    bytes at Mistral's plane) fails here, not on a ledger line. The
-    compiler's estimates, no timing."""
-    from localai_tfp_tpu.engine.kv_tier import _gather_pages
-
-    compiled = _gather_pages.lower(
-        jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e),
-        jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_v5e)).compile()
-    out_bytes = b * int(np.prod(shape)) // shape[1] \
-        * jnp.dtype(dtype).itemsize
-    cost = compiled.cost_analysis()
-    mem = compiled.memory_analysis()
-    assert mem.output_size_in_bytes >= out_bytes
-    assert cost["bytes accessed"] <= 4 * 2 * out_bytes, cost
-    # (a scale plane's pages change layout on the way out: one more
-    # copy of the 128 KB moved, nothing of the 8 MB plane)
-    room = 2 if len(shape) == 3 else 1
-    assert mem.temp_size_in_bytes < room * mem.output_size_in_bytes, (
-        mem.temp_size_in_bytes, mem.output_size_in_bytes)
