@@ -12,7 +12,8 @@ and every program builder runs the same three calls around its forward:
 ``tables`` is the dispatch's ``(page_table, write_table)`` pair on the
 pool routes and ``()`` on the dense one. On the host, ``window`` /
 ``ladder`` say which context window a dispatch is planned (and warmed)
-at. Nothing outside this file knows which of the three routes runs.
+at. Nothing outside this file knows which of the routes runs (three,
+and the ragged one's latent shape).
 """
 
 from __future__ import annotations
@@ -124,9 +125,19 @@ class RaggedRoute(_PoolRoute):
     ``q_lens`` tokens whose K/V scatter through the write table (rows
     and pages the host did not grant land on the trash page) while
     attention walks the read table's pages in-kernel. No gathered view
-    is ever materialized."""
+    is ever materialized. Over a LATENT arena (``KVCache`` of a model
+    with ``kv_lora_rank``: one plane of latent rows, no V lanes) the
+    tables, the scatter and the page walk are the same and the kernel
+    runs in its absorbed form (``v_lanes``; a capture names it
+    ``latent_paged_attention``): the route only says, by its name, that
+    the pages are latent — which form attends them is the forward's
+    business (models/transformer.py ``latent_ragged``)."""
 
-    name = "ragged_paged_kernel"
+    def __init__(self, max_seq: int, page: int, mesh: Any,
+                 latent: bool = False) -> None:
+        super().__init__(max_seq, page, mesh)
+        self.name = ("latent_paged_kernel" if latent
+                     else "ragged_paged_kernel")
 
     def open(self, cache: KVCache, tables: tuple, window: int) -> KVCache:
         return cache
@@ -238,10 +249,12 @@ class DenseRoute:
 
 
 def choose_route(*, paged: bool, kernel: bool, max_seq: int, page: int,
-                 mesh: Any):
+                 mesh: Any, latent: bool = False):
     """The engine's one route. ``paged``: the pool exists (no "seq"
     mesh axis, page >= 8); ``kernel``: ``_kernel_ineligible()`` is
-    empty."""
+    empty; ``latent``: the cache holds latent rows."""
     if not paged:
         return DenseRoute(max_seq, mesh, kernel)
-    return (RaggedRoute if kernel else GatherRoute)(max_seq, page, mesh)
+    if kernel:
+        return RaggedRoute(max_seq, page, mesh, latent)
+    return GatherRoute(max_seq, page, mesh)

@@ -486,6 +486,55 @@ class LLMEngine:
                 weight_pager=(f"{mt}: two layer stacks — the pager's "
                               "page li is row li of every leaf"))
             kv_tier = weight_paging = False
+        # a latent cache row (deepseek_v3: ONE vector a token a layer,
+        # ``KVCache`` keeps no V lanes) and an expert layer that holds a
+        # share ride the step programs, the page tables and the prefix
+        # index like any model; the paths below were not taught the
+        # share, or the row, and refuse the model by name, as above
+        self._latent = bool(spec.kv_lora_rank)
+        if spec.experts_held:
+            mt = spec.model_type or "a model that holds an expert share"
+            if mesh is not None:
+                raise NotImplementedError(
+                    f"{mt}: serving on a mesh is not supported — an "
+                    "expert share has no sharding rule yet (shares do "
+                    "not exchange tokens)")
+            self.state_refusals.update(
+                mesh=f"{mt}: a mesh is refused at load (an expert share)",
+                forward_train=(f"{mt}: the training forward has no "
+                               "expert share"))
+        if self._latent:
+            mt = spec.model_type or "a latent-attention model"
+            if mesh is not None:
+                raise NotImplementedError(
+                    f"{mt}: serving on a mesh is not supported — the "
+                    "latent arena has no sharding rule yet")
+            if cache_dtype in (jnp.int8, "int8", "q8", "q8_0"):
+                raise NotImplementedError(
+                    f"{mt}: kv_cache_dtype int8 is not supported — the "
+                    "latent row has no per-row scale plane and the "
+                    "absorbed kernel reads bf16 pages")
+            why = (f"{mt}: this path moves K and V planes of kv_heads x "
+                   "head_dim; the latent row is one plane of "
+                   f"{spec.latent_row} lanes and was not taught to it")
+            if draft is not None:
+                self.state_refusals["speculative"] = (
+                    f"{mt}: speculative verify is not supported with a "
+                    "latent cache (the draft shares the page tables of "
+                    "a K/V arena)")
+                log.warning("draft model dropped — %s",
+                            self.state_refusals["speculative"])
+                draft = self.draft = None
+            self.state_refusals.update(
+                kv_cache_dtype_int8=(
+                    f"{mt}: an int8 latent row is refused at load"),
+                kv_tier=why, kv_migrate=why, prompt_cache=why,
+                mesh=f"{mt}: a mesh is refused at load",
+                forward_train=(f"{mt}: the training forward has no "
+                               "latent attention"),
+                weight_pager=(f"{mt}: two layer stacks — the pager's "
+                              "page li is row li of every leaf"))
+            kv_tier = weight_paging = False
         self.params = params
         self.tokenizer = tokenizer
         self.n_slots = n_slots
@@ -638,7 +687,7 @@ class LLMEngine:
         # nowhere else (engine/cache_route.py)
         self._route = route = choose_route(
             paged=self._paged, kernel=self._use_kernel, max_seq=max_seq,
-            page=pg, mesh=mesh)
+            page=pg, mesh=mesh, latent=self._latent)
         self.attention_path: str = route.name
         log.info(
             "attention path %s on %s (%s)%s", self.attention_path,
@@ -649,6 +698,15 @@ class LLMEngine:
         # devices this engine's dispatches fan out over (1 unsharded)
         tm.ENGINE_MESH_DEVICES.labels(model=self._mlabel).set(
             1 if mesh is None else int(mesh.devices.size))
+        # bytes ONE cached token holds over all layers as stored (row
+        # scales and a latent row's zero lanes included): the arena's
+        # bytes over its token capacity
+        c = self.cache
+        self.kv_row_bytes: int = c.k.shape[0] * (
+            c.k.dtype.itemsize * (c.k.shape[-1] + c.v.shape[-1])
+            + (2 * 4 if c.quantized else 0))  # f32 row scales
+        tm.ENGINE_KV_ROW_BYTES.labels(model=self._mlabel).set(
+            self.kv_row_bytes)
         # cross-slot prefix cache: radix index over every slot's
         # resident cache_tokens + on-device row-to-row KV copies
         # (engine/prefix_index.py). LOCALAI_PREFIX_CACHE=off restores
@@ -909,7 +967,7 @@ class LLMEngine:
                              host=True)
             else:
                 led.register("weights", self.params)
-            led.register("kv_arena",
+            led.register("latent_cache" if self._latent else "kv_arena",
                          (self.cache.k, self.cache.v))
             if getattr(self.cache, "k_scale", None) is not None:
                 led.register("kv_scales",
@@ -986,6 +1044,9 @@ class LLMEngine:
         # paged arenas DMA whole pool pages (page-table lookups), so
         # the pool's own divisibility guarantee replaces the dense
         # kernel's max_seq % PAGE requirement
+        if self._latent and not self._paged:
+            return ("latent cache: the dense decode kernel has no "
+                    "absorbed form (the paged pool's kernel has)")
         if not self._paged and self.max_seq % PAGE:
             return f"dense cache: max_seq {self.max_seq} % {PAGE} != 0"
         if self.spec.kv_dim % 128:
@@ -1460,6 +1521,9 @@ class LLMEngine:
     # choice open for a measurement that can tell the two apart
     _STEP_TOKENS = 128
     _EXPERT_STEP_TOKENS = 512
+    # the model width from which an expert model takes ONE prompt-row
+    # shape: the narrowest at which the fault was seen (_step_buckets)
+    _EXPERT_ONE_SHAPE_D_MODEL = 7168
 
     @property
     def _step_tokens(self) -> int:
@@ -1495,6 +1559,28 @@ class LLMEngine:
         bs = self.prefill_buckets
         top = next((i for i, b in enumerate(bs)
                     if b >= self._step_tokens), len(bs) - 1)
+        if (self.spec.n_experts
+                and self.spec.d_model >= self._EXPERT_ONE_SHAPE_D_MODEL):
+            # ONE bucket, the step's own, for an EXPERT stack this wide:
+            # at DeepSeek-V3's widths (the grouped expert matmuls
+            # contract over 7168) the program XLA builds for a step
+            # rounds by the step's ROW COUNT, so the same token read
+            # other bits alone in a [1, 4] row than inside its [1, 512]
+            # chunk — the first expert layer's output off by one bf16
+            # ulp, every later row with it — and a prompt repeated over
+            # its own cached pages gave another greedy text from the 7th
+            # token on (my chip runs, PR 45: buckets 4-32 | 128-256 |
+            # 512 are three classes of bits). It is the expert layer's
+            # doing, not the cache row's: a dense model's buckets agree
+            # (Mistral's cache probe, contraction 14336), and so do a
+            # narrow expert stack's (Trinity at 2048, every run's
+            # probe), which keeps its ladder as measured; between 2048
+            # and 7168 nothing was measured (PERF section 7). With one
+            # prompt-row shape a token's arithmetic is the same whatever
+            # chunk it rides in; a short remainder pays a whole step
+            # (the last step of most admissions), and four programs
+            # fewer are compiled and warmed
+            return bs[top:top + 1]
         return bs[:top + 1]
 
     def _mixed_shape(self, rems: list[int],
@@ -2175,6 +2261,14 @@ class LLMEngine:
         kinds = self.spec.layer_types or (
             ("full_attention",) * self.spec.n_layers)
         return {k: kinds.count(k) for k in dict.fromkeys(kinds)}
+
+    def experts_held(self) -> Optional[list]:
+        """[first, last] published ids of the experts a layer holds
+        here; None for a model without experts."""
+        sp = self.spec
+        if not sp.n_experts:
+            return None
+        return [sp.experts_first, sp.experts_first + sp.n_held - 1]
 
     def cache_bytes(self) -> dict:
         """Bytes of each cache this engine holds on the device: the KV
@@ -2928,13 +3022,8 @@ class LLMEngine:
             # series that shows paging tracking expected instead of
             # worst-case context (dense equivalent: max_seq / mean ctx
             # x this value)
-            c = self.cache
-            tok_bytes = 2 * c.k.dtype.itemsize * c.k.shape[0] \
-                * c.k.shape[-1]
-            if c.quantized:
-                tok_bytes += 2 * 4 * c.k.shape[0]  # f32 row scales
             tm.ENGINE_KV_HBM_PER_TOKEN.labels(model=m).set(
-                float(st.in_use * self._page * tok_bytes)
+                float(st.in_use * self._page * self.kv_row_bytes)
                 / max(live_tokens, 1))
             # allocator outcome counters (fresh/shared/cow) sync from
             # the pool's host tallies; reclaimed/exhausted increment at
@@ -3614,7 +3703,7 @@ class LLMEngine:
         import os
 
         path = req.prompt_cache_path
-        if not path or self._stateful:  # state_refusals["prompt_cache"]
+        if not path or "prompt_cache" in self.state_refusals:
             return "unset"  # the common no-cache case: not counted
 
         def done(result: str) -> str:
@@ -3720,7 +3809,8 @@ class LLMEngine:
         save; PromptCacheAll includes the generation)."""
         req = slot.request
         if req is None or not req.prompt_cache_path or req.prompt_cache_ro \
-                or self.channel is not None or self._stateful:
+                or self.channel is not None \
+                or "prompt_cache" in self.state_refusals:
             return
         n = slot.n_past if req.prompt_cache_all else min(
             slot.n_past, slot.n_prompt)
@@ -4328,20 +4418,28 @@ class LLMEngine:
         token-steps a program) and the experts those touched."""
         if not stats:
             return
-        m, E = self._mlabel, self.spec.n_experts
+        # HELD experts only, labelled by published id; a model that
+        # holds a share also says where its assignments went
+        m, E = self._mlabel, self.spec.n_held
+        share, first = bool(self.spec.experts_held), self.spec.experts_first
         ctr = self._expert_ctr.get(kind)
         if ctr is None:
             ctr = self._expert_ctr[kind] = (
                 tm.ENGINE_EXPERT_LAYER_STEPS.labels(model=m, kind=kind),
                 tm.ENGINE_EXPERTS_TOUCHED.labels(model=m, kind=kind),
-                [tm.ENGINE_EXPERT_TOKENS.labels(model=m, expert=str(e))
-                 for e in range(E)])
+                [tm.ENGINE_EXPERT_TOKENS.labels(
+                    model=m, expert=str(first + e)) for e in range(E)],
+                [tm.ENGINE_EXPERT_ASSIGNMENTS.labels(model=m, where=w)
+                 for w in ("held", "absent")] if share else None)
         # lint: ignore[hot-path-sync] the flight these ride was ready()
         total = np.sum([np.asarray(a) for a in stats], axis=0)
         ctr[0].inc(len(stats) * steps * self._n_expert_layers)
         ctr[1].inc(int(total[E]))
         for e in np.nonzero(total[:E])[0]:
             ctr[2][e].inc(int(total[e]))
+        if share:
+            ctr[3][0].inc(int(np.sum(total[:E])))
+            ctr[3][1].inc(int(total[E + 1]))
 
     def _note_ragged_rows(self, kind: str, n: int) -> None:
         """Rows advanced through the unified ragged path by kind

@@ -298,6 +298,9 @@ def load_params(
     if mt == "olmo_hybrid":
         return spec, {**p, **_load_olmo_hybrid(spec, get, prefix, dtype,
                                                tcast)}
+    if mt == "deepseek_v3":
+        return spec, {**p, **_load_deepseek_v3(spec, get, prefix, dtype,
+                                               tcast)}
 
     fused_qkv = lp.format(i=0) + "self_attn.qkv_proj.weight" in names  # phi3
     fused_gate = lp.format(i=0) + "mlp.gate_up_proj.weight" in names
@@ -485,6 +488,102 @@ def _load_afmoe(spec: LLMSpec, get, prefix: str, dtype, tcast) -> dict:
             router_bias=jnp.asarray(np.stack(
                 [np.asarray(get(f"{prefix}layers.{i}.mlp.expert_bias"),
                             np.float32) for i in layers])),
+            moe_gate=expert_mats("gate_proj"), moe_up=expert_mats("up_proj"),
+            moe_down=expert_mats("down_proj"))
+        if spec.moe_shared_expert:
+            out.update(shared_gate=mat("mlp.shared_experts.gate_proj"),
+                       shared_up=mat("mlp.shared_experts.up_proj"),
+                       shared_down=mat("mlp.shared_experts.down_proj"))
+        return out
+
+    p = stack_of(range(Ld, L), True)
+    if Ld:
+        p.update({DENSE_STACK + k: v
+                  for k, v in stack_of(range(Ld), False).items()})
+    p["final_norm_w"] = _cast(get(f"{prefix}norm.weight"), dtype)
+    p["lm_head"] = tcast(lambda: get("lm_head.weight"))
+    return p
+
+
+def _load_deepseek_v3(spec: LLMSpec, get, prefix: str, dtype,
+                      tcast) -> dict:
+    """The leaves of a ``deepseek_v3`` checkpoint (HF
+    DeepseekV3ForCausalLM) beside the embedding: TWO stacks — the first
+    ``first_k_dense_replace`` layers (a dense SwiGLU MLP, leaves under
+    ``DENSE_STACK``) and the expert layers (router ``mlp.gate.weight``
+    [E published, D], ``mlp.gate.e_score_correction_bias`` [E] kept in
+    f32, ``mlp.experts.{e}`` for the PUBLISHED ids e this chip holds —
+    ``spec.experts_first`` .. + ``spec.n_held`` — and
+    ``mlp.shared_experts``), each layer with latent attention:
+    ``self_attn.{q_a_proj, q_a_layernorm, q_b_proj, kv_a_proj_with_mqa,
+    kv_a_layernorm, kv_b_proj, o_proj}``. ``kv_b_proj`` is kept as the
+    two halves the absorbed form contracts with (``wkv_b_k`` [H, d_n,
+    r], ``wkv_b_v`` [H, r, d_v]). The rotary columns of ``q_b_proj``
+    and ``kv_a_proj_with_mqa`` are moved from the checkpoint's
+    interleaved pairs (2i, 2i+1) to the rotate-half layout (i, i + d/2)
+    the program rotates — the same permutation on q and k, so every
+    score is unchanged. The multi-token-prediction module
+    (``model.layers.{num_hidden_layers}`` ..) is not read."""
+    L, Ld = spec.n_layers, spec.n_dense_layers
+    H, dn, dr = spec.n_heads, spec.qk_nope_dim, spec.qk_rope_dim
+    dv, r = spec.v_head_dim, spec.kv_lora_rank
+    held = range(spec.experts_first, spec.experts_first + spec.n_held)
+    half = np.concatenate([np.arange(0, dr, 2), np.arange(1, dr, 2)])
+
+    def name(i, tail):
+        return f"{prefix}layers.{i}.{tail}.weight"
+
+    def q_b(i):  # [H * (dn + dr), rq], rotary rows de-interleaved
+        w = np.asarray(get(name(i, "self_attn.q_b_proj")))
+        w = w.reshape(H, dn + dr, -1)
+        return np.concatenate([w[:, :dn], w[:, dn + half]], axis=1).reshape(
+            H * (dn + dr), -1)
+
+    def kv_a(i):  # [r + dr, D]
+        w = np.asarray(get(name(i, "self_attn.kv_a_proj_with_mqa")))
+        return np.concatenate([w[:r], w[r + half]], axis=0)
+
+    def kv_b(i):  # [H, dn + dv, r]
+        return np.asarray(get(name(i, "self_attn.kv_b_proj"))).reshape(
+            H, dn + dv, r)
+
+    def stack_of(layers, experts: bool) -> dict:
+        def vec(tail):
+            return _cast(np.stack([get(name(i, tail)) for i in layers]),
+                         dtype)
+
+        def mat(tail, fn=None):
+            return tcast(lambda: np.stack(
+                [fn(i) if fn else get(name(i, tail)) for i in layers]))
+
+        def expert_mats(proj):
+            return tcast(lambda: np.stack([np.stack(
+                [get(name(i, f"mlp.experts.{e}.{proj}")) for e in held])
+                for i in layers]))
+
+        kvb = np.stack([kv_b(i) for i in layers])
+        out = {
+            "wq_a": mat("self_attn.q_a_proj"),
+            "q_a_norm_w": vec("self_attn.q_a_layernorm"),
+            "wq_b": mat("", q_b), "wkv_a": mat("", kv_a),
+            "kv_a_norm_w": vec("self_attn.kv_a_layernorm"),
+            # [n, H, dn, r] as stored; [n, H, r, dv] transposed
+            "wkv_b_k": _cast(kvb[:, :, :dn], dtype),
+            "wkv_b_v": _cast(kvb[:, :, dn:].transpose(0, 1, 3, 2), dtype),
+            "wo": mat("self_attn.o_proj"),
+            "ln1_w": vec("input_layernorm"),
+            "ln2_w": vec("post_attention_layernorm"),
+        }
+        if not experts:
+            out.update(w_gate=mat("mlp.gate_proj"), w_up=mat("mlp.up_proj"),
+                       w_down=mat("mlp.down_proj"))
+            return out
+        out.update(
+            router=mat("mlp.gate"),
+            # the bias decides the selection in f32, whatever the dtype
+            router_bias=jnp.asarray(np.stack([np.asarray(get(
+                f"{prefix}layers.{i}.mlp.gate.e_score_correction_bias"),
+                np.float32) for i in layers])),
             moe_gate=expert_mats("gate_proj"), moe_up=expert_mats("up_proj"),
             moe_down=expert_mats("down_proj"))
         if spec.moe_shared_expert:

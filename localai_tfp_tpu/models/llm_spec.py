@@ -11,6 +11,7 @@ gating, rotary fraction, biases, residual topology.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -122,6 +123,38 @@ class LLMSpec:
     # olmo: q/k RMSNorm over the whole projection, before the head split
     qk_norm_flat: bool = False
 
+    # deepseek_v3: latent attention. ``kv_lora_rank`` > 0 switches the
+    # token mixer (models/transformer.py ``_latent_mixer``): queries go
+    # through a normed ``q_lora_rank`` bottleneck, every head's key is a
+    # ``qk_nope_dim`` part up-projected from ONE normed latent of
+    # ``kv_lora_rank`` values a token plus ONE rotary key of
+    # ``qk_rope_dim`` values shared by all heads, values are
+    # ``v_head_dim`` wide, and the cache holds the latent row alone
+    # (``latent_row``). ``d_head`` is the query/key head size,
+    # qk_nope_dim + qk_rope_dim
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # the softmax scale's multiplier (deepseek's YaRN: mscale^2 sits in
+    # the scale, not on cos/sin); see transformer.rope_attn_scale
+    attn_scale_mult: float = 1.0
+    # group-limited expert selection (deepseek_v3 ``noaux_tc``): the
+    # experts in ``moe_n_group`` equal groups, a group scored by the sum
+    # of its two best biased scores, the top-k taken inside the best
+    # ``moe_topk_group`` groups; 1 / 1 = no grouping
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    # THE SHARE: an expert layer that holds a contiguous range of the
+    # published experts — ``n_experts`` stays the published count (the
+    # router's width, the groups), ``experts_held`` (0 = all) of them
+    # from published id ``experts_first`` are on this chip. The router
+    # scores and picks over all; an assignment to an absent expert
+    # reads nothing and adds nothing (the chip that holds it adds it)
+    experts_held: int = 0
+    experts_first: int = 0
+
     extra: dict = field(default_factory=dict)
 
     @property
@@ -130,10 +163,37 @@ class LLMSpec:
 
     @property
     def kv_dim(self) -> int:
+        """Values of one cached row (a layer, a token, K or V)."""
+        if self.kv_lora_rank:
+            return self.latent_row
         return self.n_kv_heads * self.d_head
 
     @property
+    def latent_width(self) -> int:
+        """Values of a latent cache row: [c | k_r]."""
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def latent_row(self) -> int:
+        """The latent row as stored: padded with zeros to whole
+        128-lane vectors (576 -> 640; the device's tiled layout pads
+        the minor dim to 128 lanes whether the shape says so or not)."""
+        return -(-self.latent_width // 128) * 128
+
+    @property
+    def o_dim(self) -> int:
+        """Width of the heads' output, the o projection's input."""
+        return self.n_heads * (self.v_head_dim or self.d_head)
+
+    @property
+    def n_held(self) -> int:
+        """Experts a layer holds on this chip."""
+        return self.experts_held or self.n_experts
+
+    @property
     def rotary_dim(self) -> int:
+        if self.kv_lora_rank:
+            return self.qk_rope_dim
         rd = int(self.d_head * self.rotary_pct)
         return rd - (rd % 2)
 
@@ -283,12 +343,9 @@ def spec_from_hf_config(cfg: dict[str, Any]) -> LLMSpec:
         # the sliding layers only; muP embedding scale
         n_shared = int(cfg.get("num_shared_experts") or 0)
         moe_ff = int(cfg.get("moe_intermediate_size") or d_ff)
-        if int(cfg.get("n_group") or 1) != 1 \
-                or int(cfg.get("topk_group") or 1) != 1:
-            raise NotImplementedError(
-                "afmoe with grouped expert selection (n_group / "
-                "topk_group != 1) is not supported yet")
         kw.update(
+            moe_n_group=int(cfg.get("n_group") or 1),
+            moe_topk_group=int(cfg.get("topk_group") or 1),
             qk_norm=True,
             sandwich_norms=True,
             attn_output_gate=True,
@@ -308,6 +365,77 @@ def spec_from_hf_config(cfg: dict[str, Any]) -> LLMSpec:
             n_dense_layers=min(int(cfg.get("num_dense_layers") or 0),
                                n_layers),
         )
+    elif mt == "deepseek_v3":
+        # DeepSeek-V3 (HF DeepseekV3ForCausalLM): latent attention and
+        # ``noaux_tc`` routing — sigmoid scores, a selection bias, the
+        # top-k inside the best groups, weights renormalised and scaled;
+        # one always-on shared expert; the first first_k_dense_replace
+        # layers dense. The multi-token-prediction module
+        # (num_nextn_predict_layers) is NOT built: next-token logits do
+        # not depend on it and HF's own modeling file drops it at load.
+        if str(cfg.get("topk_method") or "noaux_tc") != "noaux_tc" \
+                or str(cfg.get("scoring_func") or "sigmoid") != "sigmoid":
+            raise NotImplementedError(
+                "deepseek_v3: only topk_method noaux_tc with sigmoid "
+                "scores is supported")
+        if int(cfg.get("moe_layer_freq") or 1) != 1:
+            raise NotImplementedError("deepseek_v3: moe_layer_freq != 1")
+        n_routed = int(cfg.get("n_routed_experts") or 0)
+        # keys of this repo beside the published ones: how many experts
+        # the PUBLISHED model routes over when this chip holds a share
+        # (n_routed_experts of them, from published id experts_first)
+        published = int(cfg.get("n_routed_experts_published") or n_routed)
+        first = int(cfg.get("experts_first") or 0)
+        if published < n_routed or first < 0 or first + n_routed > published:
+            raise ValueError(
+                f"deepseek_v3: experts {first}..{first + n_routed} are not "
+                f"a range of the {published} published")
+        moe_ff = int(cfg.get("moe_intermediate_size") or d_ff)
+        n_shared = int(cfg.get("n_shared_experts") or 0)
+        nope = int(cfg.get("qk_nope_head_dim") or 128)
+        rope_d = int(cfg.get("qk_rope_head_dim") or 64)
+        sc = cfg.get("rope_scaling") or {}
+        mult = 1.0
+        if (sc.get("rope_type") or sc.get("type") or "").lower() == "yarn" \
+                and sc.get("mscale_all_dim"):
+            # softmax_scale * mscale^2 (modeling_deepseek_v3)
+            m = 0.1 * float(sc["mscale_all_dim"]) * math.log(
+                float(sc.get("factor", 1.0))) + 1.0 \
+                if float(sc.get("factor", 1.0)) > 1 else 1.0
+            mult = m * m
+        kw.update(
+            n_kv_heads=1,
+            d_head=nope + rope_d,
+            kv_lora_rank=int(cfg.get("kv_lora_rank") or 512),
+            q_lora_rank=int(cfg.get("q_lora_rank") or 0),
+            qk_nope_dim=nope,
+            qk_rope_dim=rope_d,
+            v_head_dim=int(cfg.get("v_head_dim") or 128),
+            rotary_pct=rope_d / (nope + rope_d),
+            attn_scale_mult=mult,
+            n_experts=published,
+            experts_held=n_routed if n_routed < published else 0,
+            experts_first=first,
+            experts_per_token=int(cfg.get("num_experts_per_tok") or 8),
+            moe_d_ff=moe_ff,
+            moe_shared_expert=n_shared > 0,
+            moe_shared_d_ff=moe_ff * max(n_shared, 1),
+            moe_shared_gated=False,
+            moe_norm_topk=bool(cfg.get("norm_topk_prob", True)),
+            moe_score_func="sigmoid",
+            moe_select_bias=True,
+            moe_route_scale=float(cfg.get("routed_scaling_factor") or 1.0),
+            moe_n_group=int(cfg.get("n_group") or 1),
+            moe_topk_group=int(cfg.get("topk_group") or 1),
+            n_dense_layers=min(int(cfg.get("first_k_dense_replace") or 0),
+                               n_layers),
+        )
+        if not kw["q_lora_rank"]:
+            raise NotImplementedError(
+                "deepseek_v3 without q_lora_rank (a plain q projection) "
+                "is not supported yet")
+        if not n_routed:
+            raise NotImplementedError("deepseek_v3 without routed experts")
     elif mt == "olmo_hybrid":
         # allenai Olmo-Hybrid: periods of ``linear_attention`` layers
         # (gated delta rule) closed by one ``full_attention`` layer; the

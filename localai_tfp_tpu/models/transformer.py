@@ -76,6 +76,13 @@ class KVCache:
     # full-attention layers only (``spec.n_kv_layers``). None otherwise
     state: Any = None
     conv: Any = None
+    # a model with latent attention (LLMSpec.kv_lora_rank): ``k`` holds
+    # the latent row [c after its norm | k_r after rotary | zeros to a
+    # whole lane vector] (``spec.latent_row`` values) and NOTHING else
+    # is cached — ``v`` keeps its place in the tree with ZERO lanes
+    # ([L, slots, seq, 0]: no bytes), so that every path that moves
+    # pages as (k, v) pairs moves latent pages unchanged. int8 rows are
+    # refused (the engine says so by name)
 
     @classmethod
     def create(
@@ -87,9 +94,15 @@ class KVCache:
         state_slots: Optional[int] = None,  # slots of the recurrent
         # state when ``n_slots`` counts pool pages (None: n_slots)
     ) -> "KVCache":
-        shape = (spec.n_kv_layers, n_slots, max_seq,
-                 spec.n_kv_heads * spec.d_head)
+        shape = (spec.n_kv_layers, n_slots, max_seq, spec.kv_dim)
         quant = dtype in (jnp.int8, "int8", "q8", "q8_0")
+        if spec.kv_lora_rank:
+            if quant:
+                raise NotImplementedError(
+                    f"{spec.model_type}: an int8 latent cache row is not "
+                    "supported (kv_cache_dtype: int8)")
+            return cls(k=jnp.zeros(shape, dtype),
+                       v=jnp.zeros((*shape[:3], 0), dtype))
         extra = {}
         if spec.linear_heads:
             from ..ops.gated_delta import state_shape
@@ -161,11 +174,11 @@ def gather_kv_pages(arena: KVCache, phys: jax.Array, page: int) -> KVCache:
     is shape- and value-identical to the dense windowed cache, so the
     forward math — and therefore the sampled token stream — is
     byte-identical on both paths."""
-    L, F = arena.k.shape[0], arena.k.shape[-1]
+    L = arena.k.shape[0]
     B, wp = phys.shape
 
-    def g4(a):
-        return a[:, phys].reshape(L, B, wp * page, F)
+    def g4(a):  # (a latent arena's ``v`` has no lanes: its own width)
+        return a[:, phys].reshape(L, B, wp * page, a.shape[-1])
 
     def g3(a):
         return a[:, phys].reshape(a.shape[0], B, wp * page)
@@ -186,11 +199,11 @@ def scatter_kv_pages(arena: KVCache, win: KVCache, wb: jax.Array,
     duplicate trash indices are fine, the losing garbage is never read.
     The host guarantees every non-trash wb entry is privately owned, so
     no two rows ever scatter to the same live page."""
-    L, F = arena.k.shape[0], arena.k.shape[-1]
+    L = arena.k.shape[0]
     B, wp = wb.shape
 
     def s4(a, w):
-        return a.at[:, wb].set(w.reshape(L, B, wp, page, F))
+        return a.at[:, wb].set(w.reshape(L, B, wp, page, a.shape[-1]))
 
     def s3(a, w):
         return a.at[:, wb].set(w.reshape(a.shape[0], B, wp, page))
@@ -224,12 +237,26 @@ def init_params(
     def stack(L, experts, keys):
         """One homogeneous stack of L layers: attention, norms, and a
         dense or an expert MLP."""
-        p = {
-            "wq": dense(next(keys), (L, D, spec.q_dim)),
-            "wk": dense(next(keys), (L, D, spec.kv_dim)),
-            "wv": dense(next(keys), (L, D, spec.kv_dim)),
-            "wo": dense(next(keys), (L, spec.q_dim, D)),
-        }
+        if spec.kv_lora_rank:  # latent attention (``_latent_mixer``)
+            H, rq, rkv = spec.n_heads, spec.q_lora_rank, spec.kv_lora_rank
+            p = {
+                "wq_a": dense(next(keys), (L, D, rq)),
+                "q_a_norm_w": jnp.ones((L, rq), dtype),
+                "wq_b": dense(next(keys), (L, rq, spec.q_dim)),
+                "wkv_a": dense(next(keys), (L, D, spec.latent_width)),
+                "kv_a_norm_w": jnp.ones((L, rkv), dtype),
+                "wkv_b_k": dense(next(keys), (L, H, spec.qk_nope_dim, rkv),
+                                 1.0 / math.sqrt(rkv)),
+                "wkv_b_v": dense(next(keys), (L, H, rkv, spec.v_head_dim)),
+                "wo": dense(next(keys), (L, spec.o_dim, D)),
+            }
+        else:
+            p = {
+                "wq": dense(next(keys), (L, D, spec.q_dim)),
+                "wk": dense(next(keys), (L, D, spec.kv_dim)),
+                "wv": dense(next(keys), (L, D, spec.kv_dim)),
+                "wo": dense(next(keys), (L, spec.q_dim, D)),
+            }
         if spec.pre_norm:
             p["ln1_w"] = jnp.ones((L, D), dtype)
         if spec.attn_output_gate:
@@ -241,6 +268,8 @@ def init_params(
             if spec.moe_select_bias:
                 p["router_bias"] = (jax.random.normal(
                     next(keys), (L, E), jnp.float32) * 0.02)
+            E = spec.n_held  # the router is as wide as the published
+            # count; the matrices are those of the experts held
             p["moe_gate"] = dense(next(keys), (L, E, D, Fm))
             p["moe_up"] = dense(next(keys), (L, E, D, Fm))
             p["moe_down"] = dense(next(keys), (L, E, Fm, D))
@@ -408,7 +437,11 @@ def rope_inv_freq(spec: LLMSpec) -> jnp.ndarray:
 
 def rope_attn_scale(spec: LLMSpec) -> float:
     """YaRN attention scaling (mscale): HF multiplies cos/sin by
-    ``attention_factor`` (default 0.1*ln(factor)+1) for yarn-scaled models."""
+    ``attention_factor`` — the block's own when it gives one;
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim) when it
+    gives both (deepseek: equal, so 1.0 — its mscale^2 sits in the
+    softmax scale, ``LLMSpec.attn_scale_mult``); 0.1*ln(factor)+1 when
+    it gives neither. mscale(f, m) = 0.1*m*ln(f)+1 for f > 1, else 1."""
     sc = spec.rope_scaling or {}
     rtype = (sc.get("rope_type") or sc.get("type") or "").lower()
     if rtype != "yarn":
@@ -416,7 +449,15 @@ def rope_attn_scale(spec: LLMSpec) -> float:
     af = sc.get("attention_factor")
     if af is not None:
         return float(af)
-    return 0.1 * math.log(float(sc.get("factor", 1.0))) + 1.0
+    factor = float(sc.get("factor", 1.0))
+
+    def mscale(m: float) -> float:
+        return 0.1 * m * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    if sc.get("mscale") and sc.get("mscale_all_dim"):
+        return mscale(float(sc["mscale"])) / mscale(
+            float(sc["mscale_all_dim"]))
+    return 0.1 * math.log(factor) + 1.0
 
 
 def apply_rope(
@@ -514,6 +555,9 @@ def _layer_body(spec, x, lp, positions, inv_freq, rope_scale, attn_fn,
     h = _norm(spec, x, lp["ln1_w"], lp.get("ln1_b")) if "ln1_w" in lp else x
     if mixer is not None:
         attn, carry = mixer(h)
+    elif "wkv_a" in lp:  # latent attention: attn_fn(q_n, q_r, row)
+        attn, carry = _latent_mixer(spec, lp, h, positions, inv_freq,
+                                    rope_scale, attn_fn)
     else:
         q = _mm(h, lp["wq"])
         k = _mm(h, lp["wk"])
@@ -582,6 +626,108 @@ def _layer_body(spec, x, lp, positions, inv_freq, rope_scale, attn_fn,
         mlp = _norm(spec, mlp, lp["ln_post_ffw_w"], None)
     out = (x + attn + mlp) if spec.parallel_residual else (x + mlp)
     return out, carry, counts
+
+
+def latent_scale(spec) -> float:
+    """Softmax scale of latent attention: (d_n + d_r)^-1/2, times the
+    YaRN mscale^2 where the model puts it there."""
+    return spec.attn_scale_mult / math.sqrt(spec.d_head)
+
+
+def _latent_row(spec, c, kr):
+    """What a token caches, and nothing else: [c (normed) | k_r (rotated)
+    | 0...] up to ``spec.latent_row`` lanes. c [B, T, r], kr [B, T, 1,
+    d_r]. (The lower-precision controls of tests and tools/mla_parity.py
+    wrap this function.)"""
+    B, T, r = c.shape
+    dr = kr.shape[-1]
+    return jnp.concatenate(
+        [c, kr.reshape(B, T, dr),
+         jnp.zeros((B, T, spec.latent_row - r - dr), c.dtype)], axis=-1)
+
+
+def _latent_mixer(spec, lp, h, positions, inv_freq, rope_scale, attn_fn):
+    """The token mixer of a latent-attention layer (deepseek_v3) up to
+    and after the attention itself:
+
+      c_q = RMSNorm(h W_qa);  [q_n | q_r]_h = c_q W_qb      per head
+      [c | k_r] = h W_kva;  c = RMSNorm(c)
+      rotary on q_r and on the ONE k_r all heads share
+      row = [c | k_r | 0...]            what a token caches, nothing else
+      heads = attn_fn(q_n, q_r, row)    [.., H * d_v]
+      y = heads W_o
+
+    ``attn_fn`` owns where the rows live and which FORM attends them
+    (``latent_attend_expanded`` up-projects cached rows through W_kvb;
+    the kernel route absorbs W_kvb into the query and the output and
+    reads the rows as they are). Rotary is rotate-half: the loader has
+    moved a checkpoint's interleaved pairs (``_load_deepseek_v3``).
+    -> (branch [.., D], the cache arrays attn_fn wrote)."""
+    B, T = h.shape[0], h.shape[1]
+    H, dn, dr = spec.n_heads, spec.qk_nope_dim, spec.qk_rope_dim
+    r = spec.kv_lora_rank
+    cq = _norm(spec, _mm(h, lp["wq_a"]), lp["q_a_norm_w"], None)
+    q = _mm(cq, lp["wq_b"])
+    kva = _mm(h, lp["wkv_a"])
+    q, kva = lax.optimization_barrier((q, kva))  # as in _layer_body
+    q = q.reshape(B, T, H, dn + dr)
+    qn, qr = q[..., :dn], q[..., dn:]
+    c = _norm(spec, kva[..., :r], lp["kv_a_norm_w"], None)
+    kr = kva[..., r:].reshape(B, T, 1, dr)
+    qr = apply_rope(qr, positions, inv_freq, dr, rope_scale)
+    kr = apply_rope(kr, positions, inv_freq, dr, rope_scale)
+    heads, carry = attn_fn(qn, qr, _latent_row(spec, c, kr))
+    return _mm(heads, lp["wo"]), carry
+
+
+def _prec(x):
+    return (lax.Precision.HIGHEST if x.dtype == jnp.float32
+            else lax.Precision.DEFAULT)
+
+
+def latent_attend_expanded(spec, lp, qn, qr, rows, q_pos):
+    """The EXPANDED form: cached rows [B, S, latent_row] up-projected
+    through W_kvb into every head's k_n [S, H, d_n] and v [S, H, d_v],
+    then causal attention at (d_n + d_r) x d_v a head — what the
+    published description computes, and the engine's XLA route.
+    q_pos [B, T]: the queries' absolute positions. -> [B, T, H * d_v]."""
+    r, dr = spec.kv_lora_rank, spec.qk_rope_dim
+    B, T = qn.shape[0], qn.shape[1]
+    c, kr = rows[..., :r], rows[..., r:r + dr]
+    prec = _prec(qn)
+    kn = jnp.einsum("bsc,hnc->bshn", c, lp["wkv_b_k"], precision=prec)
+    v = jnp.einsum("bsc,hcv->bshv", c, lp["wkv_b_v"], precision=prec)
+    logits = (jnp.einsum("bthn,bshn->bhts", qn, kn, precision=prec,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bthr,bsr->bhts", qr, kr, precision=prec,
+                           preferred_element_type=jnp.float32)
+              ) * latent_scale(spec)
+    kv_pos = lax.broadcasted_iota(jnp.int32, (1, 1, 1, rows.shape[1]), 3)
+    logits = jnp.where(kv_pos <= q_pos[:, None, :, None], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bhts,bshv->bthv", probs.astype(v.dtype), v,
+                     precision=prec, preferred_element_type=jnp.float32)
+    return out.reshape(B, T, -1).astype(qn.dtype)
+
+
+def latent_absorb_query(spec, lp, qn, qr):
+    """The ABSORBED form's query: q~_h = W_kvb,k,h^T q_n,h beside q_r
+    and the row's zero lanes -> [B, T, H, latent_row]; its score against
+    a cached row is the expanded form's score."""
+    qa = jnp.einsum("bthn,hnc->bthc", qn, lp["wkv_b_k"],
+                    precision=_prec(qn)).astype(qn.dtype)
+    pad = spec.latent_row - spec.latent_width
+    return jnp.concatenate(
+        [qa, qr, jnp.zeros((*qr.shape[:3], pad), qr.dtype)], axis=-1)
+
+
+def latent_absorb_out(spec, lp, ctx, dtype):
+    """The absorbed form's output: sum_s p_s c_s [B, T, H, r] through
+    each head's W_kvb,v -> [B, T, H * d_v]."""
+    out = jnp.einsum("bthc,hcv->bthv", ctx.astype(dtype), lp["wkv_b_v"],
+                     precision=_prec(lp["wkv_b_v"]),
+                     preferred_element_type=jnp.float32)
+    return out.reshape(*out.shape[:2], -1).astype(dtype)
 
 
 def _linear_mixer(spec, lp, h, *, groups, split, join, valid_of, st,
@@ -686,8 +832,15 @@ def _route(spec, lp, x):
       the probabilities over all E, as they are;
     - sigmoid (afmoe): the k largest of sigmoid(logits) + the selection
       bias; the weight is the score WITHOUT the bias, divided by the
-      selected scores' sum where the spec renormalises.
-    The weights carry the routed scale."""
+      selected scores' sum where the spec renormalises;
+    - sigmoid, group-limited (deepseek_v3 ``noaux_tc``;
+      ``moe_n_group`` > 1): the experts in equal groups, a group's
+      score the sum of its two largest biased scores, the biased
+      scores outside the ``moe_topk_group`` best groups set to 0 (as
+      published: masked_fill(0.0), not -inf), then as above.
+    Ties go to the lower index (``lax.top_k``), groups and experts
+    alike. The weights carry the routed scale. The ids are PUBLISHED
+    expert ids: a layer that holds a share maps them in ``_moe_mlp``."""
     K = spec.experts_per_token
     logits = jnp.einsum(
         "nd,de->ne", x.astype(jnp.float32),
@@ -700,6 +853,16 @@ def _route(spec, lp, x):
         choose = scores
         if "router_bias" in lp:
             choose = scores + lp["router_bias"].astype(jnp.float32)
+        if spec.moe_n_group > 1:
+            G, E = spec.moe_n_group, choose.shape[-1]
+            grouped = choose.reshape(-1, G, E // G)
+            top2, _ = lax.top_k(grouped, 2)
+            _, keep = lax.top_k(jnp.sum(top2, axis=-1),
+                                spec.moe_topk_group)  # [N, topk_group]
+            kept = jnp.any(keep[:, :, None] == jnp.arange(
+                G, dtype=keep.dtype)[None, None, :], axis=1)  # [N, G]
+            choose = jnp.where(kept[:, :, None], grouped,
+                               0.0).reshape(-1, E)
         _, idx = lax.top_k(choose, K)
         w = jnp.take_along_axis(scores, idx, axis=-1)
         if spec.moe_norm_topk:
@@ -729,7 +892,8 @@ def _moe_mlp(spec, lp, x, valid, experts):
     back to token order -> weighted sum over k, in f32 -> + shared
     expert. ``valid`` [B, T] bool: positions that carry no token are
     routed nowhere (they sort past the last group and read no expert).
-    Returns (out [B, T, D], tokens per expert [E] i32).
+    Returns (out [B, T, D], tokens per HELD expert [E] i32 — for a
+    layer that holds a share, [E + 1]: then the absent assignments).
 
     ``experts`` = (the stack's EXPERT_LEAVES as [n, E, ...] arrays, this
     layer's index in them): inside a layer scan the grouped matmul takes
@@ -743,12 +907,23 @@ def _moe_mlp(spec, lp, x, valid, experts):
     mixture, un-renormalized top-k weights (norm_topk_prob=false), and
     dense-only layers (``_dense_only`` flag) where the shared slot holds a
     plain MLP whose gate is forced to 1 and the expert term is dropped."""
-    E, K = spec.n_experts, spec.experts_per_token
+    E, K = spec.n_held, spec.experts_per_token
+    share = E < spec.n_experts
     B, T, D = x.shape
     N = B * T
     xf = x.reshape(N, D)
     idx, w = _route(spec, lp, xf)
     flat = idx.reshape(N * K)
+    if share:
+        # THE SHARE: the router picked among all the published experts;
+        # this layer holds ``experts_first`` .. + E of them. An
+        # assignment to an absent expert takes the path of a position
+        # without a token — it sorts past the last group, reads no
+        # expert and adds nothing; the chip that holds that expert adds
+        # it there. No token is dropped and nothing stands in for the
+        # absent experts
+        local = flat - spec.experts_first
+        flat = jnp.where((local >= 0) & (local < E), local, E)
     if valid is not None:
         # the sentinel E sorts last and is counted in no group
         flat = jnp.where(jnp.repeat(valid.reshape(N), K), flat, E)
@@ -764,7 +939,7 @@ def _moe_mlp(spec, lp, x, valid, experts):
     u = lax.ragged_dot(xs, w_up, sizes)
     y = lax.ragged_dot((_act(spec, g) * u).astype(x.dtype),
                        w_down, sizes)  # [N*K, D]
-    if valid is not None:
+    if valid is not None or share:
         # rows past the last group are whatever the kernel left there
         y = jnp.where(
             jnp.arange(N * K, dtype=jnp.int32)[:, None] < jnp.sum(counts),
@@ -788,6 +963,13 @@ def _moe_mlp(spec, lp, x, valid, experts):
             sg = jnp.where(dense_only > 0, 1.0, sg)
             out = out * (1.0 - dense_only)
         out = out + s.astype(jnp.float32) * sg
+    if share:
+        # [E + 1]: in the last place the assignments of real tokens
+        # that went to experts held elsewhere
+        n_real = (N if valid is None
+                  else jnp.sum(valid, dtype=jnp.int32)) * K
+        counts = jnp.concatenate(
+            [counts, (n_real - jnp.sum(counts))[None].astype(jnp.int32)])
     return out.astype(x.dtype), counts
 
 
@@ -1041,10 +1223,11 @@ def forward_rows(
 ) -> tuple[tuple, KVCache, Optional[jax.Array]]:
     """``forward_hidden`` for one or more rectangles of rows in ONE
     pass: returns (one hidden [B, T, D] per group, updated cache,
-    expert statistics [E + 1] i32 — the tokens each expert took summed
-    over the expert layers, then in the last place the experts that had
-    a token, summed over those layers — or None for a model without
-    experts).
+    expert statistics [E + 1] i32 — the tokens each HELD expert took
+    summed over the expert layers, then the experts that had a token,
+    summed over those layers; a model that holds a share of its experts
+    appends the assignments that went to absent ones, [E + 2] — or None
+    for a model without experts).
 
     With more than one group the rows ride the layer's matmuls as one
     flat ``[1, sum(B*T), D]`` batch — each weight is read from HBM once
@@ -1145,7 +1328,7 @@ def forward_rows(
         use_ragged = page_table is not None
         use_kernel = use_ragged or (
             decode_kernel and single and g0.slot_ids is None
-            and x.shape[1] == 1)
+            and x.shape[1] == 1 and not spec.kv_lora_rank)
         if use_kernel:
             ck = cv = ks = vs = None  # kernel addresses the full cache
         else:
@@ -1315,14 +1498,16 @@ def forward_rows(
                         (ck_new, cv_new, ks_new, vs_new))
             return out[:, None, :].astype(x.dtype), (ck_new, cv_new)
 
-        def kv_from_cache(g, st, k, v):
+        def kv_from_cache(g, st, k, v, raw=False):
             # cache rows are head-FLAT [seq, kv_dim] (see KVCache); heads are
             # re-split transiently for the attention contraction
+            # (``raw``: the rows come back flat as they are cached — a
+            # latent cache, whose ``v`` has no lanes)
             ck, cv, ks, vs = st
             pos0, slot_ids, write_mask = g.pos0, g.slot_ids, g.write_mask
             B, T = k.shape[0], k.shape[1]
-            kf = k.reshape(B, T, spec.kv_dim)
-            vf = v.reshape(B, T, spec.kv_dim)
+            kf = k.reshape(B, T, -1)
+            vf = v.reshape(B, T, -1)
             if quant:
                 kq, ksc = _quantize_rows(kf)  # int8 [B,T,F], f32 [B,T]
                 vq, vsc = _quantize_rows(vf)
@@ -1331,6 +1516,8 @@ def forward_rows(
 
             def split(buf, scales):
                 # [B, S, kv_dim](+scales [B, S]) -> [B, S, Hkv, Dh] compute
+                if raw:
+                    return buf
                 out = buf.reshape(
                     buf.shape[0], buf.shape[1], spec.n_kv_heads, spec.d_head
                 )
@@ -1356,7 +1543,8 @@ def forward_rows(
                     # [B, T, F] read is tiny next to the layer traffic
                     def cur_row(buf_row, off):
                         return lax.dynamic_slice(
-                            buf_row, (off, 0), (kq.shape[1], kq.shape[2]))
+                            buf_row, (off, 0),
+                            (kq.shape[1], buf_row.shape[-1]))
 
                     def cur_scale(srow, off):
                         return lax.dynamic_slice(srow, (off,),
@@ -1439,8 +1627,55 @@ def forward_rows(
                            positions if single else positions_of(g),
                            lp.get("_window")), carry
 
-        one = (ragged_attn if use_ragged
-               else (kernel_attn if use_kernel else xla_attn))
+        def latent_ragged(g, st, qn, qr, row):
+            # the ragged route for a latent cache: the chunk's rows
+            # scatter into the arena through the write table as K rows
+            # do, then the ABSORBED form attends them in the kernel
+            # (ops/ragged_paged_attention.py, ``v_lanes``): 128 query
+            # heads x the whole row against a page, PV against the
+            # page's first kv_lora_rank lanes; W_kvb never touches a
+            # cached row
+            from ..ops.ragged_paged_attention import (
+                ragged_paged_attention,
+            )
+
+            ck_all = st[0]
+            pos0, q_lens = g.pos0, g.q_lens
+            attend = (q_lens if g.live is None
+                      else jnp.where(g.live, q_lens, 0))
+            B, T = row.shape[0], row.shape[1]
+            rows = jnp.arange(B, dtype=jnp.int32)
+            tpos = pos0[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
+            wpg = g.write_table[rows[:, None], tpos // kv_page]
+            wpg = jnp.where(
+                jnp.arange(T, dtype=jnp.int32)[None] < q_lens[:, None],
+                wpg, 0)
+            ck_new = ck_all.at[l, wpg, tpos % kv_page, :].set(
+                row.astype(ck_all.dtype), mode="promise_in_bounds")
+            ctx = ragged_paged_attention(
+                latent_absorb_query(spec, lp, qn, qr), ck_new, None, l,
+                g.page_table, pos0, attend, 1,
+                scale=latent_scale(spec), page=kv_page,
+                v_lanes=spec.kv_lora_rank,
+            )  # [B, T, H * r] f32
+            ctx = ctx.reshape(B, T, spec.n_heads, spec.kv_lora_rank)
+            return (latent_absorb_out(spec, lp, ctx, x.dtype),
+                    (ck_new, st[1]))
+
+        def latent_xla(g, st, qn, qr, row):
+            # the XLA route: rows written as any K row is, then the
+            # EXPANDED form over the row's whole view
+            view, _, carry = kv_from_cache(
+                g, st, row, row[..., :0], raw=True)
+            return latent_attend_expanded(
+                spec, lp, qn, qr, view,
+                positions if single else positions_of(g)), carry
+
+        if spec.kv_lora_rank:
+            one = latent_ragged if use_ragged else latent_xla
+        else:
+            one = (ragged_attn if use_ragged
+                   else (kernel_attn if use_kernel else xla_attn))
         st0 = ((ck_all, cv_all, ks_all, vs_all) if use_kernel
                else (ck, cv, ks, vs))
 
@@ -1489,9 +1724,13 @@ def forward_rows(
             (jnp.arange(first, first + n, dtype=jnp.int32),
              jnp.arange(n, dtype=jnp.int32), stacked))
         if counts is not None:  # [n, E] of an expert stack
+            absent = ()
+            if spec.experts_held:  # a share: [n, E + 1], see _moe_mlp
+                absent = (jnp.sum(counts[:, -1])[None],)
+                counts = counts[:, :-1]
             expert_tokens = jnp.concatenate([
                 jnp.sum(counts, axis=0),
-                jnp.sum(counts > 0, dtype=jnp.int32)[None]])
+                jnp.sum(counts > 0, dtype=jnp.int32)[None], *absent])
     x, new_k, new_v, new_ks, new_vs, *rec = carry
     new_cache = KVCache(new_k, new_v, new_ks if quant else None,
                         new_vs if quant else None, *rec)
@@ -1551,6 +1790,10 @@ def forward_train(
         raise NotImplementedError(
             f"{spec.model_type}: the training forward has no "
             "linear-attention layers (serving path only)")
+    if spec.kv_lora_rank or spec.experts_held:
+        raise NotImplementedError(
+            f"{spec.model_type}: the training forward has no latent "
+            "attention and no expert share (serving path only)")
     B, T = tokens.shape
     x = _embed_in(spec, params, tokens)
     positions = jnp.broadcast_to(

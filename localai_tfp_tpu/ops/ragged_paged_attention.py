@@ -133,14 +133,21 @@ def _q_tiling(T: int, n_heads: int, group: int) -> tuple[int, int]:
 
 def _ragged_kernel(*refs, scale: float, page: int, tq: int, group: int,
                    n_kv_heads: int, d_head: int, quantized: bool,
-                   seeded: bool):
+                   seeded: bool, v_lanes: int = 0):
     qlen_ref, pos_ref, layer_ref, win_ref, pt_ref, q_ref, *rest = refs
     if seeded:
         newk_ref, newv_ref, *rest = rest
-    ck_in, cv_in, *rest = rest
-    if quantized:
-        ks_ref, vs_ref, *rest = rest
-    out_ref, kbuf, vbuf, rsem, hand_ref, m_ref, l_ref, acc_ref = rest
+    if v_lanes:
+        # a latent arena: ONE plane, whose page is the key (all d_head
+        # lanes) and, in its first v_lanes lanes, the value
+        ck_in, out_ref, kbuf, rsem, hand_ref, m_ref, l_ref, acc_ref = rest
+        cv_in, vbuf = None, kbuf
+    else:
+        ck_in, cv_in, *rest = rest
+        if quantized:
+            ks_ref, vs_ref, *rest = rest
+        out_ref, kbuf, vbuf, rsem, hand_ref, m_ref, l_ref, acc_ref = rest
+    d_v = v_lanes or d_head  # lanes of a head's value and output
     b = pl.program_id(0)
     nq = pl.num_programs(1)
     step = b * nq + pl.program_id(1)  # the grid runs in this order
@@ -204,6 +211,9 @@ def _ragged_kernel(*refs, scale: float, page: int, tq: int, group: int,
     def band(h):
         return slice(h * d_head, (h + 1) * d_head)
 
+    def vband(h):
+        return slice(0, v_lanes) if v_lanes else band(h)
+
     def widen(x):
         """int8 page rows -> the query dtype. Mosaic converts int8 only
         through f32; the values (|x| <= 127) are exact in bf16."""
@@ -238,13 +248,16 @@ def _ragged_kernel(*refs, scale: float, page: int, tq: int, group: int,
         else:
             m_ref[h] = jnp.full((R, _STAT_LANES), NEG_INF, jnp.float32)
             l_ref[h] = jnp.zeros((R, _STAT_LANES), jnp.float32)
-            acc_ref[h] = jnp.zeros((R, d_head), jnp.float32)
+            acc_ref[h] = jnp.zeros((R, d_v), jnp.float32)
 
     def get_dma(slot, row, p):
         phys = pt_ref[row, p]
+        k_dma = pltpu.make_async_copy(ck_in.at[layer, phys],
+                                      kbuf.at[slot], rsem.at[slot, 0])
+        if v_lanes:
+            return (k_dma,)
         return (
-            pltpu.make_async_copy(ck_in.at[layer, phys],
-                                  kbuf.at[slot], rsem.at[slot, 0]),
+            k_dma,
             pltpu.make_async_copy(cv_in.at[layer, phys],
                                   vbuf.at[slot], rsem.at[slot, 1]),
         )
@@ -319,7 +332,7 @@ def _ragged_kernel(*refs, scale: float, page: int, tq: int, group: int,
                 if quantized:
                     pexp[h] = pexp[h] * vs_row
             for h in hs:
-                vh = widen(vbuf[slot, :, band(h)])  # [page, Dh]
+                vh = widen(vbuf[slot, :, vband(h)])  # [page, Dh]
                 pv[h] = jax.lax.dot_general(
                     pexp[h].astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32,
@@ -374,12 +387,24 @@ def ragged_paged_attention(
     seed_kv: Optional[tuple] = None,  # (new_k [B, F], new_v [B, F]):
     # T==1 decode mode — the current rows' EXACT values ride in VMEM and
     # their HBM copies are masked (ops/decode_attention.py contract)
+    v_lanes: int = 0,  # > 0: a LATENT arena (absorbed latent attention,
+    # models/transformer.py ``latent_ragged``): ``cache_v`` is None,
+    # n_kv_heads is 1, F == Dh is the whole cached row [c | k_r | pad]
+    # and a page's first ``v_lanes`` lanes are also its value — one
+    # plane is walked, each page fetched ONCE for both matmuls. The
+    # call is named ``latent_paged_attention`` in a capture
 ) -> jax.Array:
     """Ragged attention for the whole batch in ONE kernel invocation;
-    returns [B, T, H * Dh] f32."""
+    returns [B, T, H * Dh] f32 ([B, T, H * v_lanes] for a latent
+    arena)."""
     B, T, H, Dh = q.shape
     L, NP, PG, F = cache_k.shape
     assert PG == page, (PG, page)
+    if v_lanes:
+        assert cache_v is None and n_kv_heads == 1 and F == Dh \
+            and v_lanes % 128 == 0 and cache_k_scale is None \
+            and seed_kv is None, "latent arena: one bf16 plane, no seed"
+    Dv = v_lanes or Dh
     _, max_pages = page_table.shape
     group = H // n_kv_heads
     quantized = cache_k_scale is not None
@@ -411,8 +436,8 @@ def ragged_paged_attention(
         new_k, new_v = seed_kv
         operands += [new_k[:, None, :], new_v[:, None, :]]
         in_specs += [_row_spec((1, 1, F)), _row_spec((1, 1, F))]
-    operands += [cache_k, cache_v]
-    in_specs += [any_spec, any_spec]
+    operands += [cache_k] if v_lanes else [cache_k, cache_v]
+    in_specs += [any_spec] if v_lanes else [any_spec, any_spec]
     if quantized:
         # per-row scale pages gathered through the table ([B, max_pages,
         # page] — logical page p of row b lands at row p, where the
@@ -426,27 +451,29 @@ def ragged_paged_attention(
         num_scalar_prefetch=nsp,
         grid=(B, Tp // tq),
         in_specs=in_specs,
-        out_specs=q_spec,
+        out_specs=pl.BlockSpec((1, n_kv_heads, R, Dv),
+                               lambda b, qi, *_: (b, 0, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, page, F), cache_k.dtype),
-            pltpu.VMEM((2, page, F), cache_v.dtype),
+            *(() if v_lanes else (
+                pltpu.VMEM((2, page, F), cache_v.dtype),)),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),  # the cross-step hand-over
             pltpu.VMEM((n_kv_heads, R, _STAT_LANES), jnp.float32),  # m
             pltpu.VMEM((n_kv_heads, R, _STAT_LANES), jnp.float32),  # l
-            pltpu.VMEM((n_kv_heads, R, Dh), jnp.float32),  # acc
+            pltpu.VMEM((n_kv_heads, R, Dv), jnp.float32),  # acc
         ],
     )
     kernel = functools.partial(
         _ragged_kernel, scale=scale, page=page, tq=tq, group=group,
         n_kv_heads=n_kv_heads, d_head=Dh, quantized=quantized,
-        seeded=seeded,
+        seeded=seeded, v_lanes=v_lanes,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
-            (B, n_kv_heads, Tp * group, Dh), jnp.float32),
+            (B, n_kv_heads, Tp * group, Dv), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             # one page walk from the first grid step to the last: a
             # step waits for the DMA the step before it started, so the
@@ -454,11 +481,12 @@ def ragged_paged_attention(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=_interpret(),
-        name="ragged_paged_attention",
+        name=("latent_paged_attention" if v_lanes
+              else "ragged_paged_attention"),
     )(*operands)
     # [B, Hkv, Tp*group, Dh] -> [B, T, H*Dh]
-    return out.reshape(B, n_kv_heads, Tp, group, Dh).transpose(
-        0, 2, 1, 3, 4).reshape(B, Tp, H * Dh)[:, :T]
+    return out.reshape(B, n_kv_heads, Tp, group, Dv).transpose(
+        0, 2, 1, 3, 4).reshape(B, Tp, H * Dv)[:, :T]
 
 
 def mesh_ragged_eligible(mesh, n_kv_heads: int, n_heads: int,

@@ -173,6 +173,13 @@ ENGINE_KV_HBM_PER_TOKEN = REGISTRY.gauge(
     "pins this at max_seq/mean_context x the ideal",
     labels=("model",),
 )
+ENGINE_KV_ROW_BYTES = REGISTRY.gauge(
+    "engine_kv_row_bytes",
+    "Bytes ONE cached token holds over all layers as stored: the "
+    "arena's bytes (row scales, a latent row's zero lanes) over its "
+    "token capacity",
+    labels=("model",),
+)
 # tiered KV memory (engine/kv_tier.py): hot HBM pages, warm host-RAM
 # pages, cold on-disk sessions
 ENGINE_KV_TIER_PAGES = REGISTRY.gauge(
@@ -526,6 +533,16 @@ ENGINE_EXPERTS_TOUCHED = REGISTRY.counter(
     "layer-steps of engine_expert_layer_steps_total: the expert "
     "weights a step had to read, in experts",
     labels=("model", "kind"),
+)
+ENGINE_EXPERT_ASSIGNMENTS = REGISTRY.counter(
+    "engine_expert_assignments_total",
+    "(token, expert) assignments of a model whose layers hold a SHARE "
+    "of the published experts, summed over the expert layers of the "
+    "step programs harvested: where=held went to an expert on this "
+    "chip (and were computed), where=absent to one held elsewhere "
+    "(read nothing, added nothing). held / (held + absent) is the part "
+    "of the deployment's routed load this chip carries",
+    labels=("model", "where"),
 )
 ENGINE_DECODE_STEPS = REGISTRY.counter(
     "engine_decode_steps_total",

@@ -873,6 +873,11 @@ class JaxLLMBackend(Backend):
             # the reason, naming the type)
             "layer_kinds": eng.layer_kinds(),
             "cache_bytes": eng.cache_bytes(),
+            # what ONE cached token holds over all layers, as stored
+            "cache_bytes_per_token": eng.kv_row_bytes,
+            # the published ids of the experts a layer holds here
+            # ([first, last]; a share when fewer than the router scores)
+            "experts_held": eng.experts_held(),
             "state_refusals": dict(eng.state_refusals),
             "warmup_variants": eng.warmup_variants,
             "n_slots": eng.n_slots,

@@ -86,9 +86,13 @@ def test_spec_is_what_the_config_says():
 
 
 @pytest.mark.parametrize("key", ["n_group", "topk_group"])
-def test_grouped_selection_is_refused_not_guessed(key):
-    with pytest.raises(NotImplementedError, match="grouped expert"):
-        spec_from_hf_config(dict(TINY, **{key: 2}))
+def test_grouped_selection_reaches_the_spec(key):
+    """Refused until PR 45 (``_route`` had no group-limited pick); now
+    the spec carries the groups and ``_route`` honours them
+    (tests/test_deepseek_v3.py holds the pick to the reference)."""
+    spec = spec_from_hf_config(dict(TINY, **{key: 2}))
+    assert (spec.moe_n_group, spec.moe_topk_group) == (
+        (2, 1) if key == "n_group" else (1, 2))
 
 
 def test_loader_builds_two_stacks(tiny):

@@ -9,7 +9,10 @@ to one xdist worker, and a second file's fixture would skip in silence.
 
 - the KV tier's spill gather moves only its pages (PR 42);
 - the step programs' layer loops hold no op that slices a layer's
-  matrix out of its stack or copies it into another layout (PR 44).
+  matrix out of its stack or copies it into another layout (PR 44);
+- DeepSeek-V3's two step programs — the latent arena, the absorbed
+  kernel at 128 heads x 640 lanes, the share — compile at serving
+  shapes with their temporaries bounded (PR 45).
 """
 
 import json
@@ -117,3 +120,30 @@ def test_step_program_reads_its_weights_from_the_stack_in_place(
         pytest.fail("a layer's weight moved by an op that is no matmul:\n"
                     + "\n".join(f"  {leaf}: {op.line[:150]}"
                                 for _, op, leaf in bad), pytrace=False)
+
+
+@pytest.mark.parametrize("kind", ["decodek", "mixed"])
+def test_latent_step_programs_compile_with_temporaries_bounded(
+        one_v5e, kind):
+    """``deepseek-v3-ep16-share`` as served: Mosaic takes the ragged
+    kernel's latent form at 128 query heads against [256, 640] pages
+    (decode rows and a 512-token prompt row alike), no layer's weight
+    is sliced out of its stack, and the program's temporaries stay
+    under 1.5 GB beside 11 GB of weights and 1 GB of latent arena — a
+    copy of an expert stack (2.8 GB a layer) or of the arena would not
+    (PR 41 found 1.86 GB of copies this way before any run)."""
+    from tools.step_hlo import lower_program, offenders_of
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "deepseek-v3-ep16-share.json")) as f:
+        config = json.load(f)
+    with jax.default_matmul_precision("default"):
+        compiled = lower_program(config, kind, one_v5e).compile()
+    text = compiled.as_text()
+    assert "%latent_paged_attention" in text
+    assert "%ragged_paged_attention" not in text  # ops named by kernel
+    assert not offenders_of(text, config)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.5e9, mem.temp_size_in_bytes
+    # the arena is updated in place: the program's outputs alias it
+    assert mem.alias_size_in_bytes >= 6 * 513 * 256 * 640 * 2

@@ -544,3 +544,46 @@ def test_sweep_fits_row_cost_from_kernel_times(monkeypatch):
     assert res["points"][-1]["roof_share"] == round(
         2 * 24 * 2 * 256 / 50e9 / 7e-6, 4)
     assert res["page_pair_dma_us"] == round(2 * 16 * 256 / 50e9 * 1e6, 3)
+
+
+# ------------------------------------- the kernel over a LATENT arena
+
+
+@pytest.mark.parametrize("T", [1, 24], ids=["decode_rows", "prompt_chunk"])
+def test_latent_arena_at_128_heads_of_576(T):
+    """Absorbed latent attention at DeepSeek-V3's shape — 128 query
+    heads against ONE cached row of 512 + 64 values in 640 lanes, the
+    value the same page's first 512 lanes — interpreted: rows of
+    different contexts across pages, a parked row, a chunk whose tail
+    is padding; against the dense softmax over the same rows."""
+    import numpy as np
+
+    from localai_tfp_tpu.ops.ragged_paged_attention import (
+        ragged_paged_attention,
+    )
+
+    H, row, lat, r, page, B = 128, 640, 576, 512, 16, 4
+    ks = jax.random.split(jax.random.PRNGKey(T), 3)
+    n_pages = 1 + B * 4
+    arena = jax.random.normal(ks[0], (2, n_pages, page, row), jnp.float32)
+    arena = arena.at[..., lat:].set(0.0)  # the row's zero lanes
+    q = jax.random.normal(ks[1], (B, T, H, row), jnp.float32) * 0.1
+    q = q.at[..., lat:].set(0.0)
+    table = 1 + jnp.arange(B * 4, dtype=jnp.int32).reshape(B, 4)
+    pos0 = jnp.asarray([37, 0, 16, 5], jnp.int32)
+    q_lens = jnp.asarray([T, T, 0, max(1, T - 3)], jnp.int32)  # row 2 parked
+    scale = 0.11
+    out = ragged_paged_attention(
+        q, arena, None, jnp.int32(1), table, pos0, q_lens, 1, scale=scale,
+        page=page, v_lanes=r)
+    assert out.shape == (B, T, H * r)
+    out = np.asarray(out).reshape(B, T, H, r)
+    rows = np.asarray(arena[1][table]).reshape(B, 4 * page, row)
+    for b in range(B):
+        for t in range(int(q_lens[b])):
+            n = int(pos0[b]) + t + 1
+            s = np.asarray(q[b, t]) @ rows[b, :n].T * scale  # [H, n]
+            p = np.exp(s - s.max(-1, keepdims=True))
+            want = (p / p.sum(-1, keepdims=True)) @ rows[b, :n, :r]
+            np.testing.assert_allclose(out[b, t], want, rtol=2e-4,
+                                       atol=2e-5)

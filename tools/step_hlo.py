@@ -127,7 +127,8 @@ def _shell_engine(s: dict):
     eng.sampling = s["sampling"]
     eng._paged, eng._page = True, s["page"]
     eng._route = choose_route(paged=True, kernel=True, max_seq=s["max_seq"],
-                              page=s["page"], mesh=None)
+                              page=s["page"], mesh=None,
+                              latent=bool(s["spec"].kv_lora_rank))
     eng._decode_k_fns = {}
     return eng
 
