@@ -68,7 +68,8 @@ from jax import lax
 from ..config import knobs
 from ..models.llm_spec import LLMSpec
 from ..models.transformer import (
-    KVCache, Params, Rows, forward, forward_hidden, forward_rows,
+    KVCache, Params, Rows, expert_path, forward, forward_hidden,
+    forward_rows,
 )
 from ..ops.sampling import (
     SamplingState, sample, seed_windows,
@@ -689,8 +690,14 @@ class LLMEngine:
             paged=self._paged, kernel=self._use_kernel, max_seq=max_seq,
             page=pg, mesh=mesh, latent=self._latent)
         self.attention_path: str = route.name
+        # how an expert layer multiplies: the repo's grouped-matmul
+        # kernel or lax.ragged_dot (None without experts), by the
+        # conditions forward_rows decides it by
+        self.expert_path: Optional[str] = expert_path(
+            self.spec, self.params, mesh)
         log.info(
-            "attention path %s on %s (%s)%s", self.attention_path,
+            "attention path %s, expert path %s on %s (%s)%s",
+            self.attention_path, self.expert_path or "none",
             self.platform, self.device_kind,
             f" — kernel not eligible: {self.kernel_ineligible}"
             if self.kernel_ineligible else "")

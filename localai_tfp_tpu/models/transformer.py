@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops import grouped_matmul as gm
 from .llm_spec import LLMSpec
 from .quant import QTensor as _QTensor
 from .quant import mm as _mm  # plain or int8-QTensor matmul
@@ -886,22 +887,27 @@ def _moe_mlp(spec, lp, x, valid, experts):
     that have tokens are read, and each token costs k expert MLPs, not E.
 
     router (f32) -> top-k -> the N*K (token, expert) assignments sorted
-    by expert -> one grouped matmul per projection over the sorted rows
-    (``lax.ragged_dot``: on TPU XLA's own grouped-matmul kernel, ops
-    named ``ragged-dot*`` in a capture; group e = expert e's rows) ->
-    back to token order -> weighted sum over k, in f32 -> + shared
-    expert. ``valid`` [B, T] bool: positions that carry no token are
-    routed nowhere (they sort past the last group and read no expert).
-    Returns (out [B, T, D], tokens per HELD expert [E] i32 — for a
-    layer that holds a share, [E + 1]: then the absent assignments).
+    by expert -> the grouped matmuls over the sorted rows (group e =
+    expert e's rows; gate and up, the activation and their product,
+    down) -> back to token order -> weighted sum over k, in f32 ->
+    + shared expert. ``valid`` [B, T] bool: positions that carry no
+    token are routed nowhere (they sort past the last group and read no
+    expert). Returns (out [B, T, D], tokens per HELD expert [E] i32 —
+    for a layer that holds a share, [E + 1]: then the absent
+    assignments).
 
     ``experts`` = (the stack's EXPERT_LEAVES as [n, E, ...] arrays, this
-    layer's index in them): inside a layer scan the grouped matmul takes
-    the WHOLE stack as n * E groups of which only this layer's E have
-    rows — a kernel's operand cannot be a slice of the stack without
-    XLA copying the slice out first (0.5 GB a matrix a layer at 128
-    experts of 2048 x 1024; the attention kernel takes the whole cache
-    and a layer scalar for the same reason).
+    layer's index in them[, whether the repo's kernel multiplies]):
+    inside a layer scan the grouped matmul takes the WHOLE stack and
+    reads this layer's E matrices of it — a kernel's operand cannot be
+    a slice of the stack without XLA copying the slice out first (0.5
+    GB a matrix a layer at 128 experts of 2048 x 1024; the attention
+    kernel takes the whole cache and a layer scalar for the same
+    reason). The grouped matmul is ``ops/grouped_matmul.py``'s Pallas
+    kernel where ``gm.expert_path`` says so (a TPU backend, no mesh,
+    stacks its tiling covers: ``forward_rows`` asks) and
+    ``lax.ragged_dot`` — XLA's own, n * E groups of which this layer's
+    E have rows — everywhere else; a capture names both ``ragged-dot*``.
 
     qwen2_moe extras: a shared expert scaled by sigmoid(x·g) added to the
     mixture, un-renormalized top-k weights (norm_topk_prob=false), and
@@ -929,16 +935,28 @@ def _moe_mlp(spec, lp, x, valid, experts):
         flat = jnp.where(jnp.repeat(valid.reshape(N), K), flat, E)
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)  # [N*K]
     counts = jnp.zeros((E,), jnp.int32).at[flat].add(1, mode="drop")
-    xs = xf[order // K]  # [N*K, D] rows in expert order
-    whole, li = experts
+    whole, li, *kernel = experts
     w_gate, w_up, w_down = (
         whole[k].reshape(-1, *whole[k].shape[2:]) for k in EXPERT_LEAVES)
-    sizes = lax.dynamic_update_slice(
-        jnp.zeros((w_gate.shape[0],), jnp.int32), counts, (li * E,))
-    g = lax.ragged_dot(xs, w_gate, sizes)
-    u = lax.ragged_dot(xs, w_up, sizes)
-    y = lax.ragged_dot((_act(spec, g) * u).astype(x.dtype),
-                       w_down, sizes)  # [N*K, D]
+    if any(kernel):
+        # the repo's own kernel (ops/grouped_matmul.py): whole row
+        # tiles, so the sorted rows are padded — with rows no group
+        # holds, which read no expert
+        rows = gm.padded_rows(N * K)
+        xs = xf[jnp.pad(order, (0, rows - N * K)) // K]
+        sched = gm.schedule(counts, rows)
+        g, u = gm.grouped_matmul(xs, (w_gate, w_up), li, sched)
+        (y,) = gm.grouped_matmul((_act(spec, g) * u).astype(x.dtype),
+                                 (w_down,), li, sched)
+        y = y[:N * K]
+    else:
+        xs = xf[order // K]  # [N*K, D] rows in expert order
+        sizes = lax.dynamic_update_slice(
+            jnp.zeros((w_gate.shape[0],), jnp.int32), counts, (li * E,))
+        g = lax.ragged_dot(xs, w_gate, sizes)
+        u = lax.ragged_dot(xs, w_up, sizes)
+        y = lax.ragged_dot((_act(spec, g) * u).astype(x.dtype),
+                           w_down, sizes)  # [N*K, D]
     if valid is not None or share:
         # rows past the last group are whatever the kernel left there
         y = jnp.where(
@@ -971,6 +989,19 @@ def _moe_mlp(spec, lp, x, valid, experts):
         counts = jnp.concatenate(
             [counts, (n_real - jnp.sum(counts))[None].astype(jnp.int32)])
     return out.astype(x.dtype), counts
+
+
+def expert_path(spec, params, mesh) -> Optional[str]:
+    """``grouped_kernel`` | ``ragged_dot`` — how ``forward_rows`` will
+    multiply this model's expert layers (``gm.expert_path`` on the
+    stacks, the activations' dtype and the mesh) — or None for a model
+    without experts. The engine reports it at load."""
+    stacks = [params[k] for k in EXPERT_LEAVES if k in params]
+    if not spec.n_experts or not stacks:
+        return None
+    act = jax.eval_shape(
+        lambda p: _embed_in(spec, p, jnp.zeros((1, 1), jnp.int32)), params)
+    return gm.expert_path(stacks, act.dtype, mesh)
 
 
 def _layer_dense_only(spec) -> Optional[jnp.ndarray]:
@@ -1690,9 +1721,14 @@ def forward_rows(
             return (jnp.concatenate(outs, axis=1),
                     st if quant else st[:2])
 
+        experts = None
+        if spec.n_experts and whole:
+            experts = (whole, li, gm.expert_path(
+                [whole[k] for k in EXPERT_LEAVES], x.dtype,
+                mesh) == gm.GROUPED_KERNEL)
         x, out, counts = _layer_body(
             spec, x, lp, positions, inv_freq, rope_scale, attn_fn, valid,
-            (whole, li) if spec.n_experts and whole else None)
+            experts)
         if use_kernel:
             # the fused kernel updated the FULL stacked cache in place
             if quant:

@@ -619,22 +619,27 @@ def check_forward_parity(geom: Geometry, seed: int = 0) -> float:
     return worst
 
 
-def _kernel_times_us(trace_dir: str) -> list[float]:
-    """Device time of every ``ragged_paged_attention`` call in the one
-    capture under ``trace_dir``, in the order the calls ran."""
+def _device_lines(trace_dir: str):
+    """(line name, its events) of every device plane's lines in the one
+    capture under ``trace_dir``."""
     import glob
 
     from jax.profiler import ProfileData
 
     (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
-    calls = []
     for plane in ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/device:"):
-            continue
-        for line in plane.lines:
-            if line.name == "XLA Ops":
-                calls += [(e.start_ns, e.duration_ns) for e in line.events
-                          if "ragged_paged_attention" in e.name]
+        if plane.name.startswith("/device:"):
+            for line in plane.lines:
+                yield line.name, line.events
+
+
+def _kernel_times_us(trace_dir: str) -> list[float]:
+    """Device time of every ``ragged_paged_attention`` call in the one
+    capture under ``trace_dir``, in the order the calls ran."""
+    calls = [(e.start_ns, e.duration_ns)
+             for name, events in _device_lines(trace_dir)
+             if name == "XLA Ops"
+             for e in events if "ragged_paged_attention" in e.name]
     return [d / 1e3 for _, d in sorted(calls)]
 
 
@@ -742,6 +747,231 @@ def sweep_decode_kernel(geom: Geometry, cache: str, window: int = 0, *,
             2 * page * F * ak.dtype.itemsize / hbm * 1e6, 3)}
 
 
+# ---------------------------------------------------------------------------
+# the expert layer's grouped matmul (ops/grouped_matmul.py)
+# ---------------------------------------------------------------------------
+
+# (d_model, expert d_ff, experts held, experts the router scores): the
+# benchmark's two expert configurations at published widths
+EXPERT_WIDTHS = {"trinity": (2048, 1024, 128, 128),
+                 "deepseek": (7168, 2048, 16, 256)}
+_EXPERT_SMALL = (256, 128, 8, 16)  # off the chip: the interpreter's size
+_EXPERT_K = 8  # experts a token, both configurations
+_EXPERT_LAYERS = 2  # the stack's depth here: the offset is exercised
+# tokens a step: decode rows | PR 38's middle point | a 512-token
+# prompt row beside 16 decode rows
+_EXPERT_TOKENS = (16, 144, 528)
+
+
+def _expert_counts(rng, tokens: int, held: int, published: int,
+                   pool: int) -> np.ndarray:
+    """Assignments a HELD expert gets when each of ``tokens`` tokens
+    picks ``_EXPERT_K`` distinct experts among ``pool`` of the
+    ``published`` (the pool chosen at random; experts 0 .. held - 1 are
+    the held ones): uniform routing at pool == published, the served
+    skew at Trinity's pool of 50 (46 of 128 touched at 16 tokens)."""
+    ids = rng.permutation(published)[:pool]
+    picks = np.concatenate([rng.permutation(ids)[:_EXPERT_K]
+                            for _ in range(tokens)])
+    return np.bincount(picks[picks < held], minlength=held).astype(np.int32)
+
+
+def _expert_stack(key, n: int, e: int, k: int, m: int, dtype):
+    return (jax.random.normal(key, (n * e, k, m), jnp.float32)
+            * (k ** -0.5)).astype(dtype)
+
+
+def _ragged_dot_layer(lhs, w, layer, counts):
+    """The parent's form: ``lax.ragged_dot`` over the WHOLE stack with
+    one layer's groups non-empty."""
+    e = counts.shape[0]
+    sizes = jax.lax.dynamic_update_slice(
+        jnp.zeros((w.shape[0],), jnp.int32), counts, (layer * e,))
+    return jax.lax.ragged_dot(lhs, w, sizes)
+
+
+def _kernel_layer(lhs, w, layer, counts):
+    from . import grouped_matmul as gm
+
+    rows = gm.padded_rows(lhs.shape[0])
+    lhs = jnp.pad(lhs, ((0, rows - lhs.shape[0]), (0, 0)))
+    return gm.grouped_matmul(lhs, (w,), layer, gm.schedule(counts, rows))[0]
+
+
+def check_grouped_matmul(widths=EXPERT_WIDTHS["trinity"], dtype="bf16",
+                         seed: int = 0) -> dict[str, Any]:
+    """The grouped-matmul kernel against ``lax.ragged_dot`` at one
+    configuration's widths, both projections' shapes (in -> d_ff and
+    d_ff -> out), on the device JAX finds:
+
+    - ``max_rel_err``: the worst |kernel - ragged_dot| over the rows a
+      group holds, relative to the largest output, over the three row
+      counts and every layer of the stack (two roundings of one f32
+      sum: a bf16 ulp);
+    - ``rows_equal``: ONE row's output bit for bit when it rides among
+      4, 16 and 528 tokens' assignments (the same row of the same
+      expert; the other rows and the groups' sizes differ) — what the
+      fixed row tile is for; ``ragged_dot_rows_equal`` is the same
+      question put to XLA's op ALONE (equal too on a v5e: the
+      row-count rounding PR 45 found is the whole program's)."""
+    d, f, held, published = widths
+    dt = jnp.float32 if dtype == "f32" else jnp.bfloat16
+    rng = np.random.default_rng(seed)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    probe_e = held // 3
+    worst, equal, xla_equal = 0.0, True, True
+    kernel, xla = jax.jit(_kernel_layer), jax.jit(_ragged_dot_layer)
+    for i, (k, m) in enumerate(((d, f), (f, d))):
+        w = _expert_stack(keys[i], _EXPERT_LAYERS, held, k, m, dt)
+        probe = jax.random.normal(keys[2 + i], (1, k), jnp.float32)
+        seen, xla_seen = [], []
+        for tokens in (4, 16, 528):
+            counts = _expert_counts(rng, tokens, held, published, published)
+            counts[probe_e] += 1  # the probe: its group's first row
+            rows = tokens * _EXPERT_K + 1
+            lhs = jnp.asarray(rng.standard_normal((rows, k)), jnp.float32)
+            at = int(counts[:probe_e].sum())
+            lhs = lhs.at[at].set(probe[0]).astype(dt)
+            total = int(counts.sum())
+            for layer in range(_EXPERT_LAYERS):
+                got = kernel(lhs, w, layer, jnp.asarray(counts))
+                want = xla(lhs, w, layer, jnp.asarray(counts))
+                g32 = np.asarray(got[:total], np.float32)
+                w32 = np.asarray(want[:total], np.float32)
+                worst = max(worst, float(
+                    np.max(np.abs(g32 - w32)) / (np.max(np.abs(w32)) + 1e-9)))
+            seen.append(np.asarray(got[at], np.float32))
+            xla_seen.append(np.asarray(want[at], np.float32))
+        equal &= all(np.array_equal(seen[0], o) for o in seen[1:])
+        xla_equal &= all(np.array_equal(xla_seen[0], o) for o in xla_seen[1:])
+    return {"max_rel_err": worst, "rows_equal": bool(equal),
+            "ragged_dot_rows_equal": bool(xla_equal)}
+
+
+def _module_times_us(trace_dir: str, name: str) -> "tuple[list, list]":
+    """(device time of every run of the program whose name holds
+    ``name``, in the order they ran; the time of the ``ragged-dot*``
+    ops inside each — XLA's op, its set-up, or the kernel under that
+    name) in the one capture under ``trace_dir``."""
+    mods, ops = [], []
+    for line, events in _device_lines(trace_dir):
+        if line == "XLA Modules":
+            mods += [(e.start_ns, e.duration_ns) for e in events
+                     if name in e.name]
+        elif line == "XLA Ops":
+            ops += [(e.start_ns, e.duration_ns) for e in events
+                    if e.name.lstrip("%").startswith("ragged-dot")]
+    mods.sort()
+    inside = [sum(d for s, d in ops if m0 <= s < m0 + md)
+              for m0, md in mods]
+    return [d / 1e3 for _, d in mods], [d / 1e3 for d in inside]
+
+
+def sweep_grouped_matmul(widths, label: str, *, calls: int = 12,
+                         seed: int = 0) -> dict[str, Any]:
+    """Time ONE expert layer's three grouped matmuls (gate, up, the
+    activation and their product, down) ALONE at ``widths``, bf16, for
+    16 | 144 | 528 tokens' assignments under uniform routing and, for a
+    layer that holds every expert, the served skew: ``lax.ragged_dot``
+    as the parent calls it (the whole stack as groups), ``megablox.gmm``
+    (jax's Pallas grouped matmul, tiled 128 x 2048 | 1024 x 512) and
+    the kernel (gate and up in one call: each projection in a call of
+    its own read 0-6 % slower, PERF.md §6 PR 46). A point is
+    ``calls`` layer-steps in one scan that walks the stack's layers;
+    ``us_layer`` is the program's DEVICE time a layer-step (the
+    schedule or metadata ops included), ``us_matmul`` the part under
+    ops named ``ragged-dot*``; ``roof`` the touched experts' bytes over
+    peak HBM bytes per second over ``us_layer``. Chip only."""
+    import tempfile
+
+    from jax.experimental.pallas.ops.tpu.megablox import gmm as mb
+
+    from ..telemetry.costmodel import peak_rates
+    from . import grouped_matmul as gm
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit("--sweep times the compiled kernel: chip only")
+    hbm = peak_rates(dev.device_kind)[1]
+    d, f, held, published = widths
+    dt, n = jnp.bfloat16, _EXPERT_LAYERS
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    stacks = (_expert_stack(keys[0], n, held, d, f, dt),
+              _expert_stack(keys[1], n, held, d, f, dt),
+              _expert_stack(keys[2], n, held, f, d, dt))
+    act = jax.nn.silu
+
+    def xla_layer(x, ws, layer, counts):
+        g, u = (_ragged_dot_layer(x, w, layer, counts) for w in ws[:2])
+        return _ragged_dot_layer((act(g) * u).astype(dt), ws[2], layer,
+                                 counts)
+
+    def gmm_layer(x, ws, layer, counts):
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((n * held,), jnp.int32), counts, (layer * held,))
+
+        def one(a, w):
+            tk = 2048 if a.shape[1] % 2048 == 0 else 1024
+            return mb(a, w, sizes, dt, (128, min(tk, a.shape[1]), 512))
+        g, u = one(x, ws[0]), one(x, ws[1])
+        return one((act(g) * u).astype(dt), ws[2])
+
+    def kernel_layer(x, ws, layer, counts):
+        sched = gm.schedule(counts, x.shape[0])
+        g, u = gm.grouped_matmul(x, ws[:2], layer, sched)
+        return gm.grouped_matmul((act(g) * u).astype(dt), ws[2:], layer,
+                                 sched)[0]
+
+    def program(layer_fn, name):
+        def run(x, ws, counts):
+            def one(acc, i):
+                y = layer_fn(x, ws, i % n, counts)
+                return acc + y[:8].astype(jnp.float32), None
+            return jax.lax.scan(one, jnp.zeros((8, d), jnp.float32),
+                                jnp.arange(calls, dtype=jnp.int32))[0]
+        run.__name__ = name
+        return jax.jit(run)
+
+    impls = {"ragged_dot": program(xla_layer, "sweep_ragged_dot"),
+             "gmm": program(gmm_layer, "sweep_gmm"),
+             "kernel": program(kernel_layer, "sweep_kernel")}
+    rng = np.random.default_rng(seed)
+    routings = {"uniform": published}
+    if held == published:
+        routings["skew"] = 50
+    points = []
+    for routing, pool in routings.items():
+        for tokens in _EXPERT_TOKENS:
+            counts = _expert_counts(rng, tokens, held, published, pool)
+            rows = gm.padded_rows(tokens * _EXPERT_K)
+            x = jnp.asarray(rng.standard_normal((rows, d)), dt)
+            points.append((routing, tokens, jnp.asarray(counts), x))
+    out = []
+    for name, fn in impls.items():
+        for *_, counts, x in points:  # compile + warm, untraced
+            fn(x, stacks, counts).block_until_ready()
+        with tempfile.TemporaryDirectory() as tmp:
+            with jax.profiler.trace(tmp):
+                for *_, counts, x in points:
+                    fn(x, stacks, counts).block_until_ready()
+            mods, inside = _module_times_us(tmp, fn.__name__)
+        assert len(mods) == len(points), (name, len(mods))
+        for (routing, tokens, counts, _), us, us_in in zip(
+                points, mods, inside):
+            touched = int(np.count_nonzero(np.asarray(counts)))
+            floor_us = touched * 3 * d * f * 2 / hbm * 1e6
+            out.append({
+                "impl": name, "routing": routing, "tokens": tokens,
+                "assignments": int(np.asarray(counts).sum()),
+                "touched": touched,
+                "us_layer": round(us / calls, 1),
+                "us_matmul": round(us_in / calls, 1),
+                "roof": round(floor_us / (us / calls), 4)})
+    return {"device_kind": dev.device_kind, "widths": label,
+            "d_model": d, "d_ff": f, "held": held, "published": published,
+            "calls": calls, "points": out}
+
+
 # (max abs error) budgets: attention outputs are O(1) post-softmax and
 # bf16 inputs put parity at ~1e-2; int8 pages add their rounding
 _TOL_FP, _TOL_INT8 = 2e-2, 5e-2
@@ -812,6 +1042,18 @@ def run_kernel_checks(geom: Geometry = SERVING) -> dict[str, Any]:
             check_meshed_paged_gather(False), 0.0)
         leg("meshed_paged_gather_int8_max_err",
             check_meshed_paged_gather(True), 0.0)
+    # the expert layer's grouped matmul at both expert configurations'
+    # widths (interpreted off the chip: at the interpreter's): against
+    # XLA's op within bf16 rounding, and one row's bits the same
+    # whatever the step's row count
+    for label, widths in (EXPERT_WIDTHS if dev.platform == "tpu"
+                          else {"small": _EXPERT_SMALL}).items():
+        res = check_grouped_matmul(widths)
+        leg(f"grouped_matmul_{label}_max_rel_err", res["max_rel_err"],
+            _TOL_FP)
+        leg(f"grouped_matmul_{label}_rows_differ",
+            0.0 if res["rows_equal"] else 1.0, 0.0)
+        out[f"ragged_dot_{label}_rows_equal"] = res["ragged_dot_rows_equal"]
     out["failed"] = sorted(  # (NaN fails: it is not <= anything)
         name for name, tol in budget.items() if not out[name] <= tol)
     out["ok"] = not out["failed"]
@@ -834,6 +1076,10 @@ def main(argv: "list[str] | None" = None) -> int:
                     help="time the kernel alone over pages a row and "
                     "parked rows at the geometry's decode shapes (a "
                     "tool: one JSON line a cache dtype; chip only)")
+    ap.add_argument("--experts", action="store_true",
+                    help="--sweep: time the expert layer's grouped "
+                    "matmuls alone instead (lax.ragged_dot, megablox."
+                    "gmm, the kernel; one JSON line a configuration)")
     ap.add_argument("--cache", default="int8,bf16",
                     help="--sweep: arena dtypes, comma-separated")
     ap.add_argument("--window", type=int, default=0,
@@ -844,6 +1090,11 @@ def main(argv: "list[str] | None" = None) -> int:
         f.name: getattr(args, f.name)
         for f in dataclasses.fields(Geometry)
         if getattr(args, f.name) is not None})
+    if args.sweep and args.experts:
+        for label, widths in EXPERT_WIDTHS.items():
+            print(json.dumps(sweep_grouped_matmul(widths, label)),
+                  flush=True)
+        return 0
     if args.sweep:
         for cache in args.cache.split(","):
             print(json.dumps(

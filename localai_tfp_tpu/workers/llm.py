@@ -865,6 +865,8 @@ class JaxLLMBackend(Backend):
             "device_kind": eng.device_kind,
             "paged": bool(eng._paged),
             "attention_path": eng.attention_path,
+            # grouped_kernel | ragged_dot; None without experts
+            "expert_path": eng.expert_path,
             # "" on the kernel route, else the condition that ruled the
             # Pallas kernel out
             "kernel_ineligible": eng.kernel_ineligible,

@@ -430,6 +430,8 @@ def test_engine_serves_afmoe_on_the_kernel_route(monkeypatch, tiny):
     try:
         assert eng.kernel_ineligible == ""
         assert eng.attention_path == "ragged_paged_kernel"
+        # the grouped-matmul kernel is the chip's: lax.ragged_dot here
+        assert eng.expert_path == "ragged_dot"
         assert eng._layer_windows == {0: 1, 16: 3}
         assert eng._n_expert_layers == 3
         prompt = [int(t) for t in np.random.default_rng(4).integers(

@@ -90,6 +90,7 @@ def test_engine_stats_names_device_and_attention_path():
         # no Mosaic on CPU: the XLA route, and the monitor says why
         assert stats["attention_path"] == "paged_xla_gather"
         assert "Mosaic" in stats["kernel_ineligible"]
+        assert stats["expert_path"] is None  # a dense model
         json.dumps(stats)  # what /backend/monitor serializes
     finally:
         eng.close()

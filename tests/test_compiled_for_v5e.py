@@ -12,7 +12,10 @@ to one xdist worker, and a second file's fixture would skip in silence.
   matrix out of its stack or copies it into another layout (PR 44);
 - DeepSeek-V3's two step programs — the latent arena, the absorbed
   kernel at 128 heads x 640 lanes, the share — compile at serving
-  shapes with their temporaries bounded (PR 45).
+  shapes with their temporaries bounded (PR 45);
+- the expert configurations' step programs multiply their expert
+  layers in the repo's grouped-matmul kernel, under the name the
+  benchmark's readers know (PR 46).
 """
 
 import json
@@ -86,6 +89,27 @@ def test_gather_compiled_for_a_v5e_moves_only_its_pages(one_v5e, shape,
 
 STEP_CONFIGS = ("mistral-7b-instruct-v0.3", "trinity-mini-pp4-stage",
                 "olmo-hybrid-7b-pp2-stage")
+EXPERT_CONFIGS = ("trinity-mini-pp4-stage", "deepseek-v3-ep16-share")
+_COMPILED: dict = {}  # (configuration, kind) -> (config, executable)
+
+
+def _step_program(one_v5e, name, kind):
+    """A benchmark configuration's ``dispatch_<kind>`` compiled for the
+    described v5e, once a module (15-60 s each)."""
+    from tools.step_hlo import lower_program
+
+    if (name, kind) not in _COMPILED:
+        with open(os.path.join(ROOT, "benchmark", "configs",
+                               f"{name}.json")) as f:
+            config = json.load(f)
+        # tests/conftest.py asks every matmul for HIGHEST precision (the
+        # CPU comparisons need it); the server asks for none, and neither
+        # the kernel nor the program compiled here is the served one
+        # under it
+        with jax.default_matmul_precision("default"):
+            _COMPILED[name, kind] = (
+                config, lower_program(config, kind, one_v5e).compile())
+    return _COMPILED[name, kind]
 
 
 @pytest.mark.parametrize("kind", ["decodek", "mixed"])
@@ -105,17 +129,10 @@ def test_step_program_reads_its_weights_from_the_stack_in_place(
     ``constant_dynamic-slice_fusion`` over ``params['wk'].q``). It is a
     compiler heuristic: it comes back silently with a reshape next to a
     dot. ``tools/step_hlo.py`` prints the whole loop body."""
-    from tools.step_hlo import lower_program, offenders_of
+    from tools.step_hlo import offenders_of
 
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           f"{name}.json")) as f:
-        config = json.load(f)
-    # tests/conftest.py asks every matmul for HIGHEST precision (the CPU
-    # comparisons need it); the server asks for none, and neither the
-    # kernel nor the program compiled here is the served one under it
-    with jax.default_matmul_precision("default"):
-        text = lower_program(config, kind, one_v5e).compile().as_text()
-    bad = offenders_of(text, config)
+    config, compiled = _step_program(one_v5e, name, kind)
+    bad = offenders_of(compiled.as_text(), config)
     if bad:
         pytest.fail("a layer's weight moved by an op that is no matmul:\n"
                     + "\n".join(f"  {leaf}: {op.line[:150]}"
@@ -132,13 +149,10 @@ def test_latent_step_programs_compile_with_temporaries_bounded(
     under 1.5 GB beside 11 GB of weights and 1 GB of latent arena — a
     copy of an expert stack (2.8 GB a layer) or of the arena would not
     (PR 41 found 1.86 GB of copies this way before any run)."""
-    from tools.step_hlo import lower_program, offenders_of
+    from tools.step_hlo import offenders_of
 
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "deepseek-v3-ep16-share.json")) as f:
-        config = json.load(f)
-    with jax.default_matmul_precision("default"):
-        compiled = lower_program(config, kind, one_v5e).compile()
+    config, compiled = _step_program(one_v5e, "deepseek-v3-ep16-share",
+                                     kind)
     text = compiled.as_text()
     assert "%latent_paged_attention" in text
     assert "%ragged_paged_attention" not in text  # ops named by kernel
@@ -147,3 +161,27 @@ def test_latent_step_programs_compile_with_temporaries_bounded(
     assert mem.temp_size_in_bytes < 1.5e9, mem.temp_size_in_bytes
     # the arena is updated in place: the program's outputs alias it
     assert mem.alias_size_in_bytes >= 6 * 513 * 256 * 640 * 2
+
+
+@pytest.mark.parametrize("kind", ["decodek", "mixed"])
+@pytest.mark.parametrize("name", STEP_CONFIGS + EXPERT_CONFIGS[1:])
+def test_expert_layers_multiply_in_the_grouped_kernel(one_v5e, name, kind):
+    """An expert configuration's step programs hold the repo's grouped
+    matmul — a Pallas call whose INSTRUCTION name starts with
+    ``ragged-dot`` (the prefix the benchmark's expert-layer readers
+    find it by: ``benchmark/models/*.py`` ``EXPERT_KERNELS``; PR 46) —
+    two calls a layer (gate and up share one), and no XLA ``ragged-dot``
+    op; a dense configuration's hold neither."""
+    from tools.step_hlo import loop_bodies
+
+    _, compiled = _step_program(one_v5e, name, kind)
+    bodies, _ = loop_bodies(compiled.as_text())
+    ops = [op for body in bodies for op in body.ops
+           if op.name.startswith("ragged-dot")]
+    if name not in EXPERT_CONFIGS:
+        assert not ops, [op.name for op in ops]
+        return
+    assert ops and {op.opcode for op in ops} == {"custom-call"}, [
+        (op.name, op.opcode) for op in ops]
+    assert all(op.name.startswith("ragged-dot-grouped") for op in ops)
+    assert len(ops) % 2 == 0
