@@ -4187,6 +4187,8 @@ class LLMEngine:
         # a decode row is one real token; the shape is both groups'
         self._note_dispatch_tokens("mixed", len(decoding) + chunk_tokens,
                                    S + R * bucket, context)
+        if self._latent:
+            self._note_latent_prompt_tokens(bucket, chunk_tokens)
         if decoding:
             self._note_decode_advance(t_disp)
         ckey = costmodel.dispatch_key("mixed", payload)
@@ -4405,6 +4407,25 @@ class LLMEngine:
                     model=self._mlabel, kind=kind).inc(n)
                 tm.ENGINE_LINEAR_STATE_ROWS.labels(
                     model=self._mlabel, kind=kind).inc(n * len(rows))
+
+    def _note_latent_prompt_tokens(self, bucket: int, real: int) -> None:
+        """Prompt tokens of a latent model by the form the step's
+        program attends a ``bucket``-long prompt row in
+        (engine_latent_prompt_tokens_total{form}): the forward's own
+        rule on the kernel route, the expanded XLA form elsewhere."""
+        ctr = self._tok_ctr.get(("latent", bucket))
+        if ctr is None:
+            from ..ops.latent_flash_attention import (
+                EXPANDED, latent_prompt_form,
+            )
+
+            form = (latent_prompt_form(self.spec, bucket)
+                    if self._route.name == "latent_paged_kernel"
+                    else EXPANDED)
+            ctr = self._tok_ctr[("latent", bucket)] = (
+                tm.ENGINE_LATENT_PROMPT_TOKENS.labels(
+                    model=self._mlabel, form=form))
+        ctr.inc(real)
 
     def _take_expert_stats(self) -> list:
         """The expert statistics of the step programs the newest

@@ -1323,7 +1323,7 @@ def forward_rows(
         valid = (valid_of(g0) if single else jnp.concatenate(
             [valid_of(g).reshape(1, -1) for g in groups], axis=1))
 
-    def body(whole, carry, scanned):
+    def body(whole, stack, carry, scanned):
         # cache rides as the scan CARRY (not xs/ys): XLA aliases loop
         # carries in place, so the per-layer update is a true in-place
         # write of the touched rows. As xs/ys the whole cache would be
@@ -1661,11 +1661,20 @@ def forward_rows(
         def latent_ragged(g, st, qn, qr, row):
             # the ragged route for a latent cache: the chunk's rows
             # scatter into the arena through the write table as K rows
-            # do, then the ABSORBED form attends them in the kernel
+            # do, then the group's rows attend them in the form that
+            # costs them less (``latent_prompt_form``, from the
+            # widths and the group's row length alone). ABSORBED
             # (ops/ragged_paged_attention.py, ``v_lanes``): 128 query
             # heads x the whole row against a page, PV against the
             # page's first kv_lora_rank lanes; W_kvb never touches a
-            # cached row
+            # cached row. EXPANDED (ops/latent_flash_attention.py): a
+            # page's rows through W_kvb head by head in the kernel,
+            # the query as ``_latent_mixer`` has it beside the zeros
+            # of the row's tail lanes, the output written once
+            from ..ops.latent_flash_attention import (
+                EXPANDED, join_query, latent_flash_attention,
+                latent_prompt_form,
+            )
             from ..ops.ragged_paged_attention import (
                 ragged_paged_attention,
             )
@@ -1683,6 +1692,14 @@ def forward_rows(
                 wpg, 0)
             ck_new = ck_all.at[l, wpg, tpos % kv_page, :].set(
                 row.astype(ck_all.dtype), mode="promise_in_bounds")
+            if latent_prompt_form(spec, T) == EXPANDED:
+                out = latent_flash_attention(
+                    join_query(qn, qr,
+                               spec.latent_row - spec.kv_lora_rank),
+                    ck_new, l, g.page_table, pos0, attend,
+                    stack["wkv_b_k"], stack["wkv_b_v"], li,
+                    scale=latent_scale(spec), page=kv_page)
+                return out, (ck_new, st[1])
             ctx = ragged_paged_attention(
                 latent_absorb_query(spec, lp, qn, qr), ck_new, None, l,
                 g.page_table, pos0, attend, 1,
@@ -1756,7 +1773,7 @@ def forward_rows(
     expert_tokens = None
     for first, n, stacked, whole in layer_stacks(spec, params):
         carry, counts = lax.scan(
-            partial(body, whole), carry,
+            partial(body, whole, stacked), carry,
             (jnp.arange(first, first + n, dtype=jnp.int32),
              jnp.arange(n, dtype=jnp.int32), stacked))
         if counts is not None:  # [n, E] of an expert stack

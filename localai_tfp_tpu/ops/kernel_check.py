@@ -974,6 +974,86 @@ def sweep_grouped_matmul(widths, label: str, *, calls: int = 12,
 
 # (max abs error) budgets: attention outputs are O(1) post-softmax and
 # bf16 inputs put parity at ~1e-2; int8 pages add their rounding
+# latent attention's widths (heads, d_nope, d_rope, d_v, rank, page,
+# the prompt row's length): DeepSeek-V3's as published and served, and
+# the interpreter's size off the chip
+LATENT_WIDTHS = {"deepseek": (128, 128, 64, 128, 512, 256, 512)}
+_LATENT_SMALL = (4, 16, 16, 16, 128, 8, 32)
+
+
+def check_latent_flash(widths=LATENT_WIDTHS["deepseek"],
+                       seed: int = 0) -> dict[str, Any]:
+    """The expanded flash kernel (ops/latent_flash_attention.py) at one
+    configuration's widths against the XLA form it replaces for prompt
+    rows (``models/transformer.py`` ``latent_attend_expanded``, same
+    dtype), on the device JAX finds — two rows a call, one starting on
+    a page boundary with a whole chunk, one off it with a chunk cut by
+    ``q_lens``:
+
+    - ``max_rel_err``: the worst |kernel - XLA| over the valid queries,
+      relative to the largest output (two flash-vs-dense roundings of
+      bf16 probabilities);
+    - ``rows_equal``: a token's output bit for bit at two indices of
+      two chunks over the same pages (the second chunk starts T/4 + 5
+      tokens later, off the boundary, and is cut short) — what the
+      benchmark's repeated-prompt probe needs on the chip."""
+    from types import SimpleNamespace
+
+    from ..models.transformer import latent_attend_expanded
+    from .latent_flash_attention import join_query, latent_flash_attention
+
+    H, dn, dr, dv, r, page, T = widths
+    F = -(-(r + dr) // 128) * 128
+    dt = jnp.bfloat16
+    n_pos = 4 * T
+    maxp = n_pos // page
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    rows = jax.random.normal(ks[0], (2, n_pos, F))
+    rows = rows.at[..., r + dr:].set(0).astype(dt)
+    arena = jnp.concatenate(
+        [jnp.zeros((1, page, F), dt), rows.reshape(2 * maxp, page, F)])[None]
+    table = (1 + jnp.arange(2 * maxp, dtype=jnp.int32)).reshape(2, maxp)
+    wk = (jax.random.normal(ks[1], (2, H, dn, r)) * r ** -0.5).astype(dt)
+    wv = (jax.random.normal(ks[2], (2, H, r, dv)) * r ** -0.5).astype(dt)
+    qn = jax.random.normal(ks[3], (2, n_pos, H, dn)).astype(dt)
+    qr = jax.random.normal(ks[4], (2, n_pos, H, dr)).astype(dt)
+    spec = SimpleNamespace(kv_lora_rank=r, qk_rope_dim=dr, d_head=dn + dr,
+                           attn_scale_mult=1.0)
+    scale = (dn + dr) ** -0.5
+    b = np.arange(2)[:, None]
+
+    @jax.jit
+    def kernel(pos0, q_lens):
+        at = pos0[:, None] + jnp.arange(T)[None]
+        return latent_flash_attention(
+            join_query(qn[b, at], qr[b, at], F - r), arena, jnp.int32(0),
+            table, pos0, q_lens, wk, wv, jnp.int32(1), scale=scale,
+            page=page)
+
+    @jax.jit
+    def xla(pos0):
+        at = pos0[:, None] + jnp.arange(T)[None]
+        return latent_attend_expanded(
+            spec, {"wkv_b_k": wk[1], "wkv_b_v": wv[1]}, qn[b, at],
+            qr[b, at], rows, at)
+
+    off = page // 2 - 3  # a chunk that starts off a page boundary
+    pos0 = jnp.asarray([2 * T, T + off], jnp.int32)
+    q_lens = jnp.asarray([T, T - T // 3], jnp.int32)
+    got = np.asarray(kernel(pos0, q_lens), np.float32)
+    want = np.asarray(xla(pos0), np.float32)
+    worst = 0.0
+    for i, n in enumerate(np.asarray(q_lens)):
+        worst = max(worst, float(np.max(np.abs(got[i, :n] - want[i, :n]))
+                                 / (np.max(np.abs(want[i, :n])) + 1e-9)))
+    # row 0's tokens again, their chunk ``late`` tokens later, cut short
+    late = T // 4 + 5
+    again = np.asarray(
+        kernel(pos0.at[0].add(late), q_lens.at[0].set(T - 9)), np.float32)
+    equal = np.array_equal(got[0, late:], again[0, :T - late])
+    return {"max_rel_err": worst, "rows_equal": bool(equal)}
+
+
 _TOL_FP, _TOL_INT8 = 2e-2, 5e-2
 _TOL_FORWARD = 5e-2  # relative to the logit scale, bf16 end to end
 
@@ -1054,6 +1134,16 @@ def run_kernel_checks(geom: Geometry = SERVING) -> dict[str, Any]:
         leg(f"grouped_matmul_{label}_rows_differ",
             0.0 if res["rows_equal"] else 1.0, 0.0)
         out[f"ragged_dot_{label}_rows_equal"] = res["ragged_dot_rows_equal"]
+    # a latent model's prompt rows: the expanded flash kernel against
+    # the XLA form at the published widths, and a token's bits the same
+    # wherever its chunk started
+    for label, widths in (LATENT_WIDTHS if dev.platform == "tpu"
+                          else {"small": _LATENT_SMALL}).items():
+        res = check_latent_flash(widths)
+        leg(f"latent_flash_{label}_max_rel_err", res["max_rel_err"],
+            _TOL_FP)
+        leg(f"latent_flash_{label}_rows_differ",
+            0.0 if res["rows_equal"] else 1.0, 0.0)
     out["failed"] = sorted(  # (NaN fails: it is not <= anything)
         name for name, tol in budget.items() if not out[name] <= tol)
     out["ok"] = not out["failed"]

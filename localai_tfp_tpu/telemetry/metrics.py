@@ -509,6 +509,17 @@ ENGINE_ATTN_CONTEXT_HELD_TOKENS = REGISTRY.counter(
     "context that the windows let the attention read",
     labels=("model", "kind"),
 )
+ENGINE_LATENT_PROMPT_TOKENS = REGISTRY.counter(
+    "engine_latent_prompt_tokens_total",
+    "Prompt tokens of a latent-attention model dispatched, by the form "
+    "their step's program attends them in: expanded (cached rows "
+    "up-projected through W_kvb; the flash kernel "
+    "ops/latent_flash_attention.py on the kernel route, XLA elsewhere) "
+    "or absorbed (W_kvb folded into query and output, "
+    "ops/ragged_paged_attention.py) — the rule is "
+    "latent_flash_attention.latent_prompt_form, by the row's bucket",
+    labels=("model", "form"),
+)
 ENGINE_EXPERT_TOKENS = REGISTRY.counter(
     "engine_expert_tokens_total",
     "Tokens routed to each expert, summed over the expert layers of "

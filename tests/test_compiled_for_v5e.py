@@ -15,7 +15,11 @@ to one xdist worker, and a second file's fixture would skip in silence.
   shapes with their temporaries bounded (PR 45);
 - the expert configurations' step programs multiply their expert
   layers in the repo's grouped-matmul kernel, under the name the
-  benchmark's readers know (PR 46).
+  benchmark's readers know (PR 46);
+- DeepSeek-V3's ``mixed`` program attends its 512-token prompt row in
+  the expanded flash kernel, one call a layer beside the decode rows'
+  absorbed one, with none of the absorbed form's 84 MB query relayout
+  and f32 output around it; no other program holds the call (PR 50).
 """
 
 import json
@@ -185,3 +189,43 @@ def test_expert_layers_multiply_in_the_grouped_kernel(one_v5e, name, kind):
         (op.name, op.opcode) for op in ops]
     assert all(op.name.startswith("ragged-dot-grouped") for op in ops)
     assert len(ops) % 2 == 0
+
+
+@pytest.mark.parametrize("kind", ["decodek", "mixed"])
+@pytest.mark.parametrize("name", STEP_CONFIGS + EXPERT_CONFIGS[1:])
+def test_a_latent_prompt_row_attends_in_the_expanded_flash_kernel(
+        one_v5e, name, kind):
+    """``deepseek-v3-ep16-share``'s ``mixed`` program ([1, 512] prompt
+    row, 512 >= ``expanded_from`` = 170.7): a layer holds ONE
+    ``latent_paged_attention_expanded`` call (the prompt row) and ONE
+    absorbed ``latent_paged_attention`` call (the 16 decode rows) — the
+    benchmark finds both by the prefix ``latent_paged_attention`` —
+    and neither the absorbed prompt form's query in the arena's 640
+    lanes (bf16[1,512,128,640], built and then copied into the
+    kernel's layout: 84 MB each a layer) nor its f32 output
+    (f32[..,65536,512], 134 MB, rounded to bf16 by a ``convert``) nor a
+    weight sliced out of its stack. Its ``decodek`` program and every
+    other configuration's programs hold no expanded call."""
+    from tools.step_hlo import loop_bodies, offenders_of
+
+    config, compiled = _step_program(one_v5e, name, kind)
+    text = compiled.as_text()
+    bodies, _ = loop_bodies(text)
+    calls = [[op.name for op in body.ops
+              if op.name.startswith("latent_paged_attention")]
+             for body in bodies]
+    if (name, kind) != ("deepseek-v3-ep16-share", "mixed"):
+        assert "latent_paged_attention_expanded" not in text
+        return
+    layers = [c for c in calls if c]
+    assert layers, "no layer loop holds a latent attention call"
+    for c in layers:
+        expanded = [n for n in c
+                    if n.startswith("latent_paged_attention_expanded")]
+        assert len(expanded) == 1 and len(c) == 2, c
+    shapes = [(op.opcode, op.shapes) for body in bodies for op in body.ops]
+    assert not [o for o in shapes
+                if o[0] == "copy" and ("bf16", (1, 512, 128, 640)) in o[1]]
+    assert not [o for o in shapes for dt, dims in o[1]
+                if dims[-2:] == (65536, 512)]
+    assert not offenders_of(text, config)

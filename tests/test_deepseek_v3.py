@@ -18,6 +18,12 @@ models/hf_loader.py.
 - THE SHARE TEST: 8 experts in 4 shares of 2 — the shares' routed parts
   plus the shared expert counted once are the uncut layer's output;
 - rows in other slots and a parked row do not move a row's bits;
+- a prompt row long enough that up-projecting its context once costs
+  less than absorbing W_kvb into every query (``latent_prompt_form``,
+  from the widths alone) attends in the EXPANDED form inside the flash
+  kernel (ops/latent_flash_attention.py, interpreted): equal to the XLA
+  form at every position, a token's bits the same wherever its chunk
+  started, and the step programs' logits the reference's;
 - the engine serves it through its scheduler, step programs, paged pool
   and the latent kernel route, reuses latent pages through the prefix
   index, embeds long prompts in chunks, counts what it says, and
@@ -25,6 +31,7 @@ models/hf_loader.py.
 """
 
 import dataclasses
+import json
 import os
 
 import jax
@@ -184,12 +191,15 @@ T_PROMPT, T_DEC = 40, 8
 
 
 def _through_the_step_programs(spec, params, ids, others, row_kind=None,
-                               parked_extra=False):
-    """Row 0's prompt in chunks of CH beside rows 1.. decoding, then
+                               parked_extra=False, ch=CH):
+    """Row 0's prompt in chunks of ``ch`` beside rows 1.. decoding, then
     every row decoding with row 0 fed ``ids``: the forward the engine's
     step programs run (forward_rows through the latent route, the
     kernel interpreted) -> ([T, V] logits of row 0, expert statistics).
-    ``parked_extra``: row 3 is parked throughout as well."""
+    ``parked_extra``: row 3 is parked throughout as well. (At CH = 8
+    the prompt row is absorbed like the decode rows; ``ch`` = 20 is
+    past ``expanded_from`` and starts its second chunk off a page
+    boundary.)"""
     with rows_rounded(row_kind):
         cache = tr.KVCache.create(spec, S * MAXP + 1, PAGE, jnp.float32)
         assert cache.v.shape[-1] == 0 and cache.k.shape[-1] == 256
@@ -210,7 +220,7 @@ def _through_the_step_programs(spec, params, ids, others, row_kind=None,
                          live=live)
             pg = tr.Rows(ptoks, ppos, page_table=tab[:1],
                          write_table=tab[:1],
-                         q_lens=jnp.full((1,), CH, jnp.int32))
+                         q_lens=jnp.full((1,), ch, jnp.int32))
             (_, ph), cache, ex = tr.forward_rows(
                 spec, params, (dg, pg), cache, kv_page=PAGE)
             return tr._lm_head(spec, params, ph)[0], cache, ex
@@ -229,12 +239,12 @@ def _through_the_step_programs(spec, params, ids, others, row_kind=None,
         live[0] = False
         if parked_extra:
             live[3] = False
-        for c in range(T_PROMPT // CH):
+        for c in range(T_PROMPT // ch):
             lg, cache, ex = mixed(
                 cache, jnp.asarray(others[:, step][:, None]),
                 jnp.full((S,), step, jnp.int32), jnp.asarray(live),
-                jnp.asarray(ids[None, c * CH:(c + 1) * CH]),
-                jnp.asarray([c * CH], jnp.int32))
+                jnp.asarray(ids[None, c * ch:(c + 1) * ch]),
+                jnp.asarray([c * ch], jnp.int32))
             logits.append(np.asarray(lg))
             stats.append(np.asarray(ex))
             step += 1
@@ -354,6 +364,154 @@ def test_other_slots_and_a_parked_row_do_not_move_a_rows_bits(
     parked, _ = _through_the_step_programs(spec, params, ids, others,
                                            parked_extra=True)
     np.testing.assert_array_equal(parked, served[0])
+
+
+# ------------------------------------------ the expanded flash kernel
+
+
+def test_the_form_comes_from_the_widths_alone(tiny):
+    from localai_tfp_tpu.ops.latent_flash_attention import (
+        ABSORBED, EXPANDED, expanded_from, latent_prompt_form,
+    )
+
+    # T* = r (d_n + d_v) / (2 r - d_n - d_v)
+    assert expanded_from(512, 128, 128) == pytest.approx(170.67, abs=0.01)
+    assert expanded_from(128, 16, 16) == pytest.approx(18.29, abs=0.01)
+    assert expanded_from(64, 64, 64) == float("inf")  # never cheaper
+    _, spec, _ = tiny
+    assert [latent_prompt_form(spec, T) for T in (1, 8, 16, 18)] == \
+        [ABSORBED] * 4
+    assert [latent_prompt_form(spec, T) for T in (19, 32, 512)] == \
+        [EXPANDED] * 3
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "deepseek-v3-ep16-share.json")) as f:
+        published = spec_from_hf_config(json.load(f))
+    assert [latent_prompt_form(published, T)
+            for T in (1, 4, 128, 170, 171, 256, 512)] == \
+        [ABSORBED] * 4 + [EXPANDED] * 3
+    wide = dataclasses.replace(published, qk_nope_dim=512, v_head_dim=512)
+    assert latent_prompt_form(wide, 4096) == ABSORBED
+
+
+def _flash_case(spec, seed=0, n_rows=3, n_pos=64):
+    """Latent rows for ``n_rows`` slots of ``n_pos`` positions in an
+    arena of pages of 8 (layer 1 of 2), W_kvb stacks of 3 layers (the
+    kernel reads layer 2), and a query for every absolute position."""
+    r, dr, dn = spec.kv_lora_rank, spec.qk_rope_dim, spec.qk_nope_dim
+    H, F = spec.n_heads, spec.latent_row
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    maxp = n_pos // PAGE
+    rows = jax.random.normal(ks[0], (n_rows, n_pos, F))
+    rows = rows.at[..., r + dr:].set(0)
+    arena = jnp.zeros((2, 1 + n_rows * maxp, PAGE, F)).at[1, 1:].set(
+        rows.reshape(n_rows * maxp, PAGE, F))
+    table = (1 + jnp.arange(n_rows * maxp, dtype=jnp.int32)).reshape(
+        n_rows, maxp)
+    wk = jax.random.normal(ks[1], (3, H, dn, r)) * r ** -0.5
+    wv = jax.random.normal(ks[2], (3, H, r, spec.v_head_dim)) * r ** -0.5
+    qn = jax.random.normal(ks[3], (n_rows, n_pos, H, dn))
+    qr = jax.random.normal(ks[4], (n_rows, n_pos, H, dr))
+    return rows, arena, table, wk, wv, qn, qr
+
+
+def _flash(spec, case, pos0, q_lens, T):
+    """The kernel over chunks of ``T`` queries starting at ``pos0``."""
+    from localai_tfp_tpu.ops.latent_flash_attention import (
+        join_query, latent_flash_attention,
+    )
+
+    rows, arena, table, wk, wv, qn, qr = case
+    at = np.asarray(pos0)[:, None] + np.arange(T)[None]
+    b = np.arange(len(pos0))[:, None]
+    q = join_query(qn[b, at], qr[b, at],
+                   spec.latent_row - spec.kv_lora_rank)
+    return np.asarray(latent_flash_attention(
+        q, arena, jnp.int32(1), table[:len(pos0)],
+        jnp.asarray(pos0, jnp.int32), jnp.asarray(q_lens, jnp.int32),
+        wk, wv, jnp.int32(2), scale=tr.latent_scale(spec), page=PAGE))
+
+
+@pytest.mark.parametrize("pos0,q_lens", [
+    ((0, 16, 24), (32, 32, 32)),
+    ((5, 21, 3), (32, 32, 32)),
+    ((16, 11, 0), (7, 20, 1)),
+], ids=["on_page_boundaries", "off_page_boundaries", "a_last_chunk"])
+def test_expanded_kernel_equals_the_xla_form_at_every_position(
+        tiny, pos0, q_lens, monkeypatch):
+    """Three rows a call, each a 32-query chunk at its own position:
+    every valid query's output is ``latent_attend_expanded``'s over the
+    row's whole view; queries past ``q_lens`` are finite."""
+    _, spec, _ = tiny
+    case = _flash_case(spec)
+    rows, _, _, wk, wv, qn, qr = case
+    T = 32
+    got = _flash(spec, case, pos0, q_lens, T)
+    assert np.isfinite(got).all()
+    at = np.asarray(pos0)[:, None] + np.arange(T)[None]
+    b = np.arange(3)[:, None]
+    want = np.asarray(tr.latent_attend_expanded(
+        spec, {"wkv_b_k": wk[2], "wkv_b_v": wv[2]}, qn[b, at], qr[b, at],
+        rows, jnp.asarray(at, jnp.int32)))
+    for i, n in enumerate(q_lens):
+        assert _rel(got[i, :n], want[i, :n]).max() < 2e-5, (i, n)
+    # the chunk loop a row longer than ``_QUERY_CHUNK`` takes (here
+    # two chunks of 16 queries, the second skipped where it holds no
+    # token or sees nothing of a page)
+    from localai_tfp_tpu.ops import latent_flash_attention as lfa
+
+    monkeypatch.setattr(lfa, "_QUERY_CHUNK", 16)
+    halves = _flash(spec, case, pos0, q_lens, T)
+    for i, n in enumerate(q_lens):
+        assert _rel(halves[i, :n], want[i, :n]).max() < 2e-5, (i, n)
+
+
+def test_a_token_reads_equal_bits_wherever_its_chunk_started(tiny):
+    """The same token at the same absolute position over the same
+    cached pages, at two indices of two chunks (one starting on a page
+    boundary, one not, one a last chunk cut by ``q_lens``): equal bits
+    — what the benchmark's repeated-prompt probe holds."""
+    _, spec, _ = tiny
+    case = _flash_case(spec)
+    T = 32
+    a = _flash(spec, case, (16,), (32,), T)[0]  # positions 16..47
+    b = _flash(spec, case, (21,), (32,), T)[0]  # positions 21..52
+    c = _flash(spec, case, (8,), (30,), T)[0]  # positions 8..37, cut
+    np.testing.assert_array_equal(a[5:], b[:27])
+    np.testing.assert_array_equal(a[:22], c[8:30])
+
+
+def test_other_rows_and_a_parked_row_do_not_move_the_kernels_bits(tiny):
+    _, spec, _ = tiny
+    case = _flash_case(spec)
+    alone = _flash(spec, case, (11,), (32,), 32)[0]
+    among = _flash(spec, case, (11, 3, 30), (32, 17, 32), 32)[0]
+    np.testing.assert_array_equal(among, alone)
+    rows, arena, table, wk, wv, qn, qr = case
+    other = (rows, arena.at[1, 9:].multiply(3.0), table, wk, wv,
+             qn.at[1:].add(1.0), qr)  # rows 1, 2: other pages, queries
+    np.testing.assert_array_equal(
+        _flash(spec, other, (11, 3, 30), (32, 17, 32), 32)[0], alone)
+    parked = _flash(spec, case, (11, 3, 30), (32, 0, 32), 32)
+    np.testing.assert_array_equal(parked[0], alone)
+    assert not parked[1].any()  # a parked row reads and writes nothing
+
+
+def test_step_programs_with_an_expanded_prompt_row_match_the_reference(
+        tiny, sequences, served):
+    """``_through_the_step_programs`` with 20-token prompt rows (past
+    ``expanded_from``; the second starts at position 20, off a page
+    boundary): the logits are the reference's and the decode positions
+    — same absorbed kernel over the same pages — the bits the 8-token
+    chunks left; other slots and a parked row move no bit."""
+    ckpt, spec, params = tiny
+    ids, others = sequences
+    got, _ = _through_the_step_programs(spec, params, ids, others, ch=20)
+    assert _rel(got, _numpy_logits(ckpt, ids)).max() < 2e-5
+    assert _rel(got, served[0][:T_PROMPT + T_DEC]).max() < 2e-5
+    changed = others.copy()
+    changed[1:] = (changed[1:] * 7 + 3) % 257
+    np.testing.assert_array_equal(_through_the_step_programs(
+        spec, params, ids, changed, parked_extra=True, ch=20)[0], got)
 
 
 # ----------------------------------------------------- routing, the share
@@ -571,6 +729,70 @@ def test_engine_serves_it_on_the_latent_kernel_route(monkeypatch, tiny):
                               model=m) > 0
     finally:
         eng.close()
+
+
+def test_prompt_tokens_are_counted_by_the_form_of_their_step(
+        monkeypatch, tiny):
+    """engine_latent_prompt_tokens_total{form}, at the dispatch site: a
+    21-token prompt rides the 32-token bucket (past ``expanded_from`` =
+    18.3 at the toy widths: the flash kernel), a 5-token prompt the
+    8-token bucket (absorbed) — real tokens, not the buckets."""
+    ckpt, _, _ = tiny
+    m = "dsv3-forms"
+    eng = _serve(monkeypatch, tiny, tag=m)
+    try:
+        rng = np.random.default_rng(7)
+        long = [int(t) for t in rng.integers(0, 257, 21)]
+        toks = _generate(eng, long, n=3)
+        fam = "engine_latent_prompt_tokens_total"
+        assert _value(fam, model=m, form="expanded") == 21
+        assert _value(fam, model=m, form="absorbed") == 0
+        want = _numpy_logits(ckpt, long + toks)
+        assert want[len(long) - 1:-1].argmax(-1).tolist() == toks
+        short = [int(t) for t in rng.integers(0, 257, 5)]
+        _generate(eng, short, n=2)
+        assert _value(fam, model=m, form="expanded") == 21
+        assert _value(fam, model=m, form="absorbed") == 5
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("before,after,want", [
+    ({"expanded": 1000.0, "absorbed": 40.0},
+     {"expanded": 9000.0, "absorbed": 40.0}, 100.0),
+    ({"expanded": 0.0, "absorbed": 0.0},
+     {"expanded": 600.0, "absorbed": 200.0}, 75.0),
+    ({"absorbed": 10.0}, {"absorbed": 510.0}, 0.0),
+    (None, None, None),
+], ids=["every_step_a_whole_row", "a_ladder", "all_absorbed", "the_parent"])
+def test_the_benchmarks_reader_of_the_counter(before, after, want):
+    """``latent_prompt_expanded_share`` (a ``.json`` ratio reader, listed
+    for ``deepseekv3_docs_closed`` alone under the layer the other
+    attention readers have): the window's DELTA of the expanded form's
+    tokens over both forms', in percent; a program without the counter
+    — the parent — has nothing to read."""
+    from benchmark.lib import layer_metrics, manifest
+
+    def scrape(d):
+        fams = {"engine_dispatch_tokens_total": [
+            ({"model": "m", "kind": "mixed", "part": "real"}, 1.0)]}
+        if d is not None:
+            fams["engine_latent_prompt_tokens_total"] = [
+                ({"model": "m", "form": f}, v) for f, v in d.items()]
+        return fams
+
+    run = {"metrics_before": scrape(before), "metrics_after": scrape(after)}
+    got = layer_metrics.evaluate(
+        os.path.join(ROOT, "benchmark", "layer_metrics"),
+        "latent_prompt_expanded_share", None, run)
+    assert got == (want if want is None else pytest.approx(want))
+    entry = next(m for m in manifest.load(ROOT)["per_layer"]
+                 if m["name"] == "latent_prompt_expanded_share")
+    assert entry == {
+        "name": "latent_prompt_expanded_share", "unit": "%",
+        "better": "higher", "source": "program_counter",
+        "layer": "attention kernel", "moves": "tpot_p50_ms",
+        "workloads": ["deepseekv3_docs_closed"]}
 
 
 def test_prefix_reuse_shares_latent_pages(monkeypatch, tiny):

@@ -587,3 +587,16 @@ def test_latent_arena_at_128_heads_of_576(T):
             want = (p / p.sum(-1, keepdims=True)) @ rows[b, :n, :r]
             np.testing.assert_allclose(out[b, t], want, rtol=2e-4,
                                        atol=2e-5)
+
+
+def test_kernel_check_holds_the_expanded_flash_kernel():
+    """``kernel_check``'s leg for a latent model's PROMPT rows
+    (ops/latent_flash_attention.py), at the interpreter's size here and
+    at DeepSeek-V3's published widths on the chip: within bf16 rounding
+    of the XLA form, and a token's bits the same at two indices of two
+    chunks — the leg fails a run on either."""
+    from localai_tfp_tpu.ops import kernel_check as kc
+
+    res = kc.check_latent_flash(kc._LATENT_SMALL)
+    assert res["max_rel_err"] < kc._TOL_FP and res["rows_equal"], res
+    assert kc.LATENT_WIDTHS["deepseek"] == (128, 128, 64, 128, 512, 256, 512)

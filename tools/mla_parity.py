@@ -11,10 +11,12 @@ every control ``not correct``.
 
   steps   the forward the engine's step programs run (``forward_rows``
           through the latent route: page tables, the scatter, the
-          absorbed kernel): a ``--prompt``-token prompt in 512-token
-          prompt rows beside 16 decode rows (``mixed``'s two groups),
-          then ``--decode`` tokens through decode rows (``decodek``'s
-          group) — the LOGITS at every position against the plain
+          expanded flash kernel for the prompt rows and the absorbed
+          kernel for the decode rows): a ``--prompt``-token prompt in
+          512-token prompt rows beside 16 decode rows (``mixed``'s two
+          groups), then ``--decode`` tokens through decode rows
+          (``decodek``'s group) — the LOGITS at every position against
+          the plain
           numpy float32 pass over the same ids
           (``benchmark/models/deepseek_v3.py``, read from the
           checkpoint's shards). Reading: the MEDIAN over positions of
@@ -34,11 +36,17 @@ every control ``not correct``.
           ``reference.pooled``, the largest of the four against
           ``parity_tol``. As served | the row cached in fp8 | in int8.
   forms   chip only: a 512-token prompt row against cached contexts of
-          4096 and 4608 tokens, one layer: the absorbed kernel (as
-          served) and the expanded form (the row's cached latents
-          up-projected through W_kvb, then attention at 128 x 192 /
-          128), both against the float32 expanded form, microseconds a
-          call from the profiler-free wall clock of 20 calls.
+          1024, 2560, 4096 and 4608 tokens, one layer: the absorbed
+          kernel with the query and output glue it needs (what a prompt
+          row took until PR 50, what a decode row takes), the expanded
+          form as XLA writes it (the row's cached latents up-projected
+          through W_kvb, then attention at 128 x 192 / 128) and the
+          expanded flash kernel (``ops/latent_flash_attention.py``: as
+          served from ``expanded_from`` queries a row on), each against
+          the float32 expanded form: microseconds a call from the
+          profiler-free wall clock of 20 calls, and the share of the
+          MXU's peak with the form's own FLOP count over the pages the
+          row walks.
 
 ``reference_logits`` is the second copy of the plain reference the
 CPU tests use: jax.numpy, float32, ``highest`` matmul precision, the
@@ -383,27 +391,34 @@ def probe(config: dict, scratch: str, tiny: bool) -> dict:
 
 def forms(config: dict) -> dict:
     """One layer's attention for a 512-token prompt row against a
-    cached context, both forms, on the device the process holds."""
+    cached context: absorbed kernel | expanded XLA | expanded kernel,
+    on the device the process holds."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from localai_tfp_tpu.models import transformer as tr
     from localai_tfp_tpu.models.llm_spec import spec_from_hf_config
+    from localai_tfp_tpu.ops.latent_flash_attention import (
+        expanded_from, join_query, latent_flash_attention,
+    )
     from localai_tfp_tpu.ops.ragged_paged_attention import (
         ragged_paged_attention,
     )
+    from localai_tfp_tpu.telemetry.costmodel import peak_rates
 
     spec = spec_from_hf_config(config)
     H, r, page, T = spec.n_heads, spec.kv_lora_rank, 256, 512
+    dn, dr, dv = spec.qk_nope_dim, spec.qk_rope_dim, spec.v_head_dim
     k0, k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(0), 5)
     bf = jnp.bfloat16
-    lp = {"wkv_b_k": (jax.random.normal(k0, (H, spec.qk_nope_dim, r))
-                      * r ** -0.5).astype(bf),
-          "wkv_b_v": (jax.random.normal(k1, (H, r, spec.v_head_dim))
-                      * r ** -0.5).astype(bf)}
-    out = {"part": "forms", "T": T, "contexts": {}}
-    for ctx in (4096, 4608):
+    # the kernel takes the WHOLE stacks and a layer index
+    wk = (jax.random.normal(k0, (2, H, dn, r)) * r ** -0.5).astype(bf)
+    wv = (jax.random.normal(k1, (2, H, r, dv)) * r ** -0.5).astype(bf)
+    peak = peak_rates(jax.devices()[0].device_kind)[0]
+    out = {"part": "forms", "T": T, "contexts": {},
+           "expanded_from": expanded_from(r, dn, dv)}
+    for ctx in (1024, 2560, 4096, 4608):
         n_pages = (ctx + T) // page
         rows = jax.random.normal(k2, (1, n_pages * page, spec.latent_row))
         rows = rows.at[..., spec.latent_width:].set(0).astype(bf)
@@ -411,25 +426,39 @@ def forms(config: dict) -> dict:
             [jnp.zeros((1, 1, page, spec.latent_row), bf),
              rows.reshape(1, n_pages, page, -1)], axis=1)
         pt = jnp.arange(1, n_pages + 1, dtype=jnp.int32)[None]
-        qn = jax.random.normal(k3, (1, T, H, spec.qk_nope_dim)).astype(bf)
-        qr = jax.random.normal(k4, (1, T, H, spec.qk_rope_dim)).astype(bf)
+        qn = jax.random.normal(k3, (1, T, H, dn)).astype(bf)
+        qr = jax.random.normal(k4, (1, T, H, dr)).astype(bf)
         pos0 = jnp.asarray([ctx], jnp.int32)
+        q_lens = jnp.asarray([T], jnp.int32)
         qpos = ctx + jnp.arange(T, dtype=jnp.int32)[None]
 
         @jax.jit
-        def absorbed(qn, qr, arena):
+        def absorbed(qn, qr, arena, wk, wv):
+            lp = {"wkv_b_k": wk[1], "wkv_b_v": wv[1]}
             c = ragged_paged_attention(
                 tr.latent_absorb_query(spec, lp, qn, qr), arena, None,
-                jnp.int32(0), pt, pos0, jnp.asarray([T], jnp.int32), 1,
+                jnp.int32(0), pt, pos0, q_lens, 1,
                 scale=tr.latent_scale(spec), page=page, v_lanes=r)
             return tr.latent_absorb_out(
                 spec, lp, c.reshape(1, T, H, r), bf)
 
         @jax.jit
-        def expanded(qn, qr, rows):
+        def expanded(qn, qr, rows, wk, wv):
+            lp = {"wkv_b_k": wk[1], "wkv_b_v": wv[1]}
             return tr.latent_attend_expanded(spec, lp, qn, qr, rows, qpos)
 
-        f32 = {k: v.astype(jnp.float32) for k, v in lp.items()}
+        # the kernel's query, joined outside the timed call: a head's
+        # [q_n | q_r | 0] as wide as [k_n | the row's lanes past c]
+        q_cat = join_query(qn, qr, spec.latent_row - r)
+
+        @jax.jit
+        def kernel(q_cat, arena, wk, wv):
+            return latent_flash_attention(
+                q_cat, arena, jnp.int32(0), pt, pos0, q_lens, wk, wv,
+                jnp.int32(1), scale=tr.latent_scale(spec), page=page)
+
+        f32 = {"wkv_b_k": wk[1].astype(jnp.float32),
+               "wkv_b_v": wv[1].astype(jnp.float32)}
         want = np.asarray(jax.jit(
             lambda a, b, c: tr.latent_attend_expanded(
                 spec, f32, a, b, c, qpos))(
@@ -446,11 +475,24 @@ def forms(config: dict) -> dict:
             return us, float(np.linalg.norm(got - want)
                              / np.linalg.norm(want))
 
-        a_us, a_err = timed(absorbed, qn, qr, arena)
-        e_us, e_err = timed(expanded, qn, qr, rows)
-        out["contexts"][str(ctx)] = {
-            "absorbed_us": a_us, "absorbed_rel_l2": a_err,
-            "expanded_us": e_us, "expanded_rel_l2": e_err}
+        # FLOPs over the (query, key) pairs of the pages walked: the
+        # absorbed form 2 (r + d_r + r) a pair-head, the expanded form
+        # 2 (d_n + d_r + d_v) a pair-head and 2 r (d_n + d_v) a
+        # key-head once
+        keys = n_pages * page
+        flop = {"absorbed": 2.0 * (2 * r + dr) * T * keys * H,
+                "expanded": (2.0 * (dn + dr + dv) * T
+                             + 2.0 * r * (dn + dv)) * keys * H}
+        one = {}
+        for name, form, fn, a in (
+                ("absorbed", "absorbed", absorbed, (qn, qr, arena)),
+                ("expanded", "expanded", expanded, (qn, qr, rows)),
+                ("kernel", "expanded", kernel, (q_cat, arena))):
+            us, err = timed(fn, *a, wk, wv)
+            one.update({f"{name}_us": us, f"{name}_rel_l2": err,
+                        f"{name}_mxu_share": flop[form] / (us * 1e-6)
+                        / peak})
+        out["contexts"][str(ctx)] = one
     return out
 
 
@@ -477,7 +519,9 @@ def main(argv=None) -> int:
     if args.tiny:
         config.update(_TINY)
         config["serving"] = dict(config["serving"], context_size=512)
-        args.prompt, args.decode, args.step = 64, 12, 16
+        # (a 32-token row is past ``expanded_from`` at the toy widths:
+        # the rehearsal runs the expanded kernel too)
+        args.prompt, args.decode, args.step = 64, 12, 32
     both = not (args.steps or args.probe or args.forms)
     must, got = {}, {}
     if args.steps or both:
