@@ -69,8 +69,9 @@ from ..config import knobs
 from ..models.llm_spec import LLMSpec
 from ..models.transformer import (
     KVCache, Params, Rows, expert_path, forward, forward_hidden,
-    forward_rows,
+    forward_rows, held_rows_dispatch,
 )
+from ..ops.grouped_matmul import GROUPED_KERNEL
 from ..ops.sampling import (
     SamplingState, sample, seed_windows,
 )
@@ -4195,7 +4196,7 @@ class LLMEngine:
         self._flights.append(_Flight(
             kind="mixed", arrays=[toks_out, *experts],
             meta={
-                "experts": (experts, 1),
+                "experts": (experts, 1, S + R * bucket),
                 # a decode row's consumed token: the host's, or (carry)
                 # whatever the flight before this one sampled last
                 "decode": [(s, s.request,
@@ -4439,11 +4440,12 @@ class LLMEngine:
             a.copy_to_host_async()
         return out
 
-    def _note_expert_stats(self, kind: str, stats: list,
-                           steps: int) -> None:
+    def _note_expert_stats(self, kind: str, stats: list, steps: int,
+                           rows: int) -> None:
         """A harvested dispatch's expert statistics onto the counters:
         tokens by expert, the expert layer-steps it ran (``steps``
-        token-steps a program) and the experts those touched."""
+        token-steps a program of ``rows`` token rows), the experts
+        those touched and the sorted rows their dispatch moved."""
         if not stats:
             return
         # HELD experts only, labelled by published id; a model that
@@ -4458,16 +4460,25 @@ class LLMEngine:
                 [tm.ENGINE_EXPERT_TOKENS.labels(
                     model=m, expert=str(first + e)) for e in range(E)],
                 [tm.ENGINE_EXPERT_ASSIGNMENTS.labels(model=m, where=w)
-                 for w in ("held", "absent")] if share else None)
+                 for w in ("held", "absent")] if share else None,
+                [tm.ENGINE_EXPERT_DISPATCH_ROWS.labels(model=m, kind=k)
+                 for k in ("moved", "slots")])
         # lint: ignore[hot-path-sync] the flight these ride was ready()
         total = np.sum([np.asarray(a) for a in stats], axis=0)
-        ctr[0].inc(len(stats) * steps * self._n_expert_layers)
+        layer_steps = len(stats) * steps * self._n_expert_layers
+        held = int(np.sum(total[:E]))
+        ctr[0].inc(layer_steps)
         ctr[1].inc(int(total[E]))
         for e in np.nonzero(total[:E])[0]:
             ctr[2][e].inc(int(total[e]))
         if share:
-            ctr[3][0].inc(int(np.sum(total[:E])))
+            ctr[3][0].inc(held)
             ctr[3][1].inc(int(total[E + 1]))
+        slots = layer_steps * rows * self.spec.experts_per_token
+        ctr[4][0].inc(held if held_rows_dispatch(
+            self.spec, self.expert_path == GROUPED_KERNEL, rows)
+            else slots)
+        ctr[4][1].inc(slots)
 
     def _note_ragged_rows(self, kind: str, n: int) -> None:
         """Rows advanced through the unified ragged path by kind
@@ -4779,7 +4790,7 @@ class LLMEngine:
             kind="decodek", arrays=[toks, *experts],
             meta={
                 "k": k,
-                "experts": (experts, k),
+                "experts": (experts, k, S),
                 "cost": dckey,
                 "pred_ms": (self._costmodel.predict_ms("decodek", dckey)
                             if self._costmodel is not None else None),
@@ -4916,7 +4927,7 @@ class LLMEngine:
         experts = self._take_expert_stats()
         # lint: ignore[hot-path-sync] decode1 IS the blocking path: grammar masks / logit bias need every token on host before the next dispatch
         toks_host = np.asarray(toks)
-        self._note_expert_stats("decode1", experts, 1)
+        self._note_expert_stats("decode1", experts, 1, S)
         dt_ms = (time.perf_counter() - t0) * 1e3
         emitted = 0
         for s in decoding:

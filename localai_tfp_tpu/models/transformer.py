@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops import expert_rows as er
 from ..ops import grouped_matmul as gm
 from .llm_spec import LLMSpec
 from .quant import QTensor as _QTensor
@@ -908,6 +909,11 @@ def _moe_mlp(spec, lp, x, valid, experts):
     stacks its tiling covers: ``forward_rows`` asks) and
     ``lax.ragged_dot`` — XLA's own, n * E groups of which this layer's
     E have rows — everywhere else; a capture names both ``ragged-dot*``.
+    Around the kernel a layer that holds a SHARE moves the rows it owns
+    alone (``held_rows_dispatch``: ``ops/expert_rows.py`` gathers the
+    first sum(counts) sorted rows and combines them; the arrays keep
+    their worst-case shapes); every other layer's gather, mask, un-sort
+    and sum are XLA's over all N * K rows.
 
     qwen2_moe extras: a shared expert scaled by sigmoid(x·g) added to the
     mixture, un-renormalized top-k weights (norm_topk_prob=false), and
@@ -938,17 +944,24 @@ def _moe_mlp(spec, lp, x, valid, experts):
     whole, li, *kernel = experts
     w_gate, w_up, w_down = (
         whole[k].reshape(-1, *whole[k].shape[2:]) for k in EXPERT_LEAVES)
+    held_rows = held_rows_dispatch(spec, any(kernel), N)
     if any(kernel):
         # the repo's own kernel (ops/grouped_matmul.py): whole row
         # tiles, so the sorted rows are padded — with rows no group
         # holds, which read no expert
         rows = gm.padded_rows(N * K)
-        xs = xf[jnp.pad(order, (0, rows - N * K)) // K]
+        src = jnp.pad(order, (0, rows - N * K)) // K
         sched = gm.schedule(counts, rows)
+        # a share owns the first sum(counts) sorted rows and most of
+        # the rest belong to other chips: ops/expert_rows.py moves the
+        # owned rows alone, here and in the combine below
+        owned = sched.offsets[-1]
+        xs = er.gather_rows(xf, src, owned) if held_rows else xf[src]
         g, u = gm.grouped_matmul(xs, (w_gate, w_up), li, sched)
         (y,) = gm.grouped_matmul((_act(spec, g) * u).astype(x.dtype),
                                  (w_down,), li, sched)
-        y = y[:N * K]
+        if not held_rows:
+            y = y[:N * K]
     else:
         xs = xf[order // K]  # [N*K, D] rows in expert order
         sizes = lax.dynamic_update_slice(
@@ -957,15 +970,19 @@ def _moe_mlp(spec, lp, x, valid, experts):
         u = lax.ragged_dot(xs, w_up, sizes)
         y = lax.ragged_dot((_act(spec, g) * u).astype(x.dtype),
                            w_down, sizes)  # [N*K, D]
-    if valid is not None or share:
-        # rows past the last group are whatever the kernel left there
-        y = jnp.where(
-            jnp.arange(N * K, dtype=jnp.int32)[:, None] < jnp.sum(counts),
-            y, 0)
-    inv = jnp.zeros((N * K,), jnp.int32).at[order].set(
-        jnp.arange(N * K, dtype=jnp.int32))
-    out = jnp.einsum("nkd,nk->nd",
-                     y[inv].reshape(N, K, D).astype(jnp.float32), w)
+    if held_rows:
+        # no row past the owned ones is read: nothing to mask
+        out = er.combine_rows(y, src, w.reshape(N * K)[order], owned, N)
+    else:
+        if valid is not None or share:
+            # rows past the last group are whatever the kernel left
+            y = jnp.where(
+                jnp.arange(N * K, dtype=jnp.int32)[:, None]
+                < jnp.sum(counts), y, 0)
+        inv = jnp.zeros((N * K,), jnp.int32).at[order].set(
+            jnp.arange(N * K, dtype=jnp.int32))
+        out = jnp.einsum("nkd,nk->nd",
+                         y[inv].reshape(N, K, D).astype(jnp.float32), w)
     out = out.reshape(B, T, D)
     if "shared_gate" in lp:
         s = (_act(spec, x @ lp["shared_gate"]) * (x @ lp["shared_up"])) \
@@ -989,6 +1006,18 @@ def _moe_mlp(spec, lp, x, valid, experts):
         counts = jnp.concatenate(
             [counts, (n_real - jnp.sum(counts))[None].astype(jnp.int32)])
     return out.astype(x.dtype), counts
+
+
+def held_rows_dispatch(spec, kernel: bool, n_tokens: int) -> bool:
+    """Whether an expert layer's dispatch moves only the rows it holds
+    (``ops/expert_rows.py``) in a step of ``n_tokens`` token rows: the
+    layer holds a SHARE of the published experts, the repo's grouped
+    kernel multiplies (``kernel``: ``gm.expert_path`` said so — a TPU
+    backend, no mesh) and the step's token rows fit the kernels.
+    Everywhere else XLA gathers, masks and un-sorts all N * K rows.
+    ``_moe_mlp`` asks while tracing, the engine for its counter."""
+    return bool(kernel and spec.n_held < spec.n_experts
+                and er.fits(n_tokens, spec.d_model))
 
 
 def expert_path(spec, params, mesh) -> Optional[str]:

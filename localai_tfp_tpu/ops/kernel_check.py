@@ -16,7 +16,10 @@ chip_smoke model at its serving settings).
 
 ``--sweep`` is the kernel's stopwatch, not a check: the ragged kernel
 alone at the geometry's decode shapes over pages a row, parked rows and
-arena dtypes, one JSON line a dtype (``sweep_decode_kernel``).
+arena dtypes, one JSON line a dtype (``sweep_decode_kernel``);
+``--sweep --experts`` an expert layer's grouped matmuls alone,
+``--sweep --dispatch`` what surrounds them in a layer that holds a
+share (XLA's gather, mask, un-sort and sum beside the row kernels).
 """
 
 from __future__ import annotations
@@ -781,6 +784,15 @@ def _expert_stack(key, n: int, e: int, k: int, m: int, dtype):
             * (k ** -0.5)).astype(dtype)
 
 
+def _expert_stacks(seed: int, d: int, f: int, held: int, dtype):
+    """(gate, up, down) stacks of ``_EXPERT_LAYERS`` layers."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    n = _EXPERT_LAYERS
+    return (_expert_stack(keys[0], n, held, d, f, dtype),
+            _expert_stack(keys[1], n, held, d, f, dtype),
+            _expert_stack(keys[2], n, held, f, d, dtype))
+
+
 def _ragged_dot_layer(lhs, w, layer, counts):
     """The parent's form: ``lax.ragged_dot`` over the WHOLE stack with
     one layer's groups non-empty."""
@@ -867,6 +879,32 @@ def _module_times_us(trace_dir: str, name: str) -> "tuple[list, list]":
     return [d / 1e3 for _, d in mods], [d / 1e3 for d in inside]
 
 
+def _module_ops_us(trace_dir: str, name: str) -> "list[tuple]":
+    """For every run of the program whose name holds ``name``, in the
+    order they ran: (its device time, {an op's own name without its
+    number: its time}), us; ``while`` wrappers left out."""
+    import re
+
+    mods, ops = [], []
+    for line, events in _device_lines(trace_dir):
+        if line == "XLA Modules":
+            mods += [(e.start_ns, e.duration_ns) for e in events
+                     if name in e.name]
+        elif line == "XLA Ops":
+            ops += [(e.start_ns, e.duration_ns,
+                     re.sub(r"\.\d+$", "", re.split(
+                         r"[ =(]", e.name.lstrip("%"), maxsplit=1)[0]))
+                    for e in events]
+    out = []
+    for m0, md in sorted(mods):
+        by: dict = {}
+        for s, d, op in ops:
+            if m0 <= s < m0 + md and not op.startswith("while"):
+                by[op] = by.get(op, 0.0) + d / 1e3
+        out.append((md / 1e3, by))
+    return out
+
+
 def sweep_grouped_matmul(widths, label: str, *, calls: int = 12,
                          seed: int = 0) -> dict[str, Any]:
     """Time ONE expert layer's three grouped matmuls (gate, up, the
@@ -895,10 +933,7 @@ def sweep_grouped_matmul(widths, label: str, *, calls: int = 12,
     hbm = peak_rates(dev.device_kind)[1]
     d, f, held, published = widths
     dt, n = jnp.bfloat16, _EXPERT_LAYERS
-    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
-    stacks = (_expert_stack(keys[0], n, held, d, f, dt),
-              _expert_stack(keys[1], n, held, d, f, dt),
-              _expert_stack(keys[2], n, held, f, d, dt))
+    stacks = _expert_stacks(seed, d, f, held, dt)
     act = jax.nn.silu
 
     def xla_layer(x, ws, layer, counts):
@@ -970,6 +1005,186 @@ def sweep_grouped_matmul(widths, label: str, *, calls: int = 12,
     return {"device_kind": dev.device_kind, "widths": label,
             "d_model": d, "d_ff": f, "held": held, "published": published,
             "calls": calls, "points": out}
+
+
+# ---------------------------------------------------------------------------
+# the dispatch AROUND the grouped matmul of a layer that holds a share
+# (ops/expert_rows.py)
+# ---------------------------------------------------------------------------
+
+_DISPATCH_TOKENS = (16, 528)  # a decode step | a 512-token prompt step
+
+
+def _share_routing(rng, tokens: int, held: int, published: int,
+                   probe=None):
+    """Uniform routing of ``tokens`` tokens over ``published`` experts
+    of which 0 .. held - 1 are here: (group of each assignment, the
+    sentinel ``held`` for an absent one [tokens * K] i32, weights
+    [tokens, K] f32). ``probe`` = (token, its K picks, its K weights)
+    overrides one token's."""
+    ids = np.stack([rng.permutation(published)[:_EXPERT_K]
+                    for _ in range(tokens)])
+    w = rng.uniform(0.05, 0.3, (tokens, _EXPERT_K)).astype(np.float32)
+    if probe is not None:
+        ids[probe[0]], w[probe[0]] = probe[1], probe[2]
+    flat = np.where(ids < held, ids, held).reshape(-1).astype(np.int32)
+    return flat, w
+
+
+def _sorted(flat, held: int):
+    """``_moe_mlp``'s sort: (the assignments in expert order [N * K],
+    rows a held expert [held])."""
+    return (jnp.argsort(flat, stable=True).astype(jnp.int32),
+            jnp.zeros((held,), jnp.int32).at[flat].add(1, mode="drop"))
+
+
+def _dispatch_layer(form: str, act, x, ws, layer, order, counts, w):
+    """One expert layer after the router and the sort, ``_moe_mlp``'s
+    kernel branch for a share: ``xla`` as the parent traces it (gather
+    of all N * K rows, mask, un-sort, weighted sum), ``rows`` through
+    ``ops/expert_rows.py`` (the held rows alone).
+    -> (out f32 [N, D], the gathered rows, H)."""
+    from . import expert_rows as er
+    from . import grouped_matmul as gm
+
+    N, D = x.shape
+    K = _EXPERT_K
+    rows = gm.padded_rows(N * K)
+    src = jnp.pad(order, (0, rows - N * K)) // K
+    sched = gm.schedule(counts, rows)
+    held = jnp.sum(counts)
+    xs = x[src] if form == "xla" else er.gather_rows(x, src, held)
+    g, u = gm.grouped_matmul(xs, ws[:2], layer, sched)
+    (y,) = gm.grouped_matmul((act(g) * u).astype(x.dtype), ws[2:], layer,
+                             sched)
+    if form != "xla":
+        return (er.combine_rows(y, src, w.reshape(-1)[order], held, N),
+                xs, held)
+    y = y[:N * K]
+    y = jnp.where(jnp.arange(N * K, dtype=jnp.int32)[:, None] < held, y, 0)
+    inv = jnp.zeros((N * K,), jnp.int32).at[order].set(
+        jnp.arange(N * K, dtype=jnp.int32))
+    return jnp.einsum("nkd,nk->nd",
+                      y[inv].reshape(N, K, D).astype(jnp.float32), w), xs, held
+
+
+def check_expert_rows(widths=EXPERT_WIDTHS["deepseek"],
+                      seed: int = 0) -> dict[str, Any]:
+    """The row kernels of a layer that holds a share
+    (ops/expert_rows.py) at one configuration's widths against the XLA
+    dispatch they replace, the grouped kernel multiplying both times,
+    for a decode step's 16 tokens and a prompt step's 528, on the
+    device JAX finds:
+
+    - ``gather_rows_differ``: rows r < H of the gathered array that are
+      not the token's row bit for bit (0);
+    - ``max_rel_err``: the worst |rows - xla| of the layer's f32 output
+      relative to the largest (the same products, summed over a token's
+      experts in another order: f32 round-off);
+    - ``rows_equal``: ONE token's output bit for bit in both steps —
+      its row, its picks (two of them held) and its weights the same,
+      the other tokens and its index not."""
+    d, f, held, published = widths
+    dt = jnp.bfloat16
+    rng = np.random.default_rng(seed)
+    stacks = _expert_stacks(seed, d, f, held, dt)
+    picks = np.concatenate([[held - 1, 0],
+                            held + 1 + np.arange(_EXPERT_K - 2)])
+    probe_w = rng.uniform(0.05, 0.3, (_EXPERT_K,)).astype(np.float32)
+    probe_x = rng.standard_normal((d,)) * 0.1
+    layer = jax.jit(  # (the stacks as an argument: closed over, 1.4 GB
+        # of them would be constants of every program)
+        lambda form, x, ws, flat, w: _dispatch_layer(
+            form, jax.nn.silu, x, ws, 1, *_sorted(flat, held), w),
+        static_argnums=0)
+    worst, differ, seen = 0.0, 0, []
+    for tokens, at in zip(_DISPATCH_TOKENS, (3, 301)):
+        flat, w = _share_routing(rng, tokens, held, published,
+                                 (at, picks, probe_w))
+        x = rng.standard_normal((tokens, d)) * 0.1
+        x[at] = probe_x
+        args = (jnp.asarray(x, dt), stacks, jnp.asarray(flat),
+                jnp.asarray(w))
+        got, xs, h = layer("rows", *args)
+        want, xs_xla, _ = layer("xla", *args)
+        h = int(h)
+        differ += int(np.sum(np.any(
+            np.asarray(xs[:h], np.float32)
+            != np.asarray(xs_xla[:h], np.float32), axis=1)))
+        got, want = np.asarray(got), np.asarray(want)
+        worst = max(worst, float(np.max(np.abs(got - want))
+                                 / (np.max(np.abs(want)) + 1e-9)))
+        seen.append(got[at])
+    return {"max_rel_err": worst, "gather_rows_differ": differ,
+            "rows_equal": bool(np.array_equal(*seen)
+                               and np.any(seen[0] != 0))}
+
+
+def sweep_expert_dispatch(widths, label: str, *, calls: int = 10,
+                          seed: int = 0) -> dict[str, Any]:
+    """Time what surrounds ONE expert layer's grouped matmuls ALONE at
+    ``widths`` (a layer that holds ``held`` of ``published`` experts,
+    bf16, uniform routing: 6-7 % of the assignments held), for a decode
+    step's 16 tokens and a prompt step's 528: the parent's XLA form
+    beside the row kernels. A point is ``calls`` layer-steps in one
+    scan whose carry is the layer's input (x += layer(x), so nothing is
+    hoisted); router and sort are outside it. ``us_layer`` the
+    program's device time a layer-step, ``us_matmul`` the part under
+    ``ragged-dot*``, ``us_dispatch`` the rest: gather, activation,
+    mask, un-sort and sum — or the two kernels. Chip only."""
+    import tempfile
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit("--sweep times the compiled kernels: chip only")
+    d, f, held, published = widths
+    dt, n = jnp.bfloat16, _EXPERT_LAYERS
+    stacks = _expert_stacks(seed, d, f, held, dt)
+
+    def program(form):
+        def run(x, ws, flat, w):
+            order, counts = _sorted(flat, held)
+
+            def one(x, i):
+                out, _, _ = _dispatch_layer(form, jax.nn.silu, x, ws, i % n,
+                                            order, counts, w)
+                return x + out.astype(x.dtype), None
+            return jax.lax.scan(one, x,
+                                jnp.arange(calls, dtype=jnp.int32))[0]
+        run.__name__ = f"sweep_dispatch_{form}"
+        return jax.jit(run)
+
+    rng = np.random.default_rng(seed)
+    points = []
+    for tokens in _DISPATCH_TOKENS:
+        flat, w = _share_routing(rng, tokens, held, published)
+        x = jnp.asarray(rng.standard_normal((tokens, d)) * 0.1, dt)
+        points.append((tokens, jnp.asarray(flat), jnp.asarray(w), x))
+    out = []
+    for form in ("xla", "rows"):
+        fn = program(form)
+        for _, flat, w, x in points:  # compile + warm, untraced
+            fn(x, stacks, flat, w).block_until_ready()
+        with tempfile.TemporaryDirectory() as tmp:
+            with jax.profiler.trace(tmp):
+                for _, flat, w, x in points:
+                    fn(x, stacks, flat, w).block_until_ready()
+            runs = _module_ops_us(tmp, fn.__name__)
+        assert len(runs) == len(points), (form, len(runs))
+        for (tokens, flat, *_), (us, ops) in zip(points, runs):
+            us_in = sum(v for k, v in ops.items()
+                        if k.startswith("ragged-dot"))
+            top = sorted(ops.items(), key=lambda kv: -kv[1])[:8]
+            out.append({
+                "form": form, "tokens": tokens,
+                "held_rows": int(np.sum(np.asarray(flat) < held)),
+                "us_layer": round(us / calls, 1),
+                "us_matmul": round(us_in / calls, 1),
+                "us_dispatch": round((us - us_in) / calls, 1),
+                "ops_us": {k: round(v / calls, 1) for k, v in top}})
+    return {"device_kind": dev.device_kind, "widths": label, "d_model": d,
+            "d_ff": f, "held": held, "published": published, "calls": calls,
+            "points": out}
 
 
 # (max abs error) budgets: attention outputs are O(1) post-softmax and
@@ -1056,6 +1271,7 @@ def check_latent_flash(widths=LATENT_WIDTHS["deepseek"],
 
 _TOL_FP, _TOL_INT8 = 2e-2, 5e-2
 _TOL_FORWARD = 5e-2  # relative to the logit scale, bf16 end to end
+_TOL_ROWS = 1e-5  # one f32 sum of <= 8 products in two orders
 
 
 def run_kernel_checks(geom: Geometry = SERVING) -> dict[str, Any]:
@@ -1134,6 +1350,19 @@ def run_kernel_checks(geom: Geometry = SERVING) -> dict[str, Any]:
         leg(f"grouped_matmul_{label}_rows_differ",
             0.0 if res["rows_equal"] else 1.0, 0.0)
         out[f"ragged_dot_{label}_rows_equal"] = res["ragged_dot_rows_equal"]
+    # a layer that holds a share: the row kernels around the grouped
+    # matmul against XLA's gather, mask, un-sort and sum, and a token's
+    # bits the same in a decode step and in a prompt step
+    for label, widths in ({"deepseek": EXPERT_WIDTHS["deepseek"]}
+                          if dev.platform == "tpu"
+                          else {"small": _EXPERT_SMALL}).items():
+        res = check_expert_rows(widths)
+        leg(f"expert_rows_{label}_max_rel_err", res["max_rel_err"],
+            _TOL_ROWS)
+        leg(f"expert_rows_{label}_gather_rows_differ",
+            float(res["gather_rows_differ"]), 0.0)
+        leg(f"expert_rows_{label}_rows_differ",
+            0.0 if res["rows_equal"] else 1.0, 0.0)
     # a latent model's prompt rows: the expanded flash kernel against
     # the XLA form at the published widths, and a token's bits the same
     # wherever its chunk started
@@ -1170,6 +1399,10 @@ def main(argv: "list[str] | None" = None) -> int:
                     help="--sweep: time the expert layer's grouped "
                     "matmuls alone instead (lax.ragged_dot, megablox."
                     "gmm, the kernel; one JSON line a configuration)")
+    ap.add_argument("--dispatch", action="store_true",
+                    help="--sweep: time what surrounds the grouped matmul "
+                    "of a layer that holds a share instead (XLA's gather, "
+                    "mask, un-sort and sum beside the row kernels)")
     ap.add_argument("--cache", default="int8,bf16",
                     help="--sweep: arena dtypes, comma-separated")
     ap.add_argument("--window", type=int, default=0,
@@ -1180,6 +1413,10 @@ def main(argv: "list[str] | None" = None) -> int:
         f.name: getattr(args, f.name)
         for f in dataclasses.fields(Geometry)
         if getattr(args, f.name) is not None})
+    if args.sweep and args.dispatch:
+        print(json.dumps(sweep_expert_dispatch(
+            EXPERT_WIDTHS["deepseek"], "deepseek")), flush=True)
+        return 0
     if args.sweep and args.experts:
         for label, widths in EXPERT_WIDTHS.items():
             print(json.dumps(sweep_grouped_matmul(widths, label)),

@@ -555,6 +555,17 @@ ENGINE_EXPERT_ASSIGNMENTS = REGISTRY.counter(
     "of the deployment's routed load this chip carries",
     labels=("model", "where"),
 )
+ENGINE_EXPERT_DISPATCH_ROWS = REGISTRY.counter(
+    "engine_expert_dispatch_rows_total",
+    "Sorted (token, expert) rows around the grouped matmul, summed "
+    "over the expert layers of the step programs harvested: "
+    "kind=slots the rows a step's arrays hold (token rows x "
+    "experts_per_token, padding rows too), kind=moved the rows the "
+    "dispatch gathered and combined — the held assignments where a "
+    "layer that holds a share takes ops/expert_rows.py, every slot "
+    "where XLA's gather, mask and un-sort run",
+    labels=("model", "kind"),
+)
 ENGINE_DECODE_STEPS = REGISTRY.counter(
     "engine_decode_steps_total",
     "Decode token-steps dispatched by decode-only programs (k x depth "

@@ -19,7 +19,11 @@ to one xdist worker, and a second file's fixture would skip in silence.
 - DeepSeek-V3's ``mixed`` program attends its 512-token prompt row in
   the expanded flash kernel, one call a layer beside the decode rows'
   absorbed one, with none of the absorbed form's 84 MB query relayout
-  and f32 output around it; no other program holds the call (PR 50).
+  and f32 output around it; no other program holds the call (PR 50);
+- DeepSeek-V3's programs move only the rows their share holds around
+  the grouped matmul: the two row kernels a layer, and no XLA op that
+  writes a ``[padded_rows(N * K), d_model]`` array; no other
+  configuration's program holds either kernel (PR 51).
 """
 
 import json
@@ -229,3 +233,42 @@ def test_a_latent_prompt_row_attends_in_the_expanded_flash_kernel(
     assert not [o for o in shapes for dt, dims in o[1]
                 if dims[-2:] == (65536, 512)]
     assert not offenders_of(text, config)
+
+
+@pytest.mark.parametrize("kind", ["decodek", "mixed"])
+@pytest.mark.parametrize("name", STEP_CONFIGS + EXPERT_CONFIGS[1:])
+def test_a_share_moves_only_its_rows_around_the_grouped_matmul(
+        one_v5e, name, kind):
+    """``deepseek-v3-ep16-share`` holds 16 of 256 experts: each expert
+    layer of its step programs holds ONE ``expert-rows-gather`` and ONE
+    ``expert-rows-combine`` call (ops/expert_rows.py) and, but for the
+    grouped matmul's own output, nothing writes an array of all N * K
+    sorted rows at the model's width — the parent's gather, mask and
+    un-sort, 60.6 MB each a layer of a 512-token step, are gone. The
+    configurations that hold every expert (or none) hold neither
+    kernel: their dispatch is XLA's, as before."""
+    from localai_tfp_tpu.ops import expert_rows as er
+    from localai_tfp_tpu.ops import grouped_matmul as gm
+    from tools.step_hlo import loop_bodies
+
+    config, compiled = _step_program(one_v5e, name, kind)
+    text = compiled.as_text()
+    if name != "deepseek-v3-ep16-share":
+        assert er.GATHER_NAME not in text and er.COMBINE_NAME not in text
+        return
+    bodies, _ = loop_bodies(text)
+    tokens = 16 if kind == "decodek" else 16 + 512
+    wide = (gm.padded_rows(tokens * config["num_experts_per_tok"]),
+            config["hidden_size"])
+    layers = 0
+    for body in bodies:
+        names = [op.name for op in body.ops]
+        gathers = [n for n in names if n.startswith(er.GATHER_NAME)]
+        combines = [n for n in names if n.startswith(er.COMBINE_NAME)]
+        assert len(gathers) == len(combines) <= 1, names
+        layers += len(gathers)
+        for op in body.ops:
+            if any(dims[-2:] == wide for _, dims in op.shapes):
+                assert op.name.startswith(
+                    (er.GATHER_NAME, gm.KERNEL_NAME)), op.line[:200]
+    assert layers == 1  # the expert stack's layer loop

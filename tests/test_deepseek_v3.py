@@ -731,6 +731,43 @@ def test_engine_serves_it_on_the_latent_kernel_route(monkeypatch, tiny):
         eng.close()
 
 
+@pytest.mark.parametrize("on_the_kernel", [False, True],
+                         ids=["xla_dispatch", "held_rows_dispatch"])
+def test_dispatch_rows_are_counted_moved_and_slots(monkeypatch, tiny,
+                                                   on_the_kernel):
+    """engine_expert_dispatch_rows_total{kind}: ``slots`` the sorted
+    rows a step's arrays hold (token rows x K a layer-step, padding
+    too), ``moved`` what the dispatch around the grouped matmul
+    touched — every slot where XLA gathers, masks and un-sorts (the
+    CPU: ``expert_path`` is ``ragged_dot``), the held assignments where
+    a share rides the grouped kernel (``expert_path`` as a chip reports
+    it; the counts are the served programs' own either way)."""
+    m = f"dsv3-rows-{int(on_the_kernel)}"
+    eng = _serve(monkeypatch, tiny, tag=m)
+    try:
+        assert eng.expert_path == "ragged_dot"
+        if on_the_kernel:
+            eng.expert_path = "grouped_kernel"
+        prompt = [int(t) for t in np.random.default_rng(9).integers(
+            0, 257, 21)]
+        _generate(eng, prompt, n=6)
+        fam = "engine_expert_dispatch_rows_total"
+        moved, slots = (_value(fam, model=m, kind=k)
+                        for k in ("moved", "slots"))
+        held = _value("engine_expert_assignments_total", model=m,
+                      where="held")
+        absent = _value("engine_expert_assignments_total", model=m,
+                        where="absent")
+        padded = _value("engine_dispatch_tokens_total", model=m,
+                        part="padded")
+        # 2 expert layers, top-4: every padded token row is 4 slots a layer
+        assert slots == padded * 4 * 2 >= held + absent > 0
+        assert moved == (held if on_the_kernel else slots)
+        assert 0 < held < slots
+    finally:
+        eng.close()
+
+
 def test_prompt_tokens_are_counted_by_the_form_of_their_step(
         monkeypatch, tiny):
     """engine_latent_prompt_tokens_total{form}, at the dispatch site: a
@@ -792,6 +829,43 @@ def test_the_benchmarks_reader_of_the_counter(before, after, want):
         "name": "latent_prompt_expanded_share", "unit": "%",
         "better": "higher", "source": "program_counter",
         "layer": "attention kernel", "moves": "tpot_p50_ms",
+        "workloads": ["deepseekv3_docs_closed"]}
+
+
+@pytest.mark.parametrize("before,after,want", [
+    ({"moved": 0.0, "slots": 0.0}, {"moved": 285.0, "slots": 4224.0},
+     100 * 285 / 4224),
+    ({"moved": 50.0, "slots": 50.0}, {"moved": 4274.0, "slots": 4274.0},
+     100.0),
+    (None, None, None),
+], ids=["the_held_rows_alone", "every_row_by_xla", "the_parent"])
+def test_the_benchmarks_reader_of_the_dispatch_rows(before, after, want):
+    """``expert_dispatch_rows_share`` (a ``.json`` ratio reader, listed
+    for ``deepseekv3_docs_closed`` alone under the expert layer): the
+    window's DELTA of the rows the dispatch moved over the slots its
+    arrays hold, in percent; a program without the counter — the parent
+    — has nothing to read."""
+    from benchmark.lib import layer_metrics, manifest
+
+    def scrape(d):
+        fams = {"engine_dispatch_tokens_total": [
+            ({"model": "m", "kind": "mixed", "part": "real"}, 1.0)]}
+        if d is not None:
+            fams["engine_expert_dispatch_rows_total"] = [
+                ({"model": "m", "kind": k}, v) for k, v in d.items()]
+        return fams
+
+    run = {"metrics_before": scrape(before), "metrics_after": scrape(after)}
+    got = layer_metrics.evaluate(
+        os.path.join(ROOT, "benchmark", "layer_metrics"),
+        "expert_dispatch_rows_share", None, run)
+    assert got == (want if want is None else pytest.approx(want))
+    entry = next(m for m in manifest.load(ROOT)["per_layer"]
+                 if m["name"] == "expert_dispatch_rows_share")
+    assert entry == {
+        "name": "expert_dispatch_rows_share", "unit": "%",
+        "better": "lower", "source": "program_counter",
+        "layer": "expert layer", "moves": "tpot_p50_ms",
         "workloads": ["deepseekv3_docs_closed"]}
 
 
