@@ -172,9 +172,6 @@ _knob("LOCALAI_NATIVE_STORE", "on", "flag",
       "Use the native vector store when built.")
 
 # ---------------------------------------------------------------- quant
-_knob("LOCALAI_INT8_KERNEL", "off", "flag",
-      "Fused Pallas dequant-matmul inside the decode scan "
-      "(experimental; off = XLA upcast).")
 _knob("LOCALAI_QUANT_ARTIFACTS", "on", "flag",
       "Persist/reuse int8 quantization artifacts on disk.")
 _knob("LOCALAI_QUANT_CACHE_DIR", "", "str",

@@ -131,7 +131,7 @@ class RaggedRoute(_PoolRoute):
     runs in its absorbed form (``v_lanes``; a capture names it
     ``latent_paged_attention``): the route only says, by its name, that
     the pages are latent — which form attends them is the forward's
-    business (models/transformer.py ``latent_ragged``)."""
+    business (models/cache_attention.py ``latent_ragged``)."""
 
     def __init__(self, max_seq: int, page: int, mesh: Any,
                  latent: bool = False) -> None:

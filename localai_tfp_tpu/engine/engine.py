@@ -614,12 +614,8 @@ class LLMEngine:
             n_slots, spec.vocab_size, window=penalty_window
         )
         if mesh is not None:
-            from ..models import quant
             from ..parallel.sharding import shard_engine_state, shard_params
 
-            # GSPMD cannot partition the fused int8 pallas call; meshed
-            # serving takes the XLA dequant path (models/quant.py)
-            quant.set_meshed_serving(True)
             self.params = shard_params(self.params, mesh)
             self.cache, self.sampling = shard_engine_state(
                 self.cache, self.sampling, mesh, paged=self._paged
@@ -2246,12 +2242,6 @@ class LLMEngine:
         tm.ENGINE_MFU.labels(model=self._mlabel).set(0.0)
         if self._ledger is not None:
             self._ledger.reset_gauges()
-        if self.mesh is not None:
-            # release the process-wide meshed gate so a later unmeshed
-            # engine regains the fused int8 kernel (single-owner rule)
-            from ..models import quant
-
-            quant.set_meshed_serving(False)
 
     def _active_exemplar(self) -> Optional[dict]:
         """Exemplar labels for a batch-level latency sample: the first

@@ -17,8 +17,6 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..config import knobs
-
 
 class QTensor(NamedTuple):
     """int8 weight + per-output-channel scale. A NamedTuple, so it is a
@@ -98,47 +96,10 @@ def quantize_params(params: dict[str, Any],
     return out
 
 
-_MESHED_SERVING = False  # set by the engine when params are GSPMD-
-# sharded: the pallas custom call is not partitionable by GSPMD (it
-# would need a shard_map wrapper), so meshed serving stays on the XLA
-# path. Process-global is safe under the single-TPU-owner convention
-# (engine/loader.py enforces one active backend).
-
-
-def set_meshed_serving(flag: bool) -> None:
-    global _MESHED_SERVING
-    _MESHED_SERVING = flag
-
-
-def _kernel_enabled() -> bool:
-    import os
-
-    if _MESHED_SERVING:
-        return False
-    # default OFF: standalone the fused kernel beats XLA's upcast by
-    # 20%, but INSIDE the per-layer decode scan its per-grid-step
-    # overhead compounds (measured 8B serving: 588 vs 703 tok/s) — the
-    # next iteration is a whole-layer fusion; opt in to experiment
-    return knobs.flag("LOCALAI_INT8_KERNEL")
-
-
 def mm(x: jax.Array, w: Any):
-    """x @ w for plain arrays OR QTensor.
-
-    QTensor path: the fused Pallas dequant-matmul when shapes qualify
-    (weight traffic stays 1 byte/elem — XLA's inline upcast measured 5x
-    off the weight-read roofline at 8B scale); XLA upcast otherwise."""
+    """x @ w for plain arrays OR QTensor (int8 read and upcast inline,
+    one multiply by the per-channel scale on the output)."""
     if isinstance(w, QTensor):
-        from ..ops.int8_matmul import eligible, int8_matmul
-
-        lead = x.shape[:-1]
-        m = 1
-        for d in lead:
-            m *= d
-        if _kernel_enabled() and eligible(m, w.q.shape):
-            y = int8_matmul(x.reshape(m, x.shape[-1]), w.q, w.scale,
-                            out_dtype=x.dtype)
-            return y.reshape(*lead, w.q.shape[-1])
         y = x @ w.q.astype(x.dtype)
         return y * w.scale.astype(x.dtype)
     return x @ w

@@ -31,6 +31,11 @@ from jax import lax
 
 from ..ops import expert_rows as er
 from ..ops import grouped_matmul as gm
+from . import cache_attention as ca
+from .cache_attention import (  # noqa: F401  (their home; imported here)
+    _attend, _quantize_rows, latent_absorb_out, latent_absorb_query,
+    latent_attend_expanded, latent_scale,
+)
 from .llm_spec import LLMSpec
 from .quant import QTensor as _QTensor
 from .quant import mm as _mm  # plain or int8-QTensor matmul
@@ -153,14 +158,6 @@ jax.tree_util.register_pytree_node(
     lambda c: ((c.k, c.v, c.k_scale, c.v_scale, c.state, c.conv), None),
     lambda _, ch: KVCache(*ch),
 )
-
-
-def _quantize_rows(x: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """[..., F] -> (int8 rows, per-row f32 scales)."""
-    xf = x.astype(jnp.float32)
-    scale = jnp.max(jnp.abs(xf), axis=-1) / 127.0 + 1e-8
-    q = jnp.clip(jnp.round(xf / scale[..., None]), -127, 127)
-    return q.astype(jnp.int8), scale
 
 
 # ---------------------------------------------------------------------------
@@ -477,51 +474,6 @@ def apply_rope(
     return jnp.concatenate([out.astype(x.dtype), keep], axis=-1)
 
 
-def _attend(
-    spec: LLMSpec,
-    q: jax.Array,  # [B, T, H, Dh]
-    k: jax.Array,  # [B, S, Hkv, Dh]
-    v: jax.Array,  # [B, S, Hkv, Dh]
-    q_pos: jax.Array,  # [B, T] absolute positions of queries
-    window: Optional[jax.Array] = None,  # per-layer scalar; 0/neg = full
-    # (gemma2 alternates sliding/global layers — traced through the scan)
-) -> jax.Array:
-    B, T, H, Dh = q.shape
-    S = k.shape[1]
-    group = H // spec.n_kv_heads
-    scale = (
-        1.0 / math.sqrt(spec.query_pre_attn_scalar)
-        if spec.query_pre_attn_scalar
-        else 1.0 / math.sqrt(Dh)
-    )
-    # bf16 operands ride the MXU natively; fp32 operands (tests) must not be
-    # silently truncated to bf16, hence HIGHEST. Accumulation is fp32 either
-    # way via preferred_element_type — flash-attention-style numerics.
-    prec = lax.Precision.HIGHEST if q.dtype == jnp.float32 else lax.Precision.DEFAULT
-    qg = q.reshape(B, T, spec.n_kv_heads, group, Dh)
-    logits = jnp.einsum(
-        "btkgd,bskd->bktgs", qg, k,
-        preferred_element_type=jnp.float32, precision=prec,
-    ) * scale  # [B, Hkv, T, group, S]
-    if spec.attn_logit_softcap:
-        cap = spec.attn_logit_softcap
-        logits = jnp.tanh(logits / cap) * cap
-    kv_pos = lax.broadcasted_iota(jnp.int32, (1, 1, 1, 1, S), 4)
-    qp = q_pos[:, None, :, None, None]  # [B,1,T,1,1]
-    mask = kv_pos <= qp
-    if window is not None:
-        mask &= (window <= 0) | (kv_pos > qp - window)
-    elif spec.sliding_window and not spec.sliding_window_pattern:
-        mask &= kv_pos > qp - spec.sliding_window
-    logits = jnp.where(mask, logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum(
-        "bktgs,bskd->btkgd", probs.astype(v.dtype), v,
-        preferred_element_type=jnp.float32, precision=prec,
-    )
-    return out.reshape(B, T, H * Dh).astype(q.dtype)
-
-
 def _act(spec: LLMSpec, x: jax.Array) -> jax.Array:
     if spec.hidden_act == "silu":
         return jax.nn.silu(x)
@@ -630,12 +582,6 @@ def _layer_body(spec, x, lp, positions, inv_freq, rope_scale, attn_fn,
     return out, carry, counts
 
 
-def latent_scale(spec) -> float:
-    """Softmax scale of latent attention: (d_n + d_r)^-1/2, times the
-    YaRN mscale^2 where the model puts it there."""
-    return spec.attn_scale_mult / math.sqrt(spec.d_head)
-
-
 def _latent_row(spec, c, kr):
     """What a token caches, and nothing else: [c (normed) | k_r (rotated)
     | 0...] up to ``spec.latent_row`` lanes. c [B, T, r], kr [B, T, 1,
@@ -682,58 +628,7 @@ def _latent_mixer(spec, lp, h, positions, inv_freq, rope_scale, attn_fn):
     return _mm(heads, lp["wo"]), carry
 
 
-def _prec(x):
-    return (lax.Precision.HIGHEST if x.dtype == jnp.float32
-            else lax.Precision.DEFAULT)
-
-
-def latent_attend_expanded(spec, lp, qn, qr, rows, q_pos):
-    """The EXPANDED form: cached rows [B, S, latent_row] up-projected
-    through W_kvb into every head's k_n [S, H, d_n] and v [S, H, d_v],
-    then causal attention at (d_n + d_r) x d_v a head — what the
-    published description computes, and the engine's XLA route.
-    q_pos [B, T]: the queries' absolute positions. -> [B, T, H * d_v]."""
-    r, dr = spec.kv_lora_rank, spec.qk_rope_dim
-    B, T = qn.shape[0], qn.shape[1]
-    c, kr = rows[..., :r], rows[..., r:r + dr]
-    prec = _prec(qn)
-    kn = jnp.einsum("bsc,hnc->bshn", c, lp["wkv_b_k"], precision=prec)
-    v = jnp.einsum("bsc,hcv->bshv", c, lp["wkv_b_v"], precision=prec)
-    logits = (jnp.einsum("bthn,bshn->bhts", qn, kn, precision=prec,
-                         preferred_element_type=jnp.float32)
-              + jnp.einsum("bthr,bsr->bhts", qr, kr, precision=prec,
-                           preferred_element_type=jnp.float32)
-              ) * latent_scale(spec)
-    kv_pos = lax.broadcasted_iota(jnp.int32, (1, 1, 1, rows.shape[1]), 3)
-    logits = jnp.where(kv_pos <= q_pos[:, None, :, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhts,bshv->bthv", probs.astype(v.dtype), v,
-                     precision=prec, preferred_element_type=jnp.float32)
-    return out.reshape(B, T, -1).astype(qn.dtype)
-
-
-def latent_absorb_query(spec, lp, qn, qr):
-    """The ABSORBED form's query: q~_h = W_kvb,k,h^T q_n,h beside q_r
-    and the row's zero lanes -> [B, T, H, latent_row]; its score against
-    a cached row is the expanded form's score."""
-    qa = jnp.einsum("bthn,hnc->bthc", qn, lp["wkv_b_k"],
-                    precision=_prec(qn)).astype(qn.dtype)
-    pad = spec.latent_row - spec.latent_width
-    return jnp.concatenate(
-        [qa, qr, jnp.zeros((*qr.shape[:3], pad), qr.dtype)], axis=-1)
-
-
-def latent_absorb_out(spec, lp, ctx, dtype):
-    """The absorbed form's output: sum_s p_s c_s [B, T, H, r] through
-    each head's W_kvb,v -> [B, T, H * d_v]."""
-    out = jnp.einsum("bthc,hcv->bthv", ctx.astype(dtype), lp["wkv_b_v"],
-                     precision=_prec(lp["wkv_b_v"]),
-                     preferred_element_type=jnp.float32)
-    return out.reshape(*out.shape[:2], -1).astype(dtype)
-
-
-def _linear_mixer(spec, lp, h, *, groups, split, join, valid_of, st,
-                  l_lin):
+def _linear_mixer(spec, lp, h, *, groups, st, l_lin):
     """The token mixer of ONE linear-attention layer — the gated delta
     rule (ops/gated_delta.py) — over the rows of every group:
 
@@ -764,8 +659,9 @@ def _linear_mixer(spec, lp, h, *, groups, split, join, valid_of, st,
     w_conv = lp["conv_w"].astype(f32)  # [conv_dim, taps]
     parts = (("wq", 0, H * dk), ("wk", H * dk, H * dk),
              ("wv", 2 * H * dk, H * dv))
-    proj = [split(_mm(h, lp[name])) for name, _, _ in parts]
-    gate, a_raw, b_raw = (split(_mm(h, lp[k])) for k in ("wg", "wa", "wb"))
+    proj = [ca.ungroup(groups, _mm(h, lp[name])) for name, _, _ in parts]
+    gate, a_raw, b_raw = (ca.ungroup(groups, _mm(h, lp[k]))
+                          for k in ("wg", "wa", "wb"))
     decay = -jnp.exp(lp["a_log"].astype(f32))
     outs = []
     for i, g in enumerate(groups):
@@ -774,7 +670,7 @@ def _linear_mixer(spec, lp, h, *, groups, split, join, valid_of, st,
                g.slot_ids if g.slot_ids is not None else
                jnp.arange(B, dtype=jnp.int32))
         rd = jnp.minimum(ids, ns - 1)  # a pad row's sentinel: any slot
-        ok = valid_of(g)
+        ok = ca.valid_of(g)
         n_ok = jnp.sum(ok, axis=1, dtype=jnp.int32)  # a prefix of T
         fresh = (g.pos0 == 0) & (n_ok > 0)
         c0 = jnp.where(fresh[:, None, None], 0, conv[l_lin, rd])
@@ -821,7 +717,27 @@ def _linear_mixer(spec, lp, h, *, groups, split, join, valid_of, st,
                           + spec.norm_eps) * lp["o_norm_w"].astype(f32)
         o = o * jax.nn.silu(gate[i].astype(f32).reshape(B, T, H, dv))
         outs.append(o.reshape(B, T, H * dv).astype(h.dtype))
-    return _mm(join(outs), lp["wo"]), (state, conv)
+    return _mm(ca.flatten(outs), lp["wo"]), (state, conv)
+
+
+def _linear_period(spec, x, lin, li, groups, positions, inv_freq,
+                   rope_scale, rec):
+    """The linear layers of ONE period of a hybrid model, which steps
+    over periods: ``lin`` holds every linear layer's leaves
+    (``LayerStack.linear``), this period's at ``li * linear_period``
+    onwards; each advances the recurrent state ``rec`` = (state, conv)
+    the one before (and the group before) left. The period's full layer
+    follows in the caller. -> (x, rec)."""
+    per = spec.linear_period
+    for j in range(per):
+        ljp = {k: lax.dynamic_index_in_dim(v, li * per + j, 0,
+                                           keepdims=False)
+               for k, v in lin.items()}
+        x, rec, _ = _layer_body(
+            spec, x, ljp, positions, inv_freq, rope_scale, None,
+            mixer=partial(_linear_mixer, spec, ljp, groups=groups,
+                          st=tuple(rec), l_lin=li * per + j))
+    return x, rec
 
 
 def _route(spec, lp, x):
@@ -1092,19 +1008,28 @@ def _layer_rope_on(spec):
     return jnp.asarray([1 if s else 0 for s in sliding], jnp.int32)
 
 
+class LayerStack(NamedTuple):
+    """One homogeneous stack of layers, scanned in one ``lax.scan``."""
+
+    first: int  # index of its first layer in the KV cache's planes
+    n: int  # its layers (a hybrid model's: its PERIODS — one
+    # full-attention layer each, the cache's count)
+    scanned: dict  # {leaf: [n, ...]} the scan slices a layer out of
+    experts: dict  # {leaf: [n, E, ...]} an expert stack's matrices,
+    # never sliced (``_moe_mlp``); {} for any other stack
+    linear: dict  # {leaf: [n_linear_layers, ...]} a hybrid model's
+    # linear layers, without the prefix (``_linear_period`` indexes a
+    # period's layers in them); {} for any other stack
+
+
 def layer_stacks(spec, params) -> list:
-    """The model's homogeneous layer stacks in layer order, each as
-    (index of its first layer, its layers n, {leaf: [n, ...]} to scan
-    over, {leaf: [n, ...]} kept whole — an expert stack's expert
-    matrices, ``_moe_mlp``; a hybrid model's linear layers): the
-    leading dense-MLP layers of an expert model (``DENSE_STACK``
-    leaves) when it has them, then every other layer. What differs by
-    layer INSIDE a stack rides along as per-layer values (``_window``,
-    ``_inv_freq``, ``_rope_on``, ``_dense_only``), sliced to the
-    stack's layers. A hybrid model (``LINEAR_STACK`` leaves) is one
-    stack whose steps are PERIODS, first layer index and layer count
-    in full-attention layers (the KV cache's); ``whole`` holds its
-    linear layers' leaves without the prefix."""
+    """The model's homogeneous layer stacks in layer order
+    (``LayerStack``): the leading dense-MLP layers of an expert model
+    (``DENSE_STACK`` leaves) when it has them, then every other layer.
+    What differs by layer INSIDE a stack rides along as per-layer
+    values (``_window``, ``_inv_freq``, ``_rope_on``, ``_dense_only``),
+    sliced to the stack's layers. A hybrid model (``LINEAR_STACK``
+    leaves) is one stack whose steps are PERIODS."""
     per_layer = {"_window": _layer_windows(spec),
                  "_inv_freq": _layer_inv_freqs(spec),
                  "_rope_on": _layer_rope_on(spec),
@@ -1121,17 +1046,17 @@ def layer_stacks(spec, params) -> list:
         # before the first matmul reads them: 3 x the bytes)
         lin = {k[len(LINEAR_STACK):]: main.pop(k) for k in list(main)
                if k.startswith(LINEAR_STACK)}
-        return [(0, spec.n_kv_layers, main, lin)]
+        return [LayerStack(0, spec.n_kv_layers, main, {}, lin)]
     lead = {k[len(DENSE_STACK):]: params[k] for k in params
             if k.startswith(DENSE_STACK)}
     n_lead = spec.n_dense_layers if lead else 0
-    whole = {k: main.pop(k) for k in EXPERT_LEAVES if k in main}
+    experts = {k: main.pop(k) for k in EXPERT_LEAVES if k in main}
     stacks = []
     if lead:
-        stacks.append((0, n_lead, {**lead, **{
-            k: v[:n_lead] for k, v in per_layer.items()}}, {}))
-    stacks.append((n_lead, spec.n_layers - n_lead, {**main, **{
-        k: v[n_lead:] for k, v in per_layer.items()}}, whole))
+        stacks.append(LayerStack(0, n_lead, {**lead, **{
+            k: v[:n_lead] for k, v in per_layer.items()}}, {}, {}))
+    stacks.append(LayerStack(n_lead, spec.n_layers - n_lead, {**main, **{
+        k: v[n_lead:] for k, v in per_layer.items()}}, experts, {}))
     return stacks
 
 
@@ -1179,18 +1104,44 @@ def _lm_head(spec, params, x):
 
 
 class Rows(NamedTuple):
-    """One rectangular group of query rows of a forward pass: what
-    ``forward_hidden`` takes per row (see there), bundled so that a
-    pass can carry more than one rectangle (``forward_rows``)."""
+    """One rectangular group of query rows of a forward pass, bundled
+    so that a pass can carry more than one rectangle (``forward_rows``).
+    Serves both phases: prefill passes T=chunk, decode passes T=1 with
+    the full slot batch. The new K/V go into the cache at rows
+    ``slot_ids``, columns ``pos0 + [0..T)``."""
 
     tokens: jax.Array  # [B, T] int32
-    pos0: jax.Array  # [B] int32
-    slot_ids: Optional[jax.Array] = None
-    soft: Optional[tuple] = None
-    write_mask: Optional[jax.Array] = None
-    page_table: Optional[jax.Array] = None
-    q_lens: Optional[jax.Array] = None
-    write_table: Optional[jax.Array] = None
+    pos0: jax.Array  # [B] int32: absolute position of tokens[:, 0]
+    slot_ids: Optional[jax.Array] = None  # [B] i32 cache row per batch
+    # row; None => identity (row b == slot b), the batched-decode hot path
+    soft: Optional[tuple] = None  # multimodal: (embeds [B,T,D],
+    # mask [B,T]) — rows where mask is True REPLACE the token embedding
+    # (post-multiplier, matching HF's masked_scatter of image features)
+    write_mask: Optional[jax.Array] = None  # [B] bool (identity path
+    # only): rows where False RE-WRITE the cache content already at
+    # their write positions — a no-op write. Lets a full-slot-batch
+    # identity prefill park non-member rows at pos 0 without corrupting
+    # their live prefixes, which in turn lets the dispatch window follow
+    # the MEMBER rows' live context instead of max_seq.
+    page_table: Optional[jax.Array] = None  # the engine's RAGGED
+    # route (engine/cache_route.py), and only it, sets this (with
+    # kv_page, q_lens and write_table): ``cache`` is the [L, n_pages,
+    # page, F] arena and this [B, max_pages] int32 table maps each
+    # row's logical page index to its physical arena page. The paged
+    # XLA route instead gathers a dense view OUTSIDE the forward
+    # (gather_kv_pages/scatter_kv_pages), so it never sees the arena.
+    q_lens: Optional[jax.Array] = None  # [B] i32 per-row valid token
+    # counts — 1 for decode rows, the chunk length for prefill rows, kd
+    # for spec-decode verify rows. Every row kind flows through ONE
+    # ragged-paged-attention kernel invocation per layer
+    # (models/cache_attention.py ``ragged``): the chunk's K/V rows
+    # scatter into the arena through ``write_table`` (no gathered
+    # window view) and attention walks each row's pages raggedly.
+    write_table: Optional[jax.Array] = None  # [B, max_pages] i32
+    # physical WRITE pages per logical page (ragged mode): entries the
+    # host did not grant (shared prefix pages, parked rows, pages
+    # outside the dispatch's span) point at the trash page, so a
+    # dispatch persists exactly its own writes.
     state_slots: Optional[jax.Array] = None  # [B] i32: the slot whose
     # recurrent state a row advances (linear-attention layers) where
     # the cache route took ``slot_ids`` for its own use; an id beyond
@@ -1205,62 +1156,27 @@ class Rows(NamedTuple):
 def forward_hidden(
     spec: LLMSpec,
     params: Params,
-    tokens: jax.Array,  # [B, T] int32
-    pos0: jax.Array,  # [B] int32: absolute position of tokens[:, 0]
+    tokens: jax.Array,
+    pos0: jax.Array,
     cache: KVCache,
-    slot_ids: Optional[jax.Array],  # [B] i32 cache row per batch row;
-    # None => identity (row b == slot b), the batched-decode hot path
-    decode_kernel: bool = False,  # T==1 identity path via Pallas paged
-    # append/attend kernels (ragged cache reads; ops/decode_attention.py)
-    soft: Optional[tuple] = None,  # multimodal: (embeds [B,T,D],
-    # mask [B,T]) — rows where mask is True REPLACE the token embedding
-    # (post-multiplier, matching HF's masked_scatter of image features)
-    mesh: Any = None,  # serving mesh: the decode kernel runs per-shard
-    # under shard_map (attention is GQA-head-local over the "model" axis)
-    ring_prefill: bool = False,  # long-prompt FIRST-chunk prefill on a
-    # seq-sharded mesh: attention runs as ring attention over the "seq"
-    # axis (parallel/ring_attention.py) — O(T/n) attention memory and
-    # ICI-overlapped KV rotation instead of a [B, H, T, T] score tensor.
-    # Caller contract: mesh has a nontrivial "seq" axis, every row's
-    # pos0 is 0 (the chunk attends only to itself), no sliding window,
-    # and T divides the seq axis.
-    write_mask: Optional[jax.Array] = None,  # [B] bool (identity path
-    # only): rows where False RE-WRITE the cache content already at
-    # their write positions — a no-op write. Lets a full-slot-batch
-    # identity prefill park non-member rows at pos 0 without corrupting
-    # their live prefixes, which in turn lets the dispatch window follow
-    # the MEMBER rows' live context instead of max_seq.
-    page_table: Optional[jax.Array] = None,  # the engine's RAGGED
-    # route (engine/cache_route.py), and only it, sets this (with
-    # kv_page, q_lens and write_table): ``cache`` is the [L, n_pages,
-    # page, F] arena and this [B, max_pages] int32 table maps each
-    # row's logical page index to its physical arena page. The paged
-    # XLA route instead gathers a dense view OUTSIDE this function
-    # (gather_kv_pages/scatter_kv_pages), so it never sees the arena.
-    kv_page: int = 0,  # pool page size (tokens) when page_table is set
-    q_lens: Optional[jax.Array] = None,  # per-row valid token counts —
-    # 1 for decode rows, the chunk length for prefill rows, kd for
-    # spec-decode verify rows. Every row kind flows through ONE
-    # ragged-paged-attention kernel invocation per layer
-    # (ops/ragged_paged_attention.py): the chunk's K/V rows scatter
-    # into the arena through ``write_table`` (no gathered window view)
-    # and attention walks each row's pages raggedly.
-    write_table: Optional[jax.Array] = None,  # [B, max_pages] i32
-    # physical WRITE pages per logical page (ragged mode): entries the
-    # host did not grant (shared prefix pages, parked rows, pages
-    # outside the dispatch's span) point at the trash page, so a
-    # dispatch persists exactly its own writes.
-    state_slots: Optional[jax.Array] = None,  # Rows.state_slots
+    slot_ids: Optional[jax.Array],
+    decode_kernel: bool = False,
+    soft: Optional[tuple] = None,
+    mesh: Any = None,
+    ring_prefill: bool = False,
+    write_mask: Optional[jax.Array] = None,
+    page_table: Optional[jax.Array] = None,
+    kv_page: int = 0,
+    q_lens: Optional[jax.Array] = None,
+    write_table: Optional[jax.Array] = None,
+    state_slots: Optional[jax.Array] = None,
 ) -> tuple[jax.Array, KVCache]:
-    """Run the stack up to (and including) the final norm; returns
-    (hidden [B, T, D], updated cache). The LM head lives in ``forward``;
-    this entry is the embeddings path (ref: transformers backend mean-pool,
-    backend/python/transformers/backend.py:286-324).
-
-    Serves both phases: prefill passes T=chunk, decode passes T=1 with the
-    full slot batch. Writes the new K/V into ``cache`` at rows ``slot_ids``
-    columns ``pos0 + [0..T)``.
-    """
+    """Run the stack up to (and including) the final norm over ONE
+    rectangle of rows; returns (hidden [B, T, D], updated cache). The LM
+    head lives in ``forward``; this entry is the embeddings path (ref:
+    transformers backend mean-pool,
+    backend/python/transformers/backend.py:286-324). The per-row
+    arguments are ``Rows``' fields, the others ``forward_rows``'."""
     (x,), cache, _ = forward_rows(
         spec, params,
         (Rows(tokens, pos0, slot_ids, soft, write_mask, page_table,
@@ -1270,16 +1186,44 @@ def forward_hidden(
     return x, cache
 
 
+def _embed_rows(spec, params, g):
+    x = _embed_in(spec, params, g.tokens)  # gather: [B, T, D]
+    if g.soft is not None:
+        emb, emb_mask = g.soft
+        x = jnp.where(emb_mask[..., None], emb.astype(x.dtype), x)
+    return x
+
+
+def _expert_totals(spec, counts):
+    """An expert stack's per-layer counts [n, E] (a share: [n, E + 1],
+    see ``_moe_mlp``) as ``forward_rows`` reports them."""
+    absent = ()
+    if spec.experts_held:
+        absent = (jnp.sum(counts[:, -1])[None],)
+        counts = counts[:, :-1]
+    return jnp.concatenate([
+        jnp.sum(counts, axis=0),
+        jnp.sum(counts > 0, dtype=jnp.int32)[None], *absent])
+
+
 def forward_rows(
     spec: LLMSpec,
     params: Params,
     groups: tuple,  # of Rows
     cache: KVCache,
     *,
-    decode_kernel: bool = False,
-    mesh: Any = None,
-    ring_prefill: bool = False,
-    kv_page: int = 0,
+    decode_kernel: bool = False,  # one token a row in slot order on
+    # the dense cache takes the Pallas decode kernel (``ca.select``)
+    mesh: Any = None,  # serving mesh: the kernels run per-shard under
+    # shard_map (attention is GQA-head-local over the "model" axis)
+    ring_prefill: bool = False,  # long-prompt FIRST-chunk prefill on a
+    # seq-sharded mesh: attention runs as ring attention over the "seq"
+    # axis (parallel/ring_attention.py) — O(T/n) attention memory and
+    # ICI-overlapped KV rotation instead of a [B, H, T, T] score tensor.
+    # Caller contract: mesh has a nontrivial "seq" axis, every row's
+    # pos0 is 0 (the chunk attends only to itself), no sliding window,
+    # and T divides the seq axis.
+    kv_page: int = 0,  # pool page size (tokens) when page_table is set
 ) -> tuple[tuple, KVCache, Optional[jax.Array]]:
     """``forward_hidden`` for one or more rectangles of rows in ONE
     pass: returns (one hidden [B, T, D] per group, updated cache,
@@ -1294,504 +1238,58 @@ def forward_rows(
     for all of them, which is the point: a step that decodes
     ``[n_slots, 1]`` rows and admits ``[R, bucket]`` prompt rows costs
     one weight read, not two — and only attention runs per group, in
-    order, each on the cache the group before it left. Every group
-    reaches the cache the same way (all ragged, or all through the XLA
-    contraction); a row's arithmetic is that of the same row in a pass
-    of its own."""
+    order, each on the cache the group before it left
+    (``ca.attend_groups``). Every group reaches the cache the same way,
+    chosen once (``ca.select``: one of models/cache_attention.py's five
+    routes); a row's arithmetic is that of the same row in a pass of
+    its own."""
     single = len(groups) == 1
-    g0 = groups[0]
-
-    def ungroup(a):
-        # [1, N, ...] back into each group's [B, T, ...] rectangle
-        out, lo = [], 0
-        for g in groups:
-            b, t = g.tokens.shape
-            out.append(a[0, lo:lo + b * t].reshape(b, t, *a.shape[2:]))
-            lo += b * t
-        return out
-
-    def embed(g):
-        x = _embed_in(spec, params, g.tokens)  # gather: [B, T, D]
-        if g.soft is not None:
-            emb, emb_mask = g.soft
-            x = jnp.where(emb_mask[..., None], emb.astype(x.dtype), x)
-        return x
-
-    def positions_of(g):
-        return g.pos0[:, None] + jnp.arange(
-            g.tokens.shape[1], dtype=jnp.int32)[None, :]
-
-    if single:
-        x = embed(g0)
-        positions = positions_of(g0)
-    else:
-        xs = [embed(g) for g in groups]
-        x = jnp.concatenate(
-            [a.reshape(1, -1, a.shape[-1]) for a in xs], axis=1)
-        positions = jnp.concatenate(
-            [positions_of(g).reshape(1, -1) for g in groups], axis=1)
-    page_table = g0.page_table
+    x = ca.flatten([_embed_rows(spec, params, g) for g in groups])
+    positions = ca.flatten([ca.positions_of(g) for g in groups])
     inv_freq = rope_inv_freq(spec)
     rope_scale = rope_attn_scale(spec)
     quant = cache.quantized  # int8 rows + per-row scales
-
-    def valid_of(g):
-        # the positions of a group that carry a token: within the row's
-        # ragged length, on a live row
-        b, t = g.tokens.shape
-        ok = jnp.ones((b, t), bool)
-        if g.q_lens is not None:
-            ok &= jnp.arange(t, dtype=jnp.int32)[None] < g.q_lens[:, None]
-        if g.live is not None:
-            ok &= g.live[:, None]
-        return ok
-
     valid = None  # only an expert layer asks which rows are real
     if spec.n_experts and any(g.q_lens is not None
                               or g.live is not None for g in groups):
-        valid = (valid_of(g0) if single else jnp.concatenate(
-            [valid_of(g).reshape(1, -1) for g in groups], axis=1))
+        valid = ca.flatten([ca.valid_of(g) for g in groups])
+    route, stacked = ca.select(spec, groups, decode_kernel)
 
-    def body(whole, stack, carry, scanned):
+    def body(stack, carry, scanned):
         # cache rides as the scan CARRY (not xs/ys): XLA aliases loop
         # carries in place, so the per-layer update is a true in-place
         # write of the touched rows. As xs/ys the whole cache would be
         # copied through the ys stack every step (~GBs/step read+write at
         # serving shapes — measured 3-4x the decode roofline on v5e).
-        x, ck_all, cv_all, ks_all, vs_all, *rec = carry
+        x, planes, rec = carry[0], carry[1:5], carry[5:]
         l, li, lp = scanned
-        if rec:
-            # a hybrid model steps over PERIODS: this period's linear
-            # layers first (``whole`` holds every linear layer's
-            # leaves), each advancing the recurrent state the one
-            # before (and the group before) left, then its full layer
-            per = spec.linear_period
-            for j in range(per):
-                ljp = {k: lax.dynamic_index_in_dim(v, li * per + j, 0,
-                                                   keepdims=False)
-                       for k, v in whole.items()}
-                x, rec, _ = _layer_body(
-                    spec, x, ljp, positions, inv_freq, rope_scale, None,
-                    mixer=partial(
-                        _linear_mixer, spec, ljp, groups=groups,
-                        split=(lambda a: [a]) if single else ungroup,
-                        join=(lambda o: o[0]) if single else (
-                            lambda o: jnp.concatenate(
-                                [a.reshape(1, -1, a.shape[-1]) for a in o],
-                                axis=1)),
-                        valid_of=valid_of, st=tuple(rec),
-                        l_lin=li * per + j))
-        # a page table IS the ragged route. The layer's window rides the
-        # scan as a scalar (0 = full attention) into the kernels, a
-        # uniform one is the spec's own number
-        window = lp.get("_window", spec.sliding_window)
-        use_ragged = page_table is not None
-        use_kernel = use_ragged or (
-            decode_kernel and single and g0.slot_ids is None
-            and x.shape[1] == 1 and not spec.kv_lora_rank)
-        if use_kernel:
-            ck = cv = ks = vs = None  # kernel addresses the full cache
-        else:
-            ck = lax.dynamic_index_in_dim(ck_all, l, 0, keepdims=False)
-            cv = lax.dynamic_index_in_dim(cv_all, l, 0, keepdims=False)
-            if quant:
-                ks = lax.dynamic_index_in_dim(ks_all, l, 0, keepdims=False)
-                vs = lax.dynamic_index_in_dim(vs_all, l, 0, keepdims=False)
-            else:
-                ks = vs = None
-
-        # the three ways to the cache, each for ONE group ``g`` on the
-        # cache arrays ``st`` the group before it left (the stacked
-        # [L, ...] arrays for the kernels, this layer's slices for the
-        # XLA contraction); each returns (attn, the arrays it wrote)
-        def ragged_attn(g, st, q, k, v):
-            # Ragged unified path (ops/ragged_paged_attention.py): the
-            # chunk's K/V rows scatter into the arena through the WRITE
-            # table (positions beyond a row's q_len redirect to the
-            # trash page, as do pages the host did not grant), then ONE
-            # kernel invocation attends every row kind — decode rows,
-            # prefill chunks, spec-verify rows — walking pages through
-            # the READ table. No gathered window view is ever
-            # materialized. T == 1 keeps the decode kernel's
-            # VMEM-seeded current-row contract (an int8 cache attends
-            # the EXACT current row, not its quantized HBM copy).
-            from ..ops.ragged_paged_attention import (
-                ragged_paged_attention,
-            )
-
-            ck_all, cv_all, ks_all, vs_all = st
-            pos0, q_lens = g.pos0, g.q_lens
-            # a parked row attends nothing: at length 0 the kernel
-            # walks none of the pages under the position it carries
-            # (its K/V rows go to the trash page either way)
-            attend = (q_lens if g.live is None
-                      else jnp.where(g.live, q_lens, 0))
-            B, T = k.shape[0], k.shape[1]
-            kf = k.reshape(B, T, spec.kv_dim)
-            vf = v.reshape(B, T, spec.kv_dim)
-            rows = jnp.arange(B, dtype=jnp.int32)
-            if quant:
-                kq, ksc = _quantize_rows(kf)  # int8 [B,T,F], f32 [B,T]
-                vq, vsc = _quantize_rows(vf)
-            else:
-                kq, vq, ksc, vsc = kf, vf, None, None
-            scale = (
-                1.0 / math.sqrt(spec.query_pre_attn_scalar)
-                if spec.query_pre_attn_scalar
-                else 1.0 / math.sqrt(spec.d_head)
-            )
-            if mesh is not None:
-                # meshed serving: table-scatter append + ragged attend
-                # per-shard under shard_map — the arena's head-flat F
-                # dim is sharded over "model" (PAGED_KV_SPEC) and the
-                # quantization above already ran OUTSIDE (global
-                # per-row amax), so every model shard scatters
-                # identical scale values (sharded_append_attend's
-                # contract, extended to the paged arena)
-                from ..ops.ragged_paged_attention import (
-                    sharded_ragged_append_attend,
-                )
-
-                res = sharded_ragged_append_attend(
-                    mesh, q, kf, vf, kq, vq, ksc, vsc,
-                    ck_all, cv_all,
-                    ks_all if quant else None,
-                    vs_all if quant else None,
-                    l, g.page_table, g.write_table, pos0, attend,
-                    spec.n_kv_heads, scale=scale, page=kv_page,
-                    window=window,
-                )
-                return res[0].astype(x.dtype), tuple(res[1:])
-            tpos = pos0[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
-            wpg = g.write_table[rows[:, None], tpos // kv_page]
-            # pad positions beyond the row's ragged length write trash
-            wpg = jnp.where(
-                jnp.arange(T, dtype=jnp.int32)[None] < q_lens[:, None],
-                wpg, 0)
-            woff = tpos % kv_page
-            ck_new = ck_all.at[l, wpg, woff, :].set(
-                kq.astype(ck_all.dtype), mode="promise_in_bounds")
-            cv_new = cv_all.at[l, wpg, woff, :].set(
-                vq.astype(cv_all.dtype), mode="promise_in_bounds")
-            if quant:
-                ks_new = ks_all.at[l, wpg, woff].set(
-                    ksc, mode="promise_in_bounds")
-                vs_new = vs_all.at[l, wpg, woff].set(
-                    vsc, mode="promise_in_bounds")
-            else:
-                ks_new = vs_new = None
-            seed = ((kf[:, 0], vf[:, 0]) if T == 1 else None)
-            out = ragged_paged_attention(
-                q, ck_new, cv_new, l, g.page_table, pos0, attend,
-                spec.n_kv_heads, scale=scale, page=kv_page,
-                window=window,
-                cache_k_scale=ks_new, cache_v_scale=vs_new,
-                seed_kv=seed,
-            )  # [B, T, H*Dh]
-            if quant:
-                return (out.astype(x.dtype),
-                        (ck_new, cv_new, ks_new, vs_new))
-            return out.astype(x.dtype), (ck_new, cv_new)
-
-        def kernel_attn(g, st, q, k, v):
-            # Fused Pallas path: the current K/V rows are appended via an
-            # in-place scatter on the scan-CARRIED full cache (XLA keeps
-            # carry scatters in place; single bf16 rows cannot be DMA'd
-            # into the tiled HBM buffer from inside a kernel), then one
-            # read-only kernel attends over each slot's VALID pages only
-            # (ragged reads — the decode bandwidth win). int8 caches
-            # scatter quantized rows + per-row scales; the kernel
-            # dequantizes per page in VMEM (the bytes stay halved).
-            from ..ops.decode_attention import fused_decode_attention
-
-            ck_all, cv_all, ks_all, vs_all = st
-            pos0, B = g.pos0, k.shape[0]
-            kf = k.reshape(B, spec.kv_dim)
-            vf = v.reshape(B, spec.kv_dim)
-            rows = jnp.arange(B, dtype=jnp.int32)
-            if quant:
-                kq_row, ks_row = _quantize_rows(kf)  # int8 [B,F], f32 [B]
-                vq_row, vs_row = _quantize_rows(vf)
-            else:
-                kq_row, vq_row, ks_row, vs_row = kf, vf, None, None
-            scale = (
-                1.0 / math.sqrt(spec.query_pre_attn_scalar)
-                if spec.query_pre_attn_scalar
-                else 1.0 / math.sqrt(spec.d_head)
-            )
-            if mesh is not None:
-                # meshed serving: append + attend per-shard under
-                # shard_map — the quantization above already ran OUTSIDE
-                # (global per-row amax), so every model shard scatters
-                # identical scale values (VERDICT r2 weak #5)
-                from ..ops.decode_attention import sharded_append_attend
-
-                res = sharded_append_attend(
-                    mesh, q[:, 0], kf, vf, kq_row, vq_row, ks_row,
-                    vs_row, ck_all, cv_all,
-                    ks_all if quant else None,
-                    vs_all if quant else None,
-                    l, pos0, spec.n_kv_heads, scale=scale,
-                    window=window,
-                )
-                return (res[0][:, None, :].astype(x.dtype),
-                        tuple(res[1:]))
-            ck_new = ck_all.at[l, rows, pos0, :].set(
-                kq_row.astype(ck_all.dtype), mode="promise_in_bounds")
-            cv_new = cv_all.at[l, rows, pos0, :].set(
-                vq_row.astype(cv_all.dtype), mode="promise_in_bounds")
-            if quant:
-                ks_new = ks_all.at[l, rows, pos0].set(
-                    ks_row, mode="promise_in_bounds")
-                vs_new = vs_all.at[l, rows, pos0].set(
-                    vs_row, mode="promise_in_bounds")
-            else:
-                ks_new = vs_new = None
-            out = fused_decode_attention(
-                q[:, 0], kf, vf, ck_new, cv_new, l, pos0 + 1,
-                spec.n_kv_heads, scale=scale,
-                window=window,
-                cache_k_scale=ks_new, cache_v_scale=vs_new,
-            )
-            if quant:
-                return (out[:, None, :].astype(x.dtype),
-                        (ck_new, cv_new, ks_new, vs_new))
-            return out[:, None, :].astype(x.dtype), (ck_new, cv_new)
-
-        def kv_from_cache(g, st, k, v, raw=False):
-            # cache rows are head-FLAT [seq, kv_dim] (see KVCache); heads are
-            # re-split transiently for the attention contraction
-            # (``raw``: the rows come back flat as they are cached — a
-            # latent cache, whose ``v`` has no lanes)
-            ck, cv, ks, vs = st
-            pos0, slot_ids, write_mask = g.pos0, g.slot_ids, g.write_mask
-            B, T = k.shape[0], k.shape[1]
-            kf = k.reshape(B, T, -1)
-            vf = v.reshape(B, T, -1)
-            if quant:
-                kq, ksc = _quantize_rows(kf)  # int8 [B,T,F], f32 [B,T]
-                vq, vsc = _quantize_rows(vf)
-            else:
-                kq, vq, ksc, vsc = kf, vf, None, None
-
-            def split(buf, scales):
-                # [B, S, kv_dim](+scales [B, S]) -> [B, S, Hkv, Dh] compute
-                if raw:
-                    return buf
-                out = buf.reshape(
-                    buf.shape[0], buf.shape[1], spec.n_kv_heads, spec.d_head
-                )
-                if scales is not None:  # dequantize; XLA fuses the convert
-                    out = out.astype(x.dtype) * scales[
-                        :, :, None, None].astype(x.dtype)
-                return out
-
-            def one_row(buf_row, new_row, off):
-                return lax.dynamic_update_slice(
-                    buf_row, new_row.astype(buf_row.dtype), (off, 0)
-                )
-
-            def one_scale(srow, val, off):
-                return lax.dynamic_update_slice(srow, val, (off,))
-
-            if slot_ids is None:  # batch row b IS cache row b
-                # hot path: per-row dynamic_update_slice, no gather/scatter
-                # (a cross-slot scatter would copy the whole cache layer
-                # every decode step — ~GBs/step at serving shapes)
-                if write_mask is not None:
-                    # masked rows write back what is already there: the
-                    # [B, T, F] read is tiny next to the layer traffic
-                    def cur_row(buf_row, off):
-                        return lax.dynamic_slice(
-                            buf_row, (off, 0),
-                            (kq.shape[1], buf_row.shape[-1]))
-
-                    def cur_scale(srow, off):
-                        return lax.dynamic_slice(srow, (off,),
-                                                 (kq.shape[1],))
-
-                    m3 = write_mask[:, None, None]
-                    kq = jnp.where(
-                        m3, kq.astype(ck.dtype),
-                        jax.vmap(cur_row)(ck, pos0))
-                    vq = jnp.where(
-                        m3, vq.astype(cv.dtype),
-                        jax.vmap(cur_row)(cv, pos0))
-                    if quant:
-                        m2 = write_mask[:, None]
-                        ksc = jnp.where(m2, ksc,
-                                        jax.vmap(cur_scale)(ks, pos0))
-                        vsc = jnp.where(m2, vsc,
-                                        jax.vmap(cur_scale)(vs, pos0))
-                ck2 = jax.vmap(one_row)(ck, kq, pos0)
-                cv2 = jax.vmap(one_row)(cv, vq, pos0)
-                if quant:
-                    ks2 = jax.vmap(one_scale)(ks, ksc, pos0)
-                    vs2 = jax.vmap(one_scale)(vs, vsc, pos0)
-                    return (split(ck2, ks2), split(cv2, vs2),
-                            (ck2, cv2, ks2, vs2))
-                return split(ck2, None), split(cv2, None), (ck2, cv2)
-            if B == 1:
-                # single-row update (prefill/embed): DUS straight into the
-                # 3D buffer at (slot, pos, 0)
-                ck2 = lax.dynamic_update_slice(
-                    ck, kq.astype(ck.dtype), (slot_ids[0], pos0[0], 0))
-                cv2 = lax.dynamic_update_slice(
-                    cv, vq.astype(cv.dtype), (slot_ids[0], pos0[0], 0))
-                if quant:
-                    ks2 = lax.dynamic_update_slice(
-                        ks, ksc, (slot_ids[0], pos0[0]))
-                    vs2 = lax.dynamic_update_slice(
-                        vs, vsc, (slot_ids[0], pos0[0]))
-            else:
-                def write(cbuf, new):
-                    rows = jax.vmap(one_row)(cbuf[slot_ids], new, pos0)
-                    return cbuf.at[slot_ids].set(rows)
-
-                ck2 = write(ck, kq)
-                cv2 = write(cv, vq)
-                if quant:
-                    def wscale(sbuf, val):
-                        rows = jax.vmap(one_scale)(sbuf[slot_ids], val, pos0)
-                        return sbuf.at[slot_ids].set(rows)
-
-                    ks2 = wscale(ks, ksc)
-                    vs2 = wscale(vs, vsc)
-            if quant:
-                return (split(ck2[slot_ids], ks2[slot_ids]),
-                        split(cv2[slot_ids], vs2[slot_ids]),
-                        (ck2, cv2, ks2, vs2))
-            return (split(ck2[slot_ids], None), split(cv2[slot_ids], None),
-                    (ck2, cv2))
-
-        def xla_attn(g, st, q, k, v):
-            k_eff, v_eff, carry = kv_from_cache(g, st, k, v)
-            if ring_prefill:
-                # seq-parallel exact attention over the chunk itself
-                # (caller guarantees pos0 == 0, so the cache holds no
-                # earlier positions to attend). K/V still went through
-                # kv_from_cache above for the cache WRITE; attention
-                # reads the pre-quantization chunk rows.
-                from ..parallel.ring_attention import ring_attention
-
-                scale = (1.0 / math.sqrt(spec.query_pre_attn_scalar)
-                         if spec.query_pre_attn_scalar
-                         else 1.0 / math.sqrt(spec.d_head))
-                # GQA K/V go in at their native head count; the ring
-                # repeats them locally after each ICI receive
-                out = ring_attention(q, k, v, mesh, causal=True,
-                                     scale=scale)
-                B_, T_ = q.shape[0], q.shape[1]
-                return (out.reshape(B_, T_, -1).astype(x.dtype), carry)
-            return _attend(spec, q, k_eff, v_eff,
-                           positions if single else positions_of(g),
-                           lp.get("_window")), carry
-
-        def latent_ragged(g, st, qn, qr, row):
-            # the ragged route for a latent cache: the chunk's rows
-            # scatter into the arena through the write table as K rows
-            # do, then the group's rows attend them in the form that
-            # costs them less (``latent_prompt_form``, from the
-            # widths and the group's row length alone). ABSORBED
-            # (ops/ragged_paged_attention.py, ``v_lanes``): 128 query
-            # heads x the whole row against a page, PV against the
-            # page's first kv_lora_rank lanes; W_kvb never touches a
-            # cached row. EXPANDED (ops/latent_flash_attention.py): a
-            # page's rows through W_kvb head by head in the kernel,
-            # the query as ``_latent_mixer`` has it beside the zeros
-            # of the row's tail lanes, the output written once
-            from ..ops.latent_flash_attention import (
-                EXPANDED, join_query, latent_flash_attention,
-                latent_prompt_form,
-            )
-            from ..ops.ragged_paged_attention import (
-                ragged_paged_attention,
-            )
-
-            ck_all = st[0]
-            pos0, q_lens = g.pos0, g.q_lens
-            attend = (q_lens if g.live is None
-                      else jnp.where(g.live, q_lens, 0))
-            B, T = row.shape[0], row.shape[1]
-            rows = jnp.arange(B, dtype=jnp.int32)
-            tpos = pos0[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
-            wpg = g.write_table[rows[:, None], tpos // kv_page]
-            wpg = jnp.where(
-                jnp.arange(T, dtype=jnp.int32)[None] < q_lens[:, None],
-                wpg, 0)
-            ck_new = ck_all.at[l, wpg, tpos % kv_page, :].set(
-                row.astype(ck_all.dtype), mode="promise_in_bounds")
-            if latent_prompt_form(spec, T) == EXPANDED:
-                out = latent_flash_attention(
-                    join_query(qn, qr,
-                               spec.latent_row - spec.kv_lora_rank),
-                    ck_new, l, g.page_table, pos0, attend,
-                    stack["wkv_b_k"], stack["wkv_b_v"], li,
-                    scale=latent_scale(spec), page=kv_page)
-                return out, (ck_new, st[1])
-            ctx = ragged_paged_attention(
-                latent_absorb_query(spec, lp, qn, qr), ck_new, None, l,
-                g.page_table, pos0, attend, 1,
-                scale=latent_scale(spec), page=kv_page,
-                v_lanes=spec.kv_lora_rank,
-            )  # [B, T, H * r] f32
-            ctx = ctx.reshape(B, T, spec.n_heads, spec.kv_lora_rank)
-            return (latent_absorb_out(spec, lp, ctx, x.dtype),
-                    (ck_new, st[1]))
-
-        def latent_xla(g, st, qn, qr, row):
-            # the XLA route: rows written as any K row is, then the
-            # EXPANDED form over the row's whole view
-            view, _, carry = kv_from_cache(
-                g, st, row, row[..., :0], raw=True)
-            return latent_attend_expanded(
-                spec, lp, qn, qr, view,
-                positions if single else positions_of(g)), carry
-
-        if spec.kv_lora_rank:
-            one = latent_ragged if use_ragged else latent_xla
-        else:
-            one = (ragged_attn if use_ragged
-                   else (kernel_attn if use_kernel else xla_attn))
-        st0 = ((ck_all, cv_all, ks_all, vs_all) if use_kernel
-               else (ck, cv, ks, vs))
-
-        def attn_fn(q, k, v):
-            if single:
-                return one(g0, st0, q, k, v)
-            outs, st = [], st0
-            for g, qg, kg, vg in zip(groups, *map(ungroup, (q, k, v))):
-                o, wrote = one(g, st, qg, kg, vg)
-                st = tuple(wrote) + tuple(st[len(wrote):])
-                outs.append(o.reshape(1, -1, o.shape[-1]))
-            return (jnp.concatenate(outs, axis=1),
-                    st if quant else st[:2])
-
+        if rec:  # a hybrid model: the period's linear layers first
+            x, rec = _linear_period(spec, x, stack.linear, li, groups,
+                                    positions, inv_freq, rope_scale, rec)
+        ctx = ca.LayerCtx(
+            spec, l, li, lp, stack.scanned,
+            lp.get("_window", spec.sliding_window), kv_page, mesh, quant,
+            ring_prefill, x.dtype, positions if single else None)
+        # the planes this cache has (scale planes with int8 rows only):
+        # stacked for a route that addresses them in place, this
+        # layer's slices for the others
+        st = planes[:4 if quant else 2]
+        if not stacked:
+            st = tuple(lax.dynamic_index_in_dim(p, l, 0, keepdims=False)
+                       for p in st)
         experts = None
-        if spec.n_experts and whole:
-            experts = (whole, li, gm.expert_path(
-                [whole[k] for k in EXPERT_LEAVES], x.dtype,
+        if spec.n_experts and stack.experts:
+            experts = (stack.experts, li, gm.expert_path(
+                [stack.experts[k] for k in EXPERT_LEAVES], x.dtype,
                 mesh) == gm.GROUPED_KERNEL)
-        x, out, counts = _layer_body(
-            spec, x, lp, positions, inv_freq, rope_scale, attn_fn, valid,
+        x, wrote, counts = _layer_body(
+            spec, x, lp, positions, inv_freq, rope_scale,
+            partial(ca.attend_groups, route, ctx, groups, st), valid,
             experts)
-        if use_kernel:
-            # the fused kernel updated the FULL stacked cache in place
-            if quant:
-                ck_all, cv_all, ks_all, vs_all = out
-            else:
-                ck_all, cv_all = out
-        elif quant:
-            ck2, cv2, ks2, vs2 = out
-            ck_all = lax.dynamic_update_index_in_dim(ck_all, ck2, l, 0)
-            cv_all = lax.dynamic_update_index_in_dim(cv_all, cv2, l, 0)
-            ks_all = lax.dynamic_update_index_in_dim(ks_all, ks2, l, 0)
-            vs_all = lax.dynamic_update_index_in_dim(vs_all, vs2, l, 0)
-        else:
-            ck2, cv2 = out
-            ck_all = lax.dynamic_update_index_in_dim(ck_all, ck2, l, 0)
-            cv_all = lax.dynamic_update_index_in_dim(cv_all, cv2, l, 0)
-        return (x, ck_all, cv_all, ks_all, vs_all, *rec), counts
+        planes = tuple(
+            w if stacked else lax.dynamic_update_index_in_dim(p, w, l, 0)
+            for p, w in zip(planes, wrote)) + planes[len(wrote):]
+        return (x, *planes, *rec), counts
 
     # the stacks in turn, the cache's layer index running through them
     carry = (x, cache.k, cache.v,
@@ -1800,27 +1298,20 @@ def forward_rows(
     if spec.linear_heads:  # the recurrent state rides the carry too
         carry += (cache.state, cache.conv)
     expert_tokens = None
-    for first, n, stacked, whole in layer_stacks(spec, params):
+    for stack in layer_stacks(spec, params):
         carry, counts = lax.scan(
-            partial(body, whole, stacked), carry,
-            (jnp.arange(first, first + n, dtype=jnp.int32),
-             jnp.arange(n, dtype=jnp.int32), stacked))
+            partial(body, stack), carry,
+            (jnp.arange(stack.first, stack.first + stack.n,
+                        dtype=jnp.int32),
+             jnp.arange(stack.n, dtype=jnp.int32), stack.scanned))
         if counts is not None:  # [n, E] of an expert stack
-            absent = ()
-            if spec.experts_held:  # a share: [n, E + 1], see _moe_mlp
-                absent = (jnp.sum(counts[:, -1])[None],)
-                counts = counts[:, :-1]
-            expert_tokens = jnp.concatenate([
-                jnp.sum(counts, axis=0),
-                jnp.sum(counts > 0, dtype=jnp.int32)[None], *absent])
+            expert_tokens = _expert_totals(spec, counts)
     x, new_k, new_v, new_ks, new_vs, *rec = carry
     new_cache = KVCache(new_k, new_v, new_ks if quant else None,
                         new_vs if quant else None, *rec)
-
     if spec.final_norm:
         x = _norm(spec, x, params["final_norm_w"], params.get("final_norm_b"))
-    return (((x,) if single else tuple(ungroup(x))), new_cache,
-            expert_tokens)
+    return tuple(ca.ungroup(groups, x)), new_cache, expert_tokens
 
 
 def forward(
@@ -1884,19 +1375,20 @@ def forward_train(
     inv_freq = rope_inv_freq(spec)
     rope_scale = rope_attn_scale(spec)
 
-    def body(whole, x, scanned):
+    def body(experts, x, scanned):
         li, lp = scanned
         x, _, _ = _layer_body(
             spec, x, lp, positions, inv_freq, rope_scale,
             lambda q, k, v: (
                 _attend(spec, q, k, v, positions, lp.get("_window")), None),
-            experts=(whole, li) if whole else None,
+            experts=(experts, li) if experts else None,
         )
         return x, None
 
-    for _, n, stacked, whole in layer_stacks(spec, params):
-        x, _ = lax.scan(jax.checkpoint(partial(body, whole)), x,
-                        (jnp.arange(n, dtype=jnp.int32), stacked))
+    for stack in layer_stacks(spec, params):
+        x, _ = lax.scan(jax.checkpoint(partial(body, stack.experts)), x,
+                        (jnp.arange(stack.n, dtype=jnp.int32),
+                         stack.scanned))
     if spec.final_norm:
         x = _norm(spec, x, params["final_norm_w"], params.get("final_norm_b"))
     return _lm_head(spec, params, x)

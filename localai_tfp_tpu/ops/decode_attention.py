@@ -16,7 +16,7 @@ wrappers over it:
   an int8 cache attends the EXACT current row): VIEWS the dense
   ``[L, S, SEQ, F]`` cache as a page arena (free reshape) under an
   identity page table. The paged pool calls the ragged kernel itself
-  (models/transformer.py ``ragged_attn``).
+  (models/cache_attention.py ``ragged``).
 - ``sharded_append_attend``: the shard_map wrapper for meshed serving
   (append + per-shard kernel call), unchanged in contract.
 

@@ -158,7 +158,7 @@ def check_paged_gather(quantized: bool = False, seed: int = 0) -> float:
     pt_j = jnp.asarray(pt)
 
     def paged_decode(arena):
-        # the decode rows of models/transformer.py ragged_attn: one
+        # the decode rows of models/cache_attention.py ``ragged``: one
         # query a row at lengths-1, the current row seeded from VMEM
         ln = jnp.asarray(lengths)
         return ragged_paged_attention(
@@ -404,21 +404,6 @@ def check_meshed_paged_gather(quantized: bool = False,
         float(jnp.max(jnp.abs(
             win.v.astype(jnp.float32) - dense_v.astype(jnp.float32)))),
     )
-
-
-def check_int8_matmul(seed: int = 0) -> float:
-    """Max abs error of the fused Pallas dequant-matmul vs the XLA
-    upcast path."""
-    from .int8_matmul import int8_matmul
-
-    rng = np.random.default_rng(seed)
-    M, K, N = 64, 1024, 1024
-    x = jnp.asarray(rng.standard_normal((M, K)) * 0.1, jnp.bfloat16)
-    q = jnp.asarray(rng.integers(-127, 128, (K, N), np.int8))
-    s = jnp.asarray((rng.random(N) * 0.01 + 0.005).astype(np.float32))
-    got = int8_matmul(x, q, s, out_dtype=jnp.float32)
-    want = (x.astype(jnp.float32) @ q.astype(jnp.float32)) * s
-    return float(jnp.max(jnp.abs(got - want)))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1279,9 +1264,6 @@ def run_kernel_checks(geom: Geometry = SERVING) -> dict[str, Any]:
     and a pass/fail verdict. A kernel that does not compile raises: a
     crash here is the finding, and the caller (module entry, bench,
     chip_smoke) must not mistake it for a result."""
-    from . import int8_matmul  # noqa: F401  off by default (ROADMAP D7)
-    # — not checked here, but it has to keep importing
-
     dev = jax.devices()[0]
     out: dict[str, Any] = {
         "platform": dev.platform,

@@ -17,7 +17,7 @@ scores and the same output:
   ``expanded_from`` = 170.7 queries, 1.89 x fewer at 512.
 
 ``latent_prompt_form`` is that rule, from the spec's widths alone; the
-forward (models/transformer.py ``latent_ragged``) asks it per group of
+forward (models/cache_attention.py ``latent_ragged``) asks it per group of
 rows while tracing.
 
 The kernel. Grid = (row, head block), in order. A step holds its head

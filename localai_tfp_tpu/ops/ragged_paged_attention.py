@@ -368,8 +368,8 @@ def ragged_paged_attention(
     # their tail queries beyond q_lens — outputs there are garbage the
     # caller discards)
     cache_k: jax.Array,  # [L, n_pages, page, F] paged arena, already
-    # holding this dispatch's K rows at [pos0, pos0 + q_lens) (the
-    # caller scatter-appends through its write table)
+    # holding this dispatch's K rows at [pos0, pos0 + q_lens)
+    # (``append_rows``)
     cache_v: jax.Array,
     layer: jax.Array,  # [] i32 layer index
     page_table: jax.Array,  # [B, max_pages] i32 physical pages
@@ -388,7 +388,7 @@ def ragged_paged_attention(
     # T==1 decode mode — the current rows' EXACT values ride in VMEM and
     # their HBM copies are masked (ops/decode_attention.py contract)
     v_lanes: int = 0,  # > 0: a LATENT arena (absorbed latent attention,
-    # models/transformer.py ``latent_ragged``): ``cache_v`` is None,
+    # models/cache_attention.py ``latent_ragged``): ``cache_v`` is None,
     # n_kv_heads is 1, F == Dh is the whole cached row [c | k_r | pad]
     # and a page's first ``v_lanes`` lanes are also its value — one
     # plane is walked, each page fetched ONCE for both matmuls. The
@@ -489,6 +489,32 @@ def ragged_paged_attention(
         0, 2, 1, 3, 4).reshape(B, Tp, H * Dv)[:, :T]
 
 
+def append_rows(planes: tuple, values: tuple, layer: jax.Array,
+                write_table: jax.Array, pos0: jax.Array,
+                q_lens: jax.Array, page: int) -> tuple:
+    """A dispatch's rows scattered into the arena through its WRITE
+    table — what ``ragged_paged_attention`` then reads through the READ
+    table. ``planes``: arena planes [L, n_pages, page(, F)] (K and V
+    rows, their scale planes, or a latent arena's one plane);
+    ``values``: one [B, T(, F)] array a plane, token t of row b at
+    position ``pos0[b] + t``, which lands on page ``write_table[b,
+    position // page]`` of layer ``layer``. Positions beyond a row's
+    ``q_lens`` go to the trash page (page 0), as do the pages the host
+    did not grant — the table already points those at it. -> the
+    planes, written."""
+    B, T = values[0].shape[:2]
+    rows = jnp.arange(B, dtype=jnp.int32)
+    tpos = pos0[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
+    wpg = write_table[rows[:, None], tpos // page]
+    wpg = jnp.where(
+        jnp.arange(T, dtype=jnp.int32)[None] < q_lens[:, None], wpg, 0)
+    woff = tpos % page
+    return tuple(
+        p.at[layer, wpg, woff].set(new.astype(p.dtype),
+                                   mode="promise_in_bounds")
+        for p, new in zip(planes, values))
+
+
 def mesh_ragged_eligible(mesh, n_kv_heads: int, n_heads: int,
                          kv_dim: int) -> bool:
     """Whether the ragged kernel can run under ``shard_map`` on this
@@ -534,8 +560,9 @@ def sharded_ragged_append_attend(
     window=None,  # as ragged_paged_attention's
 ) -> tuple:
     """Table-scatter append + ragged attend under ``shard_map`` on a
-    serving mesh — the meshed counterpart of the caller-side scatter +
-    ``ragged_paged_attention`` pair in models/transformer.ragged_attn.
+    serving mesh — the meshed counterpart of the ``append_rows`` +
+    ``ragged_paged_attention`` pair in models/cache_attention.py
+    ``ragged``.
     The arena shards its head-flat F dim over "model"
     (parallel/sharding.PAGED_KV_SPEC): each device holds its kv-head
     slice of EVERY page, the host-owned int32 page tables stay global,
@@ -583,34 +610,17 @@ def sharded_ragged_append_attend(
 
     def body(q_l, nk_l, nv_l, kq_l, vq_l, ck, cv, lay, win, pt, wt, p0,
              qls, ksr=None, vsr=None, ksp=None, vsp=None):
-        B, T = kq_l.shape[:2]
-        rows = jnp.arange(B, dtype=jnp.int32)
-        tpos = p0[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
-        wpg = wt[rows[:, None], tpos // page]
-        # pad positions beyond the row's ragged length write trash
-        wpg = jnp.where(
-            jnp.arange(T, dtype=jnp.int32)[None] < qls[:, None], wpg, 0)
-        woff = tpos % page
-        ck = ck.at[lay, wpg, woff, :].set(
-            kq_l.astype(ck.dtype), mode="promise_in_bounds")
-        cv = cv.at[lay, wpg, woff, :].set(
-            vq_l.astype(cv.dtype), mode="promise_in_bounds")
-        if quant:
-            ksp = ksp.at[lay, wpg, woff].set(
-                ksr, mode="promise_in_bounds")
-            vsp = vsp.at[lay, wpg, woff].set(
-                vsr, mode="promise_in_bounds")
-        seed = (nk_l[:, 0], nv_l[:, 0]) if T == 1 else None
+        planes = append_rows(
+            (ck, cv, ksp, vsp) if quant else (ck, cv),
+            (kq_l, vq_l, ksr, vsr), lay, wt, p0, qls, page)
+        ksp, vsp = planes[2:] if quant else (None, None)
+        seed = (nk_l[:, 0], nv_l[:, 0]) if kq_l.shape[1] == 1 else None
         out = ragged_paged_attention(
-            q_l, ck, cv, lay, pt, p0, qls, n_kv_local,
+            q_l, planes[0], planes[1], lay, pt, p0, qls, n_kv_local,
             scale=scale, page=page, window=win[0],
-            cache_k_scale=ksp if quant else None,
-            cache_v_scale=vsp if quant else None,
-            seed_kv=seed,
+            cache_k_scale=ksp, cache_v_scale=vsp, seed_kv=seed,
         )
-        if quant:
-            return out, ck, cv, ksp, vsp
-        return out, ck, cv
+        return (out, *planes)
 
     # check_vma=False: the model-replicated scale planes are updated with
     # identical values on every model shard (global-amax quantization), a

@@ -263,14 +263,21 @@ _DECODE_ROUTES = {
 # an ``optimization_barrier`` before the split into heads
 # (models/transformer.py ``_layer_body``; values bit-equal:
 # tests/test_projection_barrier.py), and the ragged int8 route gathers
-# its scale planes over (layer, page) at once. What the pin is
-# for is unchanged: a later PR that touches none of that must leave
-# these programs as they are.
+# its scale planes over (layer, page) at once. PR 52 re-pinned the
+# ragged int8 route ALONE, for one line that moved: the table scatter
+# is ``ops/ragged_paged_attention.append_rows`` for every caller, so
+# the ``stablehlo.iota`` of its row index is emitted after the rows'
+# quantisation and not before it — the same ops (the multiset of the
+# text's lines is the parent's) and the compiled step programs
+# byte-equal (``tools/step_hlo.py --all``; CHANGES, PR 52); the other
+# seven digests are PR 44's. What the pin is for is unchanged: a
+# later PR that touches none of that must leave these programs as
+# they are.
 _PARENT = {
     ("paged_xla_gather", "float32"): ("55b97305", "3xb25c9ebd"),
     ("paged_xla_gather", "int8"): ("c8552eaa", "3x9dc4f1e3"),
     ("ragged_paged_kernel", "float32"): ("de80d6e6", "3x4095641c"),
-    ("ragged_paged_kernel", "int8"): ("0a240d06", "3x4c5f252b"),
+    ("ragged_paged_kernel", "int8"): ("01f1e95d", "3x39bf83aa"),
     ("dense_xla", "float32"): ("f373a359", "6x217c2668"),
     ("dense_xla", "int8"): ("33d1bb98", "6xd5bcc7d0"),
     ("dense_decode_kernel", "float32"): ("176f53ac", "3x44c047f9"),

@@ -117,7 +117,7 @@ def test_loader_builds_two_stacks(tiny):
         assert params[k].shape[0] == L - Ld
         assert params[DENSE_STACK + k].shape[0] == Ld
     stacks = layer_stacks(spec, params)
-    assert [(first, n) for first, n, _, _ in stacks] == [(0, 1), (1, 3)]
+    assert [(s.first, s.n) for s in stacks] == [(0, 1), (1, 3)]
     assert stacks[0][2]["_window"].tolist() == [16]
     assert sorted(stacks[1][3]) == ["moe_down", "moe_gate", "moe_up"]
     assert stacks[1][2]["_window"].tolist() == [16, 16, 0]

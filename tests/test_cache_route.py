@@ -8,7 +8,10 @@ program reaches the KV cache.
   (the open/close pair adds nothing of its own to a program);
 - on the host, the pool routes plan every dispatch at full width while
   the dense route walks its power-of-two ladder and prefers a window it
-  already compiled.
+  already compiled;
+- the forward's ``select`` (models/cache_attention.py), handed a route's
+  own ``forward_kw``, picks the function of that route's name: the two
+  places that know the decision agree.
 """
 
 import jax
@@ -18,10 +21,11 @@ import pytest
 
 from localai_tfp_tpu.config import knobs
 from localai_tfp_tpu.engine.cache_route import choose_route
-from localai_tfp_tpu.engine.engine import LLMEngine
+from localai_tfp_tpu.engine.engine import LLMEngine, _rows_kw
 from localai_tfp_tpu.engine.tokenizer import ByteTokenizer
 from localai_tfp_tpu.models.llm_spec import tiny_spec
-from localai_tfp_tpu.models.transformer import KVCache, init_params
+from localai_tfp_tpu.models import cache_attention as ca
+from localai_tfp_tpu.models.transformer import KVCache, Rows, init_params
 from localai_tfp_tpu.parallel.mesh import make_mesh
 
 
@@ -151,3 +155,42 @@ def test_host_side_window_choice(kind, need, compiled, dense, dense_kernel):
         assert rungs[-1] == 2048 and rungs == sorted(set(rungs))
         # whatever window() picks with nothing compiled is a rung
         assert route.window(need, kind) in rungs
+
+
+@pytest.mark.parametrize("name,latent,want,stacked", [
+    ("ragged_paged_kernel", False, ca.ragged, True),
+    ("latent_paged_kernel", True, ca.latent_ragged, True),
+    ("paged_xla_gather", False, ca.xla, False),
+    ("paged_xla_gather", True, ca.latent_xla, False),
+    ("dense_decode_kernel", False, ca.dense_kernel, True),
+    ("dense_xla", False, ca.xla, False),
+    ("dense_xla", True, ca.latent_xla, False),
+])
+def test_select_picks_the_function_of_the_routes_name(
+        name, latent, want, stacked):
+    """A decode step's rows as the engine builds them (``_rows_kw`` of
+    the route's ``forward_kw``) select the route's own function."""
+    route = choose_route(
+        paged=name.startswith(("paged", "ragged", "latent")),
+        kernel=name.endswith("_kernel"), max_seq=512, page=64, mesh=None,
+        latent=latent)
+    assert route.name == name
+    spec = tiny_spec(kv_lora_rank=128) if latent else tiny_spec()
+    S = 2
+    tables = ((jnp.zeros((S, 8), jnp.int32),) * 2
+              if hasattr(route, "page") else ())
+    tokens, pos0 = jnp.zeros((S, 1), jnp.int32), jnp.zeros((S,), jnp.int32)
+    per, kw = _rows_kw(route.forward_kw(
+        tables, jnp.ones((S,), jnp.int32), decode=True))
+    rows = Rows(tokens, pos0, **per)
+    assert ca.select(spec, (rows,), kw.get("decode_kernel", False)) \
+        == (want, stacked)
+    # a mixed step's two groups never take the one-token kernel of the
+    # dense cache: its prompt rows reach the cache through slot ids
+    if name == "dense_decode_kernel":
+        per2, _ = _rows_kw(route.forward_kw(
+            tables, jnp.ones((S,), jnp.int32), slot_ids=pos0))
+        assert ca.select(spec, (rows, Rows(tokens, pos0, **per2)),
+                         True) == (ca.xla, False)
+        assert ca.select(spec, (Rows(tokens, pos0, **per2),),
+                         True) == (ca.xla, False)

@@ -600,3 +600,66 @@ def test_kernel_check_holds_the_expanded_flash_kernel():
     res = kc.check_latent_flash(kc._LATENT_SMALL)
     assert res["max_rel_err"] < kc._TOL_FP and res["rows_equal"], res
     assert kc.LATENT_WIDTHS["deepseek"] == (128, 128, 64, 128, 512, 256, 512)
+
+
+@pytest.mark.parametrize("planes",
+                         ["bf16_kv", "int8_kv_scales", "latent_row"])
+def test_append_rows_lands_where_the_write_table_says(planes):
+    """``append_rows`` — the ONE table scatter of the ragged routes
+    (K/V rows, their scale planes, a latent arena's one plane): token t
+    of row b lands on page ``write_table[b, (pos0[b] + t) // page]`` at
+    offset ``(pos0[b] + t) % page`` of the given layer; positions
+    beyond ``q_lens`` and pages the table does not grant (entry 0) land
+    on the trash page 0; nothing else of the arena moves."""
+    import numpy as np
+
+    from localai_tfp_tpu.ops.ragged_paged_attention import append_rows
+
+    L, NP, PAGE, F, B, T, layer = 2, 7, 4, 8, 3, 6, 1
+    rng = np.random.default_rng(0)
+    dt, with_scales = {"bf16_kv": (jnp.bfloat16, False),
+                       "int8_kv_scales": (jnp.int8, True),
+                       "latent_row": (jnp.bfloat16, False)}[planes]
+    n_rows = 1 if planes == "latent_row" else 2
+    shapes = [(L, NP, PAGE, F)] * n_rows + [(L, NP, PAGE)] * (
+        2 if with_scales else 0)
+    dtypes = [dt] * n_rows + [jnp.float32] * (2 if with_scales else 0)
+    before = [jnp.asarray(rng.integers(-9, 9, s), d)
+              for s, d in zip(shapes, dtypes)]
+    values = [jnp.asarray(rng.integers(10, 99, (B, T, *s[3:])), d)
+              for s, d in zip(shapes, dtypes)]
+    # row 0 straddles granted pages 3 and 5; row 1 fills page 6 and runs
+    # on into a page it was not granted (entry 0); row 2 is parked
+    pos0 = jnp.asarray([2, 4, 9], jnp.int32)
+    q_lens = jnp.asarray([5, 6, 0], jnp.int32)
+    write_table = jnp.asarray([[3, 5, 0], [0, 6, 0], [0, 0, 4]], jnp.int32)
+    after = jax.jit(append_rows, static_argnums=6)(
+        tuple(before), tuple(values), jnp.int32(layer), write_table, pos0,
+        q_lens, PAGE)
+    assert len(after) == len(before)
+    for old, new, val in zip(before, after, values):
+        assert new.dtype == old.dtype and new.shape == old.shape
+        want = np.array(old)
+        touched_trash = False
+        for b in range(B):
+            for t in range(T):
+                pos = int(pos0[b]) + t
+                pg = int(write_table[b, pos // PAGE])
+                if t >= int(q_lens[b]) or pg == 0:
+                    touched_trash = True
+                    continue  # the trash page: whatever lands, unread
+                want[layer, pg, pos % PAGE] = np.asarray(val[b, t])
+        assert touched_trash
+        got = np.array(new)
+        # every page but the trash page is exactly what the table says
+        # (the other layer wholly untouched)
+        np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
+        np.testing.assert_array_equal(got[1 - layer],
+                                      np.array(old)[1 - layer])
+    # the granted positions: row 0's five tokens, row 1's first four
+    k, was, new = np.array(after[0]), np.array(before[0]), values[0]
+    np.testing.assert_array_equal(k[layer, 3, 2:], np.asarray(new[0, :2]))
+    np.testing.assert_array_equal(k[layer, 5, :3], np.asarray(new[0, 2:5]))
+    np.testing.assert_array_equal(k[layer, 5, 3], was[layer, 5, 3])
+    np.testing.assert_array_equal(k[layer, 6], np.asarray(new[1, :4]))
+    np.testing.assert_array_equal(k[layer, 4], was[layer, 4])

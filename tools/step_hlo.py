@@ -142,7 +142,7 @@ def _compile_for_tpu():
 
     mods = [importlib.import_module(f"localai_tfp_tpu.ops.{name}")
             for name in ("decode_attention", "ragged_paged_attention",
-                         "gated_delta", "int8_matmul", "grouped_matmul",
+                         "gated_delta", "grouped_matmul",
                          "latent_flash_attention", "expert_rows")]
     saved = [m._interpret for m in mods]
     try:
